@@ -316,8 +316,10 @@ define_flag("pallas_interpret", False,
             "the Pallas interpreter instead of falling back to XLA. "
             "SLOW — for kernel parity tests on CPU (the `pallas` pytest "
             "marker flips it); production CPU dispatch keeps the XLA "
-            "fallbacks. flash_attention keeps its own shape gate in "
-            "ops.attention and ignores this flag.")
+            "fallbacks. This flag is the ONLY thing that selects the "
+            "interpreter: a kernel called directly with it off compiles "
+            "for the backend or fails. flash_attention's DISPATCH keeps "
+            "its own shape/backend gate in ops.attention.")
 define_flag("pipeline_schedule", "",
             "Global pipeline-schedule override for SPMD pipeline stacks: "
             "'1f1b' (one-forward-one-backward combined program) or "

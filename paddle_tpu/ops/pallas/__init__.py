@@ -50,6 +50,7 @@ __all__ = [
     "int8_matmul", "int8_linear", "int8_amp_linear", "quantize_per_channel",
     "bgmv", "bgmv_xla",
     "kernels", "kernel_enabled", "note_fallback", "backend_supported",
+    "interpret",
     "PALLAS_STATS", "reset_pallas_stats",
 ]
 
@@ -99,6 +100,17 @@ def note_fallback(kernel: str, reason: str) -> None:
             "pallas_fallback_total",
             "ops.pallas kernel calls that degraded to the XLA fallback, "
             "by kernel and cause").inc(kernel=kernel, reason=reason)
+
+
+def interpret() -> bool:
+    """The ``interpret=`` argument of every ``pallas_call`` in this
+    package: the Pallas interpreter runs a kernel body only when
+    ``FLAGS_pallas_interpret`` asks for it (the ``pallas`` pytest marker
+    does). It is never inferred from the backend, so a process that did
+    not get the chip fails to compile a kernel instead of interpreting
+    it in silence; dispatch (:func:`kernel_enabled`) is what routes
+    other backends to the counted XLA fallbacks."""
+    return bool(get_flag("pallas_interpret"))
 
 
 def backend_supported() -> bool:

@@ -37,13 +37,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat  # noqa: F401  (pltpu.CompilerParams shim)
+from . import interpret as _interpret
 
 __all__ = ["bgmv", "bgmv_xla"]
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def bgmv_xla(x, a, b, ids):

@@ -26,9 +26,10 @@ the XLA streaming path.
 
 Grid/blocking: ``(ceil(N / block_n), ceil(V / chunk))`` with the vocab
 sweep innermost (``arbitrary``); ``block_n`` rows per program
-(``PTPU_CE_BLOCK_N``, default 128), chunk width from
-``FLAGS_chunked_ce_chunk`` (multiples of 128 keep Mosaic lane tiles
-exact; any tail is masked in-kernel, never padded in HBM).
+(``PTPU_CE_BLOCK_N``, default 128; the backward halves it until its
+two-tile footprint fits the scoped VMEM, see ``_bwd_block_n``), chunk
+width from ``FLAGS_chunked_ce_chunk`` (multiples of 128 keep Mosaic lane
+tiles exact; any tail is masked in-kernel, never padded in HBM).
 
 Tests run these kernels on CPU via the Pallas interpreter
 (FLAGS_pallas_interpret; the ``pallas`` pytest marker).
@@ -45,16 +46,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat  # noqa: F401  (pltpu.CompilerParams shim)
+from . import interpret as _interpret
 
 __all__ = ["chunked_ce_loss", "DEFAULT_BLOCK_N"]
 
 DEFAULT_BLOCK_N = 128
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _block_n() -> int:
@@ -161,8 +158,28 @@ def _dlogits_kernel(logits_ref, lab_ref, lse_ref, g_ref, dl_ref, *,
     dl_ref[...] = d.astype(dl_ref.dtype)
 
 
+#: Mosaic's default scoped-VMEM allowance is 16 MiB; the backward keeps
+#: its estimated tile footprint under this much of it
+_BWD_VMEM_BUDGET = 14 << 20
+
+
+def _bwd_block_n(block_n: int, chunk: int, itemsize: int) -> int:
+    """Rows per backward program. The backward holds a logits tile AND a
+    dlogits tile (each double-buffered by the pipeline) plus about two
+    f32 ``[block_n, chunk]`` temporaries, twice the forward's footprint:
+    at the forward's 128 rows x 8192 columns the v5e compiler refuses it
+    (16.25 MiB bf16 / 20.38 MiB f32 against the 16 MiB scoped limit). So
+    the row block is halved until the estimate fits; the forward's block
+    is unchanged."""
+    while (block_n > 8 and block_n % 16 == 0
+           and block_n * chunk * (4 * itemsize + 8) > _BWD_VMEM_BUDGET):
+        block_n //= 2
+    return block_n
+
+
 def _dlogits(logits, labels, lse, g, block_n: int, chunk: int):
     N, V = logits.shape
+    block_n = _bwd_block_n(block_n, chunk, logits.dtype.itemsize)
     ni, nj = pl.cdiv(N, block_n), pl.cdiv(V, chunk)
     row8 = pl.BlockSpec((block_n, 8), lambda i, j: (i, 0))
     return pl.pallas_call(

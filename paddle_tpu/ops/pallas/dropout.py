@@ -22,14 +22,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret as _interpret
+
 __all__ = ["fused_dropout"]
 
 _LANES = 128
 _ROWS = 512            # rows per program: 512x128 f32 tile = 256KB
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _keep_mask(idx, seed0, seed1, rate):

@@ -45,19 +45,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):
-    # jax < 0.5 names the dataclass TPUCompilerParams; same fields
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
+from . import interpret as _interpret
 
 # 512x512 tiles win on v5e: fewer grid steps amortize the VMEM loads and the
 # p-tile (512*512*4B = 1 MiB) still fits comfortably; measured ~28% faster
 # than 128x128 at S=2048 and ahead of XLA's fused sdpa.
 DEFAULT_BLOCK = 512
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _mxu_dtype(in_dtype) -> jnp.dtype:

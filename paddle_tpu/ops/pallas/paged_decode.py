@@ -24,8 +24,12 @@ f32 accumulation), additive key masking by per-slot position — the same
 math as the fallback's ``cols <= pos`` mask, so decode stays TOKEN-EXACT
 against the dense path (pinned in tests/test_pallas_kernels.py).
 
-All heads of a page ride one program (the per-head q row is [1, D];
-batching heads keeps the MXU/VPU fed); the page size ``bs`` set by
+All heads of a page ride one program. The per-head query is a single
+``[1, D]`` row, so scores and the weighted value sum are VPU
+multiply-reduces, not ``dot_general``s: the MXU has nothing to gain from
+a one-row operand, and Mosaic refuses a dot batched over heads while
+the head dimension is not leading (a page is ``[bs, H, D]``). The page
+size ``bs`` set by
 ``ServingConfig.block_size`` is the KV block size — there is no separate
 kernel block knob.
 
@@ -42,15 +46,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat  # noqa: F401  (pltpu.CompilerParams shim)
+from . import interpret as _interpret
 
 __all__ = ["paged_decode_attention", "paged_decode_attention_quant"]
 
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _decode_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, bs, H, D,
@@ -84,10 +84,10 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, bs, H, D,
             # stays token-exact against the XLA gather fallback
             k = k * ks_ref[0].astype(jnp.float32)[..., None]
             v = v * vs_ref[0].astype(jnp.float32)[..., None]
-        # s[h, c] = q[h] . k[c, h] — heads are the batch dimension
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale  # [H, bs]
+        # s[h, c] = q[h] . k[c, h] on the VPU: the query is ONE row per
+        # head, so the MXU has nothing to gain, and Mosaic refuses a
+        # dot_general batched over a non-leading dim (k is [bs, H, D])
+        s = jnp.sum(q[None] * k, axis=-1).T * scale      # [H, bs]
         cols = j * bs + jax.lax.broadcasted_iota(jnp.int32, (H, bs), 1)
         # slot b sees written positions 0..p (current token included) —
         # identical to the fallback's additive key mask
@@ -100,10 +100,8 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, bs, H, D,
         l_scr[:] = jnp.broadcast_to(
             alpha * l_scr[:, :1] + jnp.sum(pr, axis=1, keepdims=True),
             l_scr.shape)
-        # acc[h] += pr[h] @ v[:, h]
-        pv = jax.lax.dot_general(
-            pr, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)          # [H, D]
+        # acc[h] += pr[h] @ v[:, h] — same VPU form
+        pv = jnp.sum(pr.T[:, :, None] * v, axis=0)       # [H, D]
         acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
 

@@ -44,7 +44,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat  # noqa: F401  (pltpu.CompilerParams shim)
+from . import interpret as _interpret
 
 __all__ = ["int8_matmul", "int8_linear", "int8_amp_linear",
            "quantize_per_channel", "quantize_per_tensor",
@@ -53,10 +53,6 @@ __all__ = ["int8_matmul", "int8_linear", "int8_amp_linear",
 DEFAULT_BLOCK_M = 128
 DEFAULT_BLOCK_N = 128
 DEFAULT_BLOCK_K = 512
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _env_block(var: str, default: int) -> int:

@@ -14,6 +14,9 @@ import pytest
 from paddle_tpu.ops.attention import _sdpa_xla
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 
+# the kernels are called directly: the marker selects the interpreter
+pytestmark = pytest.mark.pallas
+
 B, S, H, D = 2, 256, 2, 64
 
 

@@ -7,6 +7,9 @@ import pytest
 
 from paddle_tpu.ops.pallas.dropout import fused_dropout
 
+# the kernel is called directly: the marker selects the interpreter
+pytestmark = pytest.mark.pallas
+
 
 @pytest.mark.parametrize("shape", [(512, 128), (48, 33, 77), (70000,)])
 def test_mask_statistics_and_scaling(shape):
@@ -83,8 +86,7 @@ def test_F_dropout_dispatches_to_fused(monkeypatch):
         return real(a, rate, key)
 
     monkeypatch.setattr(fd, "fused_dropout", spy)
-    # keep the kernel on the interpreter while faking the gate's backend
-    monkeypatch.setattr(fd, "_interpret", lambda: True)
+    # fake the gate's backend; the marker keeps the kernel interpreted
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     paddle.seed(0)
     x = paddle.to_tensor(np.ones((64, 1024), np.float32))

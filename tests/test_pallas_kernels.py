@@ -224,6 +224,38 @@ def test_ce_block_env_override_validated():
         del os.environ["PTPU_CE_BLOCK_N"]
 
 
+@pytest.mark.parametrize("block_n,chunk,itemsize,want", [
+    (128, 8192, 2, 64),      # GPT-2 345M bf16: refused at 128 (16.25 MiB)
+    (128, 8192, 4, 64),      # ... and f32 (20.38 MiB)
+    (128, 1024, 4, 128),     # small tiles keep the forward's block
+    (128, 65536, 4, 8),      # never below the sublane tile
+    (24, 65536, 4, 24),      # ... nor off a multiple of 8
+])
+def test_ce_backward_row_block_fits_scoped_vmem(block_n, chunk, itemsize,
+                                                want):
+    from paddle_tpu.ops.pallas.chunked_ce import _bwd_block_n
+    assert _bwd_block_n(block_n, chunk, itemsize) == want
+
+
+@pytest.mark.pallas
+def test_ce_backward_parity_when_its_row_block_is_halved(monkeypatch):
+    """The backward may run a smaller row block than the forward that
+    produced its lse residual: same gradients either way."""
+    from paddle_tpu.ops.pallas import chunked_ce as kce
+    rng = np.random.RandomState(2)
+    lg = jnp.asarray((rng.randn(40, 96) * 3).astype(np.float32))
+    lab = jnp.asarray(rng.randint(0, 96, (40,)).astype(np.int32))
+    g_ref = jax.grad(lambda l: _dense_nll(l, lab).sum())(lg)
+    # 32-row forward blocks; a budget of one 8-row tile halves twice
+    monkeypatch.setenv("PTPU_CE_BLOCK_N", "32")
+    monkeypatch.setattr(kce, "_BWD_VMEM_BUDGET", 8 * 32 * 24)
+    assert kce._bwd_block_n(32, 32, 4) == 8
+    g_got = jax.grad(
+        lambda l: kce.chunked_ce_loss(l, lab, 32).sum())(lg)
+    np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_ref),
+                               rtol=1e-5, atol=1e-6)
+
+
 # ---------------------------------------------------------------------------
 # paged flash-decode
 # ---------------------------------------------------------------------------

@@ -1,0 +1,178 @@
+"""The main-path kernels, compiled for a described TPU v5e — no chip.
+
+libtpu's compiler is installed with jaxlib and compiles for a topology
+that is described and not attached, so what Mosaic or XLA:TPU would
+refuse on the chip (VMEM over the scoped limit, a dot form Mosaic cannot
+parse, a misaligned slice) is refused here, in a CPU test run, at the
+GPT-2 345M shapes the training and serving paths use. Interpret-mode
+parity tests cannot see any of that: two of these kernels passed every
+one of them and had never compiled (ISSUE 22).
+
+Nothing here runs a kernel — results are chip_smoke.py's job. The
+topology is described inside a module-scoped fixture and nowhere at
+import: only one process may load libtpu, and under pytest-xdist every
+worker imports every test file.
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.core.flags import flag_scope
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+# GPT-2 345M: 16 heads of 64, vocab 50304; training B=8 S=1024, serving
+# 8 slots over 16-token pages with a 512-token context (256 pages + the
+# scratch page), LoRA rank 16 over 5 adapters
+TRAIN_QKV = (8, 1024, 16, 64)
+PREFILL_QKV = (4, 256, 16, 64)
+BERT_QKV = (48, 512, 12, 64)
+LOGITS = (8192, 50304)
+CE_CHUNK = 8192                      # FLAGS_chunked_ce_chunk default
+POOL, TABLE, SLOTS = (257, 16, 16, 64), (8, 32), 8
+
+
+def _kernel(name):
+    # the package re-exports same-named functions over its submodules
+    return importlib.import_module("paddle_tpu.ops.pallas." + name)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """``chip(shape, dtype)`` -> a shaped argument placed on the first
+    described device (there is no device to hold an array)."""
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    return shaped
+
+
+@pytest.fixture
+def compile_for_chip():
+    """``compile_for_chip(fn, *shaped_args)`` -> the compiled text.
+
+    The kernels compile, not interpret (``FLAGS_pallas_interpret`` off,
+    whatever marker a neighbour test carried), and the persistent cache
+    is off around the compile: an executable built for a described chip
+    is written to it but cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def run(fn, *args):
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        try:
+            with flag_scope("pallas_interpret", False):
+                return jax.jit(fn).lower(*args).compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            cc.reset_cache()
+
+    return run
+
+
+def _sum32(x):
+    return x.astype(F32).sum()
+
+
+@pytest.mark.parametrize("shape,grad,dropout,bias", [
+    (TRAIN_QKV, False, 0.0, False),
+    (TRAIN_QKV, True, 0.0, False),
+    (TRAIN_QKV, True, 0.1, False),
+    (PREFILL_QKV, False, 0.0, False),
+    (BERT_QKV, True, 0.0, True),
+], ids=["train-fwd", "train-fwd+bwd", "train-fwd+bwd-dropout",
+        "prefill-fwd", "bert-bias-fwd+bwd"])
+def test_flash_attention_compiles(chip, compile_for_chip, shape, grad,
+                                  dropout, bias):
+    fa = _kernel("flash_attention")
+    q = chip(shape, BF16)
+    key = chip((), jax.random.key(0).dtype)
+    b = chip((shape[0], 1, 1, shape[1]), F32)
+
+    def loss(q, k, v, key, b):
+        return _sum32(fa.flash_attention(
+            q, k, v, bias=b if bias else None, causal=not bias,
+            dropout_rate=dropout, dropout_key=key if dropout else None))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else loss
+    assert "tpu_custom_call" in compile_for_chip(fn, q, q, q, key, b)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_chunked_ce_compiles(chip, compile_for_chip, dtype, grad):
+    """The backward is the one the compiler refused at the forward's row
+    block: 16.25 MiB (bf16) / 20.38 MiB (f32) of scoped VMEM against a
+    16 MiB limit."""
+    ce = _kernel("chunked_ce")
+
+    def loss(logits, labels):
+        return ce.chunked_ce_loss(logits, labels, CE_CHUNK).sum()
+
+    fn = jax.grad(loss) if grad else loss
+    text = compile_for_chip(fn, chip(LOGITS, dtype),
+                            chip(LOGITS[:1], I32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["plain", "int8"])
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_paged_decode_compiles(chip, compile_for_chip, dtype, quant):
+    """Refused until ISSUE 22: a dot_general batched over a non-leading
+    dimension is not a form Mosaic parses."""
+    pd = _kernel("paged_decode")
+    q = chip((SLOTS, 16, 64), dtype)
+    table, pos = chip(TABLE, I32), chip((SLOTS,), I32)
+    if quant:
+        pool, scales = chip(POOL, I8), chip(POOL[:3], F32)
+        text = compile_for_chip(
+            lambda q, k, ks, v, vs, t, p: pd.paged_decode_attention_quant(
+                q, k, ks, v, vs, t, p, scale=0.125),
+            q, pool, scales, pool, scales, table, pos)
+    else:
+        pool = chip(POOL, dtype)
+        text = compile_for_chip(
+            lambda q, k, v, t, p: pd.paged_decode_attention(
+                q, k, v, t, p, scale=0.125),
+            q, pool, pool, table, pos)
+    assert "tpu_custom_call" in text
+
+
+def test_bgmv_compiles(chip, compile_for_chip):
+    text = compile_for_chip(
+        _kernel("bgmv").bgmv, chip((SLOTS, 1, 1024), BF16),
+        chip((5, 16, 1024), BF16), chip((5, 16, 3072), BF16),
+        chip((SLOTS,), I32))
+    assert "tpu_custom_call" in text
+
+
+def test_fused_dropout_compiles(chip, compile_for_chip):
+    dr = _kernel("dropout")
+    text = compile_for_chip(
+        jax.grad(lambda x, key: _sum32(dr.fused_dropout(x, 0.1, key))),
+        chip((8, 1024, 1024), BF16), chip((), jax.random.key(0).dtype))
+    assert "tpu_custom_call" in text
+
+
+def test_int8_matmul_compiles(chip, compile_for_chip):
+    text = compile_for_chip(
+        _kernel("quant_matmul").int8_matmul, chip((8192, 1024), I8),
+        chip((1024, 4096), I8), chip((4096,), F32), chip((), F32))
+    assert "tpu_custom_call" in text
