@@ -23,6 +23,14 @@ import jax
 import numpy as np
 
 
+#: Eager key derivation goes through ONE jitted fold_in. An rbg key (the
+#: package default) folds by vmapping the threefry fold, and doing that
+#: eagerly re-enters jax's Python tracing path on every call — host work
+#: per train step and per served token, which CompileCounter.jaxpr_traces
+#: reports. The jitted form is a warm C++ dispatch with the same bits.
+fold_in = jax.jit(jax.random.fold_in)
+
+
 class Generator:
     """Stateful key source for eager mode."""
 
@@ -40,7 +48,7 @@ class Generator:
 
     def next_key(self):
         self._count += 1
-        return jax.random.fold_in(self._key, self._count)
+        return fold_in(self._key, self._count)
 
     def get_state(self):
         return (self._seed, self._count)
@@ -90,14 +98,17 @@ def make_rng(name: Optional[str] = None):
     """
     key = getattr(_tls, "trace_key", None)
     if key is not None:
+        # inside a trace the fold is part of the program being built
+        fold = jax.random.fold_in
         _tls.trace_count = getattr(_tls, "trace_count", 0) + 1
-        key = jax.random.fold_in(key, _tls.trace_count)
+        key = fold(key, _tls.trace_count)
     else:
+        fold = fold_in
         key = _default_generator.next_key()
     if name is None:
         name = getattr(_tls, "stream_name", None)  # active stream_scope
     if name is not None:
-        key = jax.random.fold_in(key, _stream_id(name))
+        key = fold(key, _stream_id(name))
     return key
 
 

@@ -15,25 +15,9 @@ from typing import Optional
 
 import jax
 
-# jax >= 0.5 promotes shard_map to jax.shard_map (kwargs: check_vma,
-# axis_names); older builds keep it in jax.experimental with the check_rep/
-# auto spelling. One resolved, kwarg-adapting symbol for every distributed
-# module so call sites can use the modern surface unconditionally.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
-                  axis_names=None, **kw):
-        if check_vma is not None and "check_rep" not in kw:
-            kw["check_rep"] = check_vma
-        if axis_names is not None:
-            # modern axis_names lists the MANUAL axes; legacy `auto` lists
-            # the complement
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
+# one symbol for every distributed module (call sites use the check_vma /
+# axis_names surface)
+shard_map = jax.shard_map
 
 _state = threading.local()
 _global = {

@@ -153,11 +153,18 @@ def manual_collectives_ok(mesh, axis: str = PP_AXIS) -> bool:
     """Can this backend compile collectives inside a shard_map manual over
     ``axis`` with the other mesh axes auto?
 
-    XLA:CPU (jax 0.4.37): NO when any other axis has size > 1 — the SPMD
-    partitioner aborts on manual-subgroup collectives (``Check failed:
-    IsManualSubgroup``), and even reaching it requires surviving the
-    ``PartitionId`` lowering of axis_index. Trivial auto axes partition
-    to a no-op, so pp-only meshes work everywhere. TPU/GPU: yes.
+    XLA:CPU is held to meshes whose other axes are trivial. Under jax
+    0.4.37 its SPMD partitioner aborted the process on manual-subgroup
+    collectives (``Check failed: IsManualSubgroup``). Under jaxlib 0.9.0
+    that no longer holds for the three programs probed with this check
+    forced open (PR 22: the pp2 x mp2 x dp2 GPT pipeline, the ep4 x dp2
+    MoE exchange and the ps4 x dp2 table lookup all compiled and matched
+    their references), so the exclusion is now only conservative: it is
+    kept because lifting it makes the counted mixed-mesh fallbacks of
+    the pipeline, MoE and recsys layers unreachable, and that deletion
+    (with the rest of the mesh matrix re-run) is its own change — see
+    ROADMAP, Design. Other backends: yes, but no pipeline schedule has
+    been compiled for a TPU yet.
     """
     if mesh is None or axis not in mesh.axis_names:
         return False
@@ -582,7 +589,8 @@ class PipelineStageStack(Layer):
                            _cache_token=("pipe_head", head_token, n_mb,
                                          self.training))
             return apply(lambda a, b: a / jnp.maximum(b, 1.0), ls, dn,
-                         name="pipeline_loss")
+                         name="pipeline_loss",
+                         _cache_token=("pipeline_loss",))
 
         if self.num_layers % S:
             raise ValueError(f"pp degree {S} must divide num_layers "
@@ -615,8 +623,10 @@ class PipelineStageStack(Layer):
                        name="spmd_pipeline_1f1b",
                        _cache_token=("pipe_1f1b", id(mesh), S, M, mb,
                                      head_token, n_mb, self.training))
+        # token-keyed: a fresh lambda would miss the eager op cache and
+        # run an eager jax.vjp (Python-path tracing) on every warm call
         return apply(lambda a, b: a / jnp.maximum(b, 1.0), ls, dn,
-                     name="pipeline_loss")
+                     name="pipeline_loss", _cache_token=("pipeline_loss",))
 
     def _1f1b_fn(self, mesh, S: int, M: int, head_apply, n_mb: int,
                  n_stack: int, n_head: int, head_token):

@@ -18,9 +18,13 @@ REFUSES input layouts/shardings that drift from the example arguments
 (e.g. ZeRO: XLA re-shards updated params over the zero axis, so step 2's
 inputs no longer match step 1's executable — dispatch-mode jit silently
 recompiles there). :class:`AOTProgram` does the same healing explicitly:
-re-lower/re-compile on the mismatch ValueError, and after repeated
-flip-flops hand the entry to dispatch-mode jit, whose executable cache
-holds every layout at once.
+when a call is refused AND an argument's placement really differs from
+what the executable was built for, re-lower/re-compile for the new
+placement, and after repeated flip-flops hand the entry to dispatch-mode
+jit, whose executable cache holds every layout at once. The drift is
+read off the arguments and ``Compiled.input_formats``, never off the
+wording of jax's error (which changed between 0.4 and 0.9 and silently
+disabled the heal).
 """
 
 from __future__ import annotations
@@ -32,16 +36,38 @@ import jax
 __all__ = ["AOTProgram"]
 
 
+def _inputs_drifted(compiled, args) -> bool:
+    """Whether a committed array in ``args`` sits under a sharding or a
+    device layout other than the one ``compiled`` was built for — the
+    two conditions under which a ``Compiled`` refuses a call whose
+    shapes and dtypes match."""
+    want = jax.tree_util.tree_leaves(compiled.input_formats[0])
+    for arg, fmt in zip(jax.tree_util.tree_leaves(args), want):
+        if fmt.sharding is None or not isinstance(arg, jax.Array):
+            continue                    # pruned input / host scalar
+        if (jax.dtypes.issubdtype(arg.dtype, jax.dtypes.prng_key)
+                or not arg.committed):
+            continue                    # placed by the call, never refused
+        if not arg.sharding.is_equivalent_to(fmt.sharding, arg.ndim):
+            return True
+        have = arg.format.layout
+        if (have is not None and fmt.layout is not None
+                and have != fmt.layout):
+            return True
+    return False
+
+
 class AOTProgram:
     """One program signature, compiled ahead of time.
 
     ``on_attribute(kind, lowered, compiled)`` is called after every
-    successful build (including heals — newest wins), with the exact
-    lowering and executable the calls will run; attribution therefore
-    costs no extra trace or compile.  When the AOT stage is unavailable
-    (exotic backend/version), calls fall back to dispatch-mode jit and
-    ``aot_available`` is False — the program still runs, attribution is
-    skipped.
+    build (including heals — newest wins), with the exact lowering and
+    executable the calls will run; attribution therefore costs no extra
+    trace or compile. A compiler refusal (a Mosaic kernel over its VMEM
+    limit, a program that does not fit HBM) propagates from
+    :meth:`compile` with its own message — it is never retried through
+    dispatch-mode jit, which would only fail later and further from the
+    cause.
     """
 
     #: layout flip-flops tolerated under one shape signature before the
@@ -62,29 +88,26 @@ class AOTProgram:
 
     # -- construction ------------------------------------------------------
     def _build(self, args) -> Any:
-        """lower+compile for ``args``; None when the AOT stage is
-        unavailable (the dispatch path still runs the program)."""
         from .to_static import _control_flow_guidance
         with _control_flow_guidance():
             lowered = self._jitted.lower(*args)
-        try:
-            compiled = lowered.compile()
-        except Exception:
-            return None
+        compiled = lowered.compile()
         self.builds += 1
         if self._on_attribute is not None:
             self._on_attribute(self.kind, lowered, compiled)
         return compiled
 
     def compile(self, example_args) -> "AOTProgram":
-        """Build the executable for the example signature (idempotent on
-        success; a failed AOT stage leaves the dispatch fallback)."""
+        """Build the executable for the example signature."""
         self._compiled = self._build(example_args)
         return self
 
     @property
-    def aot_available(self) -> bool:
-        return self._compiled is not None
+    def compiled(self) -> Any:
+        """The ``jax.stages.Compiled`` calls run now (``as_text()``,
+        ``memory_analysis()``); None before :meth:`compile` and after
+        the hand-off to dispatch-mode jit."""
+        return self._compiled
 
     # -- dispatch ----------------------------------------------------------
     def __call__(self, *args):
@@ -92,23 +115,19 @@ class AOTProgram:
             return self._jitted(*args)
         try:
             return self._compiled(*args)
-        except ValueError as e:
-            if "Compiled object called with" not in str(e):
+        except ValueError:
+            # Refused before execution (donated args are intact). Only a
+            # placement that moved since this signature was compiled is
+            # ours to heal — the drift dispatch-mode jit silently
+            # recompiles through; anything else is the caller's error.
+            if not _inputs_drifted(self._compiled, args):
                 raise
-            # Input shardings/layouts moved since this signature was
-            # compiled — the drift dispatch-mode jit silently recompiles
-            # through. Heal the same way, re-attributing from the new
-            # executable. The mismatch is detected BEFORE execution, so
-            # donated args are intact.
-            self.heals += 1
-            if self.heals > self.MAX_HEALS:
-                # layouts keep flip-flopping under one shape signature:
-                # hand the entry to dispatch-mode jit, whose executable
-                # cache holds every layout at once
-                self._compiled = None
-                return self._jitted(*args)
-            fresh = self._build(args)
-            self._compiled = fresh
-            if fresh is None:
-                return self._jitted(*args)
-            return fresh(*args)
+        self.heals += 1
+        if self.heals > self.MAX_HEALS:
+            # layouts keep flip-flopping under one shape signature:
+            # hand the entry to dispatch-mode jit, whose executable
+            # cache holds every layout at once
+            self._compiled = None
+            return self._jitted(*args)
+        self._compiled = self._build(args)
+        return self._compiled(*args)

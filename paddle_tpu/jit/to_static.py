@@ -282,21 +282,6 @@ def _layer_health_outputs(old_params, new_params, grads):
     return out
 
 
-def _donation_safe() -> bool:
-    """jax 0.4.37 XLA:CPU hazard: executables reloaded from the PERSISTENT
-    compilation cache can lose the input-output aliasing of donated
-    buffers when the program contains while/scan bodies (the
-    scan-over-layers train step) — warm-cache steps then read clobbered
-    parameter buffers and return garbage losses (segfaults observed too).
-    Reproduced with a pure-jax scan+grad+donate step on this CPU backend;
-    TPU executable serialization is unaffected. Donation is therefore
-    kept everywhere EXCEPT cpu-backend-with-persistent-cache (the test
-    environment, where donation buys nothing)."""
-    if jax.default_backend() != "cpu":
-        return True
-    return not (jax.config.jax_compilation_cache_dir or "")
-
-
 class TrainStep:
     """Compile (model, loss, optimizer) into ONE donated XLA train step.
 
@@ -1088,11 +1073,8 @@ class TrainStep:
             if jitted is None:
                 self._note_compile("accum", mon, fr)
                 fn = self._make_accum_step(treedef)
-                # _donation_safe re-checked per compiled entry: the
-                # persistent cache may be enabled after construction
                 jitted = self._compile_program(
-                    "accum", fn,
-                    (2,) if self._donate and _donation_safe() else (),
+                    "accum", fn, (2,) if self._donate else (),
                     (self.params, self.buffers, self._acc_grads, key,
                      flat), mon)
                 self._jitted[sig] = jitted
@@ -1139,7 +1121,7 @@ class TrainStep:
                                        health=health)
             jitted = self._compile_program(
                 "apply", fn,
-                (0, 2, 3) if self._donate and _donation_safe() else (),
+                (0, 2, 3) if self._donate else (),
                 (self.params, self.buffers, self.opt_state,
                  self._acc_grads, lr, t, key, flat), mon)
             self._jitted[sig] = jitted
@@ -1258,7 +1240,7 @@ class TrainStep:
             self._note_compile("step", mon, fr)
             fn = self._make_step(treedef, check_finite=check,
                                  health=health)
-            donate = (0, 2) if self._donate and _donation_safe() else ()
+            donate = (0, 2) if self._donate else ()
             jitted = self._compile_program(
                 "step", fn, donate,
                 (self.params, self.buffers, self.opt_state, lr, t, key,

@@ -41,11 +41,8 @@ NEG_INF = -1e30
 
 def _pcast_varying(x, axis_name):
     """Mark ``x`` as device-varying over ``axis_name`` for shard_map's VMA
-    type checking (jax >= 0.5). Legacy jax has neither lax.pcast nor VMA
-    typing, where this is correctly a no-op."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis_name,), to="varying")
-    return x
+    type checking."""
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 def block_attention(q, k, v, causal: bool = False,

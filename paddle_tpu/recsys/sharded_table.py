@@ -297,12 +297,7 @@ class ShardedEmbeddingTable:
             upd = jnp.where(own[:, None], -lr * acc, 0.0)
             return data_s[0].at[local].add(upd)[None], g2_s
 
-        # donation keeps the update at ONE table copy in HBM — but the
-        # jax 0.4.37 cpu+persistent-cache reload drops input-output
-        # aliasing from donated executables (the PR 2 hazard, observed
-        # here on shard_map programs too): warm-cache updates read
-        # clobbered rows. _donation_safe gates exactly that backend.
-        from ..jit.to_static import _donation_safe
+        # donation keeps the update at ONE table copy in HBM
         shard_ids = jax.device_put(
             np.arange(n, dtype=np.int32),
             NamedSharding(self._mesh, P(axis)))
@@ -312,7 +307,7 @@ class ShardedEmbeddingTable:
                       P(), P()),
             out_specs=(P(axis, None, None), P(axis, None)),
             axis_names={axis}, check_vma=False),
-            donate_argnums=(0, 1) if _donation_safe() else ())
+            donate_argnums=(0, 1))
         wrapped = lambda data, g2, uniq, acc: prog(data, g2, shard_ids,
                                                    uniq, acc)
         self._update_progs[key] = wrapped

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.tensor import Tensor, no_grad
-from ..core.random import trace_rng
+from ..core.random import fold_in, trace_rng
 from ..jit.aot import AOTProgram
 from ..jit.functional import bind, buffer_arrays, param_arrays
 from ..monitor import get_registry
@@ -440,7 +440,7 @@ class ServingEngine:
     # -- program construction ----------------------------------------------
     def _next_key(self):
         self._dispatch_seq += 1
-        return jax.random.fold_in(self._key, self._dispatch_seq)
+        return fold_in(self._key, self._dispatch_seq)
 
     @contextlib.contextmanager
     def _mesh_scope(self):
@@ -533,19 +533,16 @@ class ServingEngine:
 
     def _donate(self) -> tuple:
         from ..core.flags import get_flag
-        from ..jit.to_static import _donation_safe
         # pools are the 2nd/3rd argument of both program kinds; donation
-        # keeps decode's HBM footprint at ONE pool copy (skipped on the
-        # cpu+persistent-cache test backend — the jax 0.4.37 scan+donate
-        # aliasing hazard, see _donation_safe). An armed watchdog also
-        # disables donation: a tripped dispatch is ABANDONED mid-flight,
-        # and retrying the step is only sound while the live pools are
-        # neither invalidated (donated away) nor mutated in place by the
-        # zombie thread — the documented trade is one extra pool copy
-        # for retryable trips.
+        # keeps decode's HBM footprint at ONE pool copy. An armed
+        # watchdog disables donation: a tripped dispatch is ABANDONED
+        # mid-flight, and retrying the step is only sound while the live
+        # pools are neither invalidated (donated away) nor mutated in
+        # place by the zombie thread — the documented trade is one extra
+        # pool copy for retryable trips.
         if float(get_flag("serve_watchdog_s") or 0.0) > 0.0:
             return ()
-        return (1, 2) if _donation_safe() else ()
+        return (1, 2)
 
     def _get_decode(self) -> AOTProgram:
         key = ("decode",)

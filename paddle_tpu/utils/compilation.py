@@ -100,7 +100,15 @@ class CompileCounter:
     Attributes after (or during) the block:
     - ``backend_compiles``: XLA backend compiles started in the block
     - ``cache_misses``: persistent compilation-cache misses
-    - ``jaxpr_traces``: jaxpr traces (every jit signature traces >= once)
+    - ``jaxpr_traces``: entries into jit's Python tracing path. jax
+      (0.9) records the event around its tracing-cache lookup, so it
+      fires whenever a jitted function — any ``jnp`` ufunc included —
+      is called with tracers (an eager ``vmap``/``vjp`` around it) or
+      misses the C++ fast path, even when the jaxpr itself is memoized.
+      A warm compiled call fires none, so zero on a warm loop still
+      means "no host-side tracing work per step"; a nonzero count
+      names eager transforms left on the hot path, not necessarily a
+      re-trace
     - ``scan_body_traces`` / ``scan_calls``: nn.scan body traces — the
       "one trace per stack, not per layer" pin
     """

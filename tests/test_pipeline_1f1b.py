@@ -306,6 +306,7 @@ def test_state_dict_bit_exact_roundtrip_across_schedules():
     step = TrainStep(model_a, lambda l, x, t: l.loss(x, t), opt)
     x, tgt = _toy_batch()
     step(Tensor(x), Tensor(tgt))
+    step.sync_to_layer()          # the step donated the layer's arrays
 
     sd = model_a.state_dict()
     # per-layer views keep template names (state_dict manifest contract)
