@@ -507,38 +507,46 @@ define_flag("serve_lifecycle", False,
             "construction.")
 define_flag("compilation_cache", True,
             "Persist compiled XLA executables to disk so warm starts skip "
-            "the 20-40s first-compile (reference analogue: the CUDA "
-            "kernel/program caches). Applied at package import.")
-define_flag("compilation_cache_dir", "",
-            "Directory for the persistent compilation cache; empty = "
-            "~/.cache/paddle_tpu/xla_cache (or $XDG_CACHE_HOME).")
+            "the first compile (reference analogue: the CUDA "
+            "kernel/program caches). Applied at package import. The "
+            "directory is placed from OUTSIDE: $JAX_COMPILATION_CACHE_DIR "
+            "when set, else `.jax_cache` at the root of this checkout.")
+
+#: where the cache goes when the environment does not place it: one fixed
+#: directory inside the checkout. The path is part of jax's cache key, so
+#: a per-user or per-machine location (~/.cache, $XDG_CACHE_HOME) never
+#: hits across the machines a run moves between.
+DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
 def apply_compilation_cache() -> Optional[str]:
-    """Enable jax's persistent compilation cache per the flags above.
-    Called once at package import; safe to call again after set_flags.
-    Returns the cache dir (or None when disabled)."""
+    """Enable jax's persistent compilation cache. Called once at package
+    import; safe to call again after set_flags. Returns the cache dir
+    (or None when FLAGS_compilation_cache is off).
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins and nothing in code sets another
+    directory over it; without it, a directory already given to
+    ``jax.config`` is kept (the test suite's), else
+    :data:`DEFAULT_COMPILATION_CACHE_DIR`."""
     if not get_flag("compilation_cache"):
         return None
+    import jax
+    cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                 or jax.config.jax_compilation_cache_dir
+                 or DEFAULT_COMPILATION_CACHE_DIR)
     try:
-        import jax
-        # never clobber a cache the user already configured (env var or
-        # jax.config) — only supply the default when none is set
-        existing = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
-                    or jax.config.jax_compilation_cache_dir)
-        cache_dir = get_flag("compilation_cache_dir")
-        if existing and not cache_dir:
-            return existing
-        if not cache_dir:
-            base = os.environ.get("XDG_CACHE_HOME",
-                                  os.path.expanduser("~/.cache"))
-            cache_dir = os.path.join(base, "paddle_tpu", "xla_cache")
         os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
+    except OSError as e:
+        # a read-only checkout must still import: run uncached, say so
+        import warnings
+        warnings.warn(f"compilation cache disabled: cannot create "
+                      f"{cache_dir!r} ({e})", RuntimeWarning, stacklevel=2)
         return None
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return cache_dir
 
 

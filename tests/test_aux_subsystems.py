@@ -115,27 +115,70 @@ def test_trainstep_nan_check_under_jit():
         paddle.set_flags({"check_nan_inf": False})
 
 
-def test_compilation_cache_flag_default_on(tmp_path):
-    """FLAGS_compilation_cache (on by default) wires jax's persistent
-    compile cache to a user cache dir; disabling returns None."""
-    from paddle_tpu.core.flags import (apply_compilation_cache, get_flag,
-                                       set_flags)
-    assert get_flag("compilation_cache") is True
-    set_flags({"compilation_cache_dir": str(tmp_path / "cc")})
-    try:
-        d = apply_compilation_cache()
-        assert d == str(tmp_path / "cc")
-        import os
-        assert os.path.isdir(d)
-        set_flags({"compilation_cache": False})
-        assert apply_compilation_cache() is None
-    finally:
-        set_flags({"compilation_cache": True,
-                   "compilation_cache_dir": ""})
-        # restore the suite's cache dir (conftest set it at session start)
-        import jax
+@pytest.fixture
+def _cache_config():
+    """Hand the test jax's cache-dir config cleared of the suite's own
+    directory, and put that back afterwards."""
+    import jax
+    was = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", None)
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+@pytest.mark.parametrize("placed", ["env", "config", "default"])
+def test_compilation_cache_is_placed_from_outside(placed, tmp_path,
+                                                  monkeypatch,
+                                                  _cache_config):
+    """The persistent compile cache (FLAGS_compilation_cache, default
+    on) goes where the environment says: $JAX_COMPILATION_CACHE_DIR wins
+    over everything and code sets no other; without it a directory
+    already given to jax.config is kept; else ONE fixed directory inside
+    the checkout — never ~/.cache or $XDG_CACHE_HOME, whose path differs
+    between machines and is part of the cache key."""
+    import os
+
+    import jax
+    from paddle_tpu.core import flags
+    assert flags.get_flag("compilation_cache") is True
+    monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path / "xdg"))
+    monkeypatch.setenv("HOME", str(tmp_path / "home"))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert flags.DEFAULT_COMPILATION_CACHE_DIR == want
+    if placed == "env":
+        want = str(tmp_path / "from_env")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
         jax.config.update("jax_compilation_cache_dir",
-                          "/tmp/jax_test_cache")
+                          str(tmp_path / "set_in_code"))
+    elif placed == "config":
+        want = str(tmp_path / "set_in_code")
+        jax.config.update("jax_compilation_cache_dir", want)
+    else:
+        # the default lives in the checkout; do not create it from a test
+        monkeypatch.setattr(flags, "DEFAULT_COMPILATION_CACHE_DIR",
+                            str(tmp_path / "checkout" / ".jax_cache"))
+        want = flags.DEFAULT_COMPILATION_CACHE_DIR
+    assert flags.apply_compilation_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert os.path.isdir(want)
+    assert not (tmp_path / "xdg").exists()
+    assert not (tmp_path / "home").exists()
+
+
+def test_compilation_cache_flag_off_and_no_dir_flag(_cache_config):
+    """Disabling returns None and touches nothing; the old
+    FLAGS_compilation_cache_dir override is gone (the environment
+    variable is the one way to place the cache)."""
+    import jax
+    from paddle_tpu.core.flags import (apply_compilation_cache, flag_scope,
+                                       get_flags)
+    with flag_scope("compilation_cache", False):
+        assert apply_compilation_cache() is None
+    assert jax.config.jax_compilation_cache_dir is None
+    with pytest.raises(KeyError, match="compilation_cache_dir"):
+        get_flags("compilation_cache_dir")
 
 
 def test_profiler_eager_op_table():
