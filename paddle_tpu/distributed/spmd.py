@@ -21,7 +21,7 @@ from . import env
 __all__ = ["make_mesh", "shard_map", "named_sharding", "current_mesh",
            "PartitionSpec", "apply_param_shardings", "constrain", "BATCH",
            "data_axes", "degrade_spec", "SERVE_KV_SPEC",
-           "shard_serving_cache"]
+           "shard_serving_cache", "auto_axes", "shard_kernel"]
 
 PartitionSpec = P
 
@@ -85,6 +85,57 @@ def constrain(x, *spec):
         return apply(lambda a: jax.lax.with_sharding_constraint(a, sh), x,
                      name="sharding_constraint")
     return jax.lax.with_sharding_constraint(x, sh)
+
+
+def auto_axes(mesh: Optional[Mesh] = None) -> frozenset:
+    """The axes of the active mesh that GSPMD partitions at this point
+    of a trace: more than one device long and not already manual (inside
+    a shard_map region, e.g. the pipeline's ``pp``). Empty off-mesh."""
+    mesh = mesh if mesh is not None else env.get_mesh()
+    if mesh is None:
+        return frozenset()
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    return frozenset(a for a in mesh.axis_names
+                     if int(mesh.shape[a]) > 1 and a not in manual)
+
+
+def shard_kernel(fn, in_specs, out_specs):
+    """``fn`` run per device shard over the active mesh — for Mosaic
+    kernels, which GSPMD cannot partition (``NotImplementedError: Mosaic
+    kernels cannot be automatically partitioned`` at lowering).
+
+    The specs name the layout the kernel's math is independent over
+    (BATCH expands to the data axes, as in :func:`constrain`); axes that
+    are absent, one device long or already manual drop out of them, and
+    every remaining auto axis goes manual for the call, so an axis no
+    spec mentions sees replicated operands and repeats the work. With
+    nothing left to partition ``fn`` is returned as it is."""
+    mesh = env.get_mesh()
+    axes = auto_axes(mesh)
+    if not axes:
+        return fn
+    batch = data_axes(mesh)
+
+    def clean(spec):
+        out = []
+        for s in tuple(spec):
+            names = batch if s == BATCH else \
+                (s,) if isinstance(s, str) else tuple(s or ())
+            kept = tuple(a for a in names if a in axes)
+            out.append(kept[0] if len(kept) == 1 else kept or None)
+        return P(*out)
+
+    as_specs = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        clean, t, is_leaf=lambda x: isinstance(x, P))
+    # a Mosaic kernel lowers only where EVERY mesh axis is manual, the
+    # one-device-long ones too; inside another shard_map the context
+    # mesh is the one to extend
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    return env.shard_map(fn, in_specs=as_specs(in_specs),
+                         out_specs=as_specs(out_specs),
+                         axis_names=set(mesh.axis_names) - manual,
+                         check_vma=False,
+                         **({} if manual else {"mesh": mesh}))
 
 
 def make_mesh(axis_sizes: Dict[str, int], devices=None) -> Mesh:

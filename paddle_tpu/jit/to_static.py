@@ -999,6 +999,16 @@ class TrainStep:
             return
         raise NonFiniteError(msg, offender=offender, step=step_index)
 
+    def aot_programs(self) -> list:
+        """The :class:`~paddle_tpu.jit.aot.AOTProgram` behind every
+        program kind and batch signature built so far — ``.compiled``
+        is the executable the next call runs (``as_text()``,
+        ``memory_analysis()``), ``.builds``/``.heals`` count its
+        compiles and sharding-drift re-compiles."""
+        from .aot import AOTProgram
+        return [p for p in self._jitted.values()
+                if isinstance(p, AOTProgram)]
+
     def stats(self) -> dict:
         """Telemetry snapshot since construction: our jit-entry builds
         (``compiles``/``recompiles`` — a warm scan-layer GPT shows exactly

@@ -1127,11 +1127,15 @@ def dropout(x, p=0.5, axis=None, training=True, mode="upscale_in_train",
     key = make_rng(rng_name)
 
     def _do(a):
+        from ..distributed.spmd import auto_axes
         if axis is None and mode == "upscale_in_train" \
-                and a.size >= 65536 and jax.default_backend() == "tpu":
+                and a.size >= 65536 and jax.default_backend() == "tpu" \
+                and not auto_axes():
             # single-pass Pallas kernel: in-kernel counter-based mask,
             # regenerated in the backward — one HBM read + one write
-            # instead of XLA's bits/mask/product round-trips
+            # instead of XLA's bits/mask/product round-trips. Not under
+            # a mesh: GSPMD cannot partition a Mosaic kernel, and it
+            # partitions the XLA composition along any activation layout
             from ..ops.pallas.dropout import fused_dropout
             return fused_dropout(a, p, key)
         if axis is None:

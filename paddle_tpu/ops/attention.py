@@ -17,6 +17,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..core.flags import matmul_precision
 from ..core.random import make_rng
@@ -45,6 +46,22 @@ def _sdpa_xla(q, k, v, mask, dropout_p, is_causal, dropout_key):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v, precision=prec)
 
 
+def _mesh_splits(B: int, H: int):
+    """(batch shards, head shards) of the layout the flash kernel runs
+    under on the active mesh — batch rows over the data axes, heads over
+    ``mp``, what GPTAttention pins around its attention — or None when
+    the shapes do not divide it. (1, 1) off-mesh."""
+    from ..distributed.spmd import auto_axes, data_axes
+    from ..distributed import env as dist_env
+    axes = auto_axes()
+    if not axes:
+        return 1, 1
+    mesh = dist_env.get_mesh()
+    nb = math.prod(int(mesh.shape[a]) for a in data_axes(mesh) if a in axes)
+    nh = int(mesh.shape["mp"]) if "mp" in axes else 1
+    return (nb, nh) if B % nb == 0 and H % nh == 0 else None
+
+
 def _flash_supported(q, k, v, mask, dropout_p, dropout_key=None) -> bool:
     if dropout_p > 0.0 and dropout_key is None:
         # no key: the XLA path silently skips dropout — keep that behavior
@@ -63,7 +80,34 @@ def _flash_supported(q, k, v, mask, dropout_p, dropout_key=None) -> bool:
         and S % 128 == 0 and Sk % 128 == 0
         and D in (64, 128, 256)
         and S >= 256
+        and _mesh_splits(B, H) is not None
     )
+
+
+def _flash(q, k, v, mask, dropout_p, is_causal, dropout_key):
+    """The flash kernel — per device shard when a mesh is active, since
+    GSPMD cannot partition a Mosaic kernel: attention is independent
+    over batch rows and heads, so each device runs the kernel on its own
+    (see :func:`_mesh_splits`); the sequence and head dims stay whole."""
+    from ..distributed.spmd import BATCH, auto_axes, shard_kernel
+    from .pallas.flash_attention import flash_attention
+    axes = tuple(sorted(auto_axes()))
+
+    def kernel(q, k, v, mask, key):
+        if key is not None and axes:
+            # one mask stream per shard: the kernel hashes LOCAL
+            # coordinates, so shards would otherwise redraw one mask
+            key = jax.random.fold_in(key, jax.lax.axis_index(axes))
+        return flash_attention(q, k, v, bias=mask, causal=is_causal,
+                               dropout_rate=dropout_p, dropout_key=key)
+
+    qkv = P(BATCH, None, "mp", None)
+    return shard_kernel(
+        kernel,
+        in_specs=(qkv, qkv, qkv,
+                  None if mask is None else P(BATCH, None, None, None),
+                  None if dropout_key is None else P()),
+        out_specs=qkv)(q, k, v, mask, dropout_key)
 
 
 def sdpa_array(q, k, v, mask=None, dropout_p=0.0, is_causal=False,
@@ -71,10 +115,7 @@ def sdpa_array(q, k, v, mask=None, dropout_p=0.0, is_causal=False,
     """Raw-array scaled dot-product attention with flash dispatch."""
     if use_flash and _flash_supported(q, k, v, mask, dropout_p,
                                       dropout_key):
-        from .pallas.flash_attention import flash_attention
-        return flash_attention(q, k, v, bias=mask, causal=is_causal,
-                               dropout_rate=dropout_p,
-                               dropout_key=dropout_key)
+        return _flash(q, k, v, mask, dropout_p, is_causal, dropout_key)
     return _sdpa_xla(q, k, v, mask, dropout_p, is_causal, dropout_key)
 
 

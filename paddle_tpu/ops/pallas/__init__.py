@@ -88,7 +88,8 @@ def note_fallback(kernel: str, reason: str) -> None:
     ``pallas_fallback_total{kernel,reason}`` registry counter in monitor
     mode. Reasons: ``flag_off`` (kill switch), ``cpu_backend`` (non-TPU
     without FLAGS_pallas_interpret), ``shape`` (unsupported geometry,
-    e.g. int8 gemm dims not 128-aligned).
+    e.g. int8 gemm dims not 128-aligned), ``mesh`` (a compiled kernel
+    under a multi-device mesh, which GSPMD cannot partition).
     """
     with _STATS_LOCK:
         PALLAS_STATS[(kernel, reason)] = \
@@ -137,6 +138,16 @@ def kernel_enabled(name: str, note: bool = True) -> bool:
         if note:
             note_fallback(name, "cpu_backend")
         return False
+    if not interpret():
+        # GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot
+        # be automatically partitioned" at lowering): under a mesh these
+        # call sites have no per-shard form yet, so they take the XLA
+        # path, which it can. flash_attention has one (ops.attention).
+        from ...distributed.spmd import auto_axes
+        if auto_axes():
+            if note:
+                note_fallback(name, "mesh")
+            return False
     return True
 
 

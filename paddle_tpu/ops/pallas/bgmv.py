@@ -103,4 +103,5 @@ def bgmv(x, a, b, ids):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=_interpret(),
+        name="bgmv",
     )(ids.astype(jnp.int32), x, a, b)

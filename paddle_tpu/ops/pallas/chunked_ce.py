@@ -135,6 +135,7 @@ def _online_lse(logits, block_n: int, chunk: int):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
+        name="chunked_ce_lse",
     )(logits)
 
 
@@ -197,6 +198,7 @@ def _dlogits(logits, labels, lse, g, block_n: int, chunk: int):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=_interpret(),
+        name="chunked_ce_dlogits",
     )(logits, labels, lse, g)
 
 

@@ -72,6 +72,7 @@ def _run(x2d, seed, rate):
         out_specs=pl.BlockSpec((rb, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
         interpret=_interpret(),
+        name="fused_dropout",
     )(seed, x2d)
 
 

@@ -245,6 +245,7 @@ def _fwd_v1(q, k, v, bias, scale, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
+        name="flash_fwd_v1",
     )(*args)
     if save_residuals:
         o, lse = out
@@ -405,6 +406,7 @@ def _fwd2(q, k, v, scale, causal, block_q, block_k, hp, width,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
+        name="flash_fwd",
     )(*args)
     if save_residuals:
         return out[0], out[1]
@@ -549,6 +551,7 @@ def _bwd2(q, k, v, o, lse, do, scale, causal, block_q, block_k, hp, width,
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=_interpret(),
+        name="flash_bwd",
     )(*args)
     return dq, dk, dv
 
@@ -708,6 +711,7 @@ def _bwd_v1(q, k, v, bias, o, lse, do, scale, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
+        name="flash_dq_v1",
     )(*dq_args)
 
     # dkv: grid (B, H, nk, nq) — i indexes k blocks, j indexes q blocks
@@ -757,6 +761,7 @@ def _bwd_v1(q, k, v, bias, o, lse, do, scale, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
+        name="flash_dkv_v1",
     )(*dkv_args)
     if bias is not None:
         dk, dv, db_h = outs

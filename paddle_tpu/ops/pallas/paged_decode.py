@@ -152,6 +152,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, pos, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
+        name="paged_decode",
     )(block_table.astype(jnp.int32), pos.astype(jnp.int32),
       q, k_pages, v_pages)
 
@@ -202,5 +203,6 @@ def paged_decode_attention_quant(q, k_pages, k_scales, v_pages, v_scales,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
+        name="paged_decode_int8",
     )(block_table.astype(jnp.int32), pos.astype(jnp.int32),
       q, k_pages, k_scales, v_pages, v_scales)

@@ -176,6 +176,7 @@ def int8_matmul(x_q, w_q, w_scale, act_scale, out_dtype=jnp.float32):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="int8_matmul",
     )(x_q, w_q, ws, act)
 
 

@@ -288,3 +288,76 @@ def test_env_block_override_validated_and_scoped(monkeypatch):
     assert seen["bq"] == 128
     fa.flash_attention(q, q, q, block_q=256)         # explicit arg wins
     assert seen["bq"] == 256
+
+
+@pytest.fixture
+def tp_dp_mesh(monkeypatch):
+    """A dp2 x mp2 mesh active, and the flash gate's backend check faked
+    (the marker keeps the kernel interpreted)."""
+    from paddle_tpu.distributed import env as dist_env
+    from paddle_tpu.distributed.spmd import make_mesh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = make_mesh({"dp": 2, "sharding": 1, "mp": 2}, jax.devices()[:4])
+    dist_env.set_mesh(mesh)
+    yield mesh
+    dist_env.reset()
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["causal", "bias"])
+def test_flash_dispatch_runs_per_shard_under_a_mesh(tp_dp_mesh, masked):
+    """GSPMD cannot partition a Mosaic kernel, so under a mesh the flash
+    dispatch is a shard_map — batch rows over the data axes, heads over
+    mp. Same values and gradients as the unsharded reference, and the
+    result keeps that layout."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.ops import attention
+
+    q, k, v = _qkv(11)
+    mask = None
+    if masked:
+        keep = np.ones((B, 1, 1, S), np.float32)
+        keep[0, ..., S // 2:] = 0.0
+        mask = jnp.asarray(np.where(keep > 0, 0.0, -1e30)
+                           .astype(np.float32))
+    assert attention._mesh_splits(B, H) == (2, 2)
+    assert attention._flash_supported(q, k, v, mask, 0.0)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * (q + 1.0))
+
+    on_mesh = lambda q, k, v: attention.sdpa_array(  # noqa: E731
+        q, k, v, mask, 0.0, not masked, None)
+    ref = lambda q, k, v: _ref(q, k, v, mask, causal=not masked)  # noqa
+    out = jax.jit(on_mesh)(q, k, v)
+    assert out.sharding.is_equivalent_to(
+        NamedSharding(tp_dp_mesh, P("dp", None, "mp", None)), out.ndim)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    g = jax.jit(jax.grad(loss(on_mesh), (0, 1, 2)))(q, k, v)
+    g_ref = jax.grad(loss(ref), (0, 1, 2))(q, k, v)
+    for a, b in zip(g, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-4, rtol=5e-4)
+
+
+def test_flash_dispatch_under_a_mesh_dropout_and_indivisible(tp_dp_mesh):
+    """Each shard folds its mesh position into the dropout key (the
+    kernel hashes LOCAL coordinates, so shards would otherwise redraw one
+    mask); shapes that do not divide the mesh layout take the XLA path."""
+    from paddle_tpu.ops import attention
+
+    q, k, v = _qkv(12)
+    out = jax.jit(lambda q, k, v, key: attention.sdpa_array(
+        q, k, v, None, 0.5, True, key))(q, k, v, jax.random.key(3))
+    out = np.asarray(out)
+    assert np.isfinite(out).all()
+    # identical inputs in both batch rows: only the masks can differ
+    same = jax.jit(lambda q, key: attention.sdpa_array(
+        q, q, q, None, 0.5, True, key))(
+            jnp.broadcast_to(q[:1], q.shape), jax.random.key(3))
+    same = np.asarray(same)
+    assert not np.allclose(same[0], same[1])
+    # 3 batch rows over dp=2: not this kernel's layout
+    q3 = jnp.concatenate([q, q[:1]])
+    assert attention._mesh_splits(3, H) is None
+    assert not attention._flash_supported(q3, q3, q3, None, 0.0)

@@ -77,6 +77,30 @@ def test_fallbacks_counted_in_stats_and_registry():
     assert pallas_ops.PALLAS_STATS[("chunked_ce", "cpu_backend")] == 1
 
 
+@pytest.mark.parametrize("name", ["chunked_ce", "paged_decode",
+                                  "int8_matmul", "bgmv"])
+def test_compiled_kernels_fall_back_counted_under_a_mesh(name, monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel (lowering raises "Mosaic
+    kernels cannot be automatically partitioned"), and these call sites
+    have no per-shard form: on a TPU under a multi-device mesh they take
+    the XLA path, counted as ``mesh``. Interpreted kernel bodies are
+    plain XLA ops and stay live; a one-device mesh partitions nothing."""
+    import jax
+    from paddle_tpu.distributed import env as dist_env
+    from paddle_tpu.distributed.spmd import make_mesh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        dist_env.set_mesh(make_mesh({"dp": 1, "mp": 1}, jax.devices()[:1]))
+        assert pallas_ops.kernel_enabled(name)
+        dist_env.set_mesh(make_mesh({"dp": 2, "mp": 2}, jax.devices()[:4]))
+        assert not pallas_ops.kernel_enabled(name)
+        assert dict(pallas_ops.PALLAS_STATS) == {(name, "mesh"): 1}
+        with flag_scope("pallas_interpret", True):
+            assert pallas_ops.kernel_enabled(name)
+    finally:
+        dist_env.reset()
+
+
 def test_monitor_report_kernels_mode(capsys):
     """tools/monitor_report.py --kernels renders the live inventory."""
     import os

@@ -91,6 +91,14 @@ def _sum32(x):
     return x.astype(F32).sum()
 
 
+def _assert_kernels(text, *names):
+    """The compiled program holds Mosaic kernels, under the stable names
+    the package gives its pallas_calls (what a device trace shows)."""
+    assert "tpu_custom_call" in text
+    for name in names:
+        assert name in text, name
+
+
 @pytest.mark.parametrize("shape,grad,dropout,bias", [
     (TRAIN_QKV, False, 0.0, False),
     (TRAIN_QKV, True, 0.0, False),
@@ -112,7 +120,11 @@ def test_flash_attention_compiles(chip, compile_for_chip, shape, grad,
             dropout_rate=dropout, dropout_key=key if dropout else None))
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else loss
-    assert "tpu_custom_call" in compile_for_chip(fn, q, q, q, key, b)
+    v = "_v1" if bias else ""            # additive bias: the v1 kernels
+    names = ["flash_fwd" + v] + (
+        ["flash_dq_v1", "flash_dkv_v1"] if grad and bias
+        else ["flash_bwd"] if grad else [])
+    _assert_kernels(compile_for_chip(fn, q, q, q, key, b), *names)
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
@@ -129,7 +141,8 @@ def test_chunked_ce_compiles(chip, compile_for_chip, dtype, grad):
     fn = jax.grad(loss) if grad else loss
     text = compile_for_chip(fn, chip(LOGITS, dtype),
                             chip(LOGITS[:1], I32))
-    assert "tpu_custom_call" in text
+    _assert_kernels(text, "chunked_ce_lse",
+                    *(["chunked_ce_dlogits"] if grad else []))
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["plain", "int8"])
@@ -152,7 +165,7 @@ def test_paged_decode_compiles(chip, compile_for_chip, dtype, quant):
             lambda q, k, v, t, p: pd.paged_decode_attention(
                 q, k, v, t, p, scale=0.125),
             q, pool, pool, table, pos)
-    assert "tpu_custom_call" in text
+    _assert_kernels(text, "paged_decode_int8" if quant else "paged_decode")
 
 
 def test_bgmv_compiles(chip, compile_for_chip):
@@ -160,7 +173,7 @@ def test_bgmv_compiles(chip, compile_for_chip):
         _kernel("bgmv").bgmv, chip((SLOTS, 1, 1024), BF16),
         chip((5, 16, 1024), BF16), chip((5, 16, 3072), BF16),
         chip((SLOTS,), I32))
-    assert "tpu_custom_call" in text
+    _assert_kernels(text, "bgmv")
 
 
 def test_fused_dropout_compiles(chip, compile_for_chip):
@@ -168,11 +181,11 @@ def test_fused_dropout_compiles(chip, compile_for_chip):
     text = compile_for_chip(
         jax.grad(lambda x, key: _sum32(dr.fused_dropout(x, 0.1, key))),
         chip((8, 1024, 1024), BF16), chip((), jax.random.key(0).dtype))
-    assert "tpu_custom_call" in text
+    _assert_kernels(text, "fused_dropout")
 
 
 def test_int8_matmul_compiles(chip, compile_for_chip):
     text = compile_for_chip(
         _kernel("quant_matmul").int8_matmul, chip((8192, 1024), I8),
         chip((1024, 4096), I8), chip((4096,), F32), chip((), F32))
-    assert "tpu_custom_call" in text
+    _assert_kernels(text, "int8_matmul")
