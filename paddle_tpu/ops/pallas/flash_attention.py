@@ -107,9 +107,18 @@ def _dropout_keep(seed_ref, b, h, qi, ki, shape, rate):
 
 
 def _dot(a, b, dims, cd=jnp.float32):
-    """MXU matmul: operands cast to the policy dtype, f32 accumulation."""
-    return jax.lax.dot_general(a.astype(cd), b.astype(cd), (dims, ((), ())),
-                               preferred_element_type=jnp.float32)
+    """MXU matmul: operands cast to the policy dtype, f32 accumulation.
+
+    f32 operands mean the policy asked for full f32 (:func:`_mxu_dtype`),
+    and that has to be said to Mosaic too: at its default precision an
+    f32 dot feeds the MXU bf16-rounded operands (measured on a v5e, PR
+    22: 2.2e-3 off the f32 reference — the error of a bf16 pass — where
+    XLA's ``highest`` composition is at 1e-6)."""
+    return jax.lax.dot_general(
+        a.astype(cd), b.astype(cd), (dims, ((), ())),
+        precision=(jax.lax.Precision.HIGHEST if cd == jnp.float32
+                   else None),
+        preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------

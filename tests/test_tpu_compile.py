@@ -99,18 +99,19 @@ def _assert_kernels(text, *names):
         assert name in text, name
 
 
-@pytest.mark.parametrize("shape,grad,dropout,bias", [
-    (TRAIN_QKV, False, 0.0, False),
-    (TRAIN_QKV, True, 0.0, False),
-    (TRAIN_QKV, True, 0.1, False),
-    (PREFILL_QKV, False, 0.0, False),
-    (BERT_QKV, True, 0.0, True),
+@pytest.mark.parametrize("shape,dtype,grad,dropout,bias", [
+    (TRAIN_QKV, BF16, False, 0.0, False),
+    (TRAIN_QKV, BF16, True, 0.0, False),
+    (TRAIN_QKV, BF16, True, 0.1, False),
+    (TRAIN_QKV, F32, True, 0.0, False),
+    (PREFILL_QKV, F32, False, 0.0, False),   # the engine serves in f32
+    (BERT_QKV, BF16, True, 0.0, True),
 ], ids=["train-fwd", "train-fwd+bwd", "train-fwd+bwd-dropout",
-        "prefill-fwd", "bert-bias-fwd+bwd"])
-def test_flash_attention_compiles(chip, compile_for_chip, shape, grad,
-                                  dropout, bias):
+        "train-f32-fwd+bwd", "prefill-f32-fwd", "bert-bias-fwd+bwd"])
+def test_flash_attention_compiles(chip, compile_for_chip, shape, dtype,
+                                  grad, dropout, bias):
     fa = _kernel("flash_attention")
-    q = chip(shape, BF16)
+    q = chip(shape, dtype)
     key = chip((), jax.random.key(0).dtype)
     b = chip((shape[0], 1, 1, shape[1]), F32)
 
