@@ -90,8 +90,9 @@ CE_LOSS_TOL = 1e-4
 #: decode logits through pages + the paged kernel vs one dense forward of
 #: the same f32 weights, absolute (logits of a random-init model are O(1))
 DECODE_LOGITS_ATOL = 2e-3
-#: step-1 loss, tensor-parallel x ZeRO mesh vs one device, absolute, on a
-#: loss near ln(50304) = 10.8 under bf16 autocast (dropout off in both)
+#: each step's loss, tensor-parallel x ZeRO mesh vs one device, absolute,
+#: on a loss near ln(50304) = 10.8 under bf16 autocast (dropout off in
+#: both, so the two runs follow one trajectory)
 MESH_LOSS_ATOL = 2e-2
 
 
@@ -627,7 +628,10 @@ def phase_mesh(args, cfg):
             say(f"  [{tag}] step {i} loss {losses[-1]:.4f} "
                 f"({time.perf_counter() - t0:.2f}s wall, blocking)")
         check(all(np.isfinite(losses)), f"[{tag}] non-finite: {losses}")
-        check(losses[-1] < losses[0], f"[{tag}] not falling: {losses}")
+        # AdamW's first steps on one batch overshoot and come back (on
+        # the chip: 11.03, 10.76, 10.97), so "falling" is held to the
+        # best step, and the trajectory to the other run's below
+        check(min(losses[1:]) < losses[0], f"[{tag}] not falling: {losses}")
         return losses
 
     def share(tree, dev):
@@ -695,10 +699,12 @@ def phase_mesh(args, cfg):
     gc.collect()
     step = build_step(args, gcfg)
     one_losses = run(step, "one device")
-    diff = abs(mesh_losses[0] - one_losses[0])
+    diffs = [abs(a - b) for a, b in zip(mesh_losses, one_losses)]
     say(f"  step-1 loss mesh {mesh_losses[0]:.5f} vs one device "
-        f"{one_losses[0]:.5f}: diff {diff:.2e} (tol {MESH_LOSS_ATOL:g})")
-    check(diff <= MESH_LOSS_ATOL, f"step-1 loss parity broken: {diff}")
+        f"{one_losses[0]:.5f}; |diff| per step "
+        f"{' '.join(f'{d:.2e}' for d in diffs)} (tol {MESH_LOSS_ATOL:g})")
+    check(max(diffs) <= MESH_LOSS_ATOL,
+          f"loss parity with one device broken: {diffs}")
 
 
 # ---------------------------------------------------------------------------

@@ -142,13 +142,8 @@ class LocalSGDTrainStep:
             in_specs = (pspec, _P(), stspec, _P(), _P(), _P(),
                         list(bspecs))
             out_specs = (pspec, stspec, _P(), _P(axis))
-            try:
-                sm = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs, check_vma=False)
-            except (AttributeError, TypeError):   # older jax
-                from jax.experimental.shard_map import shard_map
-                sm = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
+            sm = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False)
             return jax.jit(sm, donate_argnums=(0, 2))
 
         self._make_local = make_local

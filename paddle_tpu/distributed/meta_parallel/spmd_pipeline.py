@@ -158,8 +158,8 @@ def manual_collectives_ok(mesh, axis: str = PP_AXIS) -> bool:
     collectives (``Check failed: IsManualSubgroup``). Under jaxlib 0.9.0
     that no longer holds for the three programs probed with this check
     forced open (PR 22: the pp2 x mp2 x dp2 GPT pipeline, the ep4 x dp2
-    MoE exchange and the ps4 x dp2 table lookup all compiled and matched
-    their references), so the exclusion is now only conservative: it is
+    MoE exchange and the ps4 x dp2 table lookup all compiled and ran),
+    so the exclusion is now only conservative: it is
     kept because lifting it makes the counted mixed-mesh fallbacks of
     the pipeline, MoE and recsys layers unreachable, and that deletion
     (with the rest of the mesh matrix re-run) is its own change — see

@@ -87,14 +87,14 @@ def constrain(x, *spec):
     return jax.lax.with_sharding_constraint(x, sh)
 
 
-def auto_axes(mesh: Optional[Mesh] = None) -> frozenset:
+def auto_axes() -> frozenset:
     """The axes of the active mesh that GSPMD partitions at this point
     of a trace: more than one device long and not already manual (inside
     a shard_map region, e.g. the pipeline's ``pp``). Empty off-mesh."""
-    mesh = mesh if mesh is not None else env.get_mesh()
+    mesh = env.get_mesh()
     if mesh is None:
         return frozenset()
-    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    manual = jax.sharding.get_abstract_mesh().manual_axes
     return frozenset(a for a in mesh.axis_names
                      if int(mesh.shape[a]) > 1 and a not in manual)
 
@@ -107,23 +107,18 @@ def shard_kernel(fn, in_specs, out_specs):
     The specs name the layout the kernel's math is independent over
     (BATCH expands to the data axes, as in :func:`constrain`); axes that
     are absent, one device long or already manual drop out of them, and
-    every remaining auto axis goes manual for the call, so an axis no
-    spec mentions sees replicated operands and repeats the work. With
-    nothing left to partition ``fn`` is returned as it is."""
-    mesh = env.get_mesh()
-    axes = auto_axes(mesh)
+    every remaining axis goes manual for the call, so an axis no spec
+    mentions sees replicated operands and repeats the work. With nothing
+    left to partition ``fn`` is returned as it is."""
+    axes = auto_axes()
     if not axes:
         return fn
+    mesh = env.get_mesh()
     batch = data_axes(mesh)
 
     def clean(spec):
-        out = []
-        for s in tuple(spec):
-            names = batch if s == BATCH else \
-                (s,) if isinstance(s, str) else tuple(s or ())
-            kept = tuple(a for a in names if a in axes)
-            out.append(kept[0] if len(kept) == 1 else kept or None)
-        return P(*out)
+        return P(*(_degrade_entry(batch if s == BATCH else s, axes)
+                   for s in tuple(spec)))
 
     as_specs = lambda t: jax.tree_util.tree_map(  # noqa: E731
         clean, t, is_leaf=lambda x: isinstance(x, P))

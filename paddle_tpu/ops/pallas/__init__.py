@@ -119,8 +119,7 @@ def backend_supported() -> bool:
     any backend with the interpreter forced (``FLAGS_pallas_interpret``,
     flipped by the ``pallas`` pytest marker)."""
     import jax
-    return (jax.default_backend() == "tpu"
-            or bool(get_flag("pallas_interpret")))
+    return jax.default_backend() == "tpu" or interpret()
 
 
 def kernel_enabled(name: str, note: bool = True) -> bool:

@@ -172,7 +172,8 @@ def _bwd_block_n(block_n: int, chunk: int, itemsize: int) -> int:
     (16.25 MiB bf16 / 20.38 MiB f32 against the 16 MiB scoped limit). So
     the row block is halved until the estimate fits; the forward's block
     is unchanged."""
-    while (block_n > 8 and block_n % 16 == 0
+    # halving keeps a multiple of the 8-row sublane tile
+    while (block_n % 16 == 0
            and block_n * chunk * (4 * itemsize + 8) > _BWD_VMEM_BUDGET):
         block_n //= 2
     return block_n

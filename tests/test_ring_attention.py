@@ -66,9 +66,7 @@ def test_ring_attention_grads_match_full():
     spec = P(None, "sp", None, None)
 
     def ring_loss(q, k, v):
-        # check_vma/check_rep off: legacy jax's replication inference cannot
-        # type the causal lax.switch branches through the grad transpose
-        # (the framework's own shard_map call sites disable it the same way)
+        # check_vma off, as at the framework's own shard_map call sites
         out = shard_map(
             lambda a, b, c: ring_attention(a, b, c, "sp", causal=True),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
