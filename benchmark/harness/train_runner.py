@@ -1,0 +1,222 @@
+"""Runner `train`: `paddle.jit.TrainStep` fed by `paddle.io.DataLoader`.
+
+Set-up (counted in `setup_s`): the model with weights from `--seed`, its
+agreement with the plain reference, the step program compiled or loaded
+from the cache (on a ZeRO mesh the second step re-lowers: that too),
+`warm_steps` blocking steps. Window: steps dispatched back to back with
+at most `inflight` not yet finished, for `--seconds`; the last one ends
+by `block_until_ready`, and the rate is taken over all steps and all of
+the time up to there.
+"""
+
+from __future__ import annotations
+
+import collections
+import multiprocessing
+import time
+
+from . import trace_reduce
+from .loadgen import TokenStream
+from .result import BenchFailure, Run, Timed, annotate, say
+
+
+def _rel_err(got, ref) -> float:
+    import jax.numpy as jnp
+    g, r = got.astype(jnp.float32), ref.astype(jnp.float32)
+    return float(jnp.max(jnp.abs(g - r)) / (jnp.max(jnp.abs(r)) + 1e-12))
+
+
+def check_against_reference(run: Run, model, reference, stream, vocab):
+    """Evaluation-mode loss and last-position logits of the system, in
+    the compute type it trains in, against the float32 reference on the
+    same weights, for `probe_sequences` seeded sequences."""
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.jit.functional import param_arrays
+    tol = run.system["correct"]
+    n = int(tol["probe_sequences"])
+    pairs = [stream[stream.length - 1 - i] for i in range(n)]
+    ids = jnp.asarray(np.stack([p[0] for p in pairs]))
+    labels = jnp.asarray(np.stack([p[1] for p in pairs]))
+    params = param_arrays(model)
+    sys_fn = run.model.eval_loss_and_last_logits(model, run.system["amp_level"])
+    loss_s, last_s = sys_fn(params, ids, labels)
+    loss_r, last_r = reference.forward_and_loss(params, ids, labels)
+    d_loss = abs(float(loss_s) - float(loss_r))
+    d_logits = _rel_err(last_s, last_r)
+    run.notes["reference"] = {
+        "loss_system": float(loss_s), "loss_reference": float(loss_r),
+        "loss_abs_diff": d_loss, "last_logits_rel_err": d_logits}
+    run.check("reference_loss", np.isfinite(d_loss) and d_loss <= tol["loss_atol"],
+              f"|{float(loss_s):.5f} - {float(loss_r):.5f}| = {d_loss:.2e} "
+              f"(tol {tol['loss_atol']:g})")
+    run.check("reference_last_logits",
+              np.isfinite(d_logits) and d_logits <= tol["logits_rel_tol"],
+              f"max|diff|/max|ref| = {d_logits:.2e} "
+              f"(tol {tol['logits_rel_tol']:g})")
+
+
+def _stop_loader(it) -> None:
+    """Stop the workers now, and leave `__del__` nothing to do: at
+    interpreter exit it would `kill()` a native queue the collector has
+    already destroyed, and the process would die of a segmentation
+    fault after printing its result (PERF.md, Open questions)."""
+    it.inner._shutdown()
+    it.inner._native_q = None
+
+
+def run(run: Run, ledger, reference) -> None:
+    import jax
+    import numpy as np
+    import paddle_tpu as paddle
+    from paddle_tpu.ops import pallas as pallas_ops
+
+    mix, sysc, fam = run.mix, run.system, run.model
+    sz = fam.sizes(run.config, run.rehearse)
+    vocab = sz["padded_vocab_size"]
+    B = int(mix["batch"])
+    S = min(int(mix["seq"]), sz["n_positions"] // 4 if run.rehearse
+            else sz["n_positions"])
+    devices = jax.devices()[:run.chips]
+    pallas_ops.reset_pallas_stats()
+
+    t = time.perf_counter()
+    model = fam.build_model(run.config, run.seed, rehearse=run.rehearse,
+                            **sysc.get("model_overrides", {}))
+    jax.block_until_ready([p._data for p in model.parameters()])
+    n_params = sum(int(np.prod(p.shape)) for p in model.parameters())
+    say(f"  model built in {time.perf_counter() - t:.1f}s: {n_params / 1e6:.1f}M "
+        f"parameters, B={B} S={S}, {sysc.get('model_overrides', {})}")
+
+    stream = TokenStream(mix, vocab, S, run.seed)
+    t = time.perf_counter()
+    model.eval()
+    check_against_reference(run, model, reference, stream, vocab)
+    model.train()
+    say(f"  reference check took {time.perf_counter() - t:.1f}s")
+
+    kw = {}
+    if sysc.get("mesh"):
+        from jax.sharding import PartitionSpec as P
+        from paddle_tpu.distributed import fleet
+        strategy = fleet.DistributedStrategy()
+        strategy.hybrid_configs = dict(sysc["mesh"])
+        fleet.init(is_collective=True, strategy=strategy)
+        mesh = fleet.get_hybrid_communicate_group().mesh
+        if mesh.devices.size != run.chips:
+            raise BenchFailure(f"mesh spans {mesh.devices.size} devices, the "
+                               f"cell asks for {run.chips}")
+        say(f"  mesh {dict(mesh.shape)} over {[d.id for d in mesh.devices.flat]}")
+        kw = dict(mesh=mesh, data_spec=P(tuple(sysc["data_spec"])),
+                  zero_axis=sysc.get("zero_axis"))
+    o = sysc["optimizer"]
+    opt = getattr(paddle.optimizer, o["name"])(
+        learning_rate=o["learning_rate"], weight_decay=o["weight_decay"],
+        parameters=model.parameters())
+    step = paddle.jit.TrainStep(model, fam.make_loss_fn(sysc["amp_level"]),
+                                opt, **kw)
+
+    loader = paddle.io.DataLoader(stream, batch_size=B, shuffle=False,
+                                  num_workers=int(mix["num_workers"]))
+    it = iter(loader)
+    try:
+        workers = it.inner.workers
+        run.check("loader_workers", len(workers) == int(mix["num_workers"])
+                  and all(w.is_alive() for w in workers),
+                  f"{len(workers)} live workers")
+        for i in range(int(sysc["warm_steps"])):
+            t = time.perf_counter()
+            ids, labels = next(it)
+            loss = float(step(ids, labels))
+            say(f"  warm step {i + 1} loss {loss:.4f} "
+                f"({time.perf_counter() - t:.2f}s wall, blocking)")
+        _window(run, ledger, step, it, B * S)
+    finally:
+        _stop_loader(it)
+    run.check("loader_stopped", not multiprocessing.active_children(),
+              "no worker left behind")
+
+    (prog,) = step.aot_programs()
+    expect = sysc["expect"]
+    run.notes["aot"] = {"builds": prog.builds, "heals": prog.heals}
+    run.check("aot_program", prog.compiled is not None
+              and prog.heals <= expect["max_heals"],
+              f"builds {prog.builds} heals {prog.heals} "
+              f"(at most {expect['max_heals']})")
+    if not run.rehearse:
+        text = prog.compiled.as_text()
+        missing = [k for k in expect["kernels"] if k not in text]
+        run.check("kernels_in_program", not missing,
+                  f"expected {expect['kernels']}, missing {missing}")
+    fallbacks = {f"{k[0]}:{k[1]}": v for k, v in pallas_ops.PALLAS_STATS.items()}
+    allowed = set(expect["fallbacks"])
+    run.notes["pallas_fallbacks"] = fallbacks
+    if not run.rehearse:
+        run.check("no_unexpected_fallback", set(fallbacks) <= allowed,
+                  f"recorded {fallbacks}, the cell allows {sorted(allowed)}")
+    run.counts["hbm_peak_bytes"] = max(
+        int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+        for d in devices)
+    run.counts["flops_per_item"] = fam.flops_per_item(run.config, S)
+    run.counts["flash_flops_per_step"] = fam.flash_flops_per_step(
+        run.config, B, S)
+    run.counts["items_per_step"] = B * S
+
+
+def _window(run: Run, ledger, step, it, items_per_step: int) -> None:
+    import jax
+    import numpy as np
+    sysc = run.system
+    depth = int(sysc["inflight"])
+    trace_s = min(float(sysc["trace_seconds"]), run.seconds) if run.traced else 0.0
+    trace_dir = sysc["_trace_dir"]
+    losses, inflight = [], collections.deque()
+    window_span = None
+    snap0 = ledger.snap()
+    run.e2e["setup_s"] = time.perf_counter() - run.t_start
+    t0 = time.perf_counter()
+    while True:
+        now = time.perf_counter() - t0
+        if now >= run.seconds:
+            break
+        if run.traced and window_span is None and now >= run.seconds - trace_s:
+            jax.profiler.start_trace(trace_dir)
+            window_span = annotate(run, trace_reduce.WINDOW_SPAN)
+            window_span.__enter__()
+            run.counts["steps_before_trace"] = len(losses)
+        with Timed(run, "bench.input_wait"):
+            ids, labels = next(it)
+        with Timed(run, "bench.step_call"):
+            loss = step(ids, labels)
+        losses.append(loss)
+        inflight.append(loss)
+        if len(inflight) > depth:
+            with annotate(run, "bench.wait_step"):
+                inflight.popleft()._data.block_until_ready()
+    with annotate(run, "bench.wait_step"):
+        losses[-1]._data.block_until_ready()
+    t1 = time.perf_counter()
+    if window_span is not None:
+        window_span.__exit__(None, None, None)
+        jax.profiler.stop_trace()
+    snap1 = ledger.snap()
+
+    vals = [float(l) for l in losses]
+    n = len(vals)
+    run.attempted = n
+    run.failed = sum(1 for v in vals if not np.isfinite(v))
+    run.e2e["train_throughput"] = n * items_per_step / (t1 - t0)
+    run.counts["steps"] = n
+    run.counts["window_s"] = t1 - t0
+    k = max(1, min(10, n // 2))
+    first, last = float(np.mean(vals[:k])), float(np.mean(vals[-k:]))
+    run.notes["losses"] = {"steps": n, "first": vals[:3], "last": vals[-3:],
+                           f"mean_first_{k}": first, f"mean_last_{k}": last}
+    run.check("losses_finite", run.failed == 0, f"{n} steps")
+    run.check("loss_falls", n >= 2 and last < first,
+              f"mean of the last {k} {last:.4f} < mean of the first {k} {first:.4f}")
+    run.check("no_compile_in_window", snap1 == snap0,
+              f"(compiles, cache hits, cache misses) {snap0} -> {snap1}")
+    if run.traced:
+        devs, spans = trace_reduce.load(trace_reduce.find_xplane(trace_dir))
+        run.trace = trace_reduce.reduce(devs, spans)
