@@ -2,14 +2,22 @@
 (`benchmark/traffic/<traffic>.json`).
 
 Serving (`"kind": "serve"`). The lengths of a mix are a FIXED set — the
-quantiles of the stated distribution, `block` of them — and `--seed`
-only shuffles the order inside each block of `block` requests and draws
-the token ids. So every seed offers the same work in another order, and
-any prefix of a run holds nearly the same multiset of lengths. Arrivals:
+quantiles of the stated distribution, `block` of them — shuffled inside
+each block of `block` requests, so any prefix of a run holds nearly the
+same multiset of lengths. With `"order": "seed"` the shuffle is the
+seed's: every seed offers the same work in another order. With
+`"order": "fixed"` it is the mix's own (`shape_seed`) and `--seed` draws
+only the token ids and the weights: in a closed loop with greedy
+decoding and fixed reply lengths, which request sits in which slot at
+which step then does not depend on the seed at all, and a tail over some
+tens of requests is a tail of the same requests in every run. Arrivals:
 
   closed   `clients` callers, each sends its next request `think_s`
            after its last one finished; the first ones start staggered
-           over `stagger_s`
+           over `stagger_s`, and with `"first_reply": "staggered"` caller
+           i's first reply is cut to (i + 1) / clients of its length, so
+           that replies end at all phases from the start, as they do in
+           a loop that has run for long
   poisson  exponential gaps at `rate_rps`          } open loop: gaps are
   gamma    gamma gaps, squared CV = `burstiness`   } a fixed block too,
                                                      shuffled by the seed
@@ -96,11 +104,17 @@ class ServeTraffic:
         # which output length goes with which prompt length is part of
         # the mix, not of the seed
         pair = np.random.default_rng(mix.get("shape_seed", 0)).permutation(block)
-        order = _block_order(np.random.default_rng([self.seed, 1]), block, n)
+        fixed = mix.get("order", "seed") == "fixed"
+        order = _block_order(np.random.default_rng(
+            [mix.get("shape_seed", 0), 1] if fixed else [self.seed, 1]), block, n)
         self.prompt_len = plen[order]
         self.max_new = olen[pair][order]
         arrival = mix["arrival"]
         self.closed = arrival["mode"] == "closed"
+        if self.closed and arrival.get("first_reply") == "staggered":
+            c = int(arrival["clients"])
+            self.max_new[:c] = np.maximum(
+                1, -(-self.max_new[:c] * (np.arange(c) + 1) // c))
         self.dues = None
         if not self.closed:
             gaps = gap_set(arrival, block)[_block_order(

@@ -115,16 +115,33 @@ class Timed:
         return False
 
 
-def device_block(run: Run, devices) -> dict:
+def hbm_peak_bytes(run: Run, devices, programs) -> int:
+    """Peak HBM bytes on the fullest chip: the runtime allocator's
+    `peak_bytes_in_use` (arrays: weights, optimizer state, pools,
+    batches) plus the temporaries of the largest program that ran, from
+    `compiled.memory_analysis()`. The allocator's counter does not see a
+    program's temporaries (on the chip GPT-2 345M's train step read 4.31
+    GB, exactly its arguments, where the compiler counts 4.4 GB of
+    temporaries on top: my chip run, PR 25), so alone it understates
+    what the chip has to hold."""
+    arrays = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                 for d in devices)
+    temps = 0
+    for compiled in programs:
+        ma = compiled.memory_analysis() if compiled is not None else None
+        temps = max(temps, int(getattr(ma, "temp_size_in_bytes", 0) or 0))
+    run.notes["hbm"] = {"allocator_peak_bytes": arrays,
+                        "largest_program_temp_bytes": temps}
+    return arrays + temps
+
+
+def device_block(run: Run) -> dict:
     """The `device` object of the result line, as jax reports it."""
     import jax
-    peak = 0
-    for d in devices:
-        st = d.memory_stats() or {}
-        peak = max(peak, int(st.get("peak_bytes_in_use", 0)))
     dev = jax.devices()[0]
     out = {"platform": dev.platform, "kind": dev.device_kind,
-           "count": len(jax.devices()), "memory_peak_bytes": peak}
+           "count": len(jax.devices()),
+           "memory_peak_bytes": int(run.counts.get("hbm_peak_bytes", 0))}
     if run.traced and run.trace:
         out["busy_s"] = run.trace["busy_s"]
         out["window_s"] = run.trace["window_s"]

@@ -3,9 +3,12 @@ every cell, read with nothing but `jax.profiler.ProfileData`.
 
 What a TPU trace holds (looked at by hand, PR 25): one plane per chip,
 `/device:TPU:<n>`, whose line `XLA Ops` has one event per executed HLO
-instruction (a `while` covers the events of its body, so events nest),
-and `/host:CPU`, whose lines are host threads carrying the benchmark's
-own `jax.profiler.TraceAnnotation` spans (`bench.*`). All on one clock.
+instruction, named by the instruction's whole text (`%flash_fwd.19 =
+(bf16[...]) custom-call(...), custom_call_target="tpu_custom_call"`; a
+`while` covers the events of its body, so events nest), and whose line
+`XLA Modules` has one event per executed program (`jit_step(<id>)`); and
+`/host:CPU`, whose lines are host threads carrying the benchmark's own
+`jax.profiler.TraceAnnotation` spans (`bench.*`). All on one clock.
 
     busy      union of the op intervals of one chip inside the window
     self time an op's duration minus what its nested children cover
@@ -19,16 +22,29 @@ import glob
 import os
 import re
 from collections import defaultdict
-from typing import Dict, Iterable, List, Optional, Tuple
+from dataclasses import dataclass, field
+from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+MOSAIC = 'custom_call_target="tpu_custom_call"'
 WINDOW_SPAN = "bench.window"
 SPAN_PREFIX = "bench."
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
                "collective-permute", "all-to-all")
 
 Interval = Tuple[float, float, str]          # start_ns, end_ns, name
+
+
+def start(trace_dir: str) -> None:
+    """Start the profiler without its Python tracer: the device lines
+    and the `bench.*` annotations are all the reducer reads, and a
+    traced Python loop runs slower than the one that is measured."""
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -39,26 +55,50 @@ def find_xplane(trace_dir: str) -> str:
     return files[-1]
 
 
-def load(path: str) -> Tuple[Dict[int, List[Interval]], List[Interval]]:
-    """(device id -> op intervals, host `bench.*` spans) of a trace file."""
+@dataclass
+class Trace:
+    #: chip id -> op intervals, named by instruction (`flash_fwd.19`)
+    devices: Dict[int, List[Interval]] = field(default_factory=dict)
+    #: chip id -> program executions (`jit_step`)
+    modules: Dict[int, List[Interval]] = field(default_factory=dict)
+    #: host `bench.*` spans
+    spans: List[Interval] = field(default_factory=list)
+    #: names of the instructions that are Mosaic (Pallas) kernels
+    mosaic: Set[str] = field(default_factory=set)
+
+
+def op_name(text: str) -> str:
+    """`%flash_fwd.19 = (bf16[...]) custom-call(...)` -> `flash_fwd.19`.
+    Only the instruction's own name: its operands name other ops."""
+    return text.split(" = ", 1)[0].strip().lstrip("%")
+
+
+def load(path: str) -> Trace:
     import jax
     pd = jax.profiler.ProfileData.from_file(path)
-    devices: Dict[int, List[Interval]] = {}
-    spans: List[Interval] = []
+    tr = Trace()
     for plane in pd.planes:
         m = DEVICE_PLANE.match(plane.name)
         if m:
+            dev = int(m.group(1))
             for line in plane.lines:
                 if line.name == OPS_LINE:
-                    devices[int(m.group(1))] = [
-                        (e.start_ns, e.start_ns + e.duration_ns, e.name)
-                        for e in line.events]
+                    ops = tr.devices.setdefault(dev, [])
+                    for e in line.events:
+                        name = op_name(e.name)
+                        if MOSAIC in e.name:
+                            tr.mosaic.add(name)
+                        ops.append((e.start_ns, e.start_ns + e.duration_ns, name))
+                elif line.name == MODULES_LINE:
+                    tr.modules[dev] = [
+                        (e.start_ns, e.start_ns + e.duration_ns,
+                         e.name.split("(", 1)[0]) for e in line.events]
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                spans.extend((e.start_ns, e.start_ns + e.duration_ns, e.name)
-                             for e in line.events
-                             if e.name.startswith(SPAN_PREFIX))
-    return devices, spans
+                tr.spans.extend(
+                    (e.start_ns, e.start_ns + e.duration_ns, e.name)
+                    for e in line.events if e.name.startswith(SPAN_PREFIX))
+    return tr
 
 
 def clip(ivs: Iterable[Interval], lo: float, hi: float) -> List[Interval]:
@@ -125,17 +165,20 @@ def name_gap(gap: Tuple[float, float], spans: List[Interval]) -> str:
 
 
 def base_name(op: str) -> str:
-    """`fusion.123` -> `fusion`, `%flash_fwd.2` -> `flash_fwd`: the
+    """`fusion.123` -> `fusion`, `flash_fwd.2` -> `flash_fwd`: the
     instruction's name without its numbering."""
-    return re.sub(r"[.\d]+$", "", op.lstrip("%")) or op
+    return re.sub(r"[.\d]+$", "", op) or op
 
 
-def reduce(devices: Dict[int, List[Interval]], spans: List[Interval],
-           window: Optional[Tuple[float, float]] = None,
+def reduce(tr: Trace, window: Optional[Tuple[float, float]] = None,
            top: int = 10) -> Optional[dict]:
     """The traced window in numbers, or None when no device op ran.
-    Times in seconds; `by_op` is device 0's (lowest id) self time by
-    full instruction name, `busy_s` the mean over the chips."""
+    Times in seconds. `by_op` is chip 0's (lowest id) self time by
+    instruction name, `modules` its programs as name -> (executions
+    wholly inside the window, their summed seconds, seconds of all its
+    executions clipped to the window), `busy_s` the mean over the
+    chips."""
+    devices, spans = tr.devices, tr.spans
     if not devices or not any(devices.values()):
         return None
     if window is None:
@@ -148,32 +191,52 @@ def reduce(devices: Dict[int, List[Interval]], spans: List[Interval],
     lo, hi = window
     spans = clip(spans, lo, hi)
     first = min(devices)
-    busy_by_dev = {}
-    for dev, ivs in devices.items():
-        busy_by_dev[dev] = union(clip(ivs, lo, hi))
-    busy_s = [sum(e - s for s, e in b) / 1e9 for b in busy_by_dev.values()]
-    by_op_ns = self_times(clip(devices[first], lo, hi))
-    by_op = {n: ns / 1e9 for n, ns in by_op_ns.items()}
+    busy_by_dev = {dev: union(clip(ivs, lo, hi)) for dev, ivs in devices.items()}
+    busy_s = {dev: sum(e - s for s, e in b) / 1e9 for dev, b in busy_by_dev.items()}
+    by_op = {n: ns / 1e9
+             for n, ns in self_times(clip(devices[first], lo, hi)).items()}
     idle_by: Dict[str, float] = defaultdict(float)
     for g in gaps(busy_by_dev[first], lo, hi):
         idle_by[name_gap(g, spans)] += (g[1] - g[0]) / 1e9
     span_counts: Dict[str, int] = defaultdict(int)
     for _, _, n in spans:
         span_counts[n] += 1
+    modules: Dict[str, List[float]] = defaultdict(lambda: [0, 0.0, 0.0])
+    for s, e, n in tr.modules.get(first, []):
+        if s >= lo and e <= hi:
+            modules[n][0] += 1
+            modules[n][1] += (e - s) / 1e9
+        modules[n][2] += max(0.0, min(e, hi) - max(s, lo)) / 1e9
     by_base: Dict[str, float] = defaultdict(float)
-    for n, s in by_op.items():
-        by_base[base_name(n)] += s
-    rank = lambda d: [[k, v] for k, v in sorted(
-        d.items(), key=lambda kv: -kv[1])[:top]]
+    for n, sec in by_op.items():
+        by_base[base_name(n)] += sec
+
+    def rank(d):
+        return [[k, v] for k, v in sorted(d.items(), key=lambda kv: -kv[1])[:top]]
+
     return {
         "window_s": (hi - lo) / 1e9,
-        "busy_s": sum(busy_s) / len(busy_s),
-        "busy_s_device0": busy_s[sorted(devices).index(first)],
+        "busy_s": sum(busy_s.values()) / len(busy_s),
+        "busy_s_device0": busy_s[first],
         "by_op": by_op,
+        "mosaic_s": sum(sec for n, sec in by_op.items() if n in tr.mosaic),
+        "modules": {n: tuple(v) for n, v in modules.items()},
         "span_counts": dict(span_counts),
         "breakdown": {"device_ops": rank(by_base),
                       "idle_gaps": rank(idle_by)},
     }
+
+
+def main_program(summary: dict) -> Optional[Tuple[float, float]]:
+    """(seconds one execution of the window's largest program takes on
+    chip 0, how many executions the window held, parts counted as
+    parts) — a training step, whose in-flight executions straddle the
+    window's edges."""
+    whole = {n: m for n, m in summary["modules"].items() if m[0] > 0}
+    if not whole:
+        return None
+    count, total, clipped = max(whole.values(), key=lambda m: m[2])
+    return total / count, clipped / (total / count)
 
 
 def time_in(by_op: Dict[str, float], needles: Iterable[str]) -> float:
@@ -198,3 +261,15 @@ def describe(path: str, top: int = 25) -> str:
             for n, ns in sorted(tot.items(), key=lambda kv: -kv[1])[:top]:
                 out.append(f"      {ns / 1e6:10.3f} ms  {n[:110]}")
     return "\n".join(out)
+
+
+if __name__ == "__main__":
+    import json
+    import sys
+    xplane = find_xplane(sys.argv[1]) if os.path.isdir(sys.argv[1]) \
+        else sys.argv[1]
+    print(describe(xplane))
+    summary = reduce(load(xplane))
+    if summary:
+        summary.pop("by_op")
+        print(json.dumps(summary, indent=1))

@@ -17,7 +17,8 @@ import time
 
 from . import trace_reduce
 from .loadgen import TokenStream
-from .result import BenchFailure, Run, Timed, annotate, say
+from .result import (BenchFailure, Run, Timed, annotate, hbm_peak_bytes,
+                     say)
 
 
 def _rel_err(got, ref) -> float:
@@ -26,7 +27,7 @@ def _rel_err(got, ref) -> float:
     return float(jnp.max(jnp.abs(g - r)) / (jnp.max(jnp.abs(r)) + 1e-12))
 
 
-def check_against_reference(run: Run, model, reference, stream, vocab):
+def check_against_reference(run: Run, model, reference, stream):
     """Evaluation-mode loss and last-position logits of the system, in
     the compute type it trains in, against the float32 reference on the
     same weights, for `probe_sequences` seeded sequences."""
@@ -91,7 +92,7 @@ def run(run: Run, ledger, reference) -> None:
     stream = TokenStream(mix, vocab, S, run.seed)
     t = time.perf_counter()
     model.eval()
-    check_against_reference(run, model, reference, stream, vocab)
+    check_against_reference(run, model, reference, stream)
     model.train()
     say(f"  reference check took {time.perf_counter() - t:.1f}s")
 
@@ -154,9 +155,7 @@ def run(run: Run, ledger, reference) -> None:
     if not run.rehearse:
         run.check("no_unexpected_fallback", set(fallbacks) <= allowed,
                   f"recorded {fallbacks}, the cell allows {sorted(allowed)}")
-    run.counts["hbm_peak_bytes"] = max(
-        int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-        for d in devices)
+    run.counts["hbm_peak_bytes"] = hbm_peak_bytes(run, devices, [prog.compiled])
     run.counts["flops_per_item"] = fam.flops_per_item(run.config, S)
     run.counts["flash_flops_per_step"] = fam.flash_flops_per_step(
         run.config, B, S)
@@ -180,10 +179,9 @@ def _window(run: Run, ledger, step, it, items_per_step: int) -> None:
         if now >= run.seconds:
             break
         if run.traced and window_span is None and now >= run.seconds - trace_s:
-            jax.profiler.start_trace(trace_dir)
+            trace_reduce.start(trace_dir)
             window_span = annotate(run, trace_reduce.WINDOW_SPAN)
             window_span.__enter__()
-            run.counts["steps_before_trace"] = len(losses)
         with Timed(run, "bench.input_wait"):
             ids, labels = next(it)
         with Timed(run, "bench.step_call"):
@@ -218,5 +216,5 @@ def _window(run: Run, ledger, step, it, items_per_step: int) -> None:
     run.check("no_compile_in_window", snap1 == snap0,
               f"(compiles, cache hits, cache misses) {snap0} -> {snap1}")
     if run.traced:
-        devs, spans = trace_reduce.load(trace_reduce.find_xplane(trace_dir))
-        run.trace = trace_reduce.reduce(devs, spans)
+        run.trace = trace_reduce.reduce(
+            trace_reduce.load(trace_reduce.find_xplane(trace_dir)))

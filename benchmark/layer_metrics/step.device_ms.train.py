@@ -1,8 +1,8 @@
-"""Device busy milliseconds (union of op intervals, mean over the chips)
-per training step dispatched inside the traced window."""
+"""Device milliseconds one execution of the step program takes on chip 0
+(its `XLA Modules` events wholly inside the traced window, mean)."""
+from benchmark.harness import trace_reduce
 
 
 def read(run):
-    tr = run.trace
-    steps = tr and tr["span_counts"].get("bench.step_call")
-    return tr["busy_s"] / steps * 1e3 if steps else None
+    prog = run.trace and trace_reduce.main_program(run.trace)
+    return prog[0] * 1e3 if prog else None

@@ -71,6 +71,9 @@ def main() -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="toy widths on the CPU, kernels interpreted; never "
                          "prints the result line")
+    ap.add_argument("--keep-trace", default=None, metavar="DIR",
+                    help="with --trace 1: copy the profiler's files to DIR, "
+                         "to look at a trace by hand")
     args = ap.parse_args()
     faulthandler.enable()
     faulthandler.dump_traceback_later(DEADLINE_S, exit=True)
@@ -145,6 +148,9 @@ def main() -> int:
     finally:
         if run.traced:
             import shutil
+            if args.keep_trace and os.path.isdir(system["_trace_dir"]):
+                shutil.copytree(system["_trace_dir"], args.keep_trace,
+                                dirs_exist_ok=True)
             shutil.rmtree(system["_trace_dir"], ignore_errors=True)
 
     wanted = bench["per_layer"] if args.trace else bench["end_to_end"]
@@ -158,7 +164,7 @@ def main() -> int:
             value = run.e2e.get(m["name"])
         if value is not None:
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
-    device = result.device_block(run, jax.devices()[:cell["chips"]])
+    device = result.device_block(run)
     result.say(f"  compiles {ledger.compiles} ({ledger.compile_s:.1f}s), "
                f"persistent cache hits {ledger.hits} misses {ledger.misses}")
     result.say("  notes " + json.dumps(run.notes))
