@@ -26,6 +26,7 @@ class Run:
     traced: bool
     rehearse: bool
     t_start: float                # perf_counter at process start
+    trace_dir: str = ""           # where a --trace 1 run puts the profiler's files
     model: Any = None             # benchmark/models/<family>.py, imported
     #: end-to-end values by metric name
     e2e: Dict[str, float] = field(default_factory=dict)
@@ -51,6 +52,13 @@ class Run:
     @property
     def correct(self) -> bool:
         return bool(self.checks) and all(ok for _, ok, _ in self.checks)
+
+
+def rel_err(got, ref) -> float:
+    """max|got - ref| / max|ref| in float32, reduced on the device."""
+    import jax.numpy as jnp
+    g, r = got.astype(jnp.float32), ref.astype(jnp.float32)
+    return float(jnp.max(jnp.abs(g - r)) / (jnp.max(jnp.abs(r)) + 1e-12))
 
 
 def say(msg: str) -> None:
@@ -116,14 +124,15 @@ class Timed:
 
 
 def hbm_peak_bytes(run: Run, devices, programs) -> int:
-    """Peak HBM bytes on the fullest chip: the runtime allocator's
-    `peak_bytes_in_use` (arrays: weights, optimizer state, pools,
-    batches) plus the temporaries of the largest program that ran, from
-    `compiled.memory_analysis()`. The allocator's counter does not see a
-    program's temporaries (on the chip GPT-2 345M's train step read 4.31
-    GB, exactly its arguments, where the compiler counts 4.4 GB of
-    temporaries on top: my chip run, PR 25), so alone it understates
-    what the chip has to hold."""
+    """`memory_stats()["peak_bytes_in_use"]` of the fullest chip: the
+    arrays the process held at its peak (weights, optimizer state, pools,
+    batches). It does not see a program's temporaries — on the chip GPT-2
+    345M's train step read 4.31 GB, exactly its arguments (my chip run,
+    PR 25) — and they cannot simply be added: the serving programs'
+    `temp_size_in_bytes` (9.16 GB) on top of the 8.86 GB of arrays would
+    pass the chip's 15.75 GiB, and the cell runs. So the largest
+    program's temporaries, from `compiled.memory_analysis()`, are printed
+    beside it (`notes.hbm`) and not counted."""
     arrays = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                  for d in devices)
     temps = 0
@@ -132,7 +141,7 @@ def hbm_peak_bytes(run: Run, devices, programs) -> int:
         temps = max(temps, int(getattr(ma, "temp_size_in_bytes", 0) or 0))
     run.notes["hbm"] = {"allocator_peak_bytes": arrays,
                         "largest_program_temp_bytes": temps}
-    return arrays + temps
+    return arrays
 
 
 def device_block(run: Run) -> dict:

@@ -16,6 +16,7 @@ reported beside the latencies.
 
 from __future__ import annotations
 
+import gc
 import heapq
 import time
 from dataclasses import dataclass, field
@@ -23,7 +24,8 @@ from typing import List
 
 from . import trace_reduce
 from .loadgen import ServeTraffic, percentile
-from .result import BenchFailure, Run, Timed, annotate, hbm_peak_bytes, say
+from .result import (BenchFailure, Run, Timed, annotate, hbm_peak_bytes,
+                     rel_err, say)
 
 #: `--rehearse`: an engine and lengths a toy model on the CPU can serve
 _REHEARSE_ENGINE = dict(max_batch_slots=8, block_size=4, max_context_len=64,
@@ -85,7 +87,7 @@ def probe_against_reference(run: Run, eng, model, reference, vocab) -> None:
     got = replay(eng.params, pool, pool).astype(jnp.float32)
     full = np.concatenate([prompt, [nxt]])[None].astype(np.int32)
     ref = reference.forward(eng.params, jnp.asarray(full))[0, -1]
-    err = float(jnp.max(jnp.abs(got - ref)) / (jnp.max(jnp.abs(ref)) + 1e-12))
+    err = rel_err(got, ref)
     run.notes["reference"] = {
         "probe_prompt_len": plen, "logits_rel_err": err,
         "max_abs_ref_logit": float(jnp.max(jnp.abs(ref))),
@@ -165,7 +167,6 @@ def run(run: Run, ledger, reference) -> None:
 
 
 def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
-    import jax
     import numpy as np
     from paddle_tpu.serving import Request, SamplingParams
     from paddle_tpu.serving.resilience import ServerOverloaded
@@ -174,7 +175,7 @@ def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
     arrival = mix["arrival"]
     warm_s = float(mix["warm_s"]) if not run.rehearse else 2.0
     trace_s = min(float(sysc["trace_seconds"]), run.seconds) if run.traced else 0.0
-    trace_dir = sysc["_trace_dir"]
+    tracer = trace_reduce.WindowTrace(run.trace_dir) if run.traced else None
     clock = time.perf_counter
     recs: dict = {}
     pending: list = []                    # heap of due times (closed) / (due, k)
@@ -189,6 +190,11 @@ def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
         if traffic.closed and len(rec.times) == rec.max_new:
             heapq.heappush(pending, t + think)
 
+    # what set-up allocated is taken out of the collector's sight, so no
+    # full collection over the model's and jax's objects falls into the
+    # window (the loop allocates little, and it stays collectable)
+    gc.collect()
+    gc.freeze()
     t_start = clock()
     if traffic.closed:
         n = int(arrival["clients"]) if not run.rehearse else 8
@@ -201,7 +207,6 @@ def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
     t_w0 = t_start + warm_s
     t_end = t_w0 + run.seconds
     snap0 = summ0 = None
-    window_span = None
     t_trace0 = None
     while True:
         now = clock()
@@ -212,10 +217,8 @@ def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
             t_end = t_w0 + run.seconds
             snap0, summ0 = ledger.snap(), eng.metrics_summary()
             run.e2e["setup_s"] = now - run.t_start
-        if run.traced and window_span is None and now >= t_end - trace_s:
-            trace_reduce.start(trace_dir)
-            window_span = annotate(run, trace_reduce.WINDOW_SPAN)
-            window_span.__enter__()
+        if tracer and not tracer.started and now >= t_end - trace_s:
+            tracer.start()
             t_trace0 = clock()
         while pending and pending[0] <= now and next_k < len(traffic):
             due = heapq.heappop(pending)
@@ -240,9 +243,8 @@ def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
                 wait = (pending[0] - clock()) if pending else 0.005
                 time.sleep(min(max(wait, 0.0), 0.005))
     t_w1 = clock()
-    if window_span is not None:
-        window_span.__exit__(None, None, None)
-        jax.profiler.stop_trace()
+    if tracer and tracer.started:
+        run.trace = tracer.stop()
     snap1, summ1 = ledger.snap(), eng.metrics_summary()
     if snap0 is None:
         raise BenchFailure("the window never opened")
@@ -270,10 +272,14 @@ def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
     win = t_w1 - t_w0
     if tokens:
         run.e2e["serve_tokens_per_s"] = tokens / win
-    if ttft:
-        run.e2e["ttft_p95_ms"] = percentile(ttft, 95)
-    if gaps:
-        run.e2e["tpot_p95_ms"] = percentile(gaps, 95)
+    # every percentile a metric may name; BENCHMARK.json says which are
+    # end-to-end metrics and which stand beside them, per layer
+    for q in (50, 95):
+        if ttft:
+            run.e2e[f"ttft_p{q}_ms"] = percentile(ttft, q)
+    for q in (50, 90, 95, 99):
+        if gaps:
+            run.e2e[f"tpot_p{q}_ms"] = percentile(gaps, q)
     delta = lambda k: (summ1[k] or 0) - (summ0[k] or 0)
     lost = {k: delta(k) for k in ("requests_shed", "requests_failed",
                                   "requests_expired", "requests_cancelled",
@@ -292,11 +298,16 @@ def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
     run.counts["tokens"] = tokens
     run.counts["traced_decode_kv_bytes"] = (
         traced_kv_positions * run.counts["kv_bytes_per_token"])
+    steps_ms = [x * 1e3 for x in run.spans.get("bench.engine_step", [])]
     run.notes["client"] = {
         "requests_completed_in_window": done_in,
         "requests_per_s": done_in / win,
-        "ttft_samples": len(ttft), "ttft_p50_ms": percentile(ttft, 50),
-        "tpot_samples": len(gaps), "tpot_p50_ms": percentile(gaps, 50),
+        "ttft_samples": len(ttft), "tpot_samples": len(gaps),
+        "tpot_mean_ms": float(np.mean(gaps)) if gaps else None,
+        "gap_histogram_20ms": {int(k) * 20: int(v) for k, v in zip(
+            *np.unique(np.asarray(gaps) // 20, return_counts=True))},
+        "engine_step_p95_ms": percentile(steps_ms, 95),
+        "engine_step_max_ms": max(steps_ms) if steps_ms else None,
         "submit_late_p50_ms": percentile(late, 50),
         "submit_late_p95_ms": percentile(late, 95),
         "submit_late_max_ms": max(late) if late else None,
@@ -321,6 +332,3 @@ def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
               f"(compiles, cache hits, cache misses) {snap0} -> {snap1}")
     run.check("traffic_sufficed", next_k < len(traffic),
               f"{next_k} of {len(traffic)} requests of the mix were used")
-    if run.traced:
-        run.trace = trace_reduce.reduce(
-            trace_reduce.load(trace_reduce.find_xplane(trace_dir)))

@@ -37,14 +37,35 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
 Interval = Tuple[float, float, str]          # start_ns, end_ns, name
 
 
-def start(trace_dir: str) -> None:
-    """Start the profiler without its Python tracer: the device lines
-    and the `bench.*` annotations are all the reducer reads, and a
-    traced Python loop runs slower than the one that is measured."""
-    import jax
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    jax.profiler.start_trace(trace_dir, profiler_options=options)
+class WindowTrace:
+    """The profiler over the last part of a measured window: `start()`
+    from inside the runner's loop, `stop()` after it; what lies between
+    is the `bench.window` span every reduction is clipped to. The Python
+    tracer stays off: the device lines and the `bench.*` annotations are
+    all the reducer reads, and a traced Python loop runs slower than the
+    one that is measured."""
+
+    def __init__(self, trace_dir: str):
+        self.trace_dir = trace_dir
+        self.span = None
+
+    @property
+    def started(self) -> bool:
+        return self.span is not None
+
+    def start(self) -> None:
+        import jax
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(self.trace_dir, profiler_options=options)
+        self.span = jax.profiler.TraceAnnotation(WINDOW_SPAN)
+        self.span.__enter__()
+
+    def stop(self) -> Optional[dict]:
+        import jax
+        self.span.__exit__(None, None, None)
+        jax.profiler.stop_trace()
+        return reduce(load(find_xplane(self.trace_dir)))
 
 
 def find_xplane(trace_dir: str) -> str:

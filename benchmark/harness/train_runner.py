@@ -18,13 +18,7 @@ import time
 from . import trace_reduce
 from .loadgen import TokenStream
 from .result import (BenchFailure, Run, Timed, annotate, hbm_peak_bytes,
-                     say)
-
-
-def _rel_err(got, ref) -> float:
-    import jax.numpy as jnp
-    g, r = got.astype(jnp.float32), ref.astype(jnp.float32)
-    return float(jnp.max(jnp.abs(g - r)) / (jnp.max(jnp.abs(r)) + 1e-12))
+                     rel_err, say)
 
 
 def check_against_reference(run: Run, model, reference, stream):
@@ -44,7 +38,7 @@ def check_against_reference(run: Run, model, reference, stream):
     loss_s, last_s = sys_fn(params, ids, labels)
     loss_r, last_r = reference.forward_and_loss(params, ids, labels)
     d_loss = abs(float(loss_s) - float(loss_r))
-    d_logits = _rel_err(last_s, last_r)
+    d_logits = rel_err(last_s, last_r)
     run.notes["reference"] = {
         "loss_system": float(loss_s), "loss_reference": float(loss_r),
         "loss_abs_diff": d_loss, "last_logits_rel_err": d_logits}
@@ -163,14 +157,12 @@ def run(run: Run, ledger, reference) -> None:
 
 
 def _window(run: Run, ledger, step, it, items_per_step: int) -> None:
-    import jax
     import numpy as np
     sysc = run.system
     depth = int(sysc["inflight"])
     trace_s = min(float(sysc["trace_seconds"]), run.seconds) if run.traced else 0.0
-    trace_dir = sysc["_trace_dir"]
+    tracer = trace_reduce.WindowTrace(run.trace_dir) if run.traced else None
     losses, inflight = [], collections.deque()
-    window_span = None
     snap0 = ledger.snap()
     run.e2e["setup_s"] = time.perf_counter() - run.t_start
     t0 = time.perf_counter()
@@ -178,10 +170,8 @@ def _window(run: Run, ledger, step, it, items_per_step: int) -> None:
         now = time.perf_counter() - t0
         if now >= run.seconds:
             break
-        if run.traced and window_span is None and now >= run.seconds - trace_s:
-            trace_reduce.start(trace_dir)
-            window_span = annotate(run, trace_reduce.WINDOW_SPAN)
-            window_span.__enter__()
+        if tracer and not tracer.started and now >= run.seconds - trace_s:
+            tracer.start()
         with Timed(run, "bench.input_wait"):
             ids, labels = next(it)
         with Timed(run, "bench.step_call"):
@@ -194,9 +184,8 @@ def _window(run: Run, ledger, step, it, items_per_step: int) -> None:
     with annotate(run, "bench.wait_step"):
         losses[-1]._data.block_until_ready()
     t1 = time.perf_counter()
-    if window_span is not None:
-        window_span.__exit__(None, None, None)
-        jax.profiler.stop_trace()
+    if tracer and tracer.started:
+        run.trace = tracer.stop()
     snap1 = ledger.snap()
 
     vals = [float(l) for l in losses]
@@ -215,6 +204,3 @@ def _window(run: Run, ledger, step, it, items_per_step: int) -> None:
               f"mean of the last {k} {last:.4f} < mean of the first {k} {first:.4f}")
     run.check("no_compile_in_window", snap1 == snap0,
               f"(compiles, cache hits, cache misses) {snap0} -> {snap1}")
-    if run.traced:
-        run.trace = trace_reduce.reduce(
-            trace_reduce.load(trace_reduce.find_xplane(trace_dir)))

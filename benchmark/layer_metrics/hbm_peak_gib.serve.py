@@ -1,7 +1,7 @@
-"""Peak HBM on the fullest chip after the window, GiB: the allocator's
-`peak_bytes_in_use` plus the temporaries of the largest program that ran
-(harness/result.py `hbm_peak_bytes`). One process per run, so it is this
-cell's peak."""
+"""`memory_stats()["peak_bytes_in_use"]` of the fullest chip after the
+window, GiB: the arrays the process held at its peak. A program's
+temporaries are not in it (harness/result.py `hbm_peak_bytes`). One
+process per run, so it is this cell's peak."""
 
 
 def read(run):

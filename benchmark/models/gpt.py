@@ -109,6 +109,5 @@ def flash_flops_per_step(config: dict, batch: int, seq: int) -> float:
 
 def kv_bytes_per_token(config: dict, cache_dtype: str) -> int:
     """K and V bytes one cached position holds across all layers."""
-    import numpy as np
-    itemsize = 2 if cache_dtype == "bfloat16" else np.dtype(cache_dtype).itemsize
-    return 2 * config["n_layer"] * config["n_embd"] * itemsize
+    import jax.numpy as jnp
+    return 2 * config["n_layer"] * config["n_embd"] * jnp.dtype(cache_dtype).itemsize

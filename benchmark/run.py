@@ -132,12 +132,13 @@ def main() -> int:
                f"{time.perf_counter() - T_START:.1f}s")
     if not args.rehearse:
         peaks.peaks_for(dev.device_kind)          # unknown chip: an error
-    system["_trace_dir"] = os.path.join(
-        ROOT, ".bench_trace", f"{cell['name']}.{os.getpid()}")
+    trace_dir = os.path.join(ROOT, ".bench_trace",
+                             f"{cell['name']}.{os.getpid()}")
     run = result.Run(
         cell=cell["name"], config=config, mix=mix, system=system,
         chips=cell["chips"], seed=args.seed, seconds=seconds,
         traced=bool(args.trace), rehearse=args.rehearse, t_start=T_START,
+        trace_dir=trace_dir,
         model=load_module("models", config["model"]),
         device_kind=dev.device_kind)
     reference = load_module("reference", run.model.REFERENCE)
@@ -148,10 +149,11 @@ def main() -> int:
     finally:
         if run.traced:
             import shutil
-            if args.keep_trace and os.path.isdir(system["_trace_dir"]):
-                shutil.copytree(system["_trace_dir"], args.keep_trace,
-                                dirs_exist_ok=True)
-            shutil.rmtree(system["_trace_dir"], ignore_errors=True)
+            if args.keep_trace and os.path.isdir(trace_dir):
+                shutil.copytree(trace_dir, args.keep_trace, dirs_exist_ok=True)
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            if not os.listdir(os.path.dirname(trace_dir)):
+                os.rmdir(os.path.dirname(trace_dir))
 
     wanted = bench["per_layer"] if args.trace else bench["end_to_end"]
     metrics = {}
