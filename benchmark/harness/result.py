@@ -26,6 +26,7 @@ class Run:
     traced: bool
     rehearse: bool
     t_start: float                # perf_counter at process start
+    deadline: float               # perf_counter by which the run has to end
     trace_dir: str = ""           # where a --trace 1 run puts the profiler's files
     model: Any = None             # benchmark/models/<family>.py, imported
     #: end-to-end values by metric name
@@ -93,6 +94,15 @@ class CompileLedger:
         return (self.compiles, self.hits, self.misses)
 
 
+def arm_deadline(run: Run) -> None:
+    """A run that hangs dumps every thread's stack and dies at
+    `run.deadline` (faulthandler keeps one timer: whoever borrows it for
+    a shorter watch arms this one again)."""
+    import faulthandler
+    faulthandler.dump_traceback_later(
+        max(1.0, run.deadline - time.perf_counter()), exit=True)
+
+
 def annotate(run: Run, name: str):
     """A `bench.*` span in the profiler's own trace when the run is
     traced, nothing otherwise."""
@@ -116,32 +126,62 @@ class Timed:
         return self
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self.t0
+        self.seconds = time.perf_counter() - self.t0
         self.ann.__exit__(*exc)
         if self.keep:
-            self.run.spans.setdefault(self.name, []).append(dt)
+            self.run.spans.setdefault(self.name, []).append(self.seconds)
         return False
 
 
-def hbm_peak_bytes(run: Run, devices, programs) -> int:
-    """`memory_stats()["peak_bytes_in_use"]` of the fullest chip: the
-    arrays the process held at its peak (weights, optimizer state, pools,
-    batches). It does not see a program's temporaries — on the chip GPT-2
-    345M's train step read 4.31 GB, exactly its arguments (my chip run,
-    PR 25) — and they cannot simply be added: the serving programs'
-    `temp_size_in_bytes` (9.16 GB) on top of the 8.86 GB of arrays would
-    pass the chip's 15.75 GiB, and the cell runs. So the largest
-    program's temporaries, from `compiled.memory_analysis()`, are printed
-    beside it (`notes.hbm`) and not counted."""
-    arrays = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-                 for d in devices)
+def hbm_read(devices) -> list:
+    """(held, peak held) bytes of each chip now, as its allocator reports
+    them: held = `bytes_in_use` (the arrays of the process: weights,
+    optimizer state, pools, batches) + `bytes_reserved` (what the runtime
+    has set aside for the temporaries of the loaded programs, which no
+    array can take). On the chip the two and the largest free block add
+    up to `bytes_limit` within 30 MB in both one-chip cells (my chip
+    runs, PR 25). The peak is the sum of the two peaks; the reservation
+    does not move once every program has run."""
+    out = []
+    for d in devices:
+        st = d.memory_stats() or {}
+        out.append((int(st.get("bytes_in_use", 0)) + int(st.get("bytes_reserved", 0)),
+                    int(st.get("peak_bytes_in_use", 0))
+                    + int(st.get("peak_bytes_reserved", 0))))
+    return out
+
+
+def hbm_account(run: Run, devices, opened: list, programs) -> None:
+    """Call when the window closes, with `hbm_read` from when it opened.
+
+    `hbm_peak_bytes`: the peak held on the fullest chip over the whole
+    process, set-up and its checks included — the result line's
+    `memory_peak_bytes`. `hbm_window_bytes`: the fullest chip's figure
+    for the WINDOW — the peak if the window raised it, else the larger
+    of what was held when it opened and when it closed (the peak cannot
+    be reset, and a set-up that builds a model on one chip before
+    placing it on a mesh, or gathers it there for the reference, leaves
+    a peak no step ever reaches). The compiler's own count of the
+    largest program's temporaries (`compiled.memory_analysis()`) and the
+    allocator's whole reading are printed beside them (`notes.hbm`)."""
+    closed = hbm_read(devices)
+    run.counts["hbm_peak_bytes"] = max(p for _, p in closed)
+    run.counts["hbm_window_bytes"] = max(
+        p1 if p1 > p0 else max(u0, u1)
+        for (u0, p0), (u1, p1) in zip(opened, closed))
     temps = 0
     for compiled in programs:
         ma = compiled.memory_analysis() if compiled is not None else None
         temps = max(temps, int(getattr(ma, "temp_size_in_bytes", 0) or 0))
-    run.notes["hbm"] = {"allocator_peak_bytes": arrays,
-                        "largest_program_temp_bytes": temps}
-    return arrays
+    run.notes["hbm"] = {
+        "process_peak_bytes": run.counts["hbm_peak_bytes"],
+        "window_bytes": run.counts["hbm_window_bytes"],
+        "largest_program_temp_bytes": temps,
+        "per_chip_held_open_close_peak": [
+            [u0, u1, p1] for (u0, _), (u1, p1) in zip(opened, closed)],
+        "per_chip_memory_stats": [
+            {k: int(v) for k, v in (d.memory_stats() or {}).items()}
+            for d in devices]}
 
 
 def device_block(run: Run) -> dict:

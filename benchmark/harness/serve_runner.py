@@ -16,22 +16,29 @@ reported beside the latencies.
 
 from __future__ import annotations
 
+import faulthandler
 import gc
 import heapq
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import List
 
 from . import trace_reduce
 from .loadgen import ServeTraffic, percentile
-from .result import (BenchFailure, Run, Timed, annotate, hbm_peak_bytes,
-                     rel_err, say)
+from .result import (BenchFailure, Run, Timed, annotate, arm_deadline,
+                     hbm_account, hbm_read, rel_err, say)
 
 #: `--rehearse`: an engine and lengths a toy model on the CPU can serve
 _REHEARSE_ENGINE = dict(max_batch_slots=8, block_size=4, max_context_len=64,
                         prefill_buckets=(8, 32), batch_buckets=(1, 4))
 _REHEARSE_SCALE = dict(prompt_div=24, prompt_max=32, output_div=16,
                        output_max=16)
+#: an `engine.step()` that takes longer than this has every thread's
+#: stack written to stderr while it is stuck, so a stall can be named
+#: (the longest ordinary step, a decode behind prefills, takes 0.56 s;
+#: the stalls seen took 1.4 to 6 s: PERF.md, PR 25)
+STALL_S = 1.0
 
 
 @dataclass
@@ -147,7 +154,7 @@ def run(run: Run, ledger, reference) -> None:
                                "and the engine's disagree")
         traffic = ServeTraffic(mix, vocab, run.seed,
                                _REHEARSE_SCALE if run.rehearse else None)
-        _drive(run, ledger, eng, traffic)
+        _drive(run, ledger, eng, traffic, jax.devices()[:run.chips])
 
         row = {r["kernel"]: r for r in pallas_ops.kernels()}["paged_decode"]
         fallbacks = {f"{k[0]}:{k[1]}": v
@@ -159,14 +166,11 @@ def run(run: Run, ledger, reference) -> None:
             run.check("no_unexpected_fallback",
                       set(fallbacks) <= set(sysc["expect"]["fallbacks"]),
                       f"recorded {fallbacks}")
-        run.counts["hbm_peak_bytes"] = hbm_peak_bytes(
-            run, jax.devices()[:run.chips],
-            [p.compiled for p in eng._programs.values()])
     finally:
         eng.shutdown()
 
 
-def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
+def _drive(run: Run, ledger, eng, traffic: ServeTraffic, devices) -> None:
     import numpy as np
     from paddle_tpu.serving import Request, SamplingParams
     from paddle_tpu.serving.resilience import ServerOverloaded
@@ -190,11 +194,7 @@ def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
         if traffic.closed and len(rec.times) == rec.max_new:
             heapq.heappush(pending, t + think)
 
-    # what set-up allocated is taken out of the collector's sight, so no
-    # full collection over the model's and jax's objects falls into the
-    # window (the loop allocates little, and it stays collectable)
-    gc.collect()
-    gc.freeze()
+    slow_steps: list = []                 # (seconds into the window, wall, cpu)
     t_start = clock()
     if traffic.closed:
         n = int(arrival["clients"]) if not run.rehearse else 8
@@ -206,7 +206,7 @@ def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
             heapq.heappush(pending, t_start + float(due))
     t_w0 = t_start + warm_s
     t_end = t_w0 + run.seconds
-    snap0 = summ0 = None
+    snap0 = summ0 = hbm0 = gc0 = None
     t_trace0 = None
     while True:
         now = clock()
@@ -216,6 +216,8 @@ def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
             t_w0 = now
             t_end = t_w0 + run.seconds
             snap0, summ0 = ledger.snap(), eng.metrics_summary()
+            hbm0 = hbm_read(devices)
+            gc0 = [g["collections"] for g in gc.get_stats()]
             run.e2e["setup_s"] = now - run.t_start
         if tracer and not tracer.started and now >= t_end - trace_s:
             tracer.start()
@@ -236,18 +238,26 @@ def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
                     rejected += 1
             next_k += 1
         if eng.scheduler.has_work:
-            with Timed(run, "bench.engine_step", keep=snap0 is not None):
+            faulthandler.dump_traceback_later(STALL_S, file=sys.stderr)
+            cpu0 = time.thread_time()
+            with Timed(run, "bench.engine_step", keep=snap0 is not None) as span:
                 eng.step()
+            arm_deadline(run)
+            if span.seconds > STALL_S:
+                slow_steps.append((span.t0 - t_w0, span.seconds,
+                                   time.thread_time() - cpu0))
         else:
             with annotate(run, "bench.idle_sleep"):
                 wait = (pending[0] - clock()) if pending else 0.005
                 time.sleep(min(max(wait, 0.0), 0.005))
     t_w1 = clock()
+    gc1 = [g["collections"] for g in gc.get_stats()]
+    if snap0 is None:
+        raise BenchFailure("the window never opened")
+    hbm_account(run, devices, hbm0, [p.compiled for p in eng._programs.values()])
     if tracer and tracer.started:
         run.trace = tracer.stop()
     snap1, summ1 = ledger.snap(), eng.metrics_summary()
-    if snap0 is None:
-        raise BenchFailure("the window never opened")
 
     # -- what the clients saw inside [t_w0, t_w1] ---------------------------
     inside = lambda t: t_w0 <= t <= t_w1
@@ -308,6 +318,15 @@ def _drive(run: Run, ledger, eng, traffic: ServeTraffic) -> None:
             *np.unique(np.asarray(gaps) // 20, return_counts=True))},
         "engine_step_p95_ms": percentile(steps_ms, 95),
         "engine_step_max_ms": max(steps_ms) if steps_ms else None,
+        # steps of over STALL_S, warm phase included (negative times): when,
+        # how long, and how much of it this thread spent on a CPU (near
+        # all: host work; near none: it waited, for the chip or a lock)
+        "engine_steps_stalled": [
+            {"at_s": at, "wall_s": wall, "thread_cpu_s": cpu}
+            for at, wall, cpu in slow_steps],
+        # the collector runs as in any process (nothing is frozen): its
+        # passes inside the window, youngest generation first
+        "gc_collections_in_window": [c1 - c0 for c0, c1 in zip(gc0, gc1)],
         "submit_late_p50_ms": percentile(late, 50),
         "submit_late_p95_ms": percentile(late, 95),
         "submit_late_max_ms": max(late) if late else None,

@@ -1,11 +1,16 @@
 """Runner `train`: `paddle.jit.TrainStep` fed by `paddle.io.DataLoader`.
 
-Set-up (counted in `setup_s`): the model with weights from `--seed`, its
-agreement with the plain reference, the step program compiled or loaded
-from the cache (on a ZeRO mesh the second step re-lowers: that too),
-`warm_steps` blocking steps. Window: steps dispatched back to back with
-at most `inflight` not yet finished, for `--seconds`; the last one ends
-by `block_until_ready`, and the rate is taken over all steps and all of
+The runner loops and times. What is particular to a model family — its
+dataset, what an item is, its loss, its evaluation function, the counts
+its kernels' readers need — comes from `benchmark/models/<family>.py`.
+
+Set-up (counted in `setup_s`): the model with weights from `--seed`, the
+step program compiled or loaded from the cache (on a ZeRO mesh the
+second step re-lowers: that too), `warm_steps` blocking steps, then the
+system's agreement with the plain reference on the weights as the step
+holds them. Window: steps dispatched back to back with at most
+`inflight` not yet finished, for `--seconds`; the last one ends by
+`block_until_ready`, and the rate is taken over all steps and all of
 the time up to there.
 """
 
@@ -16,39 +21,53 @@ import multiprocessing
 import time
 
 from . import trace_reduce
-from .loadgen import TokenStream
-from .result import (BenchFailure, Run, Timed, annotate, hbm_peak_bytes,
+from .result import (BenchFailure, Run, Timed, annotate, hbm_account, hbm_read,
                      rel_err, say)
 
 
-def check_against_reference(run: Run, model, reference, stream):
-    """Evaluation-mode loss and last-position logits of the system, in
-    the compute type it trains in, against the float32 reference on the
-    same weights, for `probe_sequences` seeded sequences."""
+def check_against_reference(run: Run, model, step, reference, data, devices):
+    """Evaluation-mode loss and outputs of the system, in the compute
+    type it trains in, against the float32 reference on the same
+    weights, for `probe_sequences` seeded samples.
+
+    The weights are the step's own after the warm steps, WHERE it holds
+    them: in a mesh cell sharded over the chips, so the forward that is
+    compared runs the tensor-parallel products with their all-reduces
+    and flash attention per shard, on weights that have been through the
+    partitioned update. The reference runs on one chip, on a copy of the
+    same weights gathered there."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
     from paddle_tpu.jit.functional import param_arrays
     tol = run.system["correct"]
-    n = int(tol["probe_sequences"])
-    pairs = [stream[stream.length - 1 - i] for i in range(n)]
-    ids = jnp.asarray(np.stack([p[0] for p in pairs]))
-    labels = jnp.asarray(np.stack([p[1] for p in pairs]))
+    samples = [data[len(data) - 1 - i] for i in range(int(tol["probe_sequences"]))]
+    batch = [np.stack(col) for col in zip(*samples)]
+    step.sync_to_layer()
     params = param_arrays(model)
-    sys_fn = run.model.eval_loss_and_last_logits(model, run.system["amp_level"])
-    loss_s, last_s = sys_fn(params, ids, labels)
-    loss_r, last_r = reference.forward_and_loss(params, ids, labels)
-    d_loss = abs(float(loss_s) - float(loss_r))
-    d_logits = rel_err(last_s, last_r)
+    spread = sorted({len(a.sharding.device_set) for a in params.values()})
+    sys_fn = run.model.eval_loss_and_outputs(model, run.system["amp_level"])
+    loss_s, out_s = sys_fn(params, *batch)
+    loss_s, out_s = float(loss_s), np.asarray(out_s, np.float32)
+    gathered = {k: jax.device_put(a, devices[0]) for k, a in params.items()}
+    loss_r, out_r = reference.forward_and_loss(
+        gathered, *(jnp.asarray(b) for b in batch))
+    d_loss = abs(loss_s - float(loss_r))
+    d_out = rel_err(jnp.asarray(out_s), out_r)
     run.notes["reference"] = {
-        "loss_system": float(loss_s), "loss_reference": float(loss_r),
-        "loss_abs_diff": d_loss, "last_logits_rel_err": d_logits}
+        "loss_system": loss_s, "loss_reference": float(loss_r),
+        "loss_abs_diff": d_loss, "outputs_rel_err": d_out,
+        "chips_a_weight_spans": spread}
+    if run.chips > 1:
+        run.check("reference_across_chips", spread[-1] == run.chips,
+                  f"the compared forward ran on weights spanning {spread} chips")
     run.check("reference_loss", np.isfinite(d_loss) and d_loss <= tol["loss_atol"],
-              f"|{float(loss_s):.5f} - {float(loss_r):.5f}| = {d_loss:.2e} "
+              f"|{loss_s:.5f} - {float(loss_r):.5f}| = {d_loss:.2e} "
               f"(tol {tol['loss_atol']:g})")
-    run.check("reference_last_logits",
-              np.isfinite(d_logits) and d_logits <= tol["logits_rel_tol"],
-              f"max|diff|/max|ref| = {d_logits:.2e} "
-              f"(tol {tol['logits_rel_tol']:g})")
+    run.check("reference_outputs",
+              np.isfinite(d_out) and d_out <= tol["outputs_rel_tol"],
+              f"max|diff|/max|ref| = {d_out:.2e} "
+              f"(tol {tol['outputs_rel_tol']:g})")
 
 
 def _stop_loader(it) -> None:
@@ -67,11 +86,6 @@ def run(run: Run, ledger, reference) -> None:
     from paddle_tpu.ops import pallas as pallas_ops
 
     mix, sysc, fam = run.mix, run.system, run.model
-    sz = fam.sizes(run.config, run.rehearse)
-    vocab = sz["padded_vocab_size"]
-    B = int(mix["batch"])
-    S = min(int(mix["seq"]), sz["n_positions"] // 4 if run.rehearse
-            else sz["n_positions"])
     devices = jax.devices()[:run.chips]
     pallas_ops.reset_pallas_stats()
 
@@ -80,15 +94,11 @@ def run(run: Run, ledger, reference) -> None:
                             **sysc.get("model_overrides", {}))
     jax.block_until_ready([p._data for p in model.parameters()])
     n_params = sum(int(np.prod(p.shape)) for p in model.parameters())
+    data = fam.dataset(run.config, mix, run.seed, rehearse=run.rehearse)
+    items_per_step = fam.items_per_step(run.config, mix, rehearse=run.rehearse)
     say(f"  model built in {time.perf_counter() - t:.1f}s: {n_params / 1e6:.1f}M "
-        f"parameters, B={B} S={S}, {sysc.get('model_overrides', {})}")
-
-    stream = TokenStream(mix, vocab, S, run.seed)
-    t = time.perf_counter()
-    model.eval()
-    check_against_reference(run, model, reference, stream)
-    model.train()
-    say(f"  reference check took {time.perf_counter() - t:.1f}s")
+        f"parameters, {items_per_step} {run.config['item']}s a step, "
+        f"{sysc.get('model_overrides', {})}")
 
     kw = {}
     if sysc.get("mesh"):
@@ -111,7 +121,7 @@ def run(run: Run, ledger, reference) -> None:
     step = paddle.jit.TrainStep(model, fam.make_loss_fn(sysc["amp_level"]),
                                 opt, **kw)
 
-    loader = paddle.io.DataLoader(stream, batch_size=B, shuffle=False,
+    loader = paddle.io.DataLoader(data, batch_size=int(mix["batch"]), shuffle=False,
                                   num_workers=int(mix["num_workers"]))
     it = iter(loader)
     try:
@@ -121,11 +131,13 @@ def run(run: Run, ledger, reference) -> None:
                   f"{len(workers)} live workers")
         for i in range(int(sysc["warm_steps"])):
             t = time.perf_counter()
-            ids, labels = next(it)
-            loss = float(step(ids, labels))
+            loss = float(step(*next(it)))
             say(f"  warm step {i + 1} loss {loss:.4f} "
                 f"({time.perf_counter() - t:.2f}s wall, blocking)")
-        _window(run, ledger, step, it, B * S)
+        t = time.perf_counter()
+        check_against_reference(run, model, step, reference, data, devices)
+        say(f"  reference check took {time.perf_counter() - t:.1f}s")
+        _window(run, ledger, step, it, items_per_step, devices)
     finally:
         _stop_loader(it)
     run.check("loader_stopped", not multiprocessing.active_children(),
@@ -149,14 +161,11 @@ def run(run: Run, ledger, reference) -> None:
     if not run.rehearse:
         run.check("no_unexpected_fallback", set(fallbacks) <= allowed,
                   f"recorded {fallbacks}, the cell allows {sorted(allowed)}")
-    run.counts["hbm_peak_bytes"] = hbm_peak_bytes(run, devices, [prog.compiled])
-    run.counts["flops_per_item"] = fam.flops_per_item(run.config, S)
-    run.counts["flash_flops_per_step"] = fam.flash_flops_per_step(
-        run.config, B, S)
-    run.counts["items_per_step"] = B * S
+    run.counts.update(fam.step_counts(run.config, mix, rehearse=run.rehearse))
+    run.counts["items_per_step"] = items_per_step
 
 
-def _window(run: Run, ledger, step, it, items_per_step: int) -> None:
+def _window(run: Run, ledger, step, it, items_per_step: int, devices) -> None:
     import numpy as np
     sysc = run.system
     depth = int(sysc["inflight"])
@@ -164,6 +173,7 @@ def _window(run: Run, ledger, step, it, items_per_step: int) -> None:
     tracer = trace_reduce.WindowTrace(run.trace_dir) if run.traced else None
     losses, inflight = [], collections.deque()
     snap0 = ledger.snap()
+    hbm0 = hbm_read(devices)
     run.e2e["setup_s"] = time.perf_counter() - run.t_start
     t0 = time.perf_counter()
     while True:
@@ -173,9 +183,9 @@ def _window(run: Run, ledger, step, it, items_per_step: int) -> None:
         if tracer and not tracer.started and now >= run.seconds - trace_s:
             tracer.start()
         with Timed(run, "bench.input_wait"):
-            ids, labels = next(it)
+            batch = next(it)
         with Timed(run, "bench.step_call"):
-            loss = step(ids, labels)
+            loss = step(*batch)
         losses.append(loss)
         inflight.append(loss)
         if len(inflight) > depth:
@@ -184,6 +194,7 @@ def _window(run: Run, ledger, step, it, items_per_step: int) -> None:
     with annotate(run, "bench.wait_step"):
         losses[-1]._data.block_until_ready()
     t1 = time.perf_counter()
+    hbm_account(run, devices, hbm0, [p.compiled for p in step.aot_programs()])
     if tracer and tracer.started:
         run.trace = tracer.stop()
     snap1 = ledger.snap()

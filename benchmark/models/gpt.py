@@ -59,10 +59,12 @@ def make_loss_fn(amp_level: str):
     return loss_fn
 
 
-def eval_loss_and_last_logits(model, amp_level: str):
+def eval_loss_and_outputs(model, amp_level: str):
     """The system's side of the train cells' `correct`: evaluation-mode
-    loss and last-position logits, in the compute type it trains in, as
-    one jitted function of (params, ids, labels)."""
+    loss and the outputs that are compared (for this family the logits
+    at the last position), in the compute type it trains in, as one
+    jitted function of (params, *batch); the reference's
+    `forward_and_loss(params, *batch)` returns the same pair."""
     import jax
     import paddle_tpu as paddle
     from paddle_tpu.core.tensor import Tensor
@@ -77,6 +79,36 @@ def eval_loss_and_last_logits(model, amp_level: str):
             loss = crit(Tensor(logits), Tensor(labels))
         return loss._data, logits[:, -1]
     return fn
+
+
+# -- what a trainer of this family feeds on -----------------------------------
+
+def _seq(config: dict, mix: dict, rehearse: bool) -> int:
+    n_pos = sizes(config, rehearse)["n_positions"]
+    return min(int(mix["seq"]), n_pos // 4 if rehearse else n_pos)
+
+
+def dataset(config: dict, mix: dict, seed: int, *, rehearse: bool = False):
+    """Map-style dataset of one sample of a train mix, a tuple of
+    arrays (here ids[S], labels[S]); `paddle.io.DataLoader` batches it
+    and the runner hands a batch to the step, the evaluation function
+    and the reference as `*batch`."""
+    from benchmark.harness.loadgen import TokenStream
+    return TokenStream(mix, sizes(config, rehearse)["padded_vocab_size"],
+                       _seq(config, mix, rehearse), seed)
+
+
+def items_per_step(config: dict, mix: dict, *, rehearse: bool = False) -> int:
+    """Items (the configuration's `item`: tokens) one step trains."""
+    return int(mix["batch"]) * _seq(config, mix, rehearse)
+
+
+def step_counts(config: dict, mix: dict, *, rehearse: bool = False) -> dict:
+    """What the per-layer readers need of the arithmetic below for one
+    training step of this mix, by the names they read in `run.counts`."""
+    B, S = int(mix["batch"]), _seq(config, mix, rehearse)
+    return {"flops_per_item": flops_per_item(config, S),
+            "flash_flops_per_step": flash_flops_per_step(config, B, S)}
 
 
 # -- arithmetic kept with the benchmark ---------------------------------------

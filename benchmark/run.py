@@ -22,7 +22,9 @@ family or per-layer metric is a file found by its name:
     workloads/<cell>.json          runner, system settings, expectations
     models/<family>.py             build, loss, FLOPs and bytes arithmetic
     reference/<name>.py            the plain float32 reference
-    layer_metrics/<metric>.py      `read(run)` -> value, or None
+    layer_metrics/<metric>.py      `read(run)` -> value, or None; a metric
+                                   split by cell kind (`<metric>.train`,
+                                   `<metric>.serve`) may share `<metric>.py`
 """
 
 import time
@@ -56,6 +58,18 @@ def load_module(kind: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reader(metric: str):
+    """`layer_metrics/<metric>.py`, else the file of the longest dotted
+    prefix: `hbm_peak_gib.train` and `.serve` are one reader."""
+    name = metric
+    while not os.path.exists(os.path.join(HERE, "layer_metrics", name + ".py")):
+        if "." not in name:
+            raise FileNotFoundError(f"no reader for per-layer metric {metric!r} "
+                                    f"under benchmark/layer_metrics/")
+        name = name.rsplit(".", 1)[0]
+    return load_module("layer_metrics", name)
 
 
 def applies(metric: dict, cell: str) -> bool:
@@ -138,7 +152,7 @@ def main() -> int:
         cell=cell["name"], config=config, mix=mix, system=system,
         chips=cell["chips"], seed=args.seed, seconds=seconds,
         traced=bool(args.trace), rehearse=args.rehearse, t_start=T_START,
-        trace_dir=trace_dir,
+        deadline=T_START + DEADLINE_S, trace_dir=trace_dir,
         model=load_module("models", config["model"]),
         device_kind=dev.device_kind)
     reference = load_module("reference", run.model.REFERENCE)
@@ -161,7 +175,7 @@ def main() -> int:
         if not applies(m, cell["name"]):
             continue
         if args.trace:
-            value = load_module("layer_metrics", m["name"]).read(run)
+            value = load_reader(m["name"]).read(run)
         else:
             value = run.e2e.get(m["name"])
         if value is not None:

@@ -64,8 +64,10 @@ def test_metrics():
         assert m["source"] in SOURCES and 1 <= len(m["layer"]) <= 200
         # the metric it should move is reported wherever it is
         assert cells_of(m) <= cells_of(e2e[m["moves"]])
-        assert os.path.exists(os.path.join(ROOT, "benchmark", "layer_metrics",
-                                           m["name"] + ".py"))
+        parts = m["name"].split(".")
+        assert any(os.path.exists(os.path.join(
+            ROOT, "benchmark", "layer_metrics", ".".join(parts[:n]) + ".py"))
+            for n in range(len(parts), 0, -1)), m["name"]
         if m["name"].endswith("_roofline"):
             assert m["unit"] == "%"
     for m in BENCH["end_to_end"] + BENCH["per_layer"]:
@@ -83,3 +85,23 @@ def test_layers_are_perf_md_layers():
     for m in BENCH["per_layer"]:
         assert f"| {m['layer']} |" in perf, m["layer"]
         assert f"`{m['name']}`" in perf or m["name"].rsplit(".", 1)[0] in perf
+
+
+def test_a_split_metric_shares_the_reader_of_its_prefix():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "bench_run", os.path.join(ROOT, "benchmark", "run.py"))
+    run = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(run)
+    for m in BENCH["per_layer"]:
+        assert callable(run.load_reader(m["name"]).read)
+    assert (run.load_reader("hbm_peak_gib.train").__file__
+            == run.load_reader("hbm_peak_gib.serve").__file__)
+    assert run.load_reader("step.device_ms.train").__file__.endswith(
+        "step.device_ms.train.py")
+    try:
+        run.load_reader("no_such_metric.train")
+    except FileNotFoundError:
+        pass
+    else:
+        raise AssertionError("a metric with no reader has to be an error")

@@ -1,5 +1,5 @@
-"""The traffic generator: same seed, same traffic; every seed, the same
-set of lengths in another order. Run by hand:
+"""The traffic generator: same seed, same traffic; every block of
+requests, the same set of lengths in another order. Run by hand:
 `python -m pytest benchmark/tests -q -p no:cacheprovider`."""
 import json
 import os
@@ -18,44 +18,40 @@ def mix(name):
 
 
 def test_same_seed_same_traffic():
-    m = dict(mix("serve.closed64"), order="seed")
+    m = mix("serve.closed64")
     a, b = (ServeTraffic(m, 50304, 3_000_000_123) for _ in range(2))
     assert np.array_equal(a.prompt_len, b.prompt_len)
     assert np.array_equal(a.max_new, b.max_new)
     assert all(np.array_equal(a.prompt(k), b.prompt(k)) for k in (0, 7, 500))
-    c = ServeTraffic(m, 50304, 3_000_000_124)
-    assert not np.array_equal(a.prompt_len, c.prompt_len)
-    assert not np.array_equal(a.prompt(0)[:8], c.prompt(0)[:8])
 
 
-def test_fixed_order_leaves_the_seed_only_the_tokens():
+def test_the_seed_draws_the_tokens_and_not_the_schedule():
     m = mix("serve.closed64")
-    assert m["order"] == "fixed"
     a, b = ServeTraffic(m, 50304, 1), ServeTraffic(m, 50304, 2**31 + 5)
     assert np.array_equal(a.prompt_len, b.prompt_len)
     assert np.array_equal(a.max_new, b.max_new)
     assert not np.array_equal(a.prompt(0), b.prompt(0))
 
 
-def test_first_replies_are_staggered():
+def test_every_block_offers_the_same_set_of_lengths():
+    m = mix("serve.closed64")
+    t = ServeTraffic(dict(m, arrival={"mode": "poisson", "rate_rps": 2.0}), 50304, 1)
+    blk = m["block"]
+    first = sorted(zip(t.prompt_len[:blk], t.max_new[:blk]))
+    for lo in (blk, 5 * blk):
+        assert sorted(zip(t.prompt_len[lo:lo + blk], t.max_new[lo:lo + blk])) == first
+    assert not np.array_equal(t.prompt_len[:blk], t.prompt_len[blk:2 * blk])
+
+
+def test_first_replies_of_a_closed_loop_are_staggered():
     m = mix("serve.closed64")
     c = m["arrival"]["clients"]
     t = ServeTraffic(m, 50304, 1)
-    u = ServeTraffic(dict(m, arrival=dict(m["arrival"], first_reply="whole")), 50304, 1)
-    assert np.all(t.max_new[:c] <= u.max_new[:c]) and t.max_new[c - 1] == u.max_new[c - 1]
-    assert np.array_equal(t.max_new[c:], u.max_new[c:])
-    assert t.max_new[0] == max(1, -(-u.max_new[0] // c))
-
-
-def test_every_seed_offers_the_same_set_of_lengths():
-    m = dict(mix("serve.closed64"), order="seed",
-             arrival={"mode": "closed", "clients": 64})
-    a, b = ServeTraffic(m, 50304, 1), ServeTraffic(m, 50304, 2**31 + 5)
-    blk = m["block"]
-    for lo in (0, blk, 5 * blk):
-        pa = sorted(zip(a.prompt_len[lo:lo + blk], a.max_new[lo:lo + blk]))
-        pb = sorted(zip(b.prompt_len[lo:lo + blk], b.max_new[lo:lo + blk]))
-        assert pa == pb
+    whole = ServeTraffic(dict(m, arrival={"mode": "poisson", "rate_rps": 2.0}),
+                         50304, 1).max_new
+    assert np.all(t.max_new[:c] <= whole[:c]) and t.max_new[c - 1] == whole[c - 1]
+    assert np.array_equal(t.max_new[c:], whole[c:])
+    assert t.max_new[0] == max(1, -(-whole[0] // c))
 
 
 def test_length_distributions():
