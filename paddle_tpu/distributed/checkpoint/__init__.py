@@ -163,16 +163,11 @@ def _commit(tmp: str, final: str, leaves: Dict[str, dict],
     """Turn a finished staging dir into a committed checkpoint: write
     extra files + manifest (fsync'd), then atomically rename. A crash at
     ANY point before the rename leaves only the ``.tmp`` dir, which
-    every reader skips. When a structured step trace is active
-    (FLAGS_trace) the commit appears as a ``checkpoint.commit`` span."""
-    try:
-        from ...monitor import trace as _trace_mod
-        span = _trace_mod.maybe_span("checkpoint.commit", step=step,
-                                     path=final)
-    except Exception:
-        import contextlib
-        span = contextlib.nullcontext()
-    with span:
+    every reader skips. The commit is a ``checkpoint.commit`` span: in
+    the span ring, and under the step trace when one is active
+    (FLAGS_trace)."""
+    from ...monitor import trace as _trace_mod
+    with _trace_mod.span("checkpoint.commit", step=step, path=final):
         _commit_impl(tmp, final, leaves, extra_files, step)
 
 

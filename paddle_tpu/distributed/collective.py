@@ -250,8 +250,9 @@ def _comm_trace(op: str, group: Group, x, cache_key=None):
     reference analogue: the NCCL comm events CUPTI puts on the
     device_tracer timeline). Records op name, group axis/size, operand
     bytes and dispatch latency into the monitor registry, and emits a
-    ``comm::<op>`` RecordEvent so collectives show up on host timelines
-    when a profiler window is open.
+    ``comm::<op>`` RecordEvent (a :class:`~paddle_tpu.monitor.trace.span`)
+    so collectives show up on host timelines when a profiler window is
+    open.
 
     Latency here is DISPATCH latency (time for the XLA call to return,
     enqueue included, device completion not) — the single-controller
@@ -265,22 +266,16 @@ def _comm_trace(op: str, group: Group, x, cache_key=None):
     nbytes = int(getattr(x, "nbytes", 0) or 0)
     warm = cache_key is None or _eager_warm(group, cache_key)
     try:
+        # one span: the ring, the profiler's timeline while one is open,
+        # and a child of the active train.step trace (FLAGS_trace +
+        # TrainStep's activate())
         from ..profiler import RecordEvent
-        span = RecordEvent(f"comm::{op}")
+        span = RecordEvent(f"comm::{op}", group=group.axis_name,
+                           nranks=group.nranks, bytes=nbytes)
     except Exception:
         span = contextlib.nullcontext()
-    try:
-        # structured-trace child span: attaches under the active
-        # train.step trace (FLAGS_trace + TrainStep's activate()); a
-        # no-op — no allocation — when no trace is current
-        from ..monitor import trace as _trace_mod
-        tspan = _trace_mod.maybe_span(
-            f"collective::{op}", group=group.axis_name,
-            nranks=group.nranks, bytes=nbytes)
-    except Exception:
-        tspan = contextlib.nullcontext()
     t0 = time.perf_counter()
-    with span, tspan:
+    with span:
         yield
     dt = time.perf_counter() - t0
     try:

@@ -317,9 +317,12 @@ class _DevicePrefetcher:
     def __next__(self):
         # the consumer-facing wait: refill time IS the host-input-
         # pipeline time the training loop sits in — the goodput
-        # ledger's data_wait bucket (one flag read when off)
+        # ledger's data_wait bucket (one flag read when off) and the
+        # span ring's train.data_wait
         from ..monitor import goodput as _goodput
-        with _goodput.measure("data_wait"):
+        from ..monitor import trace as _trace
+        with _trace.span("train.data_wait"), \
+                _goodput.measure("data_wait"):
             if not self.buffer:
                 raise StopIteration
             out = self.buffer.pop(0)
@@ -386,7 +389,9 @@ class DataLoader:
 
             def __next__(self):
                 from ..monitor import goodput as _goodput
-                with _goodput.measure("data_wait"):
+                from ..monitor import trace as _trace
+                with _trace.span("train.data_wait"), \
+                        _goodput.measure("data_wait"):
                     batch = next(self.it)
                     def conv(x):
                         if isinstance(x, np.ndarray):

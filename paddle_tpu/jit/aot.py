@@ -29,11 +29,111 @@ disabled the heal).
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional, Sequence
+import collections
+import re
+import time
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 
-__all__ = ["AOTProgram"]
+from ..nn.layer import BLOCKS
+
+__all__ = ["AOTProgram", "SCOPES", "scopes", "parse_scopes"]
+
+#: HLO module name (``jit_train_step``; a device trace's `XLA Modules`
+#: line carries the same) -> ``{instruction name: (block, phase)}`` of
+#: the newest executable built under that name: which block of the model
+#: (``nn.layer.BLOCKS``) each instruction of the OPTIMIZED program came
+#: from, and whether it is forward, backward or recomputed-forward work.
+#: Read by whoever splits a device trace by block (the benchmark's
+#: ``block.*`` readers). Only this small dict outlives a build, never
+#: the executable or its text.
+SCOPES: Dict[str, Dict[str, Tuple[str, str]]] = {}
+#: seconds the newest build under each name spent on `as_text()` + parse
+SCOPE_PARSE_SECONDS: Dict[str, float] = {}
+
+_HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r"op_name=\"([^\"]*)\"")
+_HLO_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+#: where an instruction's operand list ends and its attributes begin
+_HLO_ATTRIBUTES = re.compile(r"\), \w+=")
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+_PATH_WORD = re.compile(r"[A-Za-z_]+")
+
+
+def _resolve(op_name: str) -> Optional[Tuple[str, str]]:
+    """``jit(train_step)/jit(main)/transpose(jvp(while))/body/
+    checkpoint/rematted_computation/attn/dot_general`` ->
+    ``("attn", "bwd")``: the block is the INNERMOST vocabulary word of
+    the path (wrappers such as ``transpose(jvp(attn))`` hold it inside
+    their parentheses); the phase is ``remat`` under a
+    ``rematted_computation``, else ``bwd`` under a ``transpose(``, else
+    ``fwd``. None when the path holds no block."""
+    block = None
+    for part in op_name.split("/")[:-1]:       # the last part is the op
+        for word in _PATH_WORD.findall(part):
+            if word in BLOCKS:
+                block = word
+    if block is None:
+        return None
+    phase = ("remat" if "rematted_computation" in op_name
+             else "bwd" if "transpose(" in op_name else "fwd")
+    return block, phase
+
+
+def parse_scopes(hlo_text: str) -> Tuple[str, Dict[str, Tuple[str, str]]]:
+    """(module name, {instruction name: (block, phase)}) from the text
+    of an optimized HLO module (``compiled.as_text()``).
+
+    An instruction resolves by its own ``op_name``. One whose own path
+    holds no block (XLA names a fusion after its root, and the root may
+    be plumbing: the scan's write of a layer's gradient into the
+    stacked buffer; a layout copy carries no metadata at all) takes the
+    block most of the instructions of its fused computation carry,
+    else that of its first operand that has one: the write counts with
+    the block that produced what is written. One hop only."""
+    m = _HLO_MODULE.match(hlo_text)
+    table: Dict[str, Tuple[str, str]] = {}
+    votes: Dict[str, collections.Counter] = {}
+    calls: Dict[str, str] = {}
+    operands: Dict[str, list] = {}
+    computation = ""
+    for line in hlo_text.splitlines():
+        inst = _HLO_INSTRUCTION.match(line)
+        if inst is None:
+            comp = _HLO_COMPUTATION.match(line)
+            if comp is not None:
+                computation = comp.group(1)
+            continue
+        op_name = _HLO_OP_NAME.search(line)
+        hit = _resolve(op_name.group(1)) if op_name else None
+        if hit is not None:
+            table[inst.group(1)] = hit
+            votes.setdefault(computation, collections.Counter())[hit] += 1
+        else:
+            callee = _HLO_CALLS.search(line)
+            if callee is not None:
+                calls[inst.group(1)] = callee.group(1)
+            head = _HLO_ATTRIBUTES.split(line, 1)[0]
+            operands[inst.group(1)] = _HLO_OPERAND.findall(head)[1:]
+    for name, callee in calls.items():
+        if callee in votes:
+            table[name] = votes[callee].most_common(1)[0][0]
+    direct = dict(table)
+    for name, ops in operands.items():
+        if name not in table:
+            hit = next((direct[o] for o in ops if o in direct), None)
+            if hit is not None:
+                table[name] = hit
+    return (m.group(1) if m else ""), table
+
+
+def scopes(module_name: str) -> Optional[Dict[str, Tuple[str, str]]]:
+    """The scope index of the newest executable built under this HLO
+    module name, or None when none was."""
+    return SCOPES.get(module_name)
 
 
 def _inputs_drifted(compiled, args) -> bool:
@@ -60,6 +160,11 @@ def _inputs_drifted(compiled, args) -> bool:
 class AOTProgram:
     """One program signature, compiled ahead of time.
 
+    ``name`` (default ``kind``) names the jitted function, hence the
+    HLO module (``jit_<name>``) and the program in a device trace; after
+    every build the instruction -> block index of the executable is kept
+    in :data:`SCOPES` under that module name.
+
     ``on_attribute(kind, lowered, compiled)`` is called after every
     build (including heals — newest wins), with the exact lowering and
     executable the calls will run; attribution therefore costs no extra
@@ -77,9 +182,12 @@ class AOTProgram:
     def __init__(self, kind: str, fn: Callable,
                  donate_argnums: Sequence[int] = (),
                  on_attribute: Optional[Callable[[str, Any, Any], None]]
-                 = None):
+                 = None, name: Optional[str] = None):
         self.kind = kind
         self.donate_argnums = tuple(donate_argnums)
+        # the function's name is the HLO module's (`jit_<name>`), which
+        # is how a device trace names the program: stable by kind
+        fn.__name__ = fn.__qualname__ = name or kind
         self._jitted = jax.jit(fn, donate_argnums=self.donate_argnums)
         self._on_attribute = on_attribute
         self._compiled: Any = None
@@ -93,6 +201,10 @@ class AOTProgram:
             lowered = self._jitted.lower(*args)
         compiled = lowered.compile()
         self.builds += 1
+        t0 = time.perf_counter()
+        module, table = parse_scopes(compiled.as_text())
+        SCOPES[module] = table
+        SCOPE_PARSE_SECONDS[module] = time.perf_counter() - t0
         if self._on_attribute is not None:
             self._on_attribute(self.kind, lowered, compiled)
         return compiled

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from ..core.random import make_rng, trace_rng
 from ..core.tensor import TapeNode, Tensor, is_grad_enabled, no_grad
+from ..monitor import trace as trace_mod
 from ..nn.layer import Layer
 from ..testing import chaos as _chaos
 from .functional import (bind, buffer_arrays, param_arrays,
@@ -600,8 +601,9 @@ class TrainStep:
 
         def step(params, buffers, opt_state, lr, t, key, flat_batch):
             (loss, new_bufs), grads = run(params, buffers, key, flat_batch)
-            new_params, new_opt = optimizer.apply_gradients(
-                params, grads, opt_state, lr, t)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = optimizer.apply_gradients(
+                    params, grads, opt_state, lr, t)
             out = (new_params, new_bufs, new_opt, loss)
             if check_finite:
                 # NaN/Inf debug under jit (reference: FLAGS_check_nan_inf +
@@ -647,8 +649,9 @@ class TrainStep:
             total = jax.tree_util.tree_map(jnp.add, acc, grads)
             if avg:
                 total = jax.tree_util.tree_map(lambda g: g / k, total)
-            new_params, new_opt = optimizer.apply_gradients(
-                params, total, opt_state, lr, t)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = optimizer.apply_gradients(
+                    params, total, opt_state, lr, t)
             zero = jax.tree_util.tree_map(jnp.zeros_like, acc)
             out = (new_params, new_bufs, new_opt, zero, loss)
             if check_finite:
@@ -707,11 +710,13 @@ class TrainStep:
         engine's bucketed signatures)."""
         from ..monitor import goodput as _goodput
         from .aot import AOTProgram
-        with _goodput.measure("compile"):
+        with trace_mod.span("train.compile", kind=kind), \
+                _goodput.measure("compile"):
             return AOTProgram(
                 kind, fn, donate_argnums=donate_argnums,
                 on_attribute=lambda k, lowered, compiled:
                     self._attribute_program(k, lowered, compiled, mon),
+                name=f"train_{kind}",
             ).compile(example_args)
 
     def _attribute_program(self, kind: str, lowered, compiled, mon: bool):
@@ -825,7 +830,6 @@ class TrainStep:
                 u.set(vals["update_ratio"], layer=layer)
         if spikes:
             self._stats["health_spikes"] += len(spikes)
-            from ..monitor import trace as trace_mod
             cur = trace_mod.current_trace()
             if cur is not None:
                 cur.mark_anomaly("health_spike", step=self.step_count,
@@ -840,31 +844,6 @@ class TrainStep:
             from ..monitor.flight_recorder import safe_record_event
             safe_record_event("health_spike", step=self.step_count,
                               layers=sorted(spikes))
-
-    #: _step_span RecordEvent name -> structured-trace span name (the
-    #: step-trace taxonomy of docs/OBSERVABILITY.md: dispatch /
-    #: grad_accum_sync; collective::<op> and checkpoint.commit attach
-    #: through the same maybe_span seam from their own modules)
-    _TRACE_SPAN_NAMES = {"TrainStep.step": "dispatch",
-                         "TrainStep.accum_microstep": "dispatch",
-                         "TrainStep.grad_accum_sync": "grad_accum_sync"}
-
-    @contextlib.contextmanager
-    def _step_span(self, mon: bool, name: str = "TrainStep.step"):
-        """RecordEvent around the dispatch in monitor mode — steps appear
-        on host timelines next to the comm/op lanes — and, when a
-        structured step trace is active (FLAGS_trace), the matching
-        child span."""
-        from ..monitor import trace as trace_mod
-        if not mon:
-            with trace_mod.maybe_span(
-                    self._TRACE_SPAN_NAMES.get(name, name)):
-                yield
-            return
-        from ..profiler import RecordEvent
-        with RecordEvent(name), trace_mod.maybe_span(
-                self._TRACE_SPAN_NAMES.get(name, name)):
-            yield
 
     def _watchdog(self, loss, prev_params, prev_buffers, key, flat,
                   treedef, step_index: int, step_kind: str = "step",
@@ -908,7 +887,6 @@ class TrainStep:
                        treedef, step_index: int, step_kind: str,
                        rollback):
         self._stats["nonfinite_trips"] += 1
-        from ..monitor import trace as trace_mod
         cur_trace = trace_mod.current_trace()
         if cur_trace is not None:
             # tail-retain the step trace even when the trip is handled
@@ -1090,8 +1068,8 @@ class TrainStep:
                 self._jitted[sig] = jitted
             from ..monitor import goodput as _goodput
             t0 = time.perf_counter() if mon else 0.0
-            with _control_flow_guidance(), self._step_span(
-                    mon, "TrainStep.accum_microstep"), \
+            with _control_flow_guidance(), \
+                    trace_mod.span("train.dispatch", kind="accum"), \
                     _goodput.measure("productive_dispatch",
                                      on_error="host_other"):
                 self.buffers, self._acc_grads, loss = self._dispatch(
@@ -1137,8 +1115,8 @@ class TrainStep:
             self._jitted[sig] = jitted
         from ..monitor import goodput as _goodput
         t0 = time.perf_counter() if mon else 0.0
-        with _control_flow_guidance(), self._step_span(
-                mon, "TrainStep.grad_accum_sync"), \
+        with _control_flow_guidance(), \
+                trace_mod.span("train.dispatch", kind="apply"), \
                 _goodput.measure("productive_dispatch",
                                  on_error="host_other"):
             out = self._dispatch(jitted, self.params, self.buffers,
@@ -1192,9 +1170,12 @@ class TrainStep:
         return Tensor(loss)
 
     def __call__(self, *batch):
-        from ..monitor import trace as trace_mod
-        if not trace_mod.enabled():
-            return self._call_impl(*batch)
+        with trace_mod.span("train.step", step=self.step_count + 1):
+            if not trace_mod.enabled():
+                return self._call_impl(*batch)
+            return self._call_traced(*batch)
+
+    def _call_traced(self, *batch):
         # one trace per step: dispatch / grad-accum sync spans attach
         # inside, eager collectives and checkpoint commits through the
         # activate() context. A non-finite trip tail-retains the trace
@@ -1228,17 +1209,20 @@ class TrainStep:
         from ..core.flags import get_flag
         mon = bool(get_flag("monitor"))
         t_wall = time.perf_counter() if mon else 0.0
-        raw = [b._data if isinstance(b, Tensor) else jnp.asarray(b) for b in batch]
-        raw = self._place_batch(raw)
-        flat, treedef = jax.tree_util.tree_flatten(raw)
+        with trace_mod.span("train.place_batch"):
+            raw = [b._data if isinstance(b, Tensor) else jnp.asarray(b)
+                   for b in batch]
+            raw = self._place_batch(raw)
+            flat, treedef = jax.tree_util.tree_flatten(raw)
         check = bool(get_flag("check_nan_inf"))
         fr = mon or bool(get_flag("flight_recorder"))
         if self.grad_accum_steps > 1:
             return self._call_accum(flat, treedef, check, mon, fr, t_wall)
         self.step_count += 1
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        t = jnp.asarray(self.step_count, jnp.int32)
-        key = make_rng("train_step")
+        with trace_mod.span("train.args"):
+            lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+            t = jnp.asarray(self.step_count, jnp.int32)
+            key = make_rng("train_step")
         # health folds into the jit-cache signature: flag off keeps the
         # exact program (and dispatch args) of every prior PR — the
         # zero-overhead pin; flag on only ADDS f32 scalar outputs
@@ -1261,7 +1245,8 @@ class TrainStep:
                 else None)
         from ..monitor import goodput as _goodput
         t0 = time.perf_counter() if mon else 0.0
-        with _control_flow_guidance(), self._step_span(mon), \
+        with _control_flow_guidance(), \
+                trace_mod.span("train.dispatch"), \
                 _goodput.measure("productive_dispatch",
                                  on_error="host_other"):
             out = self._dispatch(jitted, self.params, self.buffers,

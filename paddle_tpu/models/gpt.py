@@ -47,7 +47,7 @@ from ..distributed.meta_parallel.parallel_layers.mp_layers import (
     VocabParallelEmbedding, ParallelCrossEntropy)
 from ..nn import functional as F
 from ..nn.initializer import Constant, Normal
-from ..nn.layer import Layer, LayerList
+from ..nn.layer import Layer, LayerList, block_scope
 from ..nn.layers.common import Dropout, Embedding
 from ..nn.layers.norm import LayerNorm
 from ..nn.scan import (can_scan_layers, note_scan_fallback, scan_layers,
@@ -144,6 +144,8 @@ class GPTAttention(Layer):
     qkv weight is [E, 3, H, D] with spec P(None, None, 'mp', None): one
     logical gemm, head axis sharded, zero-copy reshape to [B, S, H, D].
     """
+
+    block = "attn"
 
     def __init__(self, cfg: GPTConfig):
         super().__init__()
@@ -287,25 +289,26 @@ class GPTAttention(Layer):
                                         write_pages_quant)
 
         quant = cache.k_scale is not None
-        if quant:
-            def updq(pages, scales, new, table, p):
-                return write_pages_quant(pages, scales, new, table, p)
+        with block_scope("kv_write", k):
+            if quant:
+                def updq(pages, scales, new, table, p):
+                    return write_pages_quant(pages, scales, new, table, p)
 
-            kp, ksc = apply(updq, cache.k_pages, cache.k_scale, k,
-                            cache.block_table, pos,
-                            name="paged_kv_write_quant")
-            vp, vsc = apply(updq, cache.v_pages, cache.v_scale, v,
-                            cache.block_table, pos,
-                            name="paged_kv_write_quant")
-        else:
-            def upd(pages, new, table, p):
-                return write_pages(pages, new, table, p)
+                kp, ksc = apply(updq, cache.k_pages, cache.k_scale, k,
+                                cache.block_table, pos,
+                                name="paged_kv_write_quant")
+                vp, vsc = apply(updq, cache.v_pages, cache.v_scale, v,
+                                cache.block_table, pos,
+                                name="paged_kv_write_quant")
+            else:
+                def upd(pages, new, table, p):
+                    return write_pages(pages, new, table, p)
 
-            kp = apply(upd, cache.k_pages, k, cache.block_table, pos,
-                       name="paged_kv_write")
-            vp = apply(upd, cache.v_pages, v, cache.block_table, pos,
-                       name="paged_kv_write")
-            ksc = vsc = None
+                kp = apply(upd, cache.k_pages, k, cache.block_table, pos,
+                           name="paged_kv_write")
+                vp = apply(upd, cache.v_pages, v, cache.block_table, pos,
+                           name="paged_kv_write")
+                ksc = vsc = None
         from ..serving.kv_cache import ContextPagedLayerCache
         is_ctx = isinstance(cache, ContextPagedLayerCache)
         new_cache = type(cache)(kp, vp, cache.block_table, ksc, vsc,
@@ -422,6 +425,8 @@ class GPTMLP(Layer):
     pair. Full logical weights, specs on the ffn axis; XLA inserts the psum
     after the second matmul."""
 
+    block = "ffn"
+
     def __init__(self, cfg: GPTConfig):
         super().__init__()
         E, FF = cfg.hidden_size, cfg.ffn_size
@@ -454,9 +459,12 @@ class GPTDecoderLayer(Layer):
         self.ln1 = LayerNorm(cfg.hidden_size)
         self.attn = GPTAttention(cfg)
         self.ln2 = LayerNorm(cfg.hidden_size)
+        self.ln1.block = self.ln2.block = "norm"
         self._build_ffn(cfg)
         self.dropout1 = Dropout(cfg.hidden_dropout_prob)
         self.dropout2 = Dropout(cfg.hidden_dropout_prob)
+
+    _ffn_block = "ffn"
 
     def _build_ffn(self, cfg: GPTConfig):
         self.mlp = GPTMLP(cfg)
@@ -472,10 +480,14 @@ class GPTDecoderLayer(Layer):
             a = self.attn(self.ln1(x))
         else:
             a, cache = self.attn(self.ln1(x), cache, pos=pos)
-        x = x + self.dropout1(a)
+        # each half's dropout and residual add count with the half
+        with block_scope("attn", x):
+            x = x + self.dropout1(a)
         if sp:
             x = _constrain(x, BATCH, sp, None)
-        x = x + self.dropout2(self._ffn(self.ln2(x)))
+        h = self._ffn(self.ln2(x))
+        with block_scope(self._ffn_block, x):
+            x = x + self.dropout2(h)
         if sp:
             x = _constrain(x, BATCH, sp, None)
         return x if cache is None else (x, cache)
@@ -491,12 +503,15 @@ class GPTMoEDecoderLayer(GPTDecoderLayer):
     telemetry; with a cache it returns ``(x, cache)`` exactly like the
     dense layer, so every decode path is unchanged."""
 
+    _ffn_block = "moe"
+
     def _build_ffn(self, cfg: GPTConfig):
         from ..incubate.moe import MoELayer
         self.moe = MoELayer(
             cfg.hidden_size, num_experts=cfg.moe_experts,
             d_hidden=cfg.ffn_size, top_k=cfg.moe_top_k,
             capacity_factor=cfg.moe_capacity_factor)
+        self.moe.block = "moe"
 
     def _ffn(self, h):
         return self.moe(h)
@@ -584,6 +599,7 @@ class GPTModel(Layer):
         for i in sorted(moe_idx):
             self.layers[i].moe._label = f"layer{i}"
         self.final_norm = LayerNorm(cfg.hidden_size)
+        self.final_norm.block = "norm"
 
     def forward(self, input_ids, position_ids=None, caches=None,
                 cache_pos=None):
@@ -609,9 +625,10 @@ class GPTModel(Layer):
             else:
                 start = 0 if caches is None else caches[0][0].shape[1]
                 position_ids = arange(start, start + S, dtype="int32")
-        x = self.word_embeddings(input_ids) + \
-            self.position_embeddings(position_ids)
-        x = self.embedding_dropout(x)
+        with block_scope("embed", input_ids):
+            x = self.word_embeddings(input_ids) + \
+                self.position_embeddings(position_ids)
+            x = self.embedding_dropout(x)
         sp = _seq_spec(self.cfg)
         if sp:
             x = _constrain(x, BATCH, sp, None)
@@ -765,9 +782,14 @@ class GPTModel(Layer):
             extras = (caches.block_table, cache_pos)
             if lora:
                 extras += (caches.lora_ids,)
-            x, new = scan_layers_with_cache(
-                self.layers, x, cache_arrs, *extras,
-                body_call=body, scan_in=scan_in, name="gpt_paged_scan")
+            # the scan's own slicing of a layer's pool out of the stacked
+            # pools, and its write back, count as kv_write; what the
+            # body traces resolves to its own (inner) block
+            with block_scope("kv_write", x):
+                x, new = scan_layers_with_cache(
+                    self.layers, x, cache_arrs, *extras,
+                    body_call=body, scan_in=scan_in,
+                    name="gpt_paged_scan")
             x = self.final_norm(x)
             if quant:
                 return x, PagedCacheView(new[0], new[1],
@@ -813,14 +835,17 @@ def parallel_logits(hidden, embedding_weight):
     def fn(h, w):
         return jnp.einsum("bse,ve->bsv", h, w, precision=prec)
 
-    logits = apply(fn, hidden, embedding_weight, name="lm_logits")
-    return _constrain(logits, BATCH, None, MP)
+    with block_scope("loss", hidden):
+        logits = apply(fn, hidden, embedding_weight, name="lm_logits")
+        return _constrain(logits, BATCH, None, MP)
 
 
 class GPTPretrainingCriterion(Layer):
     """Mean vocab-parallel CE over non-masked positions.
 
     reference: c_softmax_with_cross_entropy_op.cu + the loss-mask mean."""
+
+    block = "loss"
 
     def __init__(self):
         super().__init__()

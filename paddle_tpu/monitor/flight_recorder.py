@@ -61,7 +61,8 @@ RECOVERY_EVENTS = ("checkpoint_commit", "checkpoint_fallback",
                    "trip", "chaos", "request_failed", "request_expired",
                    "request_cancelled", "request_drained", "request_shed",
                    "decode_watchdog", "overload", "drained",
-                   "replica_migration", "health_spike")
+                   "replica_migration", "health_spike",
+                   "serve_step_stall")
 
 
 # dump-time attachment hooks: other forensic subsystems (the structured

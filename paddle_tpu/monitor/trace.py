@@ -37,10 +37,22 @@ Zero-overhead contract: with ``FLAGS_trace`` off (default),
 :func:`start_trace` returns None before allocating anything — the
 span-allocation probe :data:`TRACE_STATS` reads 0 and no registry
 series are written, pinned by tests/test_trace.py.
+
+Beside the request trees stands the **step-level span ring** (ISSUE 26):
+:class:`span` is the ONE call at every instrumentation site of the
+program — the phases of ``engine.step()`` and ``TrainStep.__call__``,
+eager collectives, checkpoint commits, ``profiler.RecordEvent``. It is
+accounting per step, not per request: always on, one tuple per span in
+a bounded process-wide ring on ``time.perf_counter`` (read with
+:func:`spans`), no :class:`Span` object. The same call lies on the
+profiler's clock while a ``jax.profiler`` session is on, and attaches
+a child :class:`Span` when the thread has a current ``FLAGS_trace``
+trace.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import itertools
 import json
@@ -51,9 +63,12 @@ import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Span", "Trace", "Tracer", "get_tracer", "set_tracer", "enabled",
-    "start_trace", "current_trace", "activate", "maybe_span",
+    "start_trace", "current_trace", "activate", "span", "spans",
+    "record", "clear_spans", "phase_table", "SPAN_RING_CAPACITY",
     "export_perfetto", "perfetto_doc", "ANOMALY_REASONS", "TRACE_STATS",
     "reset_trace_stats", "load_trace_dump",
 ]
@@ -434,7 +449,7 @@ def current_trace() -> Optional[Trace]:
 def activate(trace: Optional[Trace]) -> Iterator[Optional[Trace]]:
     """Make ``trace`` the thread's current trace for the with-block so
     nested instrumentation (eager collectives, checkpoint commits) can
-    attach child spans via :func:`maybe_span`. None = no-op."""
+    attach child spans via :class:`span`. None = no-op."""
     if trace is None:
         yield None
         return
@@ -448,21 +463,135 @@ def activate(trace: Optional[Trace]) -> Iterator[Optional[Trace]]:
         stack.pop()
 
 
-@contextlib.contextmanager
-def maybe_span(name: str, **attrs) -> Iterator[Optional[Span]]:
-    """Open ``name`` under the thread's current trace, or do nothing
-    when there is none (the cheap seam for instrumentation that cannot
-    know whether a trace is active — collective dispatches, checkpoint
-    commits). Never raises out of the guard."""
-    tr = current_trace()
-    if tr is None:
-        yield None
-        return
-    sp = tr.start_span(name, **attrs)
-    try:
-        yield sp
-    finally:
-        tr.end_span(sp)
+# ---------------------------------------------------------------------------
+# The step-level span ring: one span call on both clocks
+# ---------------------------------------------------------------------------
+
+#: records the ring holds before the oldest fall out: at 9 to 14 spans
+#: per ``engine.step()`` and 4 per ``TrainStep`` call, the last few
+#: thousand steps
+SPAN_RING_CAPACITY = 65536
+
+#: ``(name, t0, t1, span_id, parent_id, step, attrs-or-None)``,
+#: ``time.perf_counter`` seconds, appended when a span closes
+_ring: collections.deque = collections.deque(maxlen=SPAN_RING_CAPACITY)
+_span_ids = itertools.count(1)
+#: ``.stack``: the thread's open spans, innermost last
+_open = threading.local()
+
+
+class span:
+    """``with trace.span("serve.decode", n_active=64):`` — the one
+    instrumentation call of the program.
+
+    Always: one record in the process-wide ring when the span closes
+    (parent = the thread's innermost open span, ``step`` inherited from
+    it unless given). While a ``jax.profiler`` session is on
+    (``TraceAnnotation.is_enabled()``, one atomic read): the same span
+    as a ``TraceAnnotation(name, step=<n>)`` on the profiler's clock,
+    beside the device lines, joinable to its ring record by
+    ``(name, step)``. While the thread has a current ``FLAGS_trace``
+    trace: a child :class:`Span` of it, nested as the spans nest."""
+
+    __slots__ = ("name", "step", "attrs", "t0", "t1", "span_id",
+                 "parent_id", "child", "_trace", "_ann")
+
+    def __init__(self, name: str, step: Optional[int] = None, **attrs):
+        self.name = name
+        self.step = step
+        self.attrs = attrs or None
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the work is under way."""
+        if self.attrs is None:
+            self.attrs = attrs
+        else:
+            self.attrs.update(attrs)
+
+    def __enter__(self) -> "span":
+        stack = getattr(_open, "stack", None)
+        if stack is None:
+            stack = _open.stack = []
+        parent = stack[-1] if stack else None
+        self.parent_id = None
+        if parent is not None:
+            self.parent_id = parent.span_id
+            if self.step is None:
+                self.step = parent.step
+        self.span_id = next(_span_ids)
+        stack.append(self)
+        #: the Span attached to the thread's current FLAGS_trace trace
+        self._trace = tr = current_trace()
+        self.child = None if tr is None else tr.start_span(
+            self.name, parent=parent.child if parent is not None
+            else None, **(self.attrs or {}))
+        self._ann = None
+        if TraceAnnotation.is_enabled():
+            self._ann = (TraceAnnotation(self.name) if self.step is None
+                         else TraceAnnotation(self.name, step=self.step))
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if self.child is not None:
+            self._trace.end_span(self.child)
+        stack = _open.stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:            # closed out of order (a generator
+            stack.remove(self)         # abandoned mid-span)
+        _ring.append((self.name, self.t0, self.t1, self.span_id,
+                      self.parent_id, self.step, self.attrs))
+        return False
+
+
+def record(name: str, t0: float, t1: float, **attrs) -> None:
+    """A span whose ends were taken elsewhere (a request's wait in the
+    queue): one ring record under the thread's innermost open span."""
+    stack = getattr(_open, "stack", None)
+    parent = stack[-1] if stack else None
+    _ring.append((name, t0, t1, next(_span_ids),
+                  None if parent is None else parent.span_id,
+                  None if parent is None else parent.step,
+                  attrs or None))
+
+
+def spans(since: Optional[float] = None, until: Optional[float] = None,
+          name: Optional[str] = None) -> List[tuple]:
+    """The ring's records, oldest first: those named ``name`` that ended
+    at or after ``since`` and began at or before ``until``
+    (``time.perf_counter`` seconds). A copy: other threads go on
+    appending."""
+    recs = list(_ring)
+    return [r for r in recs
+            if (name is None or r[0] == name)
+            and (since is None or r[2] >= since)
+            and (until is None or r[1] <= until)]
+
+
+def clear_spans() -> None:
+    _ring.clear()
+
+
+def phase_table(root: tuple, recs: List[tuple]) -> Dict[str, float]:
+    """``{span name: summed seconds}`` of every span of ``recs`` under
+    the record ``root`` (children, grandchildren, ...) — a stalled
+    step's phases for the flight recorder, a step's coverage for a
+    reader."""
+    under = {root[3]}
+    out: Dict[str, float] = {}
+    # a child closes, and so is appended, before its parent, but ids are
+    # given when a span opens: by id, a parent comes before its children.
+    # A `record` that began before the step (a queue wait) is no phase.
+    for r in sorted(recs, key=lambda r: r[3]):
+        if r[4] in under and r[1] >= root[1]:
+            under.add(r[3])
+            out[r[0]] = out.get(r[0], 0.0) + (r[2] - r[1])
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from ..core.flags import get_flag
 from ..core.random import make_rng, trace_rng
 from ..core.tensor import Tensor, apply
+from .layer import block_scope
 
 __all__ = ["can_scan_layers", "scan_layers", "scan_layers_with_cache",
            "invalidate_scan_cache", "note_scan_fallback", "SCAN_STATS"]
@@ -190,6 +191,28 @@ def can_scan_layers(blocks) -> bool:
     return _configs_homogeneous(blocks_obj, blocks)
 
 
+def _stack_params(template, names, specs, arrs, num_layers: int):
+    """The ``[L, ...]`` stacks of every block's parameters, built inside
+    the traced fn (name-major ``arrs``), pinned to the per-layer TP specs
+    (leading layer axis replicated; no-op without an active mesh). Each
+    stack is traced under the block of the sublayer that owns the
+    parameter (``Layer.block``), so the copy — and, in the backward
+    pass, the unstacking of its gradient — counts with that block."""
+    from ..distributed.spmd import constrain
+    owners = sorted(((n, l.block) for n, l in template.named_sublayers()
+                     if l.block is not None), key=lambda nl: -len(nl[0]))
+    stacked = {}
+    for i, n in enumerate(names):
+        block = next((b for o, b in owners if n.startswith(o + ".")), None)
+        layers = arrs[i * num_layers:(i + 1) * num_layers]
+        with (block_scope(block, *layers[:1]) if block
+              else contextlib.nullcontext()):
+            stacked[n] = jnp.stack(layers, axis=0)
+            if specs[n] is not None:
+                stacked[n] = constrain(stacked[n], None, *tuple(specs[n]))
+    return stacked
+
+
 def scan_layers(blocks, x, *extra, policy=None, use_recompute: bool = False,
                 num_aux: int = 0, token_extra=None,
                 name: str = "scan_layers"):
@@ -256,17 +279,8 @@ def scan_layers(blocks, x, *extra, policy=None, use_recompute: bool = False,
         else:
             key = None
         n_p = len(names) * num_layers
-        p_stacked = {
-            n: jnp.stack(arrs[i * num_layers:(i + 1) * num_layers], axis=0)
-            for i, n in enumerate(names)}
+        p_stacked = _stack_params(template, names, specs, arrs, num_layers)
         extra_raw = arrs[n_p:]
-        # pin the stacked layout to the per-layer TP specs (leading layer
-        # axis replicated); no-op without an active mesh
-        from ..distributed.spmd import constrain
-        for n in names:
-            sp = specs[n]
-            if sp is not None:
-                p_stacked[n] = constrain(p_stacked[n], None, *tuple(sp))
 
         def body(carry, xs):
             SCAN_STATS["body_traces"] += 1
@@ -374,19 +388,10 @@ def scan_layers_with_cache(blocks, x, cache, *extra, body_call,
 
     def _scan_fn(x_arr, *arrs):
         n_p = len(names) * num_layers
-        p_stacked = {
-            n: jnp.stack(arrs[i * num_layers:(i + 1) * num_layers], axis=0)
-            for i, n in enumerate(names)}
+        p_stacked = _stack_params(template, names, specs, arrs, num_layers)
         cache_raw = arrs[n_p:n_p + n_cache]
         scan_in_raw = arrs[n_p + n_cache:n_p + n_cache + n_scan_in]
         extra_raw = arrs[n_p + n_cache + n_scan_in:]
-        # same stacked-layout TP pins as the training scan (leading layer
-        # axis replicated); no-op without an active mesh
-        from ..distributed.spmd import constrain
-        for n in names:
-            sp = specs[n]
-            if sp is not None:
-                p_stacked[n] = constrain(p_stacked[n], None, *tuple(sp))
 
         def body(carry, xs):
             SCAN_STATS["body_traces"] += 1

@@ -114,7 +114,8 @@ class Optimizer:
             # (shape, dtype), keep slots STACKED [N, *shape] per group —
             # the update runs as ~a dozen large fused kernels instead of
             # one tiny fusion per parameter (a ~300-launch, ~30 ms/step
-            # overhead on GPT-2 345M, see tools/trace_gpt.py)
+            # overhead on GPT-2 345M; a device trace shows it:
+            # python -m benchmark.harness.trace_reduce <dir>)
             groups: Dict[Any, List[str]] = {}
             for k in sorted(params):
                 gid = (tuple(params[k].shape), str(params[k].dtype))

@@ -17,6 +17,8 @@ from typing import Optional
 
 import jax
 
+from ..monitor import trace as _trace
+
 __all__ = ["Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent",
            "make_scheduler", "profiler", "start_profiler", "stop_profiler",
            "summary", "profile_train_step", "export_chrome_tracing",
@@ -38,32 +40,23 @@ def _timeline_add(name: str, t0: float, t1: float):
                       threading.get_ident()))
 
 
-class RecordEvent:
-    """Host-side RAII event marker (platform/profiler.h RecordEvent analogue);
-    also emits a jax.profiler.TraceAnnotation so events appear on xplane."""
+class RecordEvent(_trace.span):
+    """Host-side RAII event marker (platform/profiler.h RecordEvent
+    analogue): :class:`paddle_tpu.monitor.trace.span` under the
+    ``paddle.profiler`` name — the span ring, the xplane annotation
+    while a ``jax.profiler`` session is on — plus a row in the host
+    table and timeline between ``start_profiler()`` and
+    ``stop_profiler()``."""
 
-    def __init__(self, name: str):
-        self.name = name
-        self._ann = None
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        try:
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
-        except Exception:
-            self._ann = None
-        return self
+    __slots__ = ()
 
     def __exit__(self, *exc):
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
+        super().__exit__(*exc)
         if _active[0]:
-            t1 = time.perf_counter()
             rec = _events[self.name]
             rec[0] += 1
-            rec[1] += t1 - self.t0
-            _timeline_add(self.name, self.t0, t1)
+            rec[1] += self.t1 - self.t0
+            _timeline_add(self.name, self.t0, self.t1)
         return False
 
 

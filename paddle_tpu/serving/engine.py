@@ -297,6 +297,8 @@ class ServingEngine:
         #: perturbs the device RNG the flags-off oracle pins
         self._spec_rng = np.random.default_rng((int(c.seed) << 1) ^ 0x51EC)
         self._dispatch_seq = 0
+        #: engine.step() calls so far: the `step` of the serve.* spans
+        self._step_seq = 0
         self._stats = {"prefill_dispatches": 0, "decode_dispatches": 0,
                        "decode_slot_steps": 0, "decode_batch_max": 0,
                        "tokens_generated": 0, "program_compiles": 0,
@@ -561,10 +563,12 @@ class ServingEngine:
             # entry models a slot whose forward went non-finite. `ok` is
             # the per-slot fault-isolation flag: one bad request fails
             # alone, the rest of the batch streams on.
-            row = logits[:, -1, :] + poison[:, None]
-            ok = jnp.isfinite(row).all(axis=-1)
-            toks = sample_tokens(row, rng, temps, top_ks, top_ps)
-            return jnp.where(active, toks, 0), ok, k, v
+            with jax.named_scope("sampling"):
+                row = logits[:, -1, :] + poison[:, None]
+                ok = jnp.isfinite(row).all(axis=-1)
+                toks = sample_tokens(row, rng, temps, top_ks, top_ps)
+                toks = jnp.where(active, toks, 0)
+            return toks, ok, k, v
 
         B = self.config.max_batch_slots
         mb = self.cache.max_blocks_per_slot
@@ -616,16 +620,18 @@ class ServingEngine:
             pos = jnp.zeros((nb,), jnp.int32)
             logits, k, v = self._fwd(params, ids, k, v, table, pos,
                                      lora=lora or None)
-            last = jnp.take_along_axis(
-                logits, (lens - 1).astype(jnp.int32)[:, None, None],
-                axis=1)[:, 0, :]
-            row = last + poison[:, None]
-            ok = jnp.isfinite(row).all(axis=-1)
-            toks = sample_tokens(row, rng, temps, top_ks, top_ps)
+            with jax.named_scope("sampling"):
+                last = jnp.take_along_axis(
+                    logits, (lens - 1).astype(jnp.int32)[:, None, None],
+                    axis=1)[:, 0, :]
+                row = last + poison[:, None]
+                ok = jnp.isfinite(row).all(axis=-1)
+                toks = sample_tokens(row, rng, temps, top_ks, top_ps)
             return toks, ok, k, v
 
         mb = self.cache.max_blocks_per_slot
         prog = AOTProgram(f"serve_prefill_b{nb}_s{sp}", prefill_fn,
+                          name=f"serve_prefill_{nb}x{sp}",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
         with self._mesh_scope():
@@ -656,17 +662,19 @@ class ServingEngine:
                            temps, top_ks, top_ps, poison, *lora):
             logits, k, v = self._fwd(params, ids, k, v, table, pos,
                                      lora=lora or None, ctx=True)
-            last = jnp.take_along_axis(
-                logits, (lens - 1).astype(jnp.int32)[:, None, None],
-                axis=1)[:, 0, :]
-            row = last + poison[:, None]
-            ok = jnp.isfinite(row).all(axis=-1)
-            toks = sample_tokens(row, rng, temps, top_ks, top_ps)
+            with jax.named_scope("sampling"):
+                last = jnp.take_along_axis(
+                    logits, (lens - 1).astype(jnp.int32)[:, None, None],
+                    axis=1)[:, 0, :]
+                row = last + poison[:, None]
+                ok = jnp.isfinite(row).all(axis=-1)
+                toks = sample_tokens(row, rng, temps, top_ks, top_ps)
             return toks, ok, k, v
 
         mb = self.cache.max_blocks_per_slot
         prog = AOTProgram(f"serve_prefill_ctx_b{nb}_s{sp}",
                           prefill_ctx_fn,
+                          name=f"serve_prefill_ctx_{nb}x{sp}",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
         with self._mesh_scope():
@@ -710,38 +718,40 @@ class ServingEngine:
             logits, k, v = self._fwd(params, ids, k, v, table, pos,
                                      lora=lora or None,
                                      ctx=True)                # [B,S,V]
-            row0 = logits[:, 0, :] + poison[:, None]
-            ok_rows = jnp.isfinite(logits).all(axis=-1)       # [B,S]
-            ok_rows = ok_rows.at[:, 0].set(
-                jnp.isfinite(row0).all(axis=-1))
-            tok0 = sample_tokens(row0, rng, temps, top_ks, top_ps)
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            B, V = logits.shape[0], logits.shape[-1]
-            flat = filtered_logits(
-                logits.reshape(B * S, V).astype(jnp.float32),
-                jnp.repeat(temps, S), jnp.repeat(top_ks, S),
-                jnp.repeat(top_ps, S)).reshape(B, S, V)
-            probs = jax.nn.softmax(flat, axis=-1)
-            drafts = ids[:, 1:]                               # [B,S-1]
-            # p_draft[b, i] = P(draft_i | rows 0..i) — row i's filtered
-            # softmax mass on the token the drafter proposed for it
-            p_draft = jnp.take_along_axis(
-                probs[:, :-1, :], drafts[..., None],
-                axis=-1)[..., 0]                              # [B,S-1]
-            k_full, k_resid = jax.random.split(jax.random.fold_in(rng, 1))
-            tok_full = jax.random.categorical(
-                k_full, flat, axis=-1).astype(jnp.int32)      # [B,S]
-            resid = jnp.where(
-                jax.nn.one_hot(drafts, V, dtype=bool),
-                _SAMPLING_NEG, flat[:, :-1, :])
-            tok_resid = jax.random.categorical(
-                k_resid, resid, axis=-1).astype(jnp.int32)    # [B,S-1]
+            with jax.named_scope("sampling"):
+                row0 = logits[:, 0, :] + poison[:, None]
+                ok_rows = jnp.isfinite(logits).all(axis=-1)       # [B,S]
+                ok_rows = ok_rows.at[:, 0].set(
+                    jnp.isfinite(row0).all(axis=-1))
+                tok0 = sample_tokens(row0, rng, temps, top_ks, top_ps)
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                B, V = logits.shape[0], logits.shape[-1]
+                flat = filtered_logits(
+                    logits.reshape(B * S, V).astype(jnp.float32),
+                    jnp.repeat(temps, S), jnp.repeat(top_ks, S),
+                    jnp.repeat(top_ps, S)).reshape(B, S, V)
+                probs = jax.nn.softmax(flat, axis=-1)
+                drafts = ids[:, 1:]                               # [B,S-1]
+                # p_draft[b, i] = P(draft_i | rows 0..i) — row i's filtered
+                # softmax mass on the token the drafter proposed for it
+                p_draft = jnp.take_along_axis(
+                    probs[:, :-1, :], drafts[..., None],
+                    axis=-1)[..., 0]                              # [B,S-1]
+                k_full, k_resid = jax.random.split(jax.random.fold_in(rng, 1))
+                tok_full = jax.random.categorical(
+                    k_full, flat, axis=-1).astype(jnp.int32)      # [B,S]
+                resid = jnp.where(
+                    jax.nn.one_hot(drafts, V, dtype=bool),
+                    _SAMPLING_NEG, flat[:, :-1, :])
+                tok_resid = jax.random.categorical(
+                    k_resid, resid, axis=-1).astype(jnp.int32)    # [B,S-1]
             return (jnp.where(active, tok0, 0), greedy, ok_rows,
                     p_draft, tok_full, tok_resid, k, v)
 
         B = self.config.max_batch_slots
         mb = self.cache.max_blocks_per_slot
         prog = AOTProgram(f"serve_verify_s{S}", verify_fn,
+                          name="serve_verify",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
         with self._mesh_scope():
@@ -1364,36 +1374,53 @@ class ServingEngine:
         """One scheduler iteration: honour drain/cancel/deadlines at the
         boundary, admit+prefill, then one decode dispatch over every
         active slot. Returns has_work. Raises :class:`EngineDrained`
-        when a latched drain signal was honoured this step."""
-        if self._drain_latch is not None and self._drain_latch.triggered \
-                and not self._draining:
-            raise EngineDrained(self.drain())
-        if self._staged is not None:
-            # the atomic cutover point: an iteration boundary, before
-            # any admission/prefill/decode of this step
-            self._cutover()
-        sched = self.scheduler
-        # iteration-boundary sweeps: queued expiries never touch a slot;
-        # latched cancels / in-flight expiries free pages immediately.
-        # Both are O(0) when no deadline/cancel exists — and never write
-        # the registry except on an actual lifecycle event.
-        sched.expire_queued()
-        sched.sweep_active()
-        if self._overload is not None:
-            oldest_t = sched.oldest_waiting_t()
-            delay = (self.clock() - oldest_t
-                     if oldest_t is not None else 0.0)
-            transition = self._overload.observe(delay)
-            if transition is not None:
-                self._overload_transition(transition)
-        if admit:
-            sched.plan_admissions()
-        # ONE prefill pass per iteration over every prefilling slot —
-        # newly admitted ones AND chunked prefills carried from earlier
-        # iterations (they advance even under admit=False: a draining
-        # engine must finish admitted work). With chunking off and no
-        # prefix cache this reproduces the pre-ISSUE-15 groups exactly.
-        groups = self._plan_prefill_groups()
+        when a latched drain signal was honoured this step.
+
+        The iteration is a ``serve.step`` span and each phase a child
+        (docs/OBSERVABILITY.md "Step spans"); a step that outlasts its
+        kin by far is reported once it returns (:meth:`_note_stall`)."""
+        self._step_seq += 1
+        with _trace.span("serve.step", step=self._step_seq) as sp:
+            work = self._step(admit, sp)
+        if sp.t1 - sp.t0 > self.STALL_FLOOR_S:
+            self._note_stall(sp)
+        return work
+
+    def _step(self, admit: bool, sp) -> bool:
+        with _trace.span("serve.sweep"):
+            if self._drain_latch is not None \
+                    and self._drain_latch.triggered \
+                    and not self._draining:
+                raise EngineDrained(self.drain())
+            if self._staged is not None:
+                # the atomic cutover point: an iteration boundary, before
+                # any admission/prefill/decode of this step
+                self._cutover()
+            sched = self.scheduler
+            # iteration-boundary sweeps: queued expiries never touch a
+            # slot; latched cancels / in-flight expiries free pages
+            # immediately. Both are O(0) when no deadline/cancel exists —
+            # and never write the registry except on an actual lifecycle
+            # event.
+            sched.expire_queued()
+            sched.sweep_active()
+            if self._overload is not None:
+                oldest_t = sched.oldest_waiting_t()
+                delay = (self.clock() - oldest_t
+                         if oldest_t is not None else 0.0)
+                transition = self._overload.observe(delay)
+                if transition is not None:
+                    self._overload_transition(transition)
+        with _trace.span("serve.admit"):
+            if admit:
+                sched.plan_admissions()
+            # ONE prefill pass per iteration over every prefilling slot —
+            # newly admitted ones AND chunked prefills carried from
+            # earlier iterations (they advance even under admit=False: a
+            # draining engine must finish admitted work). With chunking
+            # off and no prefix cache this reproduces the pre-ISSUE-15
+            # groups exactly.
+            groups = self._plan_prefill_groups()
         for gi, group in enumerate(groups):
             try:
                 self._run_prefill(group)
@@ -1413,6 +1440,7 @@ class ServingEngine:
                 for st in pending:
                     self._trace_requeue(st, "watchdog_rollback")
                 raise
+        n_active = 0
         if self._decodable():
             if self._spec_k > 0:
                 # drafts staged BEFORE the capacity pass so the verify
@@ -1425,14 +1453,53 @@ class ServingEngine:
             # one decode/verify dispatch per live weights epoch: a
             # single batch (the live tree) outside a swap transition
             for params, pairs in self._epoch_batches(self._decodable()):
+                n_active += len(pairs)
                 if any(st.draft for _, st in pairs):
                     self._run_verify(pairs, params)
                 else:
                     self._run_decode(pairs, params)
-        if self._retired:
-            self._retire_unreferenced()
-        self._publish_gauges()
+        with _trace.span("serve.publish"):
+            if self._retired:
+                self._retire_unreferenced()
+            self._publish_gauges()
+        sp.set(n_active=n_active, n_groups=len(groups))
         return sched.has_work
+
+    #: a ``serve.step`` shorter than this is never a stall
+    STALL_FLOOR_S = 1.0
+
+    def _note_stall(self, sp) -> None:
+        """A step longer than max(1 s, 5 x the ring's median step): one
+        flight-recorder event with the step's phase table, and a count
+        by the phase it sat in — the deepest span on the path of the
+        largest children (``serve.decode.readback``: the host waited
+        for the chip; ``.dispatch``: the runtime's execute call did not
+        return). Traced or not: the stall gets a name in every run."""
+        took = sp.t1 - sp.t0
+        steps = _trace.spans(name="serve.step")
+        durs = sorted(r[2] - r[1] for r in steps)
+        median = durs[len(durs) // 2]
+        if took <= 5.0 * median:
+            return
+        recs = [r for r in _trace.spans(since=sp.t0) if r[5] == sp.step]
+        phase, parent = "serve.step", sp.span_id
+        while True:
+            kids = [r for r in recs if r[4] == parent and r[1] >= sp.t0]
+            if not kids:
+                break
+            top = max(kids, key=lambda r: r[2] - r[1])
+            phase, parent = top[0], top[3]
+        table = _trace.phase_table(
+            (sp.name, sp.t0, sp.t1, sp.span_id), recs)
+        get_registry().counter(
+            "serve_step_stalls_total",
+            "engine steps longer than max(1 s, 5 x the median step), by "
+            "the phase the step sat in").inc(phase=phase)
+        self._flight_event(
+            "serve_step_stall", step=sp.step, seconds=round(took, 4),
+            median_step_s=round(median, 4), phase=phase,
+            phases_ms={k: round(v * 1e3, 3) for k, v in table.items()},
+            **(sp.attrs or {}))
 
     def _decodable(self) -> List[Tuple[int, RequestState]]:
         """Active slots that take a decode/verify row this iteration —
@@ -1607,111 +1674,121 @@ class ServingEngine:
 
     def _run_prefill(self, group: AdmissionGroup) -> None:
         nb, sp = group.batch_bucket, group.len_bucket
-        states: List[Optional[RequestState]] = list(group.states)
-        states += [None] * (nb - len(states))
-        ids = np.zeros((nb, sp), np.int32)
-        lens = np.ones((nb,), np.int32)
-        pos = np.zeros((nb,), np.int32)
-        ctx = any(st is not None and st.prefill_pos > 0
-                  for st in states)
-        chunked = False
-        # padded rows map to None -> an all-scratch table row (their
-        # K/V writes must never land in a live slot's pages)
-        rows: List[Optional[int]] = [None] * nb
-        for i, st in enumerate(states):
-            if st is None:
-                continue
-            eff = st.effective_prompt()
-            remaining = st.prefill_len - st.prefill_pos
-            clen = min(self._chunk, remaining) if self._chunk > 0 \
-                else remaining
-            chunked = chunked or clen < remaining
-            # COW contract: writes start at prefill_pos, which is never
-            # below the shared-prefix coverage — a shared page is
-            # read-only for this slot by construction
-            assert st.prefill_pos >= (
-                self.cache.slot_shared_blocks(st.slot)
-                * self.cache.block_size)
-            ids[i, :clen] = eff[st.prefill_pos:st.prefill_pos + clen]
-            lens[i] = clen
-            pos[i] = st.prefill_pos
-            rows[i] = st.slot
-        t0 = self.clock()
-        if self._t_first_work is None:
-            self._t_first_work = t0
-        # stamp each residency's weights epoch at its FIRST chunk: the
-        # KV this dispatch writes belongs to that tree, and every later
-        # chunk/decode of the residency must keep using it across a hot
-        # swap (groups are epoch-homogeneous by construction)
-        for st in group.states:
-            if st.weights_epoch is None:
-                st.weights_epoch = self._weights_epoch
-        params = self._params_for(group.states[0].weights_epoch)
-        for st in group.states:
-            tr = st.trace
-            if tr is not None and "admitted" not in st.trace_spans:
-                # queued ends / admitted opens at the scheduler's
-                # admission stamp, not dispatch time — queueing delay
-                # and prefill wait attribute to the right spans (a
-                # chunked prefill opens them at its FIRST chunk only)
-                qs = st.trace_spans.pop("queued", None)
-                if qs is not None:
-                    tr.end_span(qs, t=st.admitted_t)
-                st.trace_spans["admitted"] = tr.start_span(
-                    "admitted", t=st.admitted_t, slot=st.slot,
-                    prefix_hit_tokens=st.prefill_pos)
-        if ctx:
-            prog = self._get_prefill_ctx(nb, sp)
-            args = (params, self.cache.k, self.cache.v,
-                    self.cache.table_array(rows), jnp.asarray(ids),
-                    jnp.asarray(lens), jnp.asarray(pos),
-                    self._next_key())
-        else:
-            prog = self._get_prefill(nb, sp)
-            args = (params, self.cache.k, self.cache.v,
-                    self.cache.table_array(rows), jnp.asarray(ids),
-                    jnp.asarray(lens), self._next_key())
-        temps, tks, tps = self._sampling_arrays(states)
+        with _trace.span("serve.prefill", nb=nb, sp=sp):
+            self._prefill_group(group, nb, sp)
+
+    def _prefill_group(self, group: AdmissionGroup, nb: int,
+                       sp: int) -> None:
+        with _trace.span("serve.prefill.build"):
+            states: List[Optional[RequestState]] = list(group.states)
+            states += [None] * (nb - len(states))
+            ids = np.zeros((nb, sp), np.int32)
+            lens = np.ones((nb,), np.int32)
+            pos = np.zeros((nb,), np.int32)
+            ctx = any(st is not None and st.prefill_pos > 0
+                      for st in states)
+            chunked = False
+            # padded rows map to None -> an all-scratch table row (their
+            # K/V writes must never land in a live slot's pages)
+            rows: List[Optional[int]] = [None] * nb
+            for i, st in enumerate(states):
+                if st is None:
+                    continue
+                eff = st.effective_prompt()
+                remaining = st.prefill_len - st.prefill_pos
+                clen = min(self._chunk, remaining) if self._chunk > 0 \
+                    else remaining
+                chunked = chunked or clen < remaining
+                # COW contract: writes start at prefill_pos, which is
+                # never below the shared-prefix coverage — a shared page
+                # is read-only for this slot by construction
+                assert st.prefill_pos >= (
+                    self.cache.slot_shared_blocks(st.slot)
+                    * self.cache.block_size)
+                ids[i, :clen] = eff[st.prefill_pos:st.prefill_pos + clen]
+                lens[i] = clen
+                pos[i] = st.prefill_pos
+                rows[i] = st.slot
+            t0 = self.clock()
+            if self._t_first_work is None:
+                self._t_first_work = t0
+            # stamp each residency's weights epoch at its FIRST chunk:
+            # the KV this dispatch writes belongs to that tree, and every
+            # later chunk/decode of the residency must keep using it
+            # across a hot swap (groups are epoch-homogeneous by
+            # construction)
+            for st in group.states:
+                if st.weights_epoch is None:
+                    st.weights_epoch = self._weights_epoch
+            params = self._params_for(group.states[0].weights_epoch)
+            for st in group.states:
+                tr = st.trace
+                if tr is not None and "admitted" not in st.trace_spans:
+                    # queued ends / admitted opens at the scheduler's
+                    # admission stamp, not dispatch time — queueing delay
+                    # and prefill wait attribute to the right spans (a
+                    # chunked prefill opens them at its FIRST chunk only)
+                    qs = st.trace_spans.pop("queued", None)
+                    if qs is not None:
+                        tr.end_span(qs, t=st.admitted_t)
+                    st.trace_spans["admitted"] = tr.start_span(
+                        "admitted", t=st.admitted_t, slot=st.slot,
+                        prefix_hit_tokens=st.prefill_pos)
+            if ctx:
+                prog = self._get_prefill_ctx(nb, sp)
+                args = (params, self.cache.k, self.cache.v,
+                        self.cache.table_array(rows), jnp.asarray(ids),
+                        jnp.asarray(lens), jnp.asarray(pos),
+                        self._next_key())
+            else:
+                prog = self._get_prefill(nb, sp)
+                args = (params, self.cache.k, self.cache.v,
+                        self.cache.table_array(rows), jnp.asarray(ids),
+                        jnp.asarray(lens), self._next_key())
+            temps, tks, tps = self._sampling_arrays(states)
+            args += (temps, tks, tps, self._poison_array(states)) \
+                + self._lora_args(states)
         # a DecodeWatchdogError here propagates to step(), which rolls
         # back every not-yet-prefilled state of the plan (token-exact:
         # the tripped dispatch's pool writes died with its thread)
-        toks, ok, new_k, new_v = self._guarded_dispatch(
-            "prefill", prog,
-            args + (temps, tks, tps, self._poison_array(states))
-            + self._lora_args(states))
-        self.cache.update(new_k, new_v)
-        toks = np.asarray(toks)
-        ok = np.asarray(ok)
-        now = self.clock()
-        self._stats["prefill_dispatches"] += 1
-        if chunked or self._chunk > 0:
-            self._stats["prefill_chunks"] += len(group.states)
-            get_registry().counter(
-                "serve_prefill_chunks_total",
-                "chunked-prefill chunk rows dispatched"
-            ).inc(len(group.states))
-        reg = get_registry()
-        reg.histogram("serve_prefill_seconds",
-                      "prefill dispatch wall time").observe(
-            now - t0, bucket=f"b{nb}_s{sp}")
-        for i, st in enumerate(states):
-            if st is None:
-                continue
-            clen = int(lens[i])
-            st.prefill_pos += clen
-            self._stats["prefill_tokens"] += clen
-            final = st.prefill_pos >= st.prefill_len
-            tr = st.trace
-            if tr is not None:
-                tr.end_span(tr.start_span(
-                    "prefill", parent=st.trace_spans.get("admitted"),
-                    t=t0, bucket=f"b{nb}_s{sp}", pos=int(pos[i]),
-                    tokens=clen), t=now)
-            if not ok[i]:
-                self.scheduler.fail(st, "non-finite logits at prefill")
-                continue
-            if final:
-                self._accept_token(st, int(toks[i]), now)
+        with _trace.span("serve.prefill.dispatch"):
+            toks, ok, new_k, new_v = self._guarded_dispatch(
+                "prefill", prog, args)
+            self.cache.update(new_k, new_v)
+        with _trace.span("serve.prefill.readback"):
+            toks = np.asarray(toks)
+            ok = np.asarray(ok)
+        with _trace.span("serve.prefill.accept"):
+            now = self.clock()
+            self._stats["prefill_dispatches"] += 1
+            if chunked or self._chunk > 0:
+                self._stats["prefill_chunks"] += len(group.states)
+                get_registry().counter(
+                    "serve_prefill_chunks_total",
+                    "chunked-prefill chunk rows dispatched"
+                ).inc(len(group.states))
+            reg = get_registry()
+            reg.histogram("serve_prefill_seconds",
+                          "prefill dispatch wall time").observe(
+                now - t0, bucket=f"b{nb}_s{sp}")
+            for i, st in enumerate(states):
+                if st is None:
+                    continue
+                clen = int(lens[i])
+                st.prefill_pos += clen
+                self._stats["prefill_tokens"] += clen
+                final = st.prefill_pos >= st.prefill_len
+                tr = st.trace
+                if tr is not None:
+                    tr.end_span(tr.start_span(
+                        "prefill", parent=st.trace_spans.get("admitted"),
+                        t=t0, bucket=f"b{nb}_s{sp}", pos=int(pos[i]),
+                        tokens=clen), t=now)
+                if not ok[i]:
+                    self.scheduler.fail(st, "non-finite logits at prefill")
+                    continue
+                if final:
+                    self._accept_token(st, int(toks[i]), now)
 
     def _poison_array(self, states: Sequence[Optional[RequestState]]):
         """[n] f32 additive logits poison: all zeros (bit-transparent)
@@ -1767,191 +1844,210 @@ class ServingEngine:
         staged drafts. The accepted prefix plus one bonus token commit
         (greedy-exact vs the non-speculative path); the rejected tail's
         pages roll back by block-table truncation."""
-        B = self.config.max_batch_slots
-        S = self._spec_k + 1
-        pos = np.zeros((B,), np.int32)
-        ids = np.zeros((B, S), np.int32)
-        active = np.zeros((B,), bool)
-        per_slot: List[Optional[RequestState]] = [None] * B
-        for slot, st in pairs:
-            pos[slot] = st.seq_len - 1
-            ids[slot, 0] = st.generated[-1]
-            n = len(st.draft)
-            if n:
-                ids[slot, 1:1 + n] = st.draft
-            active[slot] = True
-            per_slot[slot] = st
-        n_active = int(active.sum())
-        t0 = self.clock()
-        prog = self._get_verify()
-        temps, tks, tps = self._sampling_arrays(per_slot)
-        hang = chaos.active() and chaos.probe("serve.decode.hang")
-        tok0, greedy, ok_rows, p_draft, tok_full, tok_resid, new_k, \
-            new_v = self._guarded_dispatch(
-                "verify", prog,
-                (params, self.cache.k, self.cache.v,
-                 self._decode_table(per_slot), jnp.asarray(pos),
-                 jnp.asarray(ids), jnp.asarray(active), self._next_key(),
-                 temps, tks, tps, self._poison_array(per_slot))
-                + self._lora_args(per_slot),
-                hang=hang)
-        self.cache.update(new_k, new_v)
-        tok0 = np.asarray(tok0)
-        greedy = np.asarray(greedy)
-        ok_rows = np.asarray(ok_rows)
-        p_draft = np.asarray(p_draft)
-        tok_full = np.asarray(tok_full)
-        tok_resid = np.asarray(tok_resid)
-        now = self.clock()
-        dt = now - t0
-        st_ = self._stats
-        st_["decode_dispatches"] += 1
-        st_["verify_dispatches"] += 1
-        st_["decode_slot_steps"] += n_active
-        st_["decode_batch_max"] = max(st_["decode_batch_max"], n_active)
-        self._observe("decode_step", dt)
-        reg = get_registry()
-        reg.histogram("serve_decode_step_seconds",
-                      "decode dispatch wall time (all slots)").observe(dt)
-        reg.histogram("serve_decode_occupancy",
-                      "active slots per decode dispatch",
-                      buckets=tuple(range(1, B + 1))).observe(n_active)
-        accepted = rolled_back = 0
-        for slot, st in [(s, x) for s, x in enumerate(per_slot)
-                         if x is not None]:
-            n = len(st.draft)
-            tr = st.trace
-            if tr is not None:
-                tr.end_span(tr.start_span(
-                    f"verify[{len(st.generated)}]",
-                    parent=st.trace_spans.get("admitted"), t=t0,
-                    batch=n_active, proposed=n), t=now)
-            if not ok_rows[slot, 0]:
-                st.draft = []
-                self.scheduler.fail(st, "non-finite logits at decode")
-                continue
-            sampled = st.request.sampling.temperature > 0.0
-            if not sampled:
-                # greedy acceptance: draft i survives iff it equals the
-                # verifier's argmax at the previous row AND that row's
-                # logits are finite (pad/garbage rows never commit)
-                n_acc = 0
-                while n_acc < n and ok_rows[slot, n_acc] \
-                        and st.draft[n_acc] == int(greedy[slot, n_acc]):
-                    n_acc += 1
-                commit = [int(tok0[slot])] + \
-                    [int(greedy[slot, i]) for i in range(1, n_acc + 1)
-                     if ok_rows[slot, i]]
-            else:
-                # stochastic acceptance (ISSUE 16), point-mass drafter:
-                # accept draft i with probability p_i(d_i) under row i's
-                # filtered sampling distribution; on reject commit the
-                # device's residual redraw (row i with d_i masked out)
-                # and stop; on a clean sweep commit the bonus sample
-                # from row n. Marginally identical to plain sampled
-                # decode at every committed position.
-                commit = []
-                n_acc = 0
-                for i in range(n):
-                    if not ok_rows[slot, i]:
-                        break
-                    if self._spec_rng.random() < float(p_draft[slot, i]):
-                        commit.append(int(st.draft[i]))
+        with _trace.span("serve.verify", n_active=len(pairs)):
+            self._verify_batch(pairs, params)
+
+    def _verify_batch(self, pairs, params) -> None:
+        with _trace.span("serve.verify.build"):
+            B = self.config.max_batch_slots
+            S = self._spec_k + 1
+            pos = np.zeros((B,), np.int32)
+            ids = np.zeros((B, S), np.int32)
+            active = np.zeros((B,), bool)
+            per_slot: List[Optional[RequestState]] = [None] * B
+            for slot, st in pairs:
+                pos[slot] = st.seq_len - 1
+                ids[slot, 0] = st.generated[-1]
+                n = len(st.draft)
+                if n:
+                    ids[slot, 1:1 + n] = st.draft
+                active[slot] = True
+                per_slot[slot] = st
+            n_active = int(active.sum())
+            t0 = self.clock()
+            prog = self._get_verify()
+            temps, tks, tps = self._sampling_arrays(per_slot)
+            hang = chaos.active() and chaos.probe("serve.decode.hang")
+            args = (params, self.cache.k, self.cache.v,
+                    self._decode_table(per_slot), jnp.asarray(pos),
+                    jnp.asarray(ids), jnp.asarray(active),
+                    self._next_key(), temps, tks, tps,
+                    self._poison_array(per_slot)) \
+                + self._lora_args(per_slot)
+        with _trace.span("serve.verify.dispatch"):
+            tok0, greedy, ok_rows, p_draft, tok_full, tok_resid, new_k, \
+                new_v = self._guarded_dispatch("verify", prog, args,
+                                               hang=hang)
+            self.cache.update(new_k, new_v)
+        with _trace.span("serve.verify.readback"):
+            tok0 = np.asarray(tok0)
+            greedy = np.asarray(greedy)
+            ok_rows = np.asarray(ok_rows)
+            p_draft = np.asarray(p_draft)
+            tok_full = np.asarray(tok_full)
+            tok_resid = np.asarray(tok_resid)
+        with _trace.span("serve.verify.accept"):
+            now = self.clock()
+            dt = now - t0
+            st_ = self._stats
+            st_["decode_dispatches"] += 1
+            st_["verify_dispatches"] += 1
+            st_["decode_slot_steps"] += n_active
+            st_["decode_batch_max"] = max(st_["decode_batch_max"], n_active)
+            self._observe("decode_step", dt)
+            reg = get_registry()
+            reg.histogram("serve_decode_step_seconds",
+                          "decode dispatch wall time (all slots)").observe(dt)
+            reg.histogram("serve_decode_occupancy",
+                          "active slots per decode dispatch",
+                          buckets=tuple(range(1, B + 1))).observe(n_active)
+            accepted = rolled_back = 0
+            for slot, st in [(s, x) for s, x in enumerate(per_slot)
+                             if x is not None]:
+                n = len(st.draft)
+                tr = st.trace
+                if tr is not None:
+                    tr.end_span(tr.start_span(
+                        f"verify[{len(st.generated)}]",
+                        parent=st.trace_spans.get("admitted"), t=t0,
+                        batch=n_active, proposed=n), t=now)
+                if not ok_rows[slot, 0]:
+                    st.draft = []
+                    self.scheduler.fail(st, "non-finite logits at decode")
+                    continue
+                sampled = st.request.sampling.temperature > 0.0
+                if not sampled:
+                    # greedy acceptance: draft i survives iff it equals the
+                    # verifier's argmax at the previous row AND that row's
+                    # logits are finite (pad/garbage rows never commit)
+                    n_acc = 0
+                    while n_acc < n and ok_rows[slot, n_acc] \
+                            and st.draft[n_acc] == int(greedy[slot, n_acc]):
                         n_acc += 1
-                    else:
-                        commit.append(int(tok_resid[slot, i]))
-                        break
+                    commit = [int(tok0[slot])] + \
+                        [int(greedy[slot, i]) for i in range(1, n_acc + 1)
+                         if ok_rows[slot, i]]
                 else:
-                    if n == 0:
-                        commit.append(int(tok0[slot]))
-                    elif ok_rows[slot, n]:
-                        commit.append(int(tok_full[slot, n]))
-            committed = 0
-            for t in commit:
-                self._accept_token(st, t, now)
-                committed += 1
-                if st.terminal or st.is_done():
-                    break
-            acc = min(n_acc, committed) if sampled \
-                else max(0, committed - 1)
-            accepted += acc
-            rolled_back += n - acc
-            st.draft = []
-            if not st.terminal:
-                # block-table truncation: pages holding only the
-                # rejected tail's K/V leave the table now (_accept_token
-                # already finished any done request — its pages went
-                # back wholesale through _terminate)
-                self.cache.truncate_slot(st.slot, st.seq_len)
-        if accepted:
-            st_["spec_accepted"] += accepted
-            reg.counter("serve_spec_accepted_total",
-                        "speculative draft tokens accepted and "
-                        "committed").inc(accepted)
-        if rolled_back:
-            st_["spec_rolled_back"] += rolled_back
-            reg.counter("serve_spec_rolled_back_total",
-                        "speculative draft tokens rejected and rolled "
-                        "back by block-table truncation").inc(
-                rolled_back)
+                    # stochastic acceptance (ISSUE 16), point-mass drafter:
+                    # accept draft i with probability p_i(d_i) under row i's
+                    # filtered sampling distribution; on reject commit the
+                    # device's residual redraw (row i with d_i masked out)
+                    # and stop; on a clean sweep commit the bonus sample
+                    # from row n. Marginally identical to plain sampled
+                    # decode at every committed position.
+                    commit = []
+                    n_acc = 0
+                    for i in range(n):
+                        if not ok_rows[slot, i]:
+                            break
+                        if self._spec_rng.random() < float(p_draft[slot, i]):
+                            commit.append(int(st.draft[i]))
+                            n_acc += 1
+                        else:
+                            commit.append(int(tok_resid[slot, i]))
+                            break
+                    else:
+                        if n == 0:
+                            commit.append(int(tok0[slot]))
+                        elif ok_rows[slot, n]:
+                            commit.append(int(tok_full[slot, n]))
+                committed = 0
+                for t in commit:
+                    self._accept_token(st, t, now)
+                    committed += 1
+                    if st.terminal or st.is_done():
+                        break
+                acc = min(n_acc, committed) if sampled \
+                    else max(0, committed - 1)
+                accepted += acc
+                rolled_back += n - acc
+                st.draft = []
+                if not st.terminal:
+                    # block-table truncation: pages holding only the
+                    # rejected tail's K/V leave the table now (_accept_token
+                    # already finished any done request — its pages went
+                    # back wholesale through _terminate)
+                    self.cache.truncate_slot(st.slot, st.seq_len)
+            if accepted:
+                st_["spec_accepted"] += accepted
+                reg.counter("serve_spec_accepted_total",
+                            "speculative draft tokens accepted and "
+                            "committed").inc(accepted)
+            if rolled_back:
+                st_["spec_rolled_back"] += rolled_back
+                reg.counter("serve_spec_rolled_back_total",
+                            "speculative draft tokens rejected and rolled "
+                            "back by block-table truncation").inc(
+                    rolled_back)
 
     def _run_decode(self, pairs, params) -> None:
-        B = self.config.max_batch_slots
-        pos = np.zeros((B,), np.int32)
-        tokens = np.zeros((B,), np.int32)
-        active = np.zeros((B,), bool)
-        per_slot: List[Optional[RequestState]] = [None] * B
-        for slot, st in pairs:
-            # the newest generated token is not yet in the cache: this
-            # step writes its K/V at position seq_len-1 and attends over
-            # everything up to and including it
-            pos[slot] = st.seq_len - 1
-            tokens[slot] = st.generated[-1]
-            active[slot] = True
-            per_slot[slot] = st
-        n_active = int(active.sum())
-        t0 = self.clock()
-        prog = self._get_decode()
-        temps, tks, tps = self._sampling_arrays(per_slot)
-        hang = chaos.active() and chaos.probe("serve.decode.hang")
-        toks, ok, new_k, new_v = self._guarded_dispatch(
-            "decode", prog,
-            (params, self.cache.k, self.cache.v,
-             self._decode_table(per_slot), jnp.asarray(pos),
-             jnp.asarray(tokens), jnp.asarray(active), self._next_key(),
-             temps, tks, tps, self._poison_array(per_slot))
-            + self._lora_args(per_slot),
-            hang=hang)
-        self.cache.update(new_k, new_v)
-        toks = np.asarray(toks)
-        ok = np.asarray(ok)
-        now = self.clock()
-        dt = now - t0
-        st_ = self._stats
-        st_["decode_dispatches"] += 1
-        st_["decode_slot_steps"] += n_active
-        st_["decode_batch_max"] = max(st_["decode_batch_max"], n_active)
-        self._observe("decode_step", dt)
-        reg = get_registry()
-        reg.histogram("serve_decode_step_seconds",
-                      "decode dispatch wall time (all slots)").observe(dt)
-        reg.histogram("serve_decode_occupancy",
-                      "active slots per decode dispatch",
-                      buckets=tuple(range(1, B + 1))).observe(n_active)
-        for slot, st in list(pairs):
-            tr = st.trace
-            if tr is not None:
-                # decode[i]: this request's share of the batched decode
-                # dispatch that produced token i (i counts generated
-                # tokens; prefill produced token 0)
-                tr.end_span(tr.start_span(
-                    f"decode[{len(st.generated)}]",
-                    parent=st.trace_spans.get("admitted"), t=t0,
-                    batch=n_active), t=now)
-            if not ok[slot]:
-                self.scheduler.fail(st, "non-finite logits at decode")
-                continue
-            self._accept_token(st, int(toks[slot]), now)
+        with _trace.span("serve.decode", n_active=len(pairs)):
+            self._decode_batch(pairs, params)
+
+    def _decode_batch(self, pairs, params) -> None:
+        with _trace.span("serve.decode.build"):
+            B = self.config.max_batch_slots
+            pos = np.zeros((B,), np.int32)
+            tokens = np.zeros((B,), np.int32)
+            active = np.zeros((B,), bool)
+            per_slot: List[Optional[RequestState]] = [None] * B
+            for slot, st in pairs:
+                # the newest generated token is not yet in the cache:
+                # this step writes its K/V at position seq_len-1 and
+                # attends over everything up to and including it
+                pos[slot] = st.seq_len - 1
+                tokens[slot] = st.generated[-1]
+                active[slot] = True
+                per_slot[slot] = st
+            n_active = int(active.sum())
+            t0 = self.clock()
+            prog = self._get_decode()
+            temps, tks, tps = self._sampling_arrays(per_slot)
+            hang = chaos.active() and chaos.probe("serve.decode.hang")
+            args = (params, self.cache.k, self.cache.v,
+                    self._decode_table(per_slot), jnp.asarray(pos),
+                    jnp.asarray(tokens), jnp.asarray(active),
+                    self._next_key(), temps, tks, tps,
+                    self._poison_array(per_slot)) \
+                + self._lora_args(per_slot)
+        with _trace.span("serve.decode.dispatch"):
+            toks, ok, new_k, new_v = self._guarded_dispatch(
+                "decode", prog, args, hang=hang)
+            self.cache.update(new_k, new_v)
+        with _trace.span("serve.decode.readback"):
+            toks = np.asarray(toks)
+            ok = np.asarray(ok)
+        with _trace.span("serve.decode.accept"):
+            now = self.clock()
+            dt = now - t0
+            st_ = self._stats
+            st_["decode_dispatches"] += 1
+            st_["decode_slot_steps"] += n_active
+            st_["decode_batch_max"] = max(st_["decode_batch_max"],
+                                          n_active)
+            self._observe("decode_step", dt)
+            reg = get_registry()
+            reg.histogram("serve_decode_step_seconds",
+                          "decode dispatch wall time (all slots)"
+                          ).observe(dt)
+            reg.histogram("serve_decode_occupancy",
+                          "active slots per decode dispatch",
+                          buckets=tuple(range(1, B + 1))
+                          ).observe(n_active)
+            for slot, st in list(pairs):
+                tr = st.trace
+                if tr is not None:
+                    # decode[i]: this request's share of the batched
+                    # decode dispatch that produced token i (i counts
+                    # generated tokens; prefill produced token 0)
+                    tr.end_span(tr.start_span(
+                        f"decode[{len(st.generated)}]",
+                        parent=st.trace_spans.get("admitted"), t=t0,
+                        batch=n_active), t=now)
+                if not ok[slot]:
+                    self.scheduler.fail(st, "non-finite logits at decode")
+                    continue
+                self._accept_token(st, int(toks[slot]), now)
 
     def _accept_token(self, st: RequestState, token: int,
                       now: float) -> None:

@@ -34,6 +34,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..monitor import trace as _trace
 from ..testing import chaos
 from .kv_cache import PagedKVCache
 from .resilience import ServerOverloaded
@@ -129,6 +130,10 @@ class RequestState:
         self.generated: List[int] = []
         self.slot: Optional[int] = None
         self.submitted_t = now
+        #: when this request last entered the waiting queue: submission,
+        #: or its requeue after a preemption (the start of the
+        #: ``serve.queued`` span the next admission records)
+        self.queued_t = now
         self.admitted_t: Optional[float] = None
         self.first_token_t: Optional[float] = None
         self.finished_t: Optional[float] = None
@@ -627,6 +632,9 @@ class Scheduler:
             free_slots.pop(0)
             st.slot = slot
             st.admitted_t = self.clock()
+            _trace.record("serve.queued", st.queued_t, st.admitted_t,
+                          request_id=st.request.request_id,
+                          prompt_len=st.prompt_len)
             st.prefill_pos = n_hit
             st.prefill_len = int(eff.size)
             self.slots[slot] = st
@@ -713,6 +721,7 @@ class Scheduler:
         self.slots[st.slot] = None
         st.slot = None
         st.admitted_t = None
+        st.queued_t = self.clock()
         st.prefill_pos = 0
         st.prefill_len = None
         st.weights_epoch = None

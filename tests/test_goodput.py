@@ -346,7 +346,7 @@ def test_data_wait_span_attaches_to_step_trace():
                 if t.name == "train.step"]
     assert kept
     names = [s.name for s in kept[-1].spans]
-    assert "data_wait" in names and "dispatch" in names
+    assert "data_wait" in names and "train.dispatch" in names
     dw = [s for s in kept[-1].spans if s.name == "data_wait"][0]
     assert dw.t1 is not None and dw.t1 >= dw.t0
     # consumed on attach: nothing pending for the next step

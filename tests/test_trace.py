@@ -109,21 +109,22 @@ def test_retained_ring_is_bounded():
 
 def test_trace_off_allocates_nothing():
     assert trace_mod.start_trace("x") is None
-    with trace_mod.maybe_span("y"):
+    with trace_mod.span("y"):
         pass
     assert trace_mod.TRACE_STATS["spans_allocated"] == 0
     assert trace_mod.TRACE_STATS["traces_started"] == 0
 
 
-def test_activate_and_maybe_span_attach():
+def test_activate_and_span_attach():
     t = trace_mod.Tracer(capacity=4)
     with flag_scope("trace_sample", 1.0):
         tr = t.start_trace("step")
     assert trace_mod.current_trace() is None
     with trace_mod.activate(tr):
         assert trace_mod.current_trace() is tr
-        with trace_mod.maybe_span("inner", k=1) as sp:
-            assert sp is not None and sp.trace_id == tr.trace_id
+        with trace_mod.span("inner", k=1) as sp:
+            assert sp.child is not None \
+                and sp.child.trace_id == tr.trace_id
     assert trace_mod.current_trace() is None
     assert "inner" in _span_names(tr)
 
@@ -537,7 +538,7 @@ def test_train_step_trace_and_zero_overhead():
     assert len(kept) == 1
     tr = kept[0]
     assert tr.name == "train.step"
-    assert "dispatch" in _span_names(tr)
+    assert "train.dispatch" in _span_names(tr)
     assert tr.anomaly is None
 
 
