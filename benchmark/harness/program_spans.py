@@ -25,32 +25,37 @@ device metrics the traced part of it, like every other trace reader.
 
 from __future__ import annotations
 
+import importlib
 import statistics
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
 from .loadgen import percentile
 from .result import say
+from .trace_reduce import base_name
 
 NAME, T0, T1, ID, PARENT, STEP, ATTRS = range(7)
 #: runs whose table has been printed (`report` is called by every reader)
 _reported: set = set()
 
 
-def _ring():
+def _program(module: str, attr: str):
+    """`paddle_tpu.<module>` when the program has `attr` there, else
+    None (the parent of the PR that added it)."""
     try:
-        from paddle_tpu.monitor import trace
+        mod = importlib.import_module("paddle_tpu." + module)
     except ImportError:
         return None
-    return trace if hasattr(trace, "spans") else None
+    return mod if hasattr(mod, attr) else None
+
+
+def _ring():
+    return _program("monitor.trace", "spans")
 
 
 def _scopes(module: str) -> Optional[dict]:
-    try:
-        from paddle_tpu.jit import aot
-    except ImportError:
-        return None
-    return aot.scopes(module) if hasattr(aot, "scopes") else None
+    aot = _program("jit.aot", "scopes")
+    return aot.scopes(module) if aot else None
 
 
 def window(run) -> Optional[Tuple[float, float]]:
@@ -157,6 +162,7 @@ def by_block(run) -> Optional[dict]:
     program's scope index of the window's largest module.
 
     {"module", "busy_s", "unscoped_s", "blocks": {block: {phase: s}},
+     "kinds": {block: {instruction name without its number: s}},
      "unscoped_top": [(instruction, s)]} — or None without a device
     trace or an index. Instructions of OTHER programs in the window
     count as unscoped: in a train window there are next to none."""
@@ -168,6 +174,7 @@ def by_block(run) -> Optional[dict]:
     if index is None:
         return None
     blocks: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(float))
+    kinds: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(float))
     loose: Dict[str, float] = {}
     for op, sec in tr["by_op"].items():
         hit = index.get(op)
@@ -175,9 +182,11 @@ def by_block(run) -> Optional[dict]:
             loose[op] = sec
         else:
             blocks[hit[0]][hit[1]] += sec
+            kinds[hit[0]][base_name(op)] += sec
     return {"module": module, "busy_s": sum(tr["by_op"].values()),
             "unscoped_s": sum(loose.values()),
             "blocks": {b: dict(p) for b, p in blocks.items()},
+            "kinds": {b: dict(k) for b, k in kinds.items()},
             "unscoped_top": sorted(loose.items(), key=lambda kv: -kv[1])[:8]}
 
 
@@ -210,11 +219,8 @@ def report(run) -> None:
     split = by_block(run)
     if split:
         _report_blocks(split)
-    try:
-        from paddle_tpu.jit import aot
-        took = getattr(aot, "SCOPE_PARSE_SECONDS", None)
-    except ImportError:
-        took = None
+    aot = _program("jit.aot", "SCOPE_PARSE_SECONDS")
+    took = aot.SCOPE_PARSE_SECONDS if aot else None
     if took:
         say(f"  program scopes: {len(took)} programs indexed, as_text + parse "
             f"took {sum(took.values()):.3f}s of set-up in all")
@@ -270,6 +276,8 @@ def _report_blocks(split: dict) -> None:
                                 key=lambda kv: -sum(kv[1].values())):
         cells = "".join(f"{100.0 * phases.get(p, 0.0) / busy:>8.2f}"
                         for p in ("fwd", "bwd", "remat"))
-        say(f"    {block:<12}{100.0 * sum(phases.values()) / busy:>8.2f}{cells}")
+        top = sorted(split["kinds"][block].items(), key=lambda kv: -kv[1])[:4]
+        say(f"    {block:<12}{100.0 * sum(phases.values()) / busy:>8.2f}{cells}   "
+            + ", ".join(f"{k} {100.0 * s / busy:.2f}" for k, s in top))
     say(f"    {'(unscoped)':<12}{100.0 * split['unscoped_s'] / busy:>8.2f}   "
         + ", ".join(f"{op} {100.0 * s / busy:.2f}" for op, s in split["unscoped_top"]))

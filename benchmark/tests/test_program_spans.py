@@ -156,6 +156,8 @@ def test_by_block_on_the_recorded_trace(monkeypatch, capsys):
     assert split["blocks"]["attn"]["remat"] == pytest.approx(k["flash_fwd"], rel=1e-9)
     assert split["blocks"]["attn"]["bwd"] == pytest.approx(k["flash_bwd"], rel=1e-9)
     assert "loss" not in split["blocks"]
+    assert split["kinds"]["attn"] == pytest.approx(
+        {"flash_fwd": k["flash_fwd"], "flash_bwd": k["flash_bwd"]}, rel=1e-9)
     dropout = summary["by_op"]["fused_dropout.48"]
     assert ps.block_pct(run, ("attn",)) == pytest.approx(
         100.0 * (k["flash_fwd"] + k["flash_bwd"]) / busy)
@@ -166,7 +168,7 @@ def test_by_block_on_the_recorded_trace(monkeypatch, capsys):
     assert ops and split["unscoped_top"][0][0] not in index
     out = capsys.readouterr().out
     assert "device time by block, chip 0, program jit_train_step" in out
-    assert "(unscoped)" in out
+    assert "(unscoped)" in out and "flash_bwd " in out
 
 
 def test_none_on_missing_data(monkeypatch):

@@ -31,7 +31,8 @@ invalid/torn checkpoint was skipped at resume), ``collective_timeout``
 (the eager-collective watchdog tripped), ``nonfinite_skip`` (an update
 was rolled back under ``skip_nonfinite_budget``), ``preempted``
 (SIGTERM honoured with a final commit), ``chaos`` (an injected fault
-fired) — are rendered as a dedicated "Recovery timeline" section by
+fired), ``serve_step_stall`` (an ``engine.step()`` far longer than its
+kin, with its phase table) — are rendered as a dedicated "Recovery timeline" section by
 ``monitor_report.py --flight``.
 """
 

@@ -520,7 +520,8 @@ class span:
                 self.step = parent.step
         self.span_id = next(_span_ids)
         stack.append(self)
-        #: the Span attached to the thread's current FLAGS_trace trace
+        # `child`: the Span attached to the thread's current FLAGS_trace
+        # trace, nested under the enclosing span's
         self._trace = tr = current_trace()
         self.child = None if tr is None else tr.start_span(
             self.name, parent=parent.child if parent is not None
@@ -539,11 +540,12 @@ class span:
             self._ann.__exit__(exc_type, exc, tb)
         if self.child is not None:
             self._trace.end_span(self.child)
-        stack = _open.stack
-        if stack and stack[-1] is self:
-            stack.pop()
-        elif self in stack:            # closed out of order (a generator
-            stack.remove(self)         # abandoned mid-span)
+        stack = getattr(_open, "stack", None)
+        if stack:
+            if stack[-1] is self:
+                stack.pop()
+            elif self in stack:        # closed out of order (a generator
+                stack.remove(self)     # abandoned mid-span)
         _ring.append((self.name, self.t0, self.t1, self.span_id,
                       self.parent_id, self.step, self.attrs))
         return False

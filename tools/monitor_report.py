@@ -960,8 +960,7 @@ _RECOVERY_EVENTS_FALLBACK = (
     "nonfinite_skip", "preempted", "trip", "chaos", "request_failed",
     "request_expired", "request_cancelled", "request_drained",
     "request_shed", "decode_watchdog", "overload", "drained",
-    "replica_migration", "health_spike",
-                   "serve_step_stall")
+    "replica_migration", "health_spike", "serve_step_stall")
 
 
 def _recovery_events() -> tuple:
