@@ -9,9 +9,9 @@ program's own spans do not go through the device trace. They read
                                        spans, `time.perf_counter` seconds,
                                        records `(name, t0, t1, span_id,
                                        parent_id, step, attrs)`
-    paddle_tpu.jit.aot.scopes(module)  {instruction: (block, phase)} of
-                                       the newest executable built under
-                                       an HLO module name
+    paddle_tpu.jit.aot.scopes(module)  {instruction: (block, phase, rule)}
+                                       of the newest executable built
+                                       under an HLO module name
 
 and join them with what a `Run` carries: the window on the same clock
 (`t_start + setup_s`, `window_s`) and the traced window's `by_op` and
@@ -109,28 +109,18 @@ def self_time(rec: tuple, kids: List[tuple]) -> float:
     return max(0.0, (rec[T1] - rec[T0]) - covered)
 
 
-def descendants(rec: tuple, kids: Dict[int, List[tuple]]) -> List[tuple]:
-    out, todo = [], [rec]
-    while todo:
-        for k in kids.get(todo.pop()[ID], ()):
-            if k[T0] >= rec[T0]:          # a queue wait began before: no phase
-                out.append(k)
-                todo.append(k)
-    return out
-
-
 def minus_descendants_ms(run, name: str, suffix: str) -> Optional[float]:
     """Median over the spans `name` of: duration minus its descendants
     whose name ends with `suffix` (`serve.step` minus every
     `.readback`: the host's own share of a step)."""
     report(run)
-    win = window(run)
-    if win is None:
+    ring, win = _ring(), window(run)
+    if ring is None or win is None:
         return None
     recs = records(run)
-    kids = children(recs)
-    xs = [(r[T1] - r[T0]) - sum(d[T1] - d[T0] for d in descendants(r, kids)
-                                if d[NAME].endswith(suffix))
+    xs = [(r[T1] - r[T0]) - sum(sec for phase, sec
+                                in ring.phase_table(r, recs).items()
+                                if phase.endswith(suffix))
           for r in inside(recs, win) if r[NAME] == name]
     return statistics.median(xs) * 1e3 if xs else None
 
@@ -162,9 +152,12 @@ def by_block(run) -> Optional[dict]:
     program's scope index of the window's largest module.
 
     {"module", "busy_s", "unscoped_s", "blocks": {block: {phase: s}},
+     "rules": {own | vote | operand: s},
      "kinds": {block: {instruction name without its number: s}},
      "unscoped_top": [(instruction, s)]} — or None without a device
-    trace or an index. Instructions of OTHER programs in the window
+    trace or an index. `rules` says how the index found the blocks:
+    `own` is the instruction's own scope, `vote` and `operand` are the
+    index's inferences. Instructions of OTHER programs in the window
     count as unscoped: in a train window there are next to none."""
     tr = run.trace
     if not tr or not tr.get("by_op") or not tr.get("modules"):
@@ -175,6 +168,7 @@ def by_block(run) -> Optional[dict]:
         return None
     blocks: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(float))
     kinds: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(float))
+    rules: Dict[str, float] = defaultdict(float)
     loose: Dict[str, float] = {}
     for op, sec in tr["by_op"].items():
         hit = index.get(op)
@@ -182,10 +176,12 @@ def by_block(run) -> Optional[dict]:
             loose[op] = sec
         else:
             blocks[hit[0]][hit[1]] += sec
+            rules[hit[2]] += sec
             kinds[hit[0]][base_name(op)] += sec
     return {"module": module, "busy_s": sum(tr["by_op"].values()),
             "unscoped_s": sum(loose.values()),
             "blocks": {b: dict(p) for b, p in blocks.items()},
+            "rules": dict(rules),
             "kinds": {b: dict(k) for b, k in kinds.items()},
             "unscoped_top": sorted(loose.items(), key=lambda kv: -kv[1])[:8]}
 
@@ -215,18 +211,13 @@ def report(run) -> None:
     win = window(run)
     recs = records(run)
     if win and recs:
-        _report_spans(recs, win)
+        _report_spans(_ring(), recs, win)
     split = by_block(run)
     if split:
         _report_blocks(split)
-    aot = _program("jit.aot", "SCOPE_PARSE_SECONDS")
-    took = aot.SCOPE_PARSE_SECONDS if aot else None
-    if took:
-        say(f"  program scopes: {len(took)} programs indexed, as_text + parse "
-            f"took {sum(took.values()):.3f}s of set-up in all")
 
 
-def _report_spans(recs: List[tuple], win: Tuple[float, float]) -> None:
+def _report_spans(ring, recs: List[tuple], win: Tuple[float, float]) -> None:
     whole = inside(recs, win)
     by_name: Dict[str, List[float]] = defaultdict(list)
     for r in whole:
@@ -251,14 +242,13 @@ def _report_spans(recs: List[tuple], win: Tuple[float, float]) -> None:
         median = statistics.median(r[T1] - r[T0] for r in steps)
         for r in steps:
             took = r[T1] - r[T0]
-            if took > max(1.0, 5.0 * median):
-                phases = defaultdict(float)
-                for d in descendants(r, kids):
-                    phases[d[NAME]] += (d[T1] - d[T0]) * 1e3
+            if ring.stalled(took, median):           # the program's own rule
                 say(f"    STALLED {root} step {r[STEP]} at "
-                    f"{r[T0] - win[0]:.3f}s into the window: {took * 1e3:.1f} ms; "
-                    + ", ".join(f"{k} {v:.1f}" for k, v in
-                                sorted(phases.items(), key=lambda kv: -kv[1])))
+                    f"{r[T0] - win[0]:.3f}s into the window: {took * 1e3:.1f} ms, "
+                    f"sat in {ring.stall_phase(r, recs)}; "
+                    + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in
+                                sorted(ring.phase_table(r, recs).items(),
+                                       key=lambda kv: -kv[1])))
     waits = [(r[T1] - r[T0]) * 1e3 for r in recs
              if r[NAME] == "serve.queued" and win[0] <= r[T1] <= win[1]]
     if waits:
@@ -281,3 +271,10 @@ def _report_blocks(split: dict) -> None:
             + ", ".join(f"{k} {100.0 * s / busy:.2f}" for k, s in top))
     say(f"    {'(unscoped)':<12}{100.0 * split['unscoped_s'] / busy:>8.2f}   "
         + ", ".join(f"{op} {100.0 * s / busy:.2f}" for op, s in split["unscoped_top"]))
+    rules = split["rules"]
+    guessed = rules.get("vote", 0.0) + rules.get("operand", 0.0)
+    say("    block found by: "
+        + ", ".join(f"{r} {100.0 * rules.get(r, 0.0) / busy:.2f}"
+                    for r in ("own", "vote", "operand"))
+        + f" (% of busy); without the two inferences unscoped reads "
+        f"{100.0 * (split['unscoped_s'] + guessed) / busy:.2f}")

@@ -32,8 +32,15 @@ def rec(name, t0, t1, sid, parent=None, step=None, attrs=None):
 
 
 class Ring:
+    """The ring's reading side over hand-made records; the step walk and
+    the stall rule are the program's own (`monitor/trace.py`)."""
+
     def __init__(self, recs):
         self.recs = recs
+        trace = pytest.importorskip("paddle_tpu.monitor.trace")
+        self.phase_table = trace.phase_table
+        self.stalled = trace.stalled
+        self.stall_phase = trace.stall_phase
 
     def spans(self, since=None, until=None, name=None):
         return [r for r in self.recs
@@ -97,7 +104,7 @@ def test_self_time_and_minus_descendants(ring):
     step4 = next(r for r in recs if r[ps.ID] == 30)
     # the queue wait recorded under serve.admit began before the step:
     # not a phase of it, and clipped out of its parent's self time
-    assert {d[ps.NAME] for d in ps.descendants(step4, kids)} == {
+    assert set(ps._ring().phase_table(step4, recs)) == {
         "serve.admit", "serve.prefill", "serve.prefill.readback",
         "serve.decode", "serve.decode.dispatch", "serve.decode.readback"}
     admit = next(r for r in recs if r[ps.ID] == 31)
@@ -113,7 +120,8 @@ def test_report_prints_once_and_names_the_stall(ring, capsys):
     ps.report(run)
     out = capsys.readouterr().out
     assert out.count("program spans inside the window") == 1
-    assert "STALLED serve.step step 4 at 3.000s into the window: 3000.0 ms" in out
+    assert ("STALLED serve.step step 4 at 3.000s into the window: 3000.0 ms, "
+            "sat in serve.decode.readback") in out
     assert "serve.decode.readback 2200.0" in out
     assert "serve.queued ending in the window: 2 admissions" in out
     assert "children cover" in out
@@ -141,8 +149,10 @@ def test_by_block_on_the_recorded_trace(monkeypatch, capsys):
     ops = sorted(summary["by_op"])
     # a hand-made index: the flash kernels are attention, the dropout
     # kernel belongs to the ffn half, everything else has no block
-    index = {"flash_fwd.19": ("attn", "remat"), "flash_bwd.10": ("attn", "bwd"),
-             "fused_dropout.48": ("ffn", "bwd"), "not_in_the_window.1": ("loss", "fwd")}
+    index = {"flash_fwd.19": ("attn", "remat", "own"),
+             "flash_bwd.10": ("attn", "bwd", "own"),
+             "fused_dropout.48": ("ffn", "bwd", "operand"),
+             "not_in_the_window.1": ("loss", "fwd", "vote")}
     monkeypatch.setattr(ps, "_scopes", lambda module: index
                         if module == "jit_train_step" else None)
     monkeypatch.setattr(ps, "_reported", set())
@@ -159,6 +169,8 @@ def test_by_block_on_the_recorded_trace(monkeypatch, capsys):
     assert split["kinds"]["attn"] == pytest.approx(
         {"flash_fwd": k["flash_fwd"], "flash_bwd": k["flash_bwd"]}, rel=1e-9)
     dropout = summary["by_op"]["fused_dropout.48"]
+    assert split["rules"] == pytest.approx(
+        {"own": k["flash_fwd"] + k["flash_bwd"], "operand": dropout}, rel=1e-9)
     assert ps.block_pct(run, ("attn",)) == pytest.approx(
         100.0 * (k["flash_fwd"] + k["flash_bwd"]) / busy)
     assert ps.block_pct(run, ("ffn", "moe")) == pytest.approx(100.0 * dropout / busy)
@@ -169,6 +181,7 @@ def test_by_block_on_the_recorded_trace(monkeypatch, capsys):
     out = capsys.readouterr().out
     assert "device time by block, chip 0, program jit_train_step" in out
     assert "(unscoped)" in out and "flash_bwd " in out
+    assert "block found by: own " in out and ", vote 0.00, operand " in out
 
 
 def test_none_on_missing_data(monkeypatch):

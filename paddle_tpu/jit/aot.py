@@ -31,7 +31,6 @@ from __future__ import annotations
 
 import collections
 import re
-import time
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -41,16 +40,16 @@ from ..nn.layer import BLOCKS
 __all__ = ["AOTProgram", "SCOPES", "scopes", "parse_scopes"]
 
 #: HLO module name (``jit_train_step``; a device trace's `XLA Modules`
-#: line carries the same) -> ``{instruction name: (block, phase)}`` of
-#: the newest executable built under that name: which block of the model
-#: (``nn.layer.BLOCKS``) each instruction of the OPTIMIZED program came
-#: from, and whether it is forward, backward or recomputed-forward work.
-#: Read by whoever splits a device trace by block (the benchmark's
-#: ``block.*`` readers). Only this small dict outlives a build, never
-#: the executable or its text.
-SCOPES: Dict[str, Dict[str, Tuple[str, str]]] = {}
-#: seconds the newest build under each name spent on `as_text()` + parse
-SCOPE_PARSE_SECONDS: Dict[str, float] = {}
+#: line carries the same) -> ``{instruction name: (block, phase, rule)}``
+#: of the newest executable built under that name: which block of the
+#: model (``nn.layer.BLOCKS``) each instruction of the OPTIMIZED program
+#: came from, whether it is forward, backward or recomputed-forward
+#: work, and by which rule of :func:`parse_scopes` the block was found
+#: (``own`` | ``vote`` | ``operand``: the last two are inferences, and a
+#: reader shows how much time rests on them). Read by whoever splits a
+#: device trace by block (the benchmark's ``block.*`` readers). Only
+#: this small dict outlives a build, never the executable or its text.
+SCOPES: Dict[str, Dict[str, Tuple[str, str, str]]] = {}
 
 _HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
@@ -83,19 +82,22 @@ def _resolve(op_name: str) -> Optional[Tuple[str, str]]:
     return block, phase
 
 
-def parse_scopes(hlo_text: str) -> Tuple[str, Dict[str, Tuple[str, str]]]:
-    """(module name, {instruction name: (block, phase)}) from the text
-    of an optimized HLO module (``compiled.as_text()``).
+def parse_scopes(hlo_text: str
+                 ) -> Tuple[str, Dict[str, Tuple[str, str, str]]]:
+    """(module name, {instruction name: (block, phase, rule)}) from the
+    text of an optimized HLO module (``compiled.as_text()``).
 
-    An instruction resolves by its own ``op_name``. One whose own path
-    holds no block (XLA names a fusion after its root, and the root may
-    be plumbing: the scan's write of a layer's gradient into the
-    stacked buffer; a layout copy carries no metadata at all) takes the
-    block most of the instructions of its fused computation carry,
-    else that of its first operand that has one: the write counts with
-    the block that produced what is written. One hop only."""
+    An instruction resolves by its own ``op_name`` (rule ``own``). One
+    whose own path holds no block (XLA names a fusion after its root,
+    and the root may be plumbing: the scan's write of a layer's
+    gradient into the stacked buffer; a layout copy carries no metadata
+    at all) takes the block most of the instructions of its fused
+    computation carry (``vote``), else that of its first operand that
+    has one (``operand``): the write counts with the block that
+    produced what is written. One hop only. The last two rules are
+    guesses, so the rule travels with the block."""
     m = _HLO_MODULE.match(hlo_text)
-    table: Dict[str, Tuple[str, str]] = {}
+    table: Dict[str, Tuple[str, str, str]] = {}
     votes: Dict[str, collections.Counter] = {}
     calls: Dict[str, str] = {}
     operands: Dict[str, list] = {}
@@ -110,7 +112,7 @@ def parse_scopes(hlo_text: str) -> Tuple[str, Dict[str, Tuple[str, str]]]:
         op_name = _HLO_OP_NAME.search(line)
         hit = _resolve(op_name.group(1)) if op_name else None
         if hit is not None:
-            table[inst.group(1)] = hit
+            table[inst.group(1)] = hit + ("own",)
             votes.setdefault(computation, collections.Counter())[hit] += 1
         else:
             callee = _HLO_CALLS.search(line)
@@ -120,17 +122,18 @@ def parse_scopes(hlo_text: str) -> Tuple[str, Dict[str, Tuple[str, str]]]:
             operands[inst.group(1)] = _HLO_OPERAND.findall(head)[1:]
     for name, callee in calls.items():
         if callee in votes:
-            table[name] = votes[callee].most_common(1)[0][0]
+            table[name] = votes[callee].most_common(1)[0][0] + ("vote",)
     direct = dict(table)
     for name, ops in operands.items():
         if name not in table:
             hit = next((direct[o] for o in ops if o in direct), None)
             if hit is not None:
-                table[name] = hit
+                table[name] = hit[:2] + ("operand",)
     return (m.group(1) if m else ""), table
 
 
-def scopes(module_name: str) -> Optional[Dict[str, Tuple[str, str]]]:
+def scopes(module_name: str
+           ) -> Optional[Dict[str, Tuple[str, str, str]]]:
     """The scope index of the newest executable built under this HLO
     module name, or None when none was."""
     return SCOPES.get(module_name)
@@ -201,10 +204,8 @@ class AOTProgram:
             lowered = self._jitted.lower(*args)
         compiled = lowered.compile()
         self.builds += 1
-        t0 = time.perf_counter()
         module, table = parse_scopes(compiled.as_text())
         SCOPES[module] = table
-        SCOPE_PARSE_SECONDS[module] = time.perf_counter() - t0
         if self._on_attribute is not None:
             self._on_attribute(self.kind, lowered, compiled)
         return compiled

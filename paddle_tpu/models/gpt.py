@@ -47,7 +47,7 @@ from ..distributed.meta_parallel.parallel_layers.mp_layers import (
     VocabParallelEmbedding, ParallelCrossEntropy)
 from ..nn import functional as F
 from ..nn.initializer import Constant, Normal
-from ..nn.layer import Layer, LayerList, block_scope
+from ..nn.layer import Layer, LayerList
 from ..nn.layers.common import Dropout, Embedding
 from ..nn.layers.norm import LayerNorm
 from ..nn.scan import (can_scan_layers, note_scan_fallback, scan_layers,
@@ -289,7 +289,7 @@ class GPTAttention(Layer):
                                         write_pages_quant)
 
         quant = cache.k_scale is not None
-        with block_scope("kv_write", k):
+        with jax.named_scope("kv_write"):
             if quant:
                 def updq(pages, scales, new, table, p):
                     return write_pages_quant(pages, scales, new, table, p)
@@ -481,12 +481,12 @@ class GPTDecoderLayer(Layer):
         else:
             a, cache = self.attn(self.ln1(x), cache, pos=pos)
         # each half's dropout and residual add count with the half
-        with block_scope("attn", x):
+        with jax.named_scope("attn"):
             x = x + self.dropout1(a)
         if sp:
             x = _constrain(x, BATCH, sp, None)
         h = self._ffn(self.ln2(x))
-        with block_scope(self._ffn_block, x):
+        with jax.named_scope(self._ffn_block):
             x = x + self.dropout2(h)
         if sp:
             x = _constrain(x, BATCH, sp, None)
@@ -625,7 +625,7 @@ class GPTModel(Layer):
             else:
                 start = 0 if caches is None else caches[0][0].shape[1]
                 position_ids = arange(start, start + S, dtype="int32")
-        with block_scope("embed", input_ids):
+        with jax.named_scope("embed"):
             x = self.word_embeddings(input_ids) + \
                 self.position_embeddings(position_ids)
             x = self.embedding_dropout(x)
@@ -785,7 +785,7 @@ class GPTModel(Layer):
             # the scan's own slicing of a layer's pool out of the stacked
             # pools, and its write back, count as kv_write; what the
             # body traces resolves to its own (inner) block
-            with block_scope("kv_write", x):
+            with jax.named_scope("kv_write"):
                 x, new = scan_layers_with_cache(
                     self.layers, x, cache_arrs, *extras,
                     body_call=body, scan_in=scan_in,
@@ -835,7 +835,7 @@ def parallel_logits(hidden, embedding_weight):
     def fn(h, w):
         return jnp.einsum("bse,ve->bsv", h, w, precision=prec)
 
-    with block_scope("loss", hidden):
+    with jax.named_scope("loss"):
         logits = apply(fn, hidden, embedding_weight, name="lm_logits")
         return _constrain(logits, BATCH, None, MP)
 

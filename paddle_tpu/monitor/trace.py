@@ -68,7 +68,8 @@ from jax.profiler import TraceAnnotation
 __all__ = [
     "Span", "Trace", "Tracer", "get_tracer", "set_tracer", "enabled",
     "start_trace", "current_trace", "activate", "span", "spans",
-    "record", "clear_spans", "phase_table", "SPAN_RING_CAPACITY",
+    "record", "phase_table", "stalled", "stall_phase",
+    "STALL_FLOOR_S", "SPAN_RING_CAPACITY",
     "export_perfetto", "perfetto_doc", "ANOMALY_REASONS", "TRACE_STATS",
     "reset_trace_stats", "load_trace_dump",
 ]
@@ -519,18 +520,21 @@ class span:
             if self.step is None:
                 self.step = parent.step
         self.span_id = next(_span_ids)
-        stack.append(self)
+        self._ann = None
+        if TraceAnnotation.is_enabled():
+            ann = (TraceAnnotation(self.name) if self.step is None
+                   else TraceAnnotation(self.name, step=self.step))
+            ann.__enter__()
+            self._ann = ann
         # `child`: the Span attached to the thread's current FLAGS_trace
         # trace, nested under the enclosing span's
         self._trace = tr = current_trace()
         self.child = None if tr is None else tr.start_span(
             self.name, parent=parent.child if parent is not None
             else None, **(self.attrs or {}))
-        self._ann = None
-        if TraceAnnotation.is_enabled():
-            self._ann = (TraceAnnotation(self.name) if self.step is None
-                         else TraceAnnotation(self.name, step=self.step))
-            self._ann.__enter__()
+        # last: a profiler that raised above leaves no stale parent on
+        # the thread's stack
+        stack.append(self)
         self.t0 = time.perf_counter()
         return self
 
@@ -575,10 +579,6 @@ def spans(since: Optional[float] = None, until: Optional[float] = None,
             and (until is None or r[1] <= until)]
 
 
-def clear_spans() -> None:
-    _ring.clear()
-
-
 def phase_table(root: tuple, recs: List[tuple]) -> Dict[str, float]:
     """``{span name: summed seconds}`` of every span of ``recs`` under
     the record ``root`` (children, grandchildren, ...) — a stalled
@@ -594,6 +594,31 @@ def phase_table(root: tuple, recs: List[tuple]) -> Dict[str, float]:
             under.add(r[3])
             out[r[0]] = out.get(r[0], 0.0) + (r[2] - r[1])
     return out
+
+
+#: a step shorter than this is never a stall
+STALL_FLOOR_S = 1.0
+
+
+def stalled(seconds: float, median: float) -> bool:
+    """THE stall rule: a step longer than max(1 s, 5 x the median step
+    of its kind). The engine counts by it and a reader of the ring
+    marks by it."""
+    return seconds > max(STALL_FLOOR_S, 5.0 * median)
+
+
+def stall_phase(root: tuple, recs: List[tuple]) -> str:
+    """The phase a step sat in: the deepest span on the path of the
+    largest children under ``root`` (``serve.decode.readback``: the
+    host waited for the chip; ``.dispatch``: the runtime's execute call
+    did not return); ``root``'s own name when it has no child."""
+    phase, parent = root[0], root[3]
+    while True:
+        kids = [r for r in recs if r[4] == parent and r[1] >= root[1]]
+        if not kids:
+            return phase
+        top = max(kids, key=lambda r: r[2] - r[1])
+        phase, parent = top[0], top[3]
 
 
 # ---------------------------------------------------------------------------

@@ -10,7 +10,6 @@ registries, hooks, state_dict, train/eval). Parameters are eager
 from __future__ import annotations
 
 import collections
-import contextlib
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 import jax
@@ -38,18 +37,6 @@ BLOCKS = ("embed", "attn", "ffn", "moe", "norm", "loss", "optimizer",
           "sampling", "kv_write")
 
 
-def block_scope(block: str, *inputs):
-    """``jax.named_scope(block)`` while a program is being traced: the
-    operations traced inside carry the block's name in their HLO
-    metadata (``op_name``), which changes no compiled code. Eager calls
-    — no input is a tracer — get a null context: a scope must cost an
-    eager op nothing and can never key a retrace."""
-    for x in inputs:
-        if isinstance(getattr(x, "_data", x), jax.core.Tracer):
-            return jax.named_scope(block)
-    return contextlib.nullcontext()
-
-
 class Layer:
     def __init__(self, name_scope=None, dtype="float32"):
         self._parameters: Dict[str, Optional[Parameter]] = collections.OrderedDict()
@@ -64,8 +51,10 @@ class Layer:
         self._name_scope = name_scope or self.__class__.__name__.lower()
 
     #: the block of the model this layer's device operations count under
-    #: (``block_scope``): one word of :data:`BLOCKS`, set on a class or
-    #: on an instance; None = the enclosing layer's
+    #: (``__call__`` enters ``jax.named_scope(block)``: HLO metadata,
+    #: which changes no compiled code and keys no retrace): one word of
+    #: :data:`BLOCKS`, set on a class or on an instance; None = the
+    #: enclosing layer's
     block: Optional[str] = None
 
     # -- attribute plumbing -------------------------------------------------
@@ -284,7 +273,7 @@ class Layer:
         if self.block is None:
             outputs = self.forward(*inputs, **kwargs)
         else:
-            with block_scope(self.block, *inputs):
+            with jax.named_scope(self.block):
                 outputs = self.forward(*inputs, **kwargs)
         for hook in self._forward_post_hooks.values():
             res = hook(self, inputs, outputs)

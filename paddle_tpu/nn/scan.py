@@ -38,7 +38,6 @@ import jax.numpy as jnp
 from ..core.flags import get_flag
 from ..core.random import make_rng, trace_rng
 from ..core.tensor import Tensor, apply
-from .layer import block_scope
 
 __all__ = ["can_scan_layers", "scan_layers", "scan_layers_with_cache",
            "invalidate_scan_cache", "note_scan_fallback", "SCAN_STATS"]
@@ -205,7 +204,7 @@ def _stack_params(template, names, specs, arrs, num_layers: int):
     for i, n in enumerate(names):
         block = next((b for o, b in owners if n.startswith(o + ".")), None)
         layers = arrs[i * num_layers:(i + 1) * num_layers]
-        with (block_scope(block, *layers[:1]) if block
+        with (jax.named_scope(block) if block
               else contextlib.nullcontext()):
             stacked[n] = jnp.stack(layers, axis=0)
             if specs[n] is not None:

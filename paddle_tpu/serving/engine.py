@@ -575,7 +575,8 @@ class ServingEngine:
         prog = AOTProgram("serve_decode", decode_fn,
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
-        with self._mesh_scope():
+        with self._mesh_scope(), \
+                _trace.span("serve.compile", kind=prog.kind):
             prog.compile((self.params, self.cache.k, self.cache.v,
                           jnp.zeros((B, mb), jnp.int32),
                           jnp.zeros((B,), jnp.int32),
@@ -634,7 +635,8 @@ class ServingEngine:
                           name=f"serve_prefill_{nb}x{sp}",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
-        with self._mesh_scope():
+        with self._mesh_scope(), \
+                _trace.span("serve.compile", kind=prog.kind):
             prog.compile((self.params, self.cache.k, self.cache.v,
                           jnp.zeros((nb, mb), jnp.int32),
                           jnp.zeros((nb, sp), jnp.int32),
@@ -677,7 +679,8 @@ class ServingEngine:
                           name=f"serve_prefill_ctx_{nb}x{sp}",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
-        with self._mesh_scope():
+        with self._mesh_scope(), \
+                _trace.span("serve.compile", kind=prog.kind):
             prog.compile((self.params, self.cache.k, self.cache.v,
                           jnp.zeros((nb, mb), jnp.int32),
                           jnp.zeros((nb, sp), jnp.int32),
@@ -754,7 +757,8 @@ class ServingEngine:
                           name="serve_verify",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
-        with self._mesh_scope():
+        with self._mesh_scope(), \
+                _trace.span("serve.compile", kind=prog.kind):
             prog.compile((self.params, self.cache.k, self.cache.v,
                           jnp.zeros((B, mb), jnp.int32),
                           jnp.zeros((B,), jnp.int32),
@@ -1379,19 +1383,25 @@ class ServingEngine:
         The iteration is a ``serve.step`` span and each phase a child
         (docs/OBSERVABILITY.md "Step spans"); a step that outlasts its
         kin by far is reported once it returns (:meth:`_note_stall`)."""
+        if self._drain_latch is not None \
+                and self._drain_latch.triggered \
+                and not self._draining:
+            # before the span opens: the drain's own steps are steps of
+            # their own, not phases of one that lasts the drain budget
+            raise EngineDrained(self.drain())
         self._step_seq += 1
+        built = self._stats["program_compiles"]
         with _trace.span("serve.step", step=self._step_seq) as sp:
             work = self._step(admit, sp)
-        if sp.t1 - sp.t0 > self.STALL_FLOOR_S:
+        # a step that built a program (a bucket no warm-up covered, a
+        # healed layout: `serve.compile`) is slow for a known reason
+        if sp.t1 - sp.t0 > _trace.STALL_FLOOR_S \
+                and self._stats["program_compiles"] == built:
             self._note_stall(sp)
         return work
 
     def _step(self, admit: bool, sp) -> bool:
         with _trace.span("serve.sweep"):
-            if self._drain_latch is not None \
-                    and self._drain_latch.triggered \
-                    and not self._draining:
-                raise EngineDrained(self.drain())
             if self._staged is not None:
                 # the atomic cutover point: an iteration boundary, before
                 # any admission/prefill/decode of this step
@@ -1465,32 +1475,21 @@ class ServingEngine:
         sp.set(n_active=n_active, n_groups=len(groups))
         return sched.has_work
 
-    #: a ``serve.step`` shorter than this is never a stall
-    STALL_FLOOR_S = 1.0
-
     def _note_stall(self, sp) -> None:
-        """A step longer than max(1 s, 5 x the ring's median step): one
-        flight-recorder event with the step's phase table, and a count
-        by the phase it sat in — the deepest span on the path of the
-        largest children (``serve.decode.readback``: the host waited
-        for the chip; ``.dispatch``: the runtime's execute call did not
-        return). Traced or not: the stall gets a name in every run."""
+        """A step longer than max(1 s, 5 x the ring's median step)
+        (``trace.stalled``): one flight-recorder event with the step's
+        phase table, and a count by the phase it sat in
+        (``trace.stall_phase``). Traced or not: the stall gets a name in
+        every run."""
         took = sp.t1 - sp.t0
-        steps = _trace.spans(name="serve.step")
-        durs = sorted(r[2] - r[1] for r in steps)
+        durs = sorted(r[2] - r[1] for r in _trace.spans(name="serve.step"))
         median = durs[len(durs) // 2]
-        if took <= 5.0 * median:
+        if not _trace.stalled(took, median):
             return
+        root = (sp.name, sp.t0, sp.t1, sp.span_id)
         recs = [r for r in _trace.spans(since=sp.t0) if r[5] == sp.step]
-        phase, parent = "serve.step", sp.span_id
-        while True:
-            kids = [r for r in recs if r[4] == parent and r[1] >= sp.t0]
-            if not kids:
-                break
-            top = max(kids, key=lambda r: r[2] - r[1])
-            phase, parent = top[0], top[3]
-        table = _trace.phase_table(
-            (sp.name, sp.t0, sp.t1, sp.span_id), recs)
+        table = _trace.phase_table(root, recs)
+        phase = _trace.stall_phase(root, recs)
         get_registry().counter(
             "serve_step_stalls_total",
             "engine steps longer than max(1 s, 5 x the median step), by "
