@@ -9,9 +9,9 @@ program's own spans do not go through the device trace. They read
                                        spans, `time.perf_counter` seconds,
                                        records `(name, t0, t1, span_id,
                                        parent_id, step, attrs)`
-    paddle_tpu.jit.aot.scopes(module)  {instruction: (block, phase, rule)}
-                                       of the newest executable built
-                                       under an HLO module name
+    paddle_tpu.jit.aot.scopes(module)  {instruction: (block, phase)} of
+                                       the newest executable built under
+                                       an HLO module name
 
 and join them with what a `Run` carries: the window on the same clock
 (`t_start + setup_s`, `window_s`) and the traced window's `by_op` and
@@ -152,12 +152,9 @@ def by_block(run) -> Optional[dict]:
     program's scope index of the window's largest module.
 
     {"module", "busy_s", "unscoped_s", "blocks": {block: {phase: s}},
-     "rules": {own | vote | operand: s},
      "kinds": {block: {instruction name without its number: s}},
      "unscoped_top": [(instruction, s)]} — or None without a device
-    trace or an index. `rules` says how the index found the blocks:
-    `own` is the instruction's own scope, `vote` and `operand` are the
-    index's inferences. Instructions of OTHER programs in the window
+    trace or an index. Instructions of OTHER programs in the window
     count as unscoped: in a train window there are next to none."""
     tr = run.trace
     if not tr or not tr.get("by_op") or not tr.get("modules"):
@@ -168,7 +165,6 @@ def by_block(run) -> Optional[dict]:
         return None
     blocks: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(float))
     kinds: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(float))
-    rules: Dict[str, float] = defaultdict(float)
     loose: Dict[str, float] = {}
     for op, sec in tr["by_op"].items():
         hit = index.get(op)
@@ -176,12 +172,10 @@ def by_block(run) -> Optional[dict]:
             loose[op] = sec
         else:
             blocks[hit[0]][hit[1]] += sec
-            rules[hit[2]] += sec
             kinds[hit[0]][base_name(op)] += sec
     return {"module": module, "busy_s": sum(tr["by_op"].values()),
             "unscoped_s": sum(loose.values()),
             "blocks": {b: dict(p) for b, p in blocks.items()},
-            "rules": dict(rules),
             "kinds": {b: dict(k) for b, k in kinds.items()},
             "unscoped_top": sorted(loose.items(), key=lambda kv: -kv[1])[:8]}
 
@@ -271,10 +265,3 @@ def _report_blocks(split: dict) -> None:
             + ", ".join(f"{k} {100.0 * s / busy:.2f}" for k, s in top))
     say(f"    {'(unscoped)':<12}{100.0 * split['unscoped_s'] / busy:>8.2f}   "
         + ", ".join(f"{op} {100.0 * s / busy:.2f}" for op, s in split["unscoped_top"]))
-    rules = split["rules"]
-    guessed = rules.get("vote", 0.0) + rules.get("operand", 0.0)
-    say("    block found by: "
-        + ", ".join(f"{r} {100.0 * rules.get(r, 0.0) / busy:.2f}"
-                    for r in ("own", "vote", "operand"))
-        + f" (% of busy); without the two inferences unscoped reads "
-        f"{100.0 * (split['unscoped_s'] + guessed) / busy:.2f}")

@@ -29,7 +29,6 @@ disabled the heal).
 
 from __future__ import annotations
 
-import collections
 import re
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -40,25 +39,18 @@ from ..nn.layer import BLOCKS
 __all__ = ["AOTProgram", "SCOPES", "scopes", "parse_scopes"]
 
 #: HLO module name (``jit_train_step``; a device trace's `XLA Modules`
-#: line carries the same) -> ``{instruction name: (block, phase, rule)}``
-#: of the newest executable built under that name: which block of the
-#: model (``nn.layer.BLOCKS``) each instruction of the OPTIMIZED program
-#: came from, whether it is forward, backward or recomputed-forward
-#: work, and by which rule of :func:`parse_scopes` the block was found
-#: (``own`` | ``vote`` | ``operand``: the last two are inferences, and a
-#: reader shows how much time rests on them). Read by whoever splits a
-#: device trace by block (the benchmark's ``block.*`` readers). Only
-#: this small dict outlives a build, never the executable or its text.
-SCOPES: Dict[str, Dict[str, Tuple[str, str, str]]] = {}
+#: line carries the same) -> ``{instruction name: (block, phase)}`` of
+#: the newest executable built under that name: which block of the model
+#: (``nn.layer.BLOCKS``) each instruction of the OPTIMIZED program came
+#: from, and whether it is forward, backward or recomputed-forward work.
+#: Read by whoever splits a device trace by block (the benchmark's
+#: ``block.*`` readers). Only this small dict outlives a build, never
+#: the executable or its text.
+SCOPES: Dict[str, Dict[str, Tuple[str, str]]] = {}
 
 _HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
-_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r"op_name=\"([^\"]*)\"")
-_HLO_CALLS = re.compile(r"calls=%?([\w.\-]+)")
-#: where an instruction's operand list ends and its attributes begin
-_HLO_ATTRIBUTES = re.compile(r"\), \w+=")
-_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
 _PATH_WORD = re.compile(r"[A-Za-z_]+")
 
 
@@ -82,58 +74,29 @@ def _resolve(op_name: str) -> Optional[Tuple[str, str]]:
     return block, phase
 
 
-def parse_scopes(hlo_text: str
-                 ) -> Tuple[str, Dict[str, Tuple[str, str, str]]]:
-    """(module name, {instruction name: (block, phase, rule)}) from the
-    text of an optimized HLO module (``compiled.as_text()``).
+def parse_scopes(hlo_text: str) -> Tuple[str, Dict[str, Tuple[str, str]]]:
+    """(module name, {instruction name: (block, phase)}) from the text
+    of an optimized HLO module (``compiled.as_text()``).
 
-    An instruction resolves by its own ``op_name`` (rule ``own``). One
-    whose own path holds no block (XLA names a fusion after its root,
-    and the root may be plumbing: the scan's write of a layer's
-    gradient into the stacked buffer; a layout copy carries no metadata
-    at all) takes the block most of the instructions of its fused
-    computation carry (``vote``), else that of its first operand that
-    has one (``operand``): the write counts with the block that
-    produced what is written. One hop only. The last two rules are
-    guesses, so the rule travels with the block."""
+    An instruction resolves by its OWN ``op_name`` and by nothing else.
+    One whose path holds no block (XLA names a fusion after its root,
+    and the root may be plumbing; a layout copy carries no metadata at
+    all) stays out of the table, and its time reads as unscoped:
+    guessing its block from its neighbours moved under 1% of the busy
+    time of either train cell (PERF.md, PR 26) and could move time
+    between blocks without showing."""
     m = _HLO_MODULE.match(hlo_text)
-    table: Dict[str, Tuple[str, str, str]] = {}
-    votes: Dict[str, collections.Counter] = {}
-    calls: Dict[str, str] = {}
-    operands: Dict[str, list] = {}
-    computation = ""
+    table: Dict[str, Tuple[str, str]] = {}
     for line in hlo_text.splitlines():
         inst = _HLO_INSTRUCTION.match(line)
-        if inst is None:
-            comp = _HLO_COMPUTATION.match(line)
-            if comp is not None:
-                computation = comp.group(1)
-            continue
-        op_name = _HLO_OP_NAME.search(line)
+        op_name = _HLO_OP_NAME.search(line) if inst else None
         hit = _resolve(op_name.group(1)) if op_name else None
         if hit is not None:
-            table[inst.group(1)] = hit + ("own",)
-            votes.setdefault(computation, collections.Counter())[hit] += 1
-        else:
-            callee = _HLO_CALLS.search(line)
-            if callee is not None:
-                calls[inst.group(1)] = callee.group(1)
-            head = _HLO_ATTRIBUTES.split(line, 1)[0]
-            operands[inst.group(1)] = _HLO_OPERAND.findall(head)[1:]
-    for name, callee in calls.items():
-        if callee in votes:
-            table[name] = votes[callee].most_common(1)[0][0] + ("vote",)
-    direct = dict(table)
-    for name, ops in operands.items():
-        if name not in table:
-            hit = next((direct[o] for o in ops if o in direct), None)
-            if hit is not None:
-                table[name] = hit[:2] + ("operand",)
+            table[inst.group(1)] = hit
     return (m.group(1) if m else ""), table
 
 
-def scopes(module_name: str
-           ) -> Optional[Dict[str, Tuple[str, str, str]]]:
+def scopes(module_name: str) -> Optional[Dict[str, Tuple[str, str]]]:
     """The scope index of the newest executable built under this HLO
     module name, or None when none was."""
     return SCOPES.get(module_name)

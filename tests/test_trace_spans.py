@@ -590,7 +590,7 @@ def test_resolve_paths():
                            "optimizer", "sampling", "kv_write"}
 
 
-def test_parse_scopes_fusion_votes_and_operand_hop():
+def test_parse_scopes_takes_an_instructions_own_scope_and_guesses_none():
     text = '''HloModule jit_toy, is_scheduled=true
 
 %fused_computation.1 (p0: f32[4]) -> f32[4] {
@@ -604,19 +604,16 @@ ENTRY %main (a: f32[4]) -> f32[4] {
   %flash_fwd.2 = f32[4] custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(toy)/jvp(attn)/pallas_call"}
   %dus_fusion.3 = f32[4] fusion(%a), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(toy)/while/body/dynamic_update_slice"}
   %copy.4 = f32[4] copy(%flash_fwd.2)
-  %copy.5 = f32[4] copy(%copy.4)
-  ROOT %add.6 = f32[4] add(%copy.5, %a), metadata={op_name="jit(toy)/add"}
+  ROOT %add.6 = f32[4] add(%copy.4, %a), metadata={op_name="jit(toy)/transpose(jvp(norm))/add"}
 }
 '''
     module, table = aot.parse_scopes(text)
     assert module == "jit_toy"
-    assert table["flash_fwd.2"] == ("attn", "fwd", "own")
-    assert table["mul.1"] == ("ffn", "fwd", "own")
-    # its computation's vote; its operand's, one hop: the rule travels
-    # with the block, so a reader can say how much rests on a guess
-    assert table["dus_fusion.3"] == ("ffn", "fwd", "vote")
-    assert table["copy.4"] == ("attn", "fwd", "operand")
-    assert "copy.5" not in table and "add.6" not in table and "a" not in table
+    # by its own `op_name` and nothing else: a fusion named after a
+    # plumbing root and a copy without metadata stay unscoped, whatever
+    # their fused instructions or operands carry
+    assert table == {"mul.1": ("ffn", "fwd"), "flash_fwd.2": ("attn", "fwd"),
+                     "add.6": ("norm", "bwd")}
 
 
 def test_scopes_of_a_toy_gpt_step():
@@ -636,8 +633,7 @@ def test_scopes_of_a_toy_gpt_step():
         hits = {table.get(n) for n, path in op_names.items()
                 if re.search(pattern, path)}
         assert hits, pattern
-        assert {h[2] for h in hits} == {"own"}          # by their own path
-        return {h[:2] for h in hits}
+        return hits
 
     # the attention products (the flash kernel's XLA twin on the CPU),
     # forward, backward through transpose(jvp(...)), and recomputed
@@ -654,10 +650,9 @@ def test_scopes_of_a_toy_gpt_step():
     assert block_of(r"jvp\(embed\)/") == {("embed", "fwd")}
     # the AdamW update
     assert block_of(r"/optimizer/") == {("optimizer", "fwd")}
-    assert {p for _, p, _ in table.values()} == {"fwd", "bwd", "remat"}
-    assert {b for b, _, _ in table.values()} >= {"embed", "attn", "ffn", "norm",
-                                                  "loss", "optimizer"}
-    assert {r for _, _, r in table.values()} <= {"own", "vote", "operand"}
+    assert {p for _, p in table.values()} == {"fwd", "bwd", "remat"}
+    assert {b for b, _ in table.values()} >= {"embed", "attn", "ffn", "norm",
+                                               "loss", "optimizer"}
 
 
 def test_serving_programs_are_named_and_scoped(tiny_model):
@@ -667,7 +662,7 @@ def test_serving_programs_are_named_and_scoped(tiny_model):
              for p in eng._programs.values()}
     assert "jit_serve_decode" in names
     assert {"jit_serve_prefill_1x8", "jit_serve_prefill_2x16"} <= names
-    blocks = {b for b, _, _ in aot.scopes("jit_serve_decode").values()}
+    blocks = {b for b, _ in aot.scopes("jit_serve_decode").values()}
     assert {"sampling", "kv_write", "attn", "ffn", "norm", "embed"} <= blocks
     eng.shutdown()
 
