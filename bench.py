@@ -1357,8 +1357,9 @@ def bench_kernels(quick: bool = False) -> list:
     # -- paged flash-decode: one decode step over the paged KV pool --------
     B, H, D, bs, MB = (2, 4, 16, 4, 4) if quick else (8, 16, 64, 16, 32)
     P = B * MB + 1                               # page 0 = scratch
-    kp = jnp.asarray(rng.randn(P, bs, H, D).astype(np.float32))
-    vp = jnp.asarray(rng.randn(P, bs, H, D).astype(np.float32))
+    # lane-dense pools [pages, head groups, rows, H*D] (serving.kv_cache)
+    kp = jnp.asarray(rng.randn(P, 1, bs, H * D).astype(np.float32))
+    vp = jnp.asarray(rng.randn(P, 1, bs, H * D).astype(np.float32))
     tbl = jnp.asarray(
         1 + np.arange(B * MB, dtype=np.int32).reshape(B, MB))
     pos = jnp.full((B,), MB * bs - 1, jnp.int32)  # slots fully grown
@@ -1372,7 +1373,8 @@ def bench_kernels(quick: bool = False) -> list:
         from paddle_tpu.serving.kv_cache import gather_pages
 
         def _fallback(q_, kp_, vp_, tbl_, pos_):
-            gk, gv = gather_pages(kp_, tbl_), gather_pages(vp_, tbl_)
+            gk = gather_pages(kp_, tbl_, D)
+            gv = gather_pages(vp_, tbl_, D)
             cols = jnp.arange(gk.shape[1])
             mask = jnp.where(cols[None, :] <= pos_[:, None], 0.0, -1e30)
             s = (jnp.einsum("bhd,bkhd->bhk", q_, gk) * scale

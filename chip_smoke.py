@@ -326,7 +326,7 @@ def phase_kernels(args, cfg):
     scale = 1.0 / float(np.sqrt(D))
 
     def paged_ref(q, k, v, table, pos):
-        gk, gv = gather_pages(k, table), gather_pages(v, table)
+        gk, gv = gather_pages(k, table, D), gather_pages(v, table, D)
         cols = jnp.arange(gk.shape[1])
         mask = jnp.where(cols[None, :] <= pos[:, None], 0.0, -1e30)
         with jax.default_matmul_precision("highest"):
@@ -337,8 +337,8 @@ def phase_kernels(args, cfg):
     for dtype in (jnp.float32, jnp.bfloat16):
         name = jnp.dtype(dtype).name
         lo = (normal((B, H, D)).astype(dtype),
-              normal((P, bs, H, D)).astype(dtype),
-              normal((P, bs, H, D)).astype(dtype))
+              normal((P, 1, bs, H * D)).astype(dtype),   # lane-dense pools
+              normal((P, 1, bs, H * D)).astype(dtype))
         out_k = run_kernel(
             lambda q, k, v, t, p: pd.paged_decode_attention(
                 q, k, v, t, p, scale=scale),
@@ -346,7 +346,7 @@ def phase_kernels(args, cfg):
         # the reference reads the same rounded inputs, in f32
         out_r = jax.jit(paged_ref)(*(x.astype(jnp.float32) for x in lo),
                                    table, pos)
-        report(f"paged_decode q{(B, H, D)} pool{(P, bs, H, D)} {name}",
+        report(f"paged_decode q{(B, H, D)} pool{(P, 1, bs, H * D)} {name}",
                [rel_err(out_k, out_r)], TOL[name])
 
 

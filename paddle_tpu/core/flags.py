@@ -130,11 +130,13 @@ define_flag("scan_layers", True,
 define_flag("scan_decode", True,
             "Run paged-KV-cache decode/prefill through the SAME "
             "scan-over-layers program layout as training (nn.scan."
-            "scan_layers_with_cache): per-layer KV pages ride the scan as "
-            "scanned-over state, so the decode program's trace+compile "
-            "cost stays O(1) in depth. Off = the per-layer Python loop "
-            "layout (same math, O(num_layers) trace; the kill switch if "
-            "a backend mishandles scanned cache state). Legacy "
+            "scan_layers_with_cache): the KV page pools ride the scan's "
+            "carry whole (never sliced by layer; layer l addresses pages "
+            "l*P + block_table and scatters its new rows in place), so "
+            "the decode program's trace+compile cost stays O(1) in depth. "
+            "Off = the per-layer Python loop over the same pools with the "
+            "same addressing (same math, O(num_layers) trace; the kill "
+            "switch if a backend mishandles the carried cache). Legacy "
             "list-of-StaticCache decoding always uses the loop and "
             "records a scan_fallback_total counter.")
 define_flag("chunked_ce_threshold", 4096,

@@ -214,36 +214,29 @@ def apply_hybrid_specs(layer, mp_axis: str = "mp"):
     return layer
 
 
-#: layout of a serving paged K/V pool ``[L, P, bs, H, D]`` under tensor
-#: parallelism (ISSUE 16): heads shard over the mp axis — the same split
-#: apply_hybrid_specs gives the q/k/v projections, so the TP decode
-#: program reads/writes its local head shard without any gather. Layers,
-#: pages and the per-page token dim stay replicated (page tables index
-#: them host-side).
-SERVE_KV_SPEC = P(None, None, None, "mp", None)
+#: layout of a serving paged K/V pool ``[L, P, G, bs, (H/G)*D]`` under
+#: tensor parallelism (ISSUE 16): the head-group axis ``G`` (= the mp
+#: size; each group holds ``H/G`` consecutive heads, fused into the minor
+#: dim) shards over the mp axis — the same split apply_hybrid_specs gives
+#: the q/k/v projections, so the TP decode program reads/writes its local
+#: head shard without any gather. Layers, pages and the per-page token
+#: dim stay replicated (page tables index them host-side). The
+#: ``[L, P, G, bs, H/G]`` scale pools of a quantized cache shard the
+#: same axis.
+SERVE_KV_SPEC = P(None, None, "mp", None, None)
 
 
 def shard_serving_cache(cache, mesh: Mesh):
-    """Lay a serving PagedKVCache's pools out on the TP mesh (heads over
-    ``mp`` per :data:`SERVE_KV_SPEC`, degraded for meshes without an mp
-    axis). Called once at engine init, before the first AOT compile, so
-    the serving programs see sharded donors and GSPMD keeps the pools
-    resident in the split layout — per-chip HBM then holds ``1/mp`` of
-    the KV footprint, which is what lets models beyond single-chip HBM
-    serve at all."""
+    """Lay a serving PagedKVCache's pools out on the TP mesh (head
+    groups over ``mp`` per :data:`SERVE_KV_SPEC`, degraded for meshes
+    without an mp axis). Called once at engine init, before the first
+    AOT compile, so the serving programs see sharded donors and GSPMD
+    keeps the pools resident in the split layout — per-chip HBM then
+    holds ``1/mp`` of the KV footprint, which is what lets models beyond
+    single-chip HBM serve at all."""
     sh = NamedSharding(mesh, degrade_spec(SERVE_KV_SPEC, mesh))
-    # quantized pools (FLAGS_serve_kv_quant) are (pages, scales) tuples:
-    # the [L, P, bs, H] scale pool shards its heads dim the same way
-    sc = NamedSharding(mesh, degrade_spec(P(None, None, None, "mp"), mesh))
-
-    def _put(pool):
-        if isinstance(pool, tuple):
-            pages, scales = pool
-            return (jax.device_put(pages, sh), jax.device_put(scales, sc))
-        return jax.device_put(pool, sh)
-
-    cache.k = _put(cache.k)
-    cache.v = _put(cache.v)
+    # quantized pools (FLAGS_serve_kv_quant) are (pages, scales) tuples
+    cache.k, cache.v = jax.device_put((cache.k, cache.v), sh)
     return cache
 
 

@@ -269,14 +269,19 @@ class GPTAttention(Layer):
         """Block-table K/V path (paddle_tpu.serving, ISSUE 6).
 
         ``cache``: :class:`~paddle_tpu.serving.kv_cache.PagedLayerCache`
-        (``[P, bs, H, D]`` pools + ``[B, MB]`` block table); ``pos``:
-        per-slot write positions ``[B]``. The chunk's K/V scatter into
-        the pools at logical positions ``pos + 0..S-1`` (a bucketed
-        prefill's padded tail routes to the scratch page). Prefill
-        (S > 1, fresh slots) attends causally over its own K/V — the
-        exact math of the full-context forward; decode (S == 1) gathers
-        the slot's pages and masks columns past ``pos``, i.e.
-        PagedAttention as one XLA gather + masked SDPA.
+        (the WHOLE lane-dense pools ``[L*P, G, bs, (H/G)*D]``, the
+        ``[B, MB]`` block table and this layer's first page
+        ``page_base``); ``pos``: per-slot write positions ``[B]``. The
+        chunk's K/V rows scatter into the pool at logical positions
+        ``pos + 0..S-1`` of pages ``page_base + table`` (a bucketed
+        prefill's padded tail routes to the layer's scratch page);
+        nothing else of the pool moves. Prefill (S > 1, fresh slots)
+        attends causally over its own K/V — the exact math of the
+        full-context forward; decode (S == 1) reads the slot's pages
+        through the Pallas kernel or, as the fallback, gathers them and
+        masks columns past ``pos``, i.e. PagedAttention as one XLA
+        gather + masked SDPA; context prefill (S > 1 at ``pos > 0``)
+        is that same gather with one mask row per chunk row.
 
         A quantized cache (``cache.k_scale is not None``,
         ``FLAGS_serve_kv_quant=int8``) quantizes at write time and
@@ -284,137 +289,77 @@ class GPTAttention(Layer):
         and the XLA gather fallback — so the two dispatch paths stay
         token-exact against each other.
         """
-        from ..serving.kv_cache import (PagedLayerCache, gather_pages,
-                                        gather_pages_quant, write_pages,
-                                        write_pages_quant)
+        from ..serving.kv_cache import (ContextPagedLayerCache,
+                                        gather_pages, gather_pages_quant,
+                                        write_pages, write_pages_quant)
 
         quant = cache.k_scale is not None
+        table, base = cache.block_table, cache.page_base
         with jax.named_scope("kv_write"):
             if quant:
-                def updq(pages, scales, new, table, p):
-                    return write_pages_quant(pages, scales, new, table, p)
-
-                kp, ksc = apply(updq, cache.k_pages, cache.k_scale, k,
-                                cache.block_table, pos,
+                kp, ksc = apply(write_pages_quant, cache.k_pages,
+                                cache.k_scale, k, table, pos, base,
                                 name="paged_kv_write_quant")
-                vp, vsc = apply(updq, cache.v_pages, cache.v_scale, v,
-                                cache.block_table, pos,
+                vp, vsc = apply(write_pages_quant, cache.v_pages,
+                                cache.v_scale, v, table, pos, base,
                                 name="paged_kv_write_quant")
             else:
-                def upd(pages, new, table, p):
-                    return write_pages(pages, new, table, p)
-
-                kp = apply(upd, cache.k_pages, k, cache.block_table, pos,
-                           name="paged_kv_write")
-                vp = apply(upd, cache.v_pages, v, cache.block_table, pos,
-                           name="paged_kv_write")
+                kp = apply(write_pages, cache.k_pages, k, table, pos,
+                           base, name="paged_kv_write")
+                vp = apply(write_pages, cache.v_pages, v, table, pos,
+                           base, name="paged_kv_write")
                 ksc = vsc = None
-        from ..serving.kv_cache import ContextPagedLayerCache
-        is_ctx = isinstance(cache, ContextPagedLayerCache)
-        new_cache = type(cache)(kp, vp, cache.block_table, ksc, vsc,
-                                cache.lora_a, cache.lora_b, cache.lora_ids)
-        S = x.shape[1]
-        if S > 1 and not is_ctx:
+        new_cache = cache._replace(k_pages=kp, v_pages=vp, k_scale=ksc,
+                                   v_scale=vsc)
+        S, D = x.shape[1], q.shape[-1]
+        if S > 1 and not isinstance(cache, ContextPagedLayerCache):
             from ..ops.attention import scaled_dot_product_attention
             out = scaled_dot_product_attention(
                 q, k, v, dropout_p=0.0, is_causal=True, training=False)
-            return out, new_cache
-        if S > 1:
-            # CONTEXT prefill (ISSUE 15): the chunk starts at pos > 0 —
-            # a chunked-prefill continuation, a prefix-cache-hit tail or
-            # a speculative verify window — so row i must see every
-            # page-resident position <= pos + i, not just its own
-            # chunk. Same gather + additive-mask construction as the
-            # S == 1 decode fallback, one row of mask per chunk row.
-            def _ctx_mask(n_cols, p):
-                cols = jnp.arange(n_cols, dtype=jnp.int32)
-                rows = (p[:, None].astype(jnp.int32)
-                        + jnp.arange(S, dtype=jnp.int32)[None, :])
-                return jnp.where(
-                    cols[None, None, :] <= rows[:, :, None],
-                    0.0, -1e30)[:, None]          # [B, 1, S, MB*bs]
-
-            if quant:
-                def attend_ctx_q(q_, kpages, kscales, vpages, vscales,
-                                 table, p):
-                    from ..ops.attention import sdpa_array
-                    gk = gather_pages_quant(kpages, kscales, table)
-                    gv = gather_pages_quant(vpages, vscales, table)
-                    mask = _ctx_mask(gk.shape[1], p)
-                    return sdpa_array(q_, gk, gv, mask=mask,
-                                      dropout_p=0.0, is_causal=False)
-
-                out = apply(attend_ctx_q, q, kp, ksc, vp, vsc,
-                            cache.block_table, pos,
-                            name="paged_context_attention_quant")
-                return out, new_cache
-
-            def attend_ctx(q_, kpages, vpages, table, p):
-                from ..ops.attention import sdpa_array
-                from ..serving.kv_cache import gather_pages as _gp
-                gk = _gp(kpages, table)
-                gv = _gp(vpages, table)
-                mask = _ctx_mask(gk.shape[1], p)
-                return sdpa_array(q_, gk, gv, mask=mask, dropout_p=0.0,
-                                  is_causal=False)
-
-            out = apply(attend_ctx, q, kp, vp, cache.block_table, pos,
-                        name="paged_context_attention")
             return out, new_cache
 
         # decode kernel dispatch resolved OUTSIDE the traced fn so the
         # path choice is stable for any cached trace (kill switch:
         # FLAGS_pallas_paged_decode -> the gather+SDPA composition)
-        from ..ops import pallas as pallas_ops
-        use_kernel = pallas_ops.kernel_enabled("paged_decode")
+        use_kernel = False
+        if S == 1:
+            from ..ops import pallas as pallas_ops
+            use_kernel = pallas_ops.kernel_enabled("paged_decode")
+        pools = (kp, ksc, vp, vsc) if quant else (kp, vp)
 
-        def _decode_mask(n_cols, p):
-            cols = jnp.arange(n_cols, dtype=jnp.int32)
-            # additive key mask [B, 1, 1, Lk]: slot b sees written
-            # positions 0..p[b] (its current token included)
-            return jnp.where(cols[None, :] <= p[:, None].astype(jnp.int32),
-                             0.0, -1e30)[:, None, None, :]
-
-        if quant:
-            def attend_q(q_, kpages, kscales, vpages, vscales, table, p):
-                if use_kernel:
-                    from ..ops.pallas.paged_decode import \
-                        paged_decode_attention_quant
-                    o = paged_decode_attention_quant(
-                        q_[:, 0], kpages, kscales, vpages, vscales,
-                        table, p.astype(jnp.int32),
-                        scale=1.0 / math.sqrt(q_.shape[-1]))
-                    return o[:, None]
-                from ..ops.attention import sdpa_array
-                gk = gather_pages_quant(kpages, kscales, table)
-                gv = gather_pages_quant(vpages, vscales, table)
-                mask = _decode_mask(gk.shape[1], p)
-                return sdpa_array(q_, gk, gv, mask=mask, dropout_p=0.0,
-                                  is_causal=False)
-
-            out = apply(attend_q, q, kp, ksc, vp, vsc, cache.block_table,
-                        pos, name="paged_attention_quant")
-            return out, new_cache
-
-        def attend(q_, kpages, vpages, table, p):
+        def attend(q_, tbl, p, first, *pool):
+            p = p.astype(jnp.int32)
             if use_kernel:
                 # pages read in place via the block table: the gathered
                 # [B, MB*bs, H, D] context never materializes in HBM
-                from ..ops.pallas.paged_decode import paged_decode_attention
-                o = paged_decode_attention(
-                    q_[:, 0], kpages, vpages, table,
-                    p.astype(jnp.int32),
-                    scale=1.0 / math.sqrt(q_.shape[-1]))
-                return o[:, None]
+                from ..ops.pallas import paged_decode as pd
+                kernel = (pd.paged_decode_attention_quant if quant
+                          else pd.paged_decode_attention)
+                return kernel(q_[:, 0], *pool, tbl + first, p,
+                              scale=1.0 / math.sqrt(D))[:, None]
             from ..ops.attention import sdpa_array
-            gk = gather_pages(kpages, table)
-            gv = gather_pages(vpages, table)
-            mask = _decode_mask(gk.shape[1], p)
+            if quant:
+                gk = gather_pages_quant(pool[0], pool[1], tbl, D, first)
+                gv = gather_pages_quant(pool[2], pool[3], tbl, D, first)
+            else:
+                gk = gather_pages(pool[0], tbl, D, first)
+                gv = gather_pages(pool[1], tbl, D, first)
+            # additive key mask [B, 1, S, Lk]: row i of slot b sees the
+            # page-resident positions 0..p[b]+i, its own included. S == 1
+            # is the decode step; S > 1 a CONTEXT prefill (ISSUE 15): a
+            # chunked-prefill continuation, a prefix-cache-hit tail or a
+            # speculative verify window starting at pos > 0
+            cols = jnp.arange(gk.shape[1], dtype=jnp.int32)
+            rows = p[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+            mask = jnp.where(cols[None, None, :] <= rows[:, :, None],
+                             0.0, -1e30)[:, None]
             return sdpa_array(q_, gk, gv, mask=mask, dropout_p=0.0,
                               is_causal=False)
 
-        out = apply(attend, q, kp, vp, cache.block_table, pos,
-                    name="paged_attention")
+        out = apply(attend, q, table, pos, base, *pools,
+                    name=("paged_attention" if S == 1
+                          else "paged_context_attention")
+                    + ("_quant" if quant else ""))
         return out, new_cache
 
 
@@ -523,50 +468,46 @@ class GPTMoEDecoderLayer(GPTDecoderLayer):
         return out, self.moe.moe_vec
 
 
-def _paged_body(cls, template, x, cache_slices, extras, scan_in):
-    """Shared core of the paged scan bodies: rebuild one layer's cache
-    view from the scanned slices and run the block.
+def _paged_body(cls, template, x, pools, extras, scan_in):
+    """Shared core of the paged scan bodies: build one layer's window on
+    the carried pools and run the block.
 
-    ``cache_slices`` is ``(k, v)`` or — quantized cache
-    (``FLAGS_serve_kv_quant``) — ``(k, v, k_scale, v_scale)``;
-    ``extras`` is ``(block_table, pos)`` plus, when the LoRA ``scan_in``
-    pools ride along, the broadcast ``lora_ids``. Layout changes key
-    distinct traces via the scan token's ``(n_cache, n_scan_in,
-    len(extra))`` components."""
-    if len(cache_slices) == 4:
-        k_pages, v_pages, ksc, vsc = cache_slices
-    else:
-        (k_pages, v_pages), ksc, vsc = cache_slices, None, None
-    block_table, pos = extras[0], extras[1]
-    la = lb = ids = None
-    if scan_in:
-        la, lb = scan_in
-        ids = extras[2]
-    x, c = template(x, cls(k_pages, v_pages, block_table, ksc, vsc,
-                           la, lb, ids), pos=pos)
+    ``pools`` is ``(k, v)`` or — quantized cache
+    (``FLAGS_serve_kv_quant``) — ``(k, v, k_scale, v_scale)``, each the
+    WHOLE pool of ``L*P`` pages; ``scan_in`` is this layer's first page
+    ``(page_base,)`` plus, on a LoRA engine, its ``(lora_a, lora_b)``
+    slices; ``extras`` is ``(block_table, pos)`` plus the broadcast
+    ``lora_ids`` then. Layout changes key distinct traces via the scan
+    token's ``(n_cache, n_scan_in, len(extra))`` components."""
+    ksc, vsc = pools[2:] if len(pools) == 4 else (None, None)
+    base, la, lb = scan_in if len(scan_in) == 3 else (scan_in[0], None, None)
+    ids = extras[2] if la is not None else None
+    x, c = template(x, cls(pools[0], pools[1], extras[0], ksc, vsc,
+                           la, lb, ids, base), pos=extras[1])
     if ksc is not None:
         return x, (c.k_pages, c.v_pages, c.k_scale, c.v_scale)
     return x, (c.k_pages, c.v_pages)
 
 
-def _paged_scan_body(template, x, cache_slices, extras, scan_in=()):
-    """scan_layers_with_cache adapter for GPT blocks: one layer's page
-    pools in, the block's updated pools out (module-level so its identity
-    is stable in the eager jit-cache token)."""
+def _paged_scan_body(template, x, pools, extras, scan_in):
+    """scan_layers_with_cache adapter for GPT blocks: the carried page
+    pools in, the same pools with this layer's rows written out
+    (module-level so its identity is stable in the eager jit-cache
+    token)."""
     from ..serving.kv_cache import PagedLayerCache
-    return _paged_body(PagedLayerCache, template, x, cache_slices,
-                       extras, scan_in)
+    return _paged_body(PagedLayerCache, template, x, pools, extras,
+                       scan_in)
 
 
-def _paged_scan_body_ctx(template, x, cache_slices, extras, scan_in=()):
+def _paged_scan_body_ctx(template, x, pools, extras, scan_in):
     """Context-prefill twin of :func:`_paged_scan_body` (ISSUE 15): the
     layer cache is the :class:`ContextPagedLayerCache` marker, so S>1
     chunks attend over prior pages. A distinct module-level function —
     its identity keys the scan cache token, so the two attention paths
     can never share a trace."""
     from ..serving.kv_cache import ContextPagedLayerCache
-    return _paged_body(ContextPagedLayerCache, template, x, cache_slices,
-                       extras, scan_in)
+    return _paged_body(ContextPagedLayerCache, template, x, pools, extras,
+                       scan_in)
 
 
 class GPTModel(Layer):
@@ -752,76 +693,66 @@ class GPTModel(Layer):
         return arr.shape[0]
 
     def _forward_paged(self, x, caches, cache_pos):
-        """Run the stack over a paged KV view: under scan
-        (``FLAGS_scan_decode``, default) each layer's page pools thread
-        the one ``lax.scan`` as scanned-over state — decode keeps the
+        """Run the stack over a paged KV view. The pools are viewed as
+        ONE pool of ``L*P`` pages (leading dims merged: a bitcast) that
+        every layer shares; layer ``l`` reads and writes pages
+        ``l*P + block_table`` of it, so no layer's pool is ever sliced
+        out or stacked back. Under scan (``FLAGS_scan_decode``, default)
+        the pools ride the one ``lax.scan``'s carry — decode keeps the
         O(1)-in-depth trace/compile cost of training; the loop layout
-        (kill switch / heterogeneous stacks) computes the same math per
-        layer."""
+        (kill switch / heterogeneous stacks) walks the same pools with
+        the same addressing, layer by layer."""
         from ..core.flags import get_flag
         from ..serving.kv_cache import (ContextPagedCacheView,
                                         ContextPagedLayerCache,
-                                        PagedCacheView, PagedLayerCache)
+                                        PagedLayerCache)
         # the view CLASS carries the attention-path choice: a
         # ContextPagedCacheView (chunked prefill / prefix-hit tails /
         # speculative verify) selects the gather-over-prior-pages S>1
         # path at trace time (ISSUE 15)
         is_ctx = isinstance(caches, ContextPagedCacheView)
-        layer_cls = ContextPagedLayerCache if is_ctx else PagedLayerCache
-        body = _paged_scan_body_ctx if is_ctx else _paged_scan_body
-        quant = caches.k_scale is not None
         lora = caches.lora_a is not None
+        L, P = caches.k.shape[:2]
+        names = ("k", "v") + (("k_scale", "v_scale")
+                              if caches.k_scale is not None else ())
+
+        def view(pool, shape, *spec):
+            # [L, P, G, ...] <-> [L*P, G, ...]; the head-group axis a
+            # serving mesh shards stays an axis of its own
+            return _constrain(apply(lambda a: a.reshape(shape), pool,
+                                    name="paged_pool_view"), *spec)
+
+        pools = tuple(
+            view(p, (L * P,) + tuple(p.shape[2:]), None, MP)
+            for p in (getattr(caches, n) for n in names))
+        extras = (caches.block_table, cache_pos)
+        if lora:
+            extras += (caches.lora_ids,)
         eligible = self.cfg.scan_layers and can_scan_layers(self.layers)
         if eligible and get_flag("scan_decode"):
-            cache_arrs = (caches.k, caches.v)
-            if quant:
-                cache_arrs += (caches.k_scale, caches.v_scale)
-            # LoRA pools are [L, ...] per-layer state the decode step
-            # READS but never writes: scanned-over inputs, no outputs
-            scan_in = (caches.lora_a, caches.lora_b) if lora else ()
-            extras = (caches.block_table, cache_pos)
+            # scanned-over INPUTS: each layer's first page, and the LoRA
+            # pools' [L, ...] per-layer state the step reads, never writes
+            scan_in = (jnp.arange(L, dtype=jnp.int32) * P,)
             if lora:
-                extras += (caches.lora_ids,)
-            # the scan's own slicing of a layer's pool out of the stacked
-            # pools, and its write back, count as kv_write; what the
-            # body traces resolves to its own (inner) block
-            with jax.named_scope("kv_write"):
-                x, new = scan_layers_with_cache(
-                    self.layers, x, cache_arrs, *extras,
-                    body_call=body, scan_in=scan_in,
-                    name="gpt_paged_scan")
-            x = self.final_norm(x)
-            if quant:
-                return x, PagedCacheView(new[0], new[1],
-                                         caches.block_table,
-                                         new[2], new[3])
-            return x, PagedCacheView(new[0], new[1], caches.block_table)
-        if eligible:
-            note_scan_fallback("scan_decode_disabled", "gpt")
-        from ..tensor.manipulation import stack as tstack
-        ks, vs, kscs, vscs = [], [], [], []
-        for i, blk in enumerate(self.layers):
-            layer_cache = layer_cls(
-                caches.k[i], caches.v[i], caches.block_table,
-                caches.k_scale[i] if quant else None,
-                caches.v_scale[i] if quant else None,
-                caches.lora_a[i] if lora else None,
-                caches.lora_b[i] if lora else None,
-                caches.lora_ids if lora else None)
-            x, c = blk(x, layer_cache, pos=cache_pos)
-            ks.append(c.k_pages)
-            vs.append(c.v_pages)
-            if quant:
-                kscs.append(c.k_scale)
-                vscs.append(c.v_scale)
+                scan_in += (caches.lora_a, caches.lora_b)
+            x, pools = scan_layers_with_cache(
+                self.layers, x, pools, *extras,
+                body_call=(_paged_scan_body_ctx if is_ctx
+                           else _paged_scan_body),
+                scan_in=scan_in, name="gpt_paged_scan")
+        else:
+            if eligible:
+                note_scan_fallback("scan_decode_disabled", "gpt")
+            layer_cls = ContextPagedLayerCache if is_ctx else PagedLayerCache
+            for i, blk in enumerate(self.layers):
+                x, pools = _paged_body(
+                    layer_cls, blk, x, pools, extras,
+                    (i * P,) + ((caches.lora_a[i], caches.lora_b[i])
+                                if lora else ()))
         x = self.final_norm(x)
-        if quant:
-            return x, PagedCacheView(
-                tstack(ks, axis=0), tstack(vs, axis=0),
-                caches.block_table,
-                tstack(kscs, axis=0), tstack(vscs, axis=0))
-        return x, PagedCacheView(tstack(ks, axis=0), tstack(vs, axis=0),
-                                 caches.block_table)
+        return x, caches._replace(**{
+            n: view(p, getattr(caches, n).shape, None, None, MP)
+            for n, p in zip(names, pools)})
 
 
 def parallel_logits(hidden, embedding_weight):

@@ -334,35 +334,35 @@ def scan_layers(blocks, x, *extra, policy=None, use_recompute: bool = False,
 def scan_layers_with_cache(blocks, x, cache, *extra, body_call,
                            scan_in=(), name: str = "scan_layers_cache"):
     """Run ``x`` through ``blocks`` as ONE ``jax.lax.scan`` while
-    threading per-layer cache state — the decode-time counterpart of
-    :func:`scan_layers` (the paged-KV serving path, ISSUE 6).
+    threading cache state that every layer reads and updates — the
+    decode-time counterpart of :func:`scan_layers` (the paged-KV serving
+    path, ISSUE 6).
 
-    ``cache``: tuple of Tensors/arrays stacked along a leading layer
-    axis (``[L, ...]`` — e.g. per-layer K/V page pools); each layer's
-    slice enters the scan as a scanned-over input and the updated slice
-    leaves as a scanned-over output, so the whole decode step stays one
-    O(1)-trace program. ``extra``: broadcast (non-scanned) arguments
+    ``cache``: tuple of Tensors/arrays the layers SHARE (the K/V page
+    pools, viewed as one pool of ``L*P`` pages). They are never scanned
+    over: they ride the scan CARRY whole, beside ``x``, so no iteration
+    slices a layer's part out or stacks it back, and an update a layer
+    makes (a scatter of the rows it produced) is made in place on the
+    carried buffer. A layer finds its own part by what ``scan_in`` hands
+    it (its first page). ``extra``: broadcast (non-scanned) arguments
     shared by every layer (block tables, per-slot positions).
 
-    ``body_call(template, x, cache_slices, extras)`` adapts the generic
-    scan to the stack's block signature: it must run ``template`` (the
-    first block, with that layer's params bound) and return
-    ``(x, new_cache_slices)`` with ``new_cache_slices`` matching
-    ``cache``'s structure and per-layer shapes. Pass a module-level
-    function — its identity rides the eager jit-cache token.
-
     ``scan_in``: per-layer stacked arrays (``[L, ...]``) that scan as
-    INPUTS ONLY — each layer sees its slice but no updated slice is
-    carried out (the serving LoRA pools: per-layer adapter weights that
-    the decode step reads but never writes). When non-empty,
-    ``body_call`` is invoked with a fifth argument
-    ``(template, x, cache_slices, extras, scan_in_slices)``; when empty
-    the four-argument form is kept, so existing bodies are untouched.
+    INPUTS ONLY — each layer sees its slice (its first page in the
+    pool; the serving LoRA pools: per-layer adapter weights that the
+    decode step reads but never writes).
+
+    ``body_call(template, x, cache, extras, scan_in_slices)`` adapts the
+    generic scan to the stack's block signature: it must run
+    ``template`` (the first block, with that layer's params bound) and
+    return ``(x, new_cache)`` with ``new_cache`` matching ``cache``'s
+    structure and shapes. Pass a module-level function — its identity
+    rides the eager jit-cache token.
 
     Eval-mode only (decode never trains): a training-mode template is
     rejected rather than silently dropping dropout randomness.
 
-    Returns ``(y, new_cache)`` with ``new_cache`` stacked ``[L, ...]``.
+    Returns ``(y, new_cache)``.
     """
     blocks = list(blocks)
     template = blocks[0]
@@ -394,31 +394,24 @@ def scan_layers_with_cache(blocks, x, cache, *extra, body_call,
 
         def body(carry, xs):
             SCAN_STATS["body_traces"] += 1
-            p_slice, cache_slice = xs[0], xs[1]
+            h, cache_c = carry
+            p_slice, scan_in_slice = xs
             extras_t = tuple(Tensor(e) if hasattr(e, "dtype") else e
                              for e in extra_raw)
             with bind_(template, p_slice, None):
-                if n_scan_in:
-                    out, new_cache = body_call(
-                        template, Tensor(carry),
-                        tuple(Tensor(c) for c in cache_slice),
-                        extras_t,
-                        tuple(Tensor(s) for s in xs[2]))
-                else:
-                    out, new_cache = body_call(
-                        template, Tensor(carry),
-                        tuple(Tensor(c) for c in cache_slice),
-                        extras_t)
+                out, new_cache = body_call(
+                    template, Tensor(h),
+                    tuple(Tensor(c) for c in cache_c), extras_t,
+                    tuple(Tensor(s) for s in scan_in_slice))
             out = out._data if isinstance(out, Tensor) else out
             new_cache = tuple(c._data if isinstance(c, Tensor) else c
                               for c in new_cache)
-            return out.astype(carry.dtype), new_cache
+            return (out.astype(h.dtype), new_cache), None
 
-        xs = (p_stacked, tuple(cache_raw))
-        if n_scan_in:
-            xs = xs + (tuple(scan_in_raw),)
-        y, new_cache_stacked = jax.lax.scan(body, x_arr, xs)
-        return (y,) + tuple(new_cache_stacked)
+        (y, new_cache), _ = jax.lax.scan(
+            body, (x_arr, tuple(cache_raw)),
+            (p_stacked, tuple(scan_in_raw)))
+        return (y,) + tuple(new_cache)
 
     x_t = x if isinstance(x, Tensor) else Tensor(x)
     token = ("scan_layers_cache", name, id(template), num_layers, n_cache,

@@ -18,9 +18,14 @@ The execution model (ISSUE 6; docs/SERVING.md):
   positions, sampling params and the active mask are all ARGUMENTS, so
   membership changes never recompile);
 - **decode under scan**: with ``FLAGS_scan_decode`` (default on) the
-  layer stack runs as one ``lax.scan`` threading each layer's K/V pages
-  (``nn.scan.scan_layers_with_cache``) — O(1) trace/compile in depth,
-  same as training;
+  layer stack runs as one ``lax.scan`` with the K/V page pools in its
+  carry (``nn.scan.scan_layers_with_cache``) — O(1) trace/compile in
+  depth, same as training;
+- **one pool layout**: the pools the engine holds
+  (``[L, P, G, bs, (H/G)*D]``, ``serving.kv_cache``) are donated to
+  every program and come back updated in place — a step writes the rows
+  it produced and moves nothing else; no program holds a pool-sized
+  temporary (guarded by tests/test_tpu_compile.py);
 - **telemetry**: per-request TTFT / TPOT / end-to-end latency and
   queue/occupancy gauges stream into the ``paddle_tpu.monitor`` registry
   (serving metrics are always on — an engine exists to be observed; the
@@ -206,6 +211,7 @@ class ServingEngine:
         self.clock = clock
         model.eval()
         self.mesh = self.config.mesh
+        mp = 1
         if self.mesh is not None:
             # TP-sharded serving (ISSUE 16): stamp Megatron specs on any
             # params still unplaced and lay the model out on the mesh
@@ -224,13 +230,15 @@ class ServingEngine:
         self.params = param_arrays(model)
         self.buffers = buffer_arrays(model)
         c = self.config
+        # one head group a chip of the mp axis: the pools' sharded axis
         self.cache = PagedKVCache(
             cfg.num_layers, cfg.num_heads, cfg.head_dim,
             num_pages=c.num_pages, block_size=c.block_size,
             max_slots=c.max_batch_slots,
             max_blocks_per_slot=blocks_needed(c.max_context_len,
                                               c.block_size),
-            dtype=jnp.dtype(c.cache_dtype))
+            dtype=jnp.dtype(c.cache_dtype),
+            head_groups=mp)
         if self.mesh is not None:
             from ..distributed.spmd import shard_serving_cache
             shard_serving_cache(self.cache, self.mesh)
@@ -546,12 +554,24 @@ class ServingEngine:
             return ()
         return (1, 2)
 
-    def _get_decode(self) -> AOTProgram:
-        key = ("decode",)
+    def _program(self, key: tuple, build) -> AOTProgram:
+        """The compiled program under ``key``; ``build()`` returns
+        ``(AOTProgram, example_args)`` on the first ask. One place
+        compiles: under the mesh scope, inside a ``serve.compile``
+        span."""
         prog = self._programs.get(key)
-        if prog is not None:
-            return prog
+        if prog is None:
+            prog, args = build()
+            with self._mesh_scope(), \
+                    _trace.span("serve.compile", kind=prog.kind):
+                prog.compile(args)
+            self._programs[key] = prog
+        return prog
 
+    def _get_decode(self) -> AOTProgram:
+        return self._program(("decode",), self._decode_program)
+
+    def _decode_program(self):
         def decode_fn(params, k, v, table, pos, tokens, active, rng,
                       temps, top_ks, top_ps, poison, *lora):
             # *lora is (a_pool, b_pool, rows) on a multi-tenant engine
@@ -575,20 +595,15 @@ class ServingEngine:
         prog = AOTProgram("serve_decode", decode_fn,
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
-        with self._mesh_scope(), \
-                _trace.span("serve.compile", kind=prog.kind):
-            prog.compile((self.params, self.cache.k, self.cache.v,
-                          jnp.zeros((B, mb), jnp.int32),
-                          jnp.zeros((B,), jnp.int32),
-                          jnp.zeros((B,), jnp.int32),
-                          jnp.zeros((B,), bool), self._key,
-                          jnp.ones((B,), jnp.float32),
-                          jnp.zeros((B,), jnp.int32),
-                          jnp.ones((B,), jnp.float32),
-                          jnp.zeros((B,), jnp.float32))
-                         + self._lora_sig(B))
-        self._programs[key] = prog
-        return prog
+        return prog, (self.params, self.cache.k, self.cache.v,
+                      jnp.zeros((B, mb), jnp.int32),
+                      jnp.zeros((B,), jnp.int32),
+                      jnp.zeros((B,), jnp.int32),
+                      jnp.zeros((B,), bool), self._key,
+                      jnp.ones((B,), jnp.float32),
+                      jnp.zeros((B,), jnp.int32),
+                      jnp.ones((B,), jnp.float32),
+                      jnp.zeros((B,), jnp.float32)) + self._lora_sig(B)
 
     def _lora_sig(self, n: int) -> tuple:
         """Compile-time LoRA argument suffix for an ``n``-row program:
@@ -611,11 +626,10 @@ class ServingEngine:
         return (self.lora.a, self.lora.b, rows)
 
     def _get_prefill(self, nb: int, sp: int) -> AOTProgram:
-        key = ("prefill", nb, sp)
-        prog = self._programs.get(key)
-        if prog is not None:
-            return prog
+        return self._program(("prefill", nb, sp),
+                             lambda: self._prefill_program(nb, sp))
 
+    def _prefill_program(self, nb: int, sp: int):
         def prefill_fn(params, k, v, table, ids, lens, rng, temps,
                        top_ks, top_ps, poison, *lora):
             pos = jnp.zeros((nb,), jnp.int32)
@@ -635,19 +649,14 @@ class ServingEngine:
                           name=f"serve_prefill_{nb}x{sp}",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
-        with self._mesh_scope(), \
-                _trace.span("serve.compile", kind=prog.kind):
-            prog.compile((self.params, self.cache.k, self.cache.v,
-                          jnp.zeros((nb, mb), jnp.int32),
-                          jnp.zeros((nb, sp), jnp.int32),
-                          jnp.ones((nb,), jnp.int32), self._key,
-                          jnp.ones((nb,), jnp.float32),
-                          jnp.zeros((nb,), jnp.int32),
-                          jnp.ones((nb,), jnp.float32),
-                          jnp.zeros((nb,), jnp.float32))
-                         + self._lora_sig(nb))
-        self._programs[key] = prog
-        return prog
+        return prog, (self.params, self.cache.k, self.cache.v,
+                      jnp.zeros((nb, mb), jnp.int32),
+                      jnp.zeros((nb, sp), jnp.int32),
+                      jnp.ones((nb,), jnp.int32), self._key,
+                      jnp.ones((nb,), jnp.float32),
+                      jnp.zeros((nb,), jnp.int32),
+                      jnp.ones((nb,), jnp.float32),
+                      jnp.zeros((nb,), jnp.float32)) + self._lora_sig(nb)
 
     def _get_prefill_ctx(self, nb: int, sp: int) -> AOTProgram:
         """Context-prefill program (ISSUE 15): same shape contract as
@@ -655,11 +664,10 @@ class ServingEngine:
         chunk's rows occupy positions ``pos .. pos+lens-1`` and attend
         over every page-resident position before them. Serves chunked-
         prefill continuation chunks and prefix-cache-hit tails."""
-        key = ("prefill_ctx", nb, sp)
-        prog = self._programs.get(key)
-        if prog is not None:
-            return prog
+        return self._program(("prefill_ctx", nb, sp),
+                             lambda: self._prefill_ctx_program(nb, sp))
 
+    def _prefill_ctx_program(self, nb: int, sp: int):
         def prefill_ctx_fn(params, k, v, table, ids, lens, pos, rng,
                            temps, top_ks, top_ps, poison, *lora):
             logits, k, v = self._fwd(params, ids, k, v, table, pos,
@@ -679,20 +687,15 @@ class ServingEngine:
                           name=f"serve_prefill_ctx_{nb}x{sp}",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
-        with self._mesh_scope(), \
-                _trace.span("serve.compile", kind=prog.kind):
-            prog.compile((self.params, self.cache.k, self.cache.v,
-                          jnp.zeros((nb, mb), jnp.int32),
-                          jnp.zeros((nb, sp), jnp.int32),
-                          jnp.ones((nb,), jnp.int32),
-                          jnp.zeros((nb,), jnp.int32), self._key,
-                          jnp.ones((nb,), jnp.float32),
-                          jnp.zeros((nb,), jnp.int32),
-                          jnp.ones((nb,), jnp.float32),
-                          jnp.zeros((nb,), jnp.float32))
-                         + self._lora_sig(nb))
-        self._programs[key] = prog
-        return prog
+        return prog, (self.params, self.cache.k, self.cache.v,
+                      jnp.zeros((nb, mb), jnp.int32),
+                      jnp.zeros((nb, sp), jnp.int32),
+                      jnp.ones((nb,), jnp.int32),
+                      jnp.zeros((nb,), jnp.int32), self._key,
+                      jnp.ones((nb,), jnp.float32),
+                      jnp.zeros((nb,), jnp.int32),
+                      jnp.ones((nb,), jnp.float32),
+                      jnp.zeros((nb,), jnp.float32)) + self._lora_sig(nb)
 
     def _get_verify(self) -> AOTProgram:
         """Speculative-verify program (ISSUE 15): ONE dispatch scores
@@ -710,10 +713,10 @@ class ServingEngine:
         masked out — so the host can run point-mass-drafter
         Leviathan-style acceptance and the committed stream keeps the
         plain sampled-decode distribution exactly."""
-        key = ("verify", self._spec_k + 1)
-        prog = self._programs.get(key)
-        if prog is not None:
-            return prog
+        return self._program(("verify", self._spec_k + 1),
+                             self._verify_program)
+
+    def _verify_program(self):
         S = self._spec_k + 1
 
         def verify_fn(params, k, v, table, pos, ids, active, rng,
@@ -757,20 +760,15 @@ class ServingEngine:
                           name="serve_verify",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
-        with self._mesh_scope(), \
-                _trace.span("serve.compile", kind=prog.kind):
-            prog.compile((self.params, self.cache.k, self.cache.v,
-                          jnp.zeros((B, mb), jnp.int32),
-                          jnp.zeros((B,), jnp.int32),
-                          jnp.zeros((B, S), jnp.int32),
-                          jnp.zeros((B,), bool), self._key,
-                          jnp.ones((B,), jnp.float32),
-                          jnp.zeros((B,), jnp.int32),
-                          jnp.ones((B,), jnp.float32),
-                          jnp.zeros((B,), jnp.float32))
-                         + self._lora_sig(B))
-        self._programs[key] = prog
-        return prog
+        return prog, (self.params, self.cache.k, self.cache.v,
+                      jnp.zeros((B, mb), jnp.int32),
+                      jnp.zeros((B,), jnp.int32),
+                      jnp.zeros((B, S), jnp.int32),
+                      jnp.zeros((B,), bool), self._key,
+                      jnp.ones((B,), jnp.float32),
+                      jnp.zeros((B,), jnp.int32),
+                      jnp.ones((B,), jnp.float32),
+                      jnp.zeros((B,), jnp.float32)) + self._lora_sig(B)
 
     def warmup(self, prefill_signatures: Optional[Sequence[Tuple[int, int]]]
                = None) -> int:

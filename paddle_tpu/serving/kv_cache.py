@@ -5,13 +5,24 @@ static-shape program — the generalization of ``GPTAttention.StaticCache``
 (one contiguous ``[B, L_max, H, D]`` buffer per request) to a shared pool
 of fixed-size pages:
 
-- K/V live in ONE pool per layer, ``[num_pages, block_size, H, D]``,
-  stacked ``[L, ...]`` at the model level so scan-over-layers can thread
-  each layer's slice through the decode program
-  (:func:`paddle_tpu.nn.scan.scan_layers_with_cache`);
+- K/V live in ONE lane-dense pool, ``[L, P, G, bs, (H/G)*D]``: ``P``
+  pages a layer, ``bs`` token rows a page, the heads of a token row
+  fused into the minor dim (GPT-2 345M: 16 x 64 = 1024 lanes, whole
+  ``(16, 128)`` bf16 tiles, nothing padded). ``G`` is the number of head
+  groups — the ``mp`` size of a serving mesh, which shards that axis
+  (``distributed.spmd.SERVE_KV_SPEC``), 1 without one. The array the
+  engine holds, the array a serving program takes and donates, and the
+  block a kernel DMAs all have this ONE physical layout;
+- inside a program the pool is viewed as ``L*P`` pages
+  ``[L*P, G, bs, (H/G)*D]`` (a bitcast: leading dims merge) and is never
+  sliced by layer: it rides the scan CARRY
+  (:func:`paddle_tpu.nn.scan.scan_layers_with_cache`), and layer ``l``
+  addresses its pages as ``l*P + block_table`` (``base`` below). A step
+  scatters only the rows it produced, in place;
 - each batch slot owns a row of a **block table** ``[slots, MB]`` mapping
   logical block ``j`` (token positions ``j*bs .. j*bs+bs-1``) to a
-  physical page; unallocated entries point at the reserved scratch page 0;
+  layer-relative page; unallocated entries point at the reserved scratch
+  page 0 (physical page ``l*P`` of layer ``l``);
 - pages are allocated incrementally as a request's sequence grows and
   freed the step it finishes — HBM scales with tokens actually held, not
   with ``slots * max_context`` (the fragmentation PagedAttention exists
@@ -21,11 +32,13 @@ of fixed-size pages:
   small int32 *arguments* of the compiled step, so admitting/evicting a
   request between steps never recompiles anything.
 
-The write/gather kernels are plain XLA scatter/gather (TPU-friendly:
-one ``.at[].set`` and one ``pages[table]`` gather per layer); out-of-range
-logical positions (a bucketed prefill's padded tail) route to the scratch
-page by construction and are masked at read time, so no branch guards the
-hot path.
+Everything that touches pages goes through two primitives and their
+``_quant`` twins — :func:`write_pages` (one XLA scatter of the new rows)
+and :func:`gather_pages` (one ``pages[base + table]`` gather, the XLA
+fallback of the Pallas decode kernel and the context-prefill read) — so
+there is one addressing rule. Out-of-range logical positions (a bucketed
+prefill's padded tail) route to the layer's scratch page by construction
+and are masked at read time, so no branch guards the hot path.
 """
 
 from __future__ import annotations
@@ -55,13 +68,14 @@ def blocks_needed(num_tokens: int, block_size: int) -> int:
 
 class PagedCacheView(NamedTuple):
     """Model-level traced view of the cache: what ``GPTModel.forward``
-    receives as ``caches``. ``k``/``v`` are layer-stacked pools
-    ``[L, P, bs, H, D]``; ``block_table`` is ``[B, MB]`` int32. Being a
-    NamedTuple it is a pytree — it flows through jit/scan unchanged.
+    receives as ``caches``. ``k``/``v`` are the lane-dense pools
+    ``[L, P, G, bs, (H/G)*D]``; ``block_table`` is ``[B, MB]`` int32.
+    Being a NamedTuple it is a pytree — it flows through jit/scan
+    unchanged.
 
     Optional trailing fields (all default ``None`` so every pre-existing
     3-arg construction is unchanged): ``k_scale``/``v_scale`` are the
-    ``[L, P, bs, H]`` f32 scale pools of a quantized cache
+    ``[L, P, G, bs, H/G]`` f32 scale pools of a quantized cache
     (``FLAGS_serve_kv_quant``); ``lora_a``/``lora_b`` are per-layer
     stacked LoRA pools ``[L, A, r, E]`` / ``[L, A, r, O]`` and
     ``lora_ids`` the ``[B]`` int32 per-slot adapter rows (serving.lora).
@@ -78,11 +92,15 @@ class PagedCacheView(NamedTuple):
 
 
 class PagedLayerCache(NamedTuple):
-    """One layer's slice of the view (``[P, bs, H, D]`` pools), handed to
-    ``GPTAttention.forward`` by both the scan body and the loop layout.
-    Optional trailing fields mirror :class:`PagedCacheView` (per-layer
-    slices: ``[P, bs, H]`` scales, ``[A, r, E]``/``[A, r, O]`` LoRA
-    pools)."""
+    """One layer's window on the view, handed to ``GPTAttention.forward``
+    by both the scan body and the loop layout. ``k_pages``/``v_pages``
+    are the WHOLE pools viewed as ``[L*P, G, bs, (H/G)*D]`` — no layer
+    is sliced out — and ``page_base`` (``l*P``; a traced scalar under
+    the scan, an int in the loop) is the layer's first physical page:
+    the layer reads and writes pages ``page_base + block_table`` only.
+    Optional trailing fields mirror :class:`PagedCacheView` (whole
+    ``[L*P, G, bs, H/G]`` scale pools under the same addressing;
+    per-layer ``[A, r, E]``/``[A, r, O]`` LoRA slices)."""
 
     k_pages: object
     v_pages: object
@@ -92,6 +110,7 @@ class PagedLayerCache(NamedTuple):
     lora_a: object = None
     lora_b: object = None
     lora_ids: object = None
+    page_base: object = 0
 
 
 class ContextPagedCacheView(PagedCacheView):
@@ -113,29 +132,48 @@ class ContextPagedLayerCache(PagedLayerCache):
     marker contract at the attention-block level)."""
 
 
-def write_pages(pages, new, block_table, pos):
-    """Scatter ``new`` ``[B, S, H, D]`` into ``pages`` ``[P, bs, H, D]``
-    at logical positions ``pos[b] + 0..S-1`` through ``block_table``
-    ``[B, MB]``. Positions past ``MB*bs`` (padded prefill tails) route to
-    the scratch page. Returns the updated pool."""
-    bs = pages.shape[1]
+def _physical_rows(block_table, pos, S, bs, base):
+    """``(page, row)`` ``[B, S]`` of logical positions ``pos[b] + 0..S-1``
+    through ``block_table`` ``[B, MB]``: the ONE addressing rule of the
+    flat pool — physical page = ``base`` (the layer's first page) + the
+    table's layer-relative entry. Positions past ``MB*bs`` (padded
+    prefill tails) route to the layer's scratch page."""
     mb = block_table.shape[1]
-    S = new.shape[1]
     idx = pos[:, None].astype(jnp.int32) + \
         jnp.arange(S, dtype=jnp.int32)[None, :]                  # [B, S]
     blk_logical = jnp.minimum(idx // bs, mb - 1)
     blk = jnp.take_along_axis(block_table, blk_logical, axis=1)  # [B, S]
     blk = jnp.where(idx >= bs * mb, SCRATCH_PAGE, blk)
-    off = idx % bs
-    return pages.at[blk, off].set(new.astype(pages.dtype))
+    return blk + base, idx % bs
 
 
-def gather_pages(pages, block_table):
+def write_pages(pages, new, block_table, pos, base=0):
+    """Scatter ``new`` ``[B, S, H, D]`` into the pool ``pages``
+    ``[N, G, bs, (H/G)*D]`` at logical positions ``pos[b] + 0..S-1``
+    through ``block_table`` ``[B, MB]``, whose entries are relative to
+    physical page ``base``. Only the ``B*S`` new rows move: inside a
+    compiled step the scatter updates the (donated, carried) pool in
+    place. Returns the updated pool."""
+    _, G, bs, F = pages.shape
+    B, S = new.shape[:2]
+    blk, off = _physical_rows(block_table, pos, S, bs, base)
+    rows = new.reshape(B, S, G, F).astype(pages.dtype)
+    return pages.at[blk, :, off].set(rows)
+
+
+def _contiguous(g, head_dim):
+    """Gathered pages ``[B, MB, G, bs, (H/G)*D]`` as a slot-contiguous
+    context ``[B, MB*bs, H, D]`` (a free reshape when ``G == 1``)."""
+    B, MB, G, bs, F = g.shape
+    return jnp.swapaxes(g, 2, 3).reshape(
+        B, MB * bs, G * F // head_dim, head_dim)
+
+
+def gather_pages(pages, block_table, head_dim, base=0):
     """Gather a slot-contiguous context ``[B, MB*bs, H, D]`` out of the
-    pool via the block table (the PagedAttention read)."""
-    g = pages[block_table]                        # [B, MB, bs, H, D]
-    B, MB, bs, H, D = g.shape
-    return g.reshape(B, MB * bs, H, D)
+    pool ``[N, G, bs, (H/G)*D]`` via the block table (the PagedAttention
+    read), table entries relative to physical page ``base``."""
+    return _contiguous(pages[block_table + base], head_dim)
 
 
 #: int8 quant range: symmetric, -127..127 (no -128 — keeps the scale
@@ -145,47 +183,46 @@ _QMAX = 127.0
 _QEPS = 1e-8
 
 
-def write_pages_quant(pages, scales, new, block_table, pos):
+def write_pages_quant(pages, scales, new, block_table, pos, base=0):
     """Quantizing scatter (``FLAGS_serve_kv_quant=int8``): same indexing
     as :func:`write_pages`, but ``new`` ``[B, S, H, D]`` is stored as
     int8 in ``pages`` with a per-token-row, per-head absmax scale in the
-    parallel f32 pool ``scales`` ``[P, bs, H]``. Quantization happens at
-    write time — every token row is quantized exactly once, so pages can
-    move between slots (COW sharing, radix donation, ``truncate_slot``,
-    drain snapshots) without ever touching the payload: the scale rides
-    the same physical page index. Returns ``(pages, scales)``."""
-    bs = pages.shape[1]
-    mb = block_table.shape[1]
-    S = new.shape[1]
-    idx = pos[:, None].astype(jnp.int32) + \
-        jnp.arange(S, dtype=jnp.int32)[None, :]                  # [B, S]
-    blk_logical = jnp.minimum(idx // bs, mb - 1)
-    blk = jnp.take_along_axis(block_table, blk_logical, axis=1)  # [B, S]
-    blk = jnp.where(idx >= bs * mb, SCRATCH_PAGE, blk)
-    off = idx % bs
+    parallel f32 pool ``scales`` ``[N, G, bs, H/G]``. Quantization
+    happens at write time — every token row is quantized exactly once,
+    so pages can move between slots (COW sharing, radix donation,
+    ``truncate_slot``, drain snapshots) without ever touching the
+    payload: the scale rides the same physical page index. Returns
+    ``(pages, scales)``."""
+    _, G, bs, F = pages.shape
+    B, S = new.shape[:2]
+    blk, off = _physical_rows(block_table, pos, S, bs, base)
     newf = new.astype(jnp.float32)                               # [B,S,H,D]
     scale = jnp.maximum(jnp.max(jnp.abs(newf), axis=-1),
                         _QEPS) / _QMAX                           # [B,S,H]
     q = jnp.clip(jnp.round(newf / scale[..., None]),
                  -_QMAX, _QMAX).astype(jnp.int8)
-    return (pages.at[blk, off].set(q),
-            scales.at[blk, off].set(scale.astype(scales.dtype)))
+    return (pages.at[blk, :, off].set(q.reshape(B, S, G, F)),
+            scales.at[blk, :, off].set(
+                scale.reshape(B, S, G, -1).astype(scales.dtype)))
 
 
 def dequant_pages(pages, scales):
     """Dequantize an int8 pool (or any gathered slice of one) back to
-    f32: ``pages [..., H, D] * scales [..., H, None]``."""
-    return pages.astype(jnp.float32) * scales.astype(jnp.float32)[..., None]
+    f32 in the pool's own shape: each ``D``-wide head segment of the
+    fused minor dim ``[..., (H/G)*D]`` times its scale ``[..., H/G]``."""
+    hg = scales.shape[-1]
+    seg = pages.astype(jnp.float32).reshape(pages.shape[:-1] + (hg, -1))
+    return (seg * scales.astype(jnp.float32)[..., None]).reshape(
+        pages.shape)
 
 
-def gather_pages_quant(pages, scales, block_table):
+def gather_pages_quant(pages, scales, block_table, head_dim, base=0):
     """Quantized PagedAttention read: gather int8 pages and their scales
     through the block table and dequantize to a slot-contiguous f32
     ``[B, MB*bs, H, D]`` context (the XLA fallback the quant Pallas
     decode kernel must match)."""
-    g = dequant_pages(pages[block_table], scales[block_table])
-    B, MB, bs, H, D = g.shape
-    return g.reshape(B, MB * bs, H, D)
+    tbl = block_table + base
+    return _contiguous(dequant_pages(pages[tbl], scales[tbl]), head_dim)
 
 
 class BlockAllocator:
@@ -271,11 +308,19 @@ class PagedKVCache:
 
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  *, num_pages: int, block_size: int, max_slots: int,
-                 max_blocks_per_slot: int, dtype=jnp.float32):
+                 max_blocks_per_slot: int, dtype=jnp.float32,
+                 head_groups: int = 1):
         from ..core.flags import get_flag
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
+        #: head groups G: the pools' third axis, which a serving mesh
+        #: shards over ``mp`` (one group a chip); 1 on a single chip
+        self.head_groups = int(head_groups)
+        if self.num_heads % self.head_groups:
+            raise ValueError(
+                f"num_heads={num_heads} not divisible by "
+                f"head_groups={head_groups}")
         self.block_size = int(block_size)
         self.max_slots = int(max_slots)
         self.max_blocks_per_slot = int(max_blocks_per_slot)
@@ -290,9 +335,11 @@ class PagedKVCache:
             raise ValueError(
                 f"FLAGS_serve_kv_quant={self.quant!r}: supported modes "
                 "are '' (full precision) and 'int8'")
-        shape = (num_layers, num_pages, block_size, num_heads, head_dim)
+        hg = self.num_heads // self.head_groups
+        shape = (num_layers, num_pages, self.head_groups, block_size,
+                 hg * head_dim)
         if self.quant == "int8":
-            scale_shape = shape[:-1]              # [L, P, bs, H]
+            scale_shape = shape[:-1] + (hg,)      # [L, P, G, bs, H/G]
             self.k = (jnp.zeros(shape, jnp.int8),
                       jnp.zeros(scale_shape, jnp.float32))
             self.v = (jnp.zeros(shape, jnp.int8),

@@ -138,18 +138,26 @@ def _quant_state(rng, B=2, n=(7, 3), P=8, bs=4, H=2, D=8):
     return tbl, new, P, bs, H, D
 
 
-def test_write_pages_quant_round_trip_error_bound():
+def _quant_pools(P, bs, H, D, G):
+    """Empty lane-dense int8 pool + its scale pool, ``G`` head groups."""
+    return (jnp.zeros((P, G, bs, (H // G) * D), jnp.int8),
+            jnp.zeros((P, G, bs, H // G), jnp.float32))
+
+
+@pytest.mark.parametrize("G", [1, 2], ids=["one-group", "two-groups"])
+def test_write_pages_quant_round_trip_error_bound(G):
     """Per-(position, head) absmax int8: dequantized values sit within
     half a quantization step (absmax/127/2 per position+head row)."""
     rng = np.random.default_rng(3)
     tbl, new, P, bs, H, D = _quant_state(rng)
-    pages = jnp.zeros((P, bs, H, D), jnp.int8)
-    scales = jnp.zeros((P, bs, H), jnp.float32)
+    pages, scales = _quant_pools(P, bs, H, D, G)
     for b in range(2):
         pages, scales = write_pages_quant(
             pages, scales, jnp.asarray(new[b]),
             jnp.asarray(tbl[b:b + 1]), jnp.zeros((1,), jnp.int32))
-    deq = np.asarray(dequant_pages(pages, scales))
+    # [P, G, bs, (H/G)*D] -> [P, bs, H, D]
+    deq = np.asarray(dequant_pages(pages, scales)).reshape(
+        P, G, bs, H // G, D).transpose(0, 2, 1, 3, 4).reshape(P, bs, H, D)
     for b, blocks in ((0, [1, 2]), (1, [3])):
         x = new[b][0]                                   # [n, H, D]
         nb = len(blocks)
@@ -159,19 +167,20 @@ def test_write_pages_quant_round_trip_error_bound():
         assert nb * bs >= x.shape[0]
 
 
-def test_gather_pages_quant_matches_dequant_then_gather():
+@pytest.mark.parametrize("G", [1, 2], ids=["one-group", "two-groups"])
+def test_gather_pages_quant_matches_dequant_then_gather(G):
     rng = np.random.default_rng(4)
     tbl, new, P, bs, H, D = _quant_state(rng)
-    pages = jnp.zeros((P, bs, H, D), jnp.int8)
-    scales = jnp.zeros((P, bs, H), jnp.float32)
+    pages, scales = _quant_pools(P, bs, H, D, G)
     for b in range(2):
         pages, scales = write_pages_quant(
             pages, scales, jnp.asarray(new[b]),
             jnp.asarray(tbl[b:b + 1]), jnp.zeros((1,), jnp.int32))
-    got = np.asarray(gather_pages_quant(pages, scales, jnp.asarray(tbl)))
+    got = np.asarray(gather_pages_quant(pages, scales, jnp.asarray(tbl), D))
     ref = np.asarray(gather_pages(dequant_pages(pages, scales),
-                                  jnp.asarray(tbl)))
+                                  jnp.asarray(tbl), D))
     np.testing.assert_array_equal(got, ref)
+    assert got.shape == (2, 4 * bs, H, D)
 
 
 def test_quant_cache_pools_and_footprint_accounting():

@@ -285,59 +285,91 @@ def test_ce_backward_parity_when_its_row_block_is_halved(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def _paged_state(rng, B=3, MB=4, bs=4, H=2, D=8, P=10):
-    """Pools + tables + positions with slots at different fill levels,
-    written through the production write_pages path."""
-    from paddle_tpu.serving.kv_cache import write_pages
-    kp = jnp.zeros((P, bs, H, D), jnp.float32)
-    vp = jnp.zeros((P, bs, H, D), jnp.float32)
+def _paged_state(rng, G=1, dtype=jnp.float32, quant=False, layer=0,
+                 B=3, MB=4, bs=4, H=2, D=8, P=10):
+    """Lane-dense pools ``[2*P, G, bs, (H/G)*D]`` of a two-layer cache +
+    tables + positions with slots at different fill levels, written
+    through the production write_pages path into ``layer``'s pages.
+    Returns ``(pools, tbl, pos, base)``; ``pools`` is ``(k, v)`` or,
+    quantized, ``(k, k_scales, v, v_scales)``."""
+    from paddle_tpu.serving.kv_cache import write_pages, write_pages_quant
+    hg, base = H // G, layer * P
     tbl = np.zeros((B, MB), np.int32)
     tbl[0, :3] = [1, 2, 3]
     tbl[1, :1] = [4]
     tbl[2, :4] = [6, 7, 8, 9]
     tbl = jnp.asarray(tbl)
     pos = jnp.asarray(np.array([9, 2, 14], np.int32))
-    for b in range(B):
-        n = int(pos[b]) + 1
-        kp = write_pages(kp, jnp.asarray(
-            rng.randn(1, n, H, D).astype(np.float32)),
-            tbl[b:b + 1], jnp.zeros((1,), jnp.int32))
-        vp = write_pages(vp, jnp.asarray(
-            rng.randn(1, n, H, D).astype(np.float32)),
-            tbl[b:b + 1], jnp.zeros((1,), jnp.int32))
-    return kp, vp, tbl, pos
+    pools = []
+    for _ in "kv":
+        pages = jnp.zeros((2 * P, G, bs, hg * D),
+                          jnp.int8 if quant else dtype)
+        scales = jnp.zeros((2 * P, G, bs, hg), jnp.float32)
+        for b in range(B):
+            new = jnp.asarray(rng.randn(1, int(pos[b]) + 1, H, D)
+                              .astype(np.float32))
+            at = (tbl[b:b + 1], jnp.zeros((1,), jnp.int32), base)
+            if quant:
+                pages, scales = write_pages_quant(pages, scales, new, *at)
+            else:
+                pages = write_pages(pages, new, *at)
+        pools += [pages, scales] if quant else [pages]
+    return tuple(pools), tbl, pos, base
 
 
-def _dense_decode_ref(q, kp, vp, tbl, pos, scale):
+def _dense_decode_ref(q, pools, tbl, pos, base, scale):
     """The XLA fallback's math: gather_pages + masked softmax."""
-    from paddle_tpu.serving.kv_cache import gather_pages
-    gk, gv = gather_pages(kp, tbl), gather_pages(vp, tbl)
+    from paddle_tpu.serving.kv_cache import gather_pages, gather_pages_quant
+    D = q.shape[-1]
+    if len(pools) == 4:
+        gk = gather_pages_quant(pools[0], pools[1], tbl, D, base)
+        gv = gather_pages_quant(pools[2], pools[3], tbl, D, base)
+    else:
+        gk = gather_pages(pools[0], tbl, D, base).astype(jnp.float32)
+        gv = gather_pages(pools[1], tbl, D, base).astype(jnp.float32)
     cols = jnp.arange(gk.shape[1])
     mask = jnp.where(cols[None, :] <= pos[:, None], 0.0, -1e30)
-    s = jnp.einsum("bhd,bkhd->bhk", q, gk) * scale + mask[:, None, :]
+    s = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32), gk) * scale \
+        + mask[:, None, :]
     pr = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
     return jnp.einsum("bhk,bkhd->bhd", pr, gv)
 
 
 @pytest.mark.pallas
-def test_paged_decode_kernel_parity():
-    from paddle_tpu.ops.pallas.paged_decode import paged_decode_attention
+@pytest.mark.parametrize("G,dtype,quant,layer,tol", [
+    (1, jnp.float32, False, 0, 1e-5),
+    (1, jnp.bfloat16, False, 0, 2e-2),   # probabilities round to bf16
+    (1, jnp.float32, True, 0, 1e-5),
+    (2, jnp.float32, False, 0, 1e-5),
+    (2, jnp.float32, True, 1, 1e-5),
+    (1, jnp.float32, False, 1, 1e-5),
+], ids=["f32", "bf16", "int8", "f32-two-groups", "int8-two-groups-layer1",
+        "f32-layer1"])
+def test_paged_decode_kernel_parity(G, dtype, quant, layer, tol):
+    """Both entry points on the lane-dense pool, against the gather
+    fallback: one and two head groups, pages of layer 0 and of layer 1
+    (table offset by the layer's first page)."""
+    from paddle_tpu.ops.pallas.paged_decode import (
+        paged_decode_attention, paged_decode_attention_quant)
     rng = np.random.RandomState(0)
-    kp, vp, tbl, pos = _paged_state(rng)
-    q = jnp.asarray(rng.randn(3, 2, 8).astype(np.float32))
+    pools, tbl, pos, base = _paged_state(rng, G, dtype, quant, layer)
+    q = jnp.asarray(rng.randn(3, 2, 8).astype(np.float32)).astype(dtype)
     scale = 1.0 / np.sqrt(8)
-    ref = _dense_decode_ref(q, kp, vp, tbl, pos, scale)
-    got = paged_decode_attention(q, kp, vp, tbl, pos, scale=scale)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-5, atol=1e-6)
+    ref = _dense_decode_ref(q, pools, tbl, pos, base, scale)
+    kernel = paged_decode_attention_quant if quant else paged_decode_attention
+    got = kernel(q, *pools, tbl + base, pos, scale=scale)
+    assert got.dtype == q.dtype and got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                               np.asarray(ref), rtol=tol, atol=tol)
     # under jit (the serving decode program wraps it)
-    got_j = jax.jit(lambda *a: paged_decode_attention(
-        *a, scale=scale))(q, kp, vp, tbl, pos)
-    np.testing.assert_allclose(np.asarray(got_j), np.asarray(ref),
-                               rtol=1e-5, atol=1e-6)
+    got_j = jax.jit(lambda *a: kernel(*a, scale=scale))(
+        q, *pools, tbl + base, pos)
+    np.testing.assert_allclose(np.asarray(got_j.astype(jnp.float32)),
+                               np.asarray(ref), rtol=tol, atol=tol)
 
 
-def _gpt_paged_decode_logits(pallas_on, scan_on=True):
+def _gpt_paged_decode_logits(pallas_on, scan_on=True, quant="",
+                             head_groups=1):
     """One prefill + one batched decode step through GPTModel over the
     paged cache; returns the decode-step hidden states."""
     from paddle_tpu.models.gpt import GPTModel, gpt_tiny
@@ -346,9 +378,19 @@ def _gpt_paged_decode_logits(pallas_on, scan_on=True):
     cfg = gpt_tiny()
     m = GPTModel(cfg)
     m.eval()
-    cache = PagedKVCache(cfg.num_layers, cfg.num_heads, cfg.head_dim,
-                         num_pages=10, block_size=4, max_slots=2,
-                         max_blocks_per_slot=4)
+    with flag_scope("serve_kv_quant", quant):
+        cache = PagedKVCache(cfg.num_layers, cfg.num_heads, cfg.head_dim,
+                             num_pages=10, block_size=4, max_slots=2,
+                             max_blocks_per_slot=4,
+                             head_groups=head_groups)
+
+    def view(rows):
+        if quant:
+            return PagedCacheView(cache.k[0], cache.v[0],
+                                  cache.table_array(rows),
+                                  cache.k[1], cache.v[1])
+        return PagedCacheView(cache.k, cache.v, cache.table_array(rows))
+
     assert cache.alloc_slot(0, 7) and cache.alloc_slot(1, 4)
     rng = np.random.RandomState(5)
     prompts = [rng.randint(0, cfg.vocab_size, (1, n)).astype(np.int32)
@@ -356,42 +398,52 @@ def _gpt_paged_decode_logits(pallas_on, scan_on=True):
     ctx = flag_scope("pallas_paged_decode", pallas_on)
     with ctx, flag_scope("scan_decode", scan_on), paddle.no_grad():
         for slot, ids in enumerate(prompts):
-            view = PagedCacheView(cache.k, cache.v,
-                                  cache.table_array([slot]))
-            _, nc = m(paddle.to_tensor(ids), caches=view,
+            _, nc = m(paddle.to_tensor(ids), caches=view([slot]),
                       cache_pos=paddle.to_tensor(np.zeros(1, np.int32)))
-            cache.update(nc.k._data, nc.v._data)
+            if quant:
+                cache.update((nc.k._data, nc.k_scale._data),
+                             (nc.v._data, nc.v_scale._data))
+            else:
+                cache.update(nc.k._data, nc.v._data)
         dec = rng.randint(0, cfg.vocab_size, (2, 1)).astype(np.int32)
-        view = PagedCacheView(cache.k, cache.v, cache.table_array([0, 1]))
-        hd, _ = m(paddle.to_tensor(dec), caches=view,
+        hd, _ = m(paddle.to_tensor(dec), caches=view([0, 1]),
                   cache_pos=paddle.to_tensor(np.array([6, 3], np.int32)))
     return np.asarray(hd._data)
 
 
+#: cache variants the model-level parity runs over: (quant, head groups)
+CACHES = [("", 1), ("int8", 1), ("", 2), ("int8", 2)]
+CACHE_IDS = ["f32", "int8", "f32-two-groups", "int8-two-groups"]
+
+
 @pytest.mark.pallas
 @pytest.mark.serve
-def test_paged_decode_token_exact_in_gpt_model():
+@pytest.mark.parametrize("quant,groups", CACHES, ids=CACHE_IDS)
+def test_paged_decode_token_exact_in_gpt_model(quant, groups):
     """Decode through the full GPT paged path (scan layout): kernel-on
     states match the dense fallback to float tolerance and the greedy
     token choice is EXACT."""
-    h_off = _gpt_paged_decode_logits(pallas_on=False)
-    h_on = _gpt_paged_decode_logits(pallas_on=True)
+    h_off = _gpt_paged_decode_logits(False, quant=quant, head_groups=groups)
+    h_on = _gpt_paged_decode_logits(True, quant=quant, head_groups=groups)
     np.testing.assert_allclose(h_on, h_off, rtol=1e-5, atol=1e-5)
     assert (h_on.argmax(-1) == h_off.argmax(-1)).all()
 
 
 @pytest.mark.pallas
 @pytest.mark.serve
-def test_paged_decode_kill_switch_loop_layout():
+@pytest.mark.parametrize("quant,groups", CACHES, ids=CACHE_IDS)
+def test_paged_decode_kill_switch_loop_layout(quant, groups):
     """Kill switch off + loop layout = the pre-kernel gather+SDPA path;
-    kernel-on loop layout agrees with it."""
+    kernel-on loop layout agrees with it, and with the scan layout."""
     import warnings
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", RuntimeWarning)  # scan fallback
-        h_off = _gpt_paged_decode_logits(pallas_on=False, scan_on=False)
-        h_on = _gpt_paged_decode_logits(pallas_on=True, scan_on=False)
+        h_off = _gpt_paged_decode_logits(False, False, quant, groups)
+        h_on = _gpt_paged_decode_logits(True, False, quant, groups)
     assert ("paged_decode", "flag_off") in pallas_ops.PALLAS_STATS
     np.testing.assert_allclose(h_on, h_off, rtol=1e-5, atol=1e-5)
+    h_scan = _gpt_paged_decode_logits(True, True, quant, groups)
+    np.testing.assert_allclose(h_on, h_scan, rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

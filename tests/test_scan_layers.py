@@ -297,3 +297,42 @@ def test_scan_fallback_paths():
     with paddle.no_grad():
         m(ids)
     assert nnscan.SCAN_STATS["scan_calls"] == 1
+
+
+def test_scan_with_cache_carries_the_cache_whole():
+    """scan_layers_with_cache never scans over the cache: every layer's
+    body sees the WHOLE array (shape unchanged), finds its own part by
+    its ``scan_in`` slice, and what it writes is what the next layer and
+    the caller see; the body is traced once whatever the depth."""
+    import jax.numpy as jnp
+    from paddle_tpu.models.gpt import GPTModel, gpt_tiny
+    from paddle_tpu.nn import scan as nn_scan
+
+    paddle.seed(3)
+    m = GPTModel(gpt_tiny(num_layers=4))
+    m.eval()
+    seen = []
+
+    def body(template, x, cache, extras, scan_in):
+        (log,), (row,) = cache, scan_in
+        seen.append((log.shape, row.shape))
+        # layer l adds 1 + what layer l-1 left beside it into ITS row
+        prev = jnp.roll(log._data, 1, axis=0)[row._data]
+        return template(x), (paddle.to_tensor(
+            log._data.at[row._data].add(1.0 + prev + extras[0]._data)),)
+
+    x = paddle.to_tensor(np.random.RandomState(0).randn(2, 5, 64)
+                         .astype(np.float32))
+    nn_scan.reset_scan_stats()
+    with paddle.no_grad():
+        y, (log,) = nn_scan.scan_layers_with_cache(
+            m.layers, x, (jnp.zeros((4, 3)),), jnp.full((3,), 0.5),
+            body_call=body, scan_in=(jnp.arange(4),))
+        ref = x
+        for blk in m.layers:
+            ref = blk(ref)
+    assert nn_scan.SCAN_STATS["body_traces"] == 1
+    assert seen == [([4, 3], [])]        # paddle shapes are lists
+    np.testing.assert_allclose(np.asarray(log._data)[:, 0],
+                               [1.5, 3.0, 4.5, 6.0])
+    np.testing.assert_allclose(y.numpy(), ref.numpy(), rtol=1e-5, atol=1e-5)

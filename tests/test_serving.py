@@ -132,29 +132,69 @@ def test_sampling_greedy_matches_argmax():
 # ---------------------------------------------------------------------------
 
 
-def test_paged_decode_token_exact_scan_layout(tiny_model):
+#: every way a serving program reads and writes the pool: plain decode,
+#: context prefill (chunked), speculative verify, a LoRA engine on the
+#: zero adapter, int8 pools — flags are read at engine construction
+FEATURES = {
+    "plain": ({}, {}),
+    "chunked-prefill": ({"serve_prefill_chunk": 4}, {}),
+    "prefix-cache": ({"serve_prefix_cache": True}, {}),
+    "spec-verify": ({"serve_spec_k": 2}, {}),
+    "lora": ({}, {"lora_adapters": 2, "lora_rank": 4}),
+    "int8": ({"serve_kv_quant": "int8"}, {}),
+    "bf16-cache": ({}, {"cache_dtype": "bfloat16"}),
+}
+
+
+def _feature_engine(model, feature, **kw):
+    import contextlib
+    flags, cfg = FEATURES[feature]
+    with contextlib.ExitStack() as st:
+        for k, v in flags.items():
+            st.enter_context(flag_scope(k, v))
+        return _engine(model, **cfg, **kw)
+
+
+@pytest.mark.parametrize("feature", [f for f in FEATURES
+                                     if f != "bf16-cache"])
+def test_paged_decode_token_exact_scan_layout(tiny_model, feature):
     """prefill+decode split under scan == full forward, several prompt/
-    generation lengths, slots finishing early."""
-    eng = _engine(tiny_model)
+    generation lengths, slots finishing early — through every serving
+    program that addresses the pool (FEATURES)."""
+    eng = _feature_engine(tiny_model, feature)
     rng = np.random.default_rng(1)
     prompts = [rng.integers(2, 250, (n,)).astype(np.int32)
                for n in (3, 7, 14)]
+    if feature == "spec-verify":        # something for the drafter to eat
+        prompts.append(np.array([3, 4, 5, 3, 4, 5, 3, 4], np.int32))
+    if feature == "prefix-cache":       # a donated prefix, then its hit
+        eng.generate([np.concatenate([prompts[2], [7, 8]])],
+                     max_new_tokens=2)
     outs = eng.generate(prompts, max_new_tokens=6)
     for p, out in zip(prompts, outs):
         np.testing.assert_array_equal(out, _golden(tiny_model, p, 6))
+    if feature == "spec-verify":
+        assert eng._stats["verify_dispatches"] > 0
+    if feature == "chunked-prefill":
+        assert eng._stats["prefill_chunks"] > 0
+    if feature == "prefix-cache":
+        assert eng.prefix_cache.stats["hits"] > 0
 
 
-def test_paged_decode_loop_layout_matches_scan(tiny_model):
+@pytest.mark.parametrize("feature", list(FEATURES))
+def test_paged_decode_loop_layout_matches_scan(tiny_model, feature):
     from paddle_tpu.nn import scan as nn_scan
     rng = np.random.default_rng(2)
     prompts = [rng.integers(2, 250, (n,)).astype(np.int32)
                for n in (5, 11)]
-    scan_out = _engine(tiny_model).generate(prompts, max_new_tokens=5)
+    scan_out = _feature_engine(tiny_model, feature).generate(
+        prompts, max_new_tokens=5)
     nn_scan.reset_scan_stats()
     with flag_scope("scan_decode", False), warnings.catch_warnings(
             record=True) as w:
         warnings.simplefilter("always")
-        loop_out = _engine(tiny_model).generate(prompts, max_new_tokens=5)
+        loop_out = _feature_engine(tiny_model, feature).generate(
+            prompts, max_new_tokens=5)
     for a, b in zip(scan_out, loop_out):
         np.testing.assert_array_equal(a, b)
     # the kill switch is a RECORDED degradation, not a silent one
@@ -162,6 +202,73 @@ def test_paged_decode_loop_layout_matches_scan(tiny_model):
     msgs = [str(x.message) for x in w
             if "scan-over-layers fell back" in str(x.message)]
     assert len(msgs) == 1              # one-time warning, not per step
+
+
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "loop"])
+@pytest.mark.parametrize("ctx", [False, True], ids=["prefill", "context"])
+def test_layer_writes_stay_in_its_own_pages(tiny_model, scan, ctx):
+    """The pool is one array of L*P pages and layer ``l`` owns pages
+    ``l*P .. l*P+P-1`` of it: a forward writes each layer's rows — the
+    padded tail's and an all-scratch row's scratch writes included —
+    into the pages its table names and into its OWN scratch page
+    ``l*P``, nowhere else, in both layouts."""
+    import jax.numpy as jnp
+    with flag_scope("scan_decode", scan), warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)   # loop fallback
+        eng = _engine(tiny_model)
+        L, P = eng.cache.k.shape[:2]
+        table = np.zeros((2, eng.cache.max_blocks_per_slot), np.int32)
+        table[0, :3] = [5, 2, 9]            # row 1: all scratch (padded)
+        ids = np.random.default_rng(3).integers(2, 250, (2, 8))
+        pos = np.array([3, 0], np.int32)    # row 0 writes positions 3..10
+        _, k, v = eng._fwd(eng.params, jnp.asarray(ids, jnp.int32),
+                           eng.cache.k, eng.cache.v, jnp.asarray(table),
+                           jnp.asarray(pos), ctx=ctx)
+    for pool in (k, v):
+        pool = np.asarray(pool)
+        assert pool.shape == eng.cache.k.shape
+        written = np.abs(pool).reshape(L, P, -1).max(-1) > 0
+        for layer in range(L):              # page 0 = the layer's scratch
+            assert set(np.flatnonzero(written[layer])) == {0, 5, 2, 9}
+        rows = np.abs(pool[:, 5, 0]).max(-1) > 0          # [L, bs]
+        assert (rows == [False, False, False, True]).all()   # pos 3 only
+
+
+def test_harness_probe_pool_runs_through_fwd(tiny_model):
+    """benchmark/harness/serve_runner.py builds a small pool by
+    repeating the engine pool's trailing dims after ``(L, n)`` and runs
+    prefill + one decode step through ``eng._fwd`` with page 0 as
+    scratch: any such pool is a valid pool, whatever its page count."""
+    import jax
+    import jax.numpy as jnp
+    eng = _engine(tiny_model)
+    L, _, a, b, c = eng.cache.k.shape
+    need = 3
+    pool = jnp.zeros((L, need + 1, a, b, c), eng.cache.k.dtype)
+    prompt = np.random.default_rng(5).integers(2, 250, (7,)).astype(np.int32)
+    slots, mb = eng.config.max_batch_slots, eng.cache.max_blocks_per_slot
+    table = np.zeros((slots, mb), np.int32)
+    table[0, :need] = 1 + np.arange(need)
+    ids = np.zeros((1, 8), np.int32)
+    ids[0, :7] = prompt
+    toks = np.zeros((slots,), np.int32)
+    toks[0] = 11
+    pos = np.zeros((slots,), np.int32)
+    pos[0] = 7
+
+    @jax.jit
+    def replay(params, k, v):
+        _, k, v = eng._fwd(params, jnp.asarray(ids), k, v,
+                           jnp.asarray(table[:1]), jnp.zeros((1,), jnp.int32))
+        logits, _, _ = eng._fwd(params, jnp.asarray(toks)[:, None], k, v,
+                                jnp.asarray(table), jnp.asarray(pos))
+        return logits[0, -1]
+
+    got = np.asarray(replay(eng.params, pool, pool))
+    with no_grad():
+        ref = tiny_model(paddle.to_tensor(
+            np.concatenate([prompt, [11]])[None].astype(np.int32))).numpy()
+    np.testing.assert_allclose(got, ref[0, -1], rtol=1e-4, atol=1e-5)
 
 
 def test_mixed_finish_early_eos(tiny_model):

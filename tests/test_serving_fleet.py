@@ -79,12 +79,14 @@ def test_tp_mesh_decode_token_identical(tiny_model):
     eng = _engine(tiny_model, mesh=mesh)
     got = [o.tolist() for o in eng.generate(PROMPTS, max_new_tokens=6)]
     assert got == want
-    # the paged KV pool is physically sharded over the head axis
-    # (trailing Nones may be normalized away by XLA output shardings)
+    # the paged KV pool [L, P, G, bs, (H/G)*D] is physically sharded
+    # over its head-group axis, one group a chip (trailing Nones may be
+    # normalized away by XLA output shardings)
     for arr in (eng.cache.k, eng.cache.v):
+        assert arr.shape[2] == 2
         spec = tuple(arr.sharding.spec)
-        assert spec[3] == "mp"
-        assert all(ax is None for i, ax in enumerate(spec) if i != 3)
+        assert spec[2] == "mp"
+        assert all(ax is None for i, ax in enumerate(spec) if i != 2)
     eng.shutdown()
 
 

@@ -8,6 +8,12 @@ GPT-2 345M shapes the training and serving paths use. Interpret-mode
 parity tests cannot see any of that: two of these kernels passed every
 one of them and had never compiled (ISSUE 22).
 
+The serving programs compile here too, whole, at the serve cell's
+engine settings: what XLA:TPU does to the page pools between the
+program's arguments and the kernel (a relayout copy, a per-layer slice)
+is invisible to every CPU parity test and was 81% of the cell's device
+time before ISSUE 27.
+
 Nothing here runs a kernel — results are chip_smoke.py's job. The
 topology is described inside a module-scoped fixture and nowhere at
 import: only one process may load libtpu, and under pytest-xdist every
@@ -15,9 +21,11 @@ worker imports every test file.
 """
 
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -26,14 +34,15 @@ from paddle_tpu.core.flags import flag_scope
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
 # GPT-2 345M: 16 heads of 64, vocab 50304; training B=8 S=1024, serving
-# 8 slots over 16-token pages with a 512-token context (256 pages + the
-# scratch page), LoRA rank 16 over 5 adapters
+# 8 slots over 16-token lane-dense pages [pages, 1, 16, 16*64] with a
+# 512-token context (256 pages + the scratch page), LoRA rank 16 over 5
+# adapters
 TRAIN_QKV = (8, 1024, 16, 64)
 PREFILL_QKV = (4, 256, 16, 64)
 BERT_QKV = (48, 512, 12, 64)
 LOGITS = (8192, 50304)
 CE_CHUNK = 8192                      # FLAGS_chunked_ce_chunk default
-POOL, TABLE, SLOTS = (257, 16, 16, 64), (8, 32), 8
+POOL, TABLE, SLOTS = (257, 1, 16, 1024), (8, 32), 8
 
 
 def _kernel(name):
@@ -64,8 +73,9 @@ def chip(topo):
 
 
 @pytest.fixture
-def compile_for_chip():
-    """``compile_for_chip(fn, *shaped_args)`` -> the compiled text.
+def build_for_chip():
+    """``build_for_chip(lower, *shaped_args)`` -> the ``Compiled`` of
+    ``lower(*shaped_args).compile()``.
 
     The kernels compile, not interpret (``FLAGS_pallas_interpret`` off,
     whatever marker a neighbour test carried), and the persistent cache
@@ -73,18 +83,25 @@ def compile_for_chip():
     is written to it but cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
-    def run(fn, *args):
+    def run(lower, *args):
         was = jax.config.jax_enable_compilation_cache
         jax.config.update("jax_enable_compilation_cache", False)
         cc.reset_cache()
         try:
             with flag_scope("pallas_interpret", False):
-                return jax.jit(fn).lower(*args).compile().as_text()
+                return lower(*args).compile()
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
             cc.reset_cache()
 
     return run
+
+
+@pytest.fixture
+def compile_for_chip(build_for_chip):
+    """``compile_for_chip(fn, *shaped_args)`` -> the compiled text."""
+    return lambda fn, *args: build_for_chip(jax.jit(fn).lower,
+                                            *args).as_text()
 
 
 def _sum32(x):
@@ -155,7 +172,7 @@ def test_paged_decode_compiles(chip, compile_for_chip, dtype, quant):
     q = chip((SLOTS, 16, 64), dtype)
     table, pos = chip(TABLE, I32), chip((SLOTS,), I32)
     if quant:
-        pool, scales = chip(POOL, I8), chip(POOL[:3], F32)
+        pool, scales = chip(POOL, I8), chip(POOL[:3] + (16,), F32)
         text = compile_for_chip(
             lambda q, k, ks, v, vs, t, p: pd.paged_decode_attention_quant(
                 q, k, ks, v, vs, t, p, scale=0.125),
@@ -190,3 +207,90 @@ def test_int8_matmul_compiles(chip, compile_for_chip):
         _kernel("quant_matmul").int8_matmul, chip((8192, 1024), I8),
         chip((1024, 4096), I8), chip((4096,), F32), chip((), F32))
     _assert_kernels(text, "int8_matmul")
+
+
+# -- the serving programs, whole ---------------------------------------------
+
+# the serve cell's engine (benchmark/workloads/gpt2_345m.serve.closed64.json)
+CELL_ENGINE = dict(max_batch_slots=64, block_size=16, max_context_len=1024,
+                   prefill_buckets=(256, 768), batch_buckets=(1, 4),
+                   cache_dtype="bfloat16")
+CELL_PAGES = 1 + 64 * 64
+POOL_SIZED = 100e6                   # bytes; one layer's K pool is 134 MB
+_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
+          "u8": 1, "pred": 1}
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(", re.M)
+
+
+@pytest.fixture(scope="module")
+def gpt2_345m():
+    import paddle_tpu as paddle
+    from paddle_tpu.models.gpt import GPTForPretraining, gpt2_medium
+    paddle.seed(0)
+    return GPTForPretraining(gpt2_medium())
+
+
+@pytest.fixture
+def cell_engine(gpt2_345m):
+    """GPT-2 345M (24 layers, bf16 weights) behind the cell's engine
+    settings. The engine's own pools are one slot small — the compiles
+    below take the cell's 4097-page pools as SHAPES. (An engine lives
+    one test long: conftest resets the serving layer after each.)"""
+    from paddle_tpu import inference
+    from paddle_tpu.serving import ServingConfig
+    cfg = inference.Config.from_layer(gpt2_345m, input_spec=[])
+    cfg.enable_tpu_bf16()
+    return inference.create_serving_engine(
+        cfg, ServingConfig(num_pages=65, **CELL_ENGINE))
+
+
+def _pool_sized_moves(text, exempt_sizes):
+    """Instructions of the optimized HLO that copy, slice or update-
+    slice (alone or as the root of a fusion XLA named after them) into
+    a result of ``POOL_SIZED`` bytes or more — except the per-step
+    stacks of the layers' parameters, known by their element counts."""
+    found = []
+    for name, dtype, dims, op in _INSTR.findall(text):
+        n = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+        what = name if op == "fusion" else op
+        if (re.search(r"copy|dynamic-slice|dynamic-update-slice", what)
+                and n * _BYTES.get(dtype, 4) >= POOL_SIZED
+                and n not in exempt_sizes):
+            found.append((name, op, dtype, dims))
+    return found
+
+
+@pytest.mark.parametrize("kind,temp_limit", [
+    ("decode", 1.1e9), ("prefill_4x768", 1.0e9)])
+def test_serving_program_moves_no_pool(chip, build_for_chip, cell_engine,
+                                       monkeypatch, kind, temp_limit):
+    """ISSUE 27's guard: the page pools go from the program's donated
+    arguments through the layer scan's carry to the kernel in ONE
+    layout. No instruction copies, slices or update-slices a pool-sized
+    array, the pools are updated in place (aliased to the arguments),
+    and the temporaries are what is left: at 24 layers the per-step
+    stacks of the layers' parameters (0.60 GB), the embedding's
+    copies and the sampler — 9.16 GB before, with pool copies."""
+    eng = cell_engine
+    prog, args = (eng._decode_program() if kind == "decode"
+                  else eng._prefill_program(4, 768))
+    L = eng.cache.num_layers
+    pool = chip((L, CELL_PAGES) + eng.cache.k.shape[2:], BF16)
+    shaped = jax.tree.map(lambda a: chip(a.shape, a.dtype), args)
+    shaped = (shaped[0], pool, pool) + tuple(shaped[3:])
+    # dispatch asks the backend whether kernels can run: they can, there
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = build_for_chip(prog.lower, *shaped)
+    monkeypatch.undo()
+    text = compiled.as_text()
+    _assert_kernels(text, "paged_decode" if kind == "decode"
+                    else "flash_fwd")
+    stacks = {L * int(np.prod(p.shape))
+              for p in eng.model.gpt.layers[0].parameters()}
+    embed = {int(np.prod(eng.model.gpt.word_embeddings.weight.shape))}
+    assert _pool_sized_moves(text, stacks | embed) == []
+    mem = compiled.memory_analysis()
+    pools = 2 * int(np.prod(pool.shape)) * 2
+    assert mem.alias_size_in_bytes >= pools, mem
+    assert mem.temp_size_in_bytes < temp_limit, mem
