@@ -178,13 +178,6 @@ class AOTProgram:
         self._compiled = self._build(example_args)
         return self
 
-    def lower(self, *args):
-        """The jitted function (donation included) lowered for ``args``
-        and not built or kept: arrays, or shapes placed on a described
-        device, which is how tests/test_tpu_compile.py compiles a
-        serving program for a chip that is not attached."""
-        return self._jitted.lower(*args)
-
     @property
     def compiled(self) -> Any:
         """The ``jax.stages.Compiled`` calls run now (``as_text()``,

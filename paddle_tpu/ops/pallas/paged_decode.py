@@ -40,12 +40,18 @@ block-diagonally once a slot (row ``h`` holds ``q[h]`` in head ``h``'s
 ``[H/G, bs]`` with no reshape of the fused dim, and ``p @ v``
 ``[H/G, (H/G)*D]`` holds head ``h``'s weighted value sum in head ``h``'s
 lanes of row ``h`` (the other lanes are dropped when the slot finishes).
-bf16 pools under a bf16 query multiply in one bf16 pass with f32
-accumulation (products of bf16 values are exact in f32; the
-probabilities round to bf16 as in the fallback's ``probs.astype``);
-everything else multiplies in f32 at the highest precision. The page
+bf16 pools under a bf16 query multiply in bf16 passes with f32
+accumulation and lose nothing by it: a product of two bf16 values is
+exact in f32, and the f32 probabilities go through ``p @ v`` as three
+bf16 parts (``_probs_dot``), so scores, probabilities and the weighted
+sum have the precision of f32 arithmetic on values read from bf16.
+Everything else multiplies in f32 at the highest precision. The page
 size ``bs`` set by ``ServingConfig.block_size`` is the KV block size —
 there is no separate kernel block knob.
+
+``G > 1`` is the layout of a pool sharded over an ``mp`` mesh. Under a
+mesh ``kernel_enabled`` routes decode to the XLA fallback, so until a
+per-shard call exists only the tests run the kernel with two groups.
 
 Tests run this kernel on CPU via the Pallas interpreter
 (FLAGS_pallas_interpret; the ``pallas`` pytest marker).
@@ -73,6 +79,27 @@ def _head_lanes(hg, D):
     lane = jax.lax.broadcasted_iota(jnp.int32, (hg, hg * D), 1)
     head = jax.lax.broadcasted_iota(jnp.int32, (hg, hg * D), 0)
     return (lane >= head * D) & (lane < (head + 1) * D)
+
+
+def _probs_dot(pr, v, prec):
+    """``pr @ v`` with the f32 probabilities at their full precision.
+    Against bf16 values the probabilities split into three bf16 parts
+    (8 + 8 + 8 = the 24 bits of an f32 mantissa): every product is exact
+    in f32, so three one-pass products, the small parts summed first,
+    give what an f32 multiply on the VPU gives, in half the passes of
+    f32 operands at the highest precision."""
+    if v.dtype != jnp.bfloat16:
+        return jnp.dot(pr, v, precision=prec,
+                       preferred_element_type=jnp.float32)
+
+    def dot(part):
+        return jnp.dot(part, v, preferred_element_type=jnp.float32)
+
+    hi = pr.astype(jnp.bfloat16)
+    rest = pr - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return (dot(lo) + dot(mid)) + dot(hi)
 
 
 def _decode_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, bs, hg, D,
@@ -135,8 +162,7 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, bs, hg, D,
             alpha * l_scr[:, :1] + jnp.sum(pr, axis=1, keepdims=True),
             l_scr.shape)
         # acc[h] += pr[h] @ v: head h's sum lands in head h's lanes
-        pv = jnp.dot(pr.astype(v.dtype), v, precision=prec,
-                     preferred_element_type=jnp.float32)  # [H/G, F]
+        pv = _probs_dot(pr, v, prec)                     # [H/G, F]
         acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
 

@@ -216,81 +216,79 @@ CELL_ENGINE = dict(max_batch_slots=64, block_size=16, max_context_len=1024,
                    prefill_buckets=(256, 768), batch_buckets=(1, 4),
                    cache_dtype="bfloat16")
 CELL_PAGES = 1 + 64 * 64
-POOL_SIZED = 100e6                   # bytes; one layer's K pool is 134 MB
+# GPT-2 345M cut to 4 layers, ISSUE 27's second choice: at 24 the two
+# compiles take 70 s, and every execution stacks the layers' parameters
+# into 0.60 GB of temporaries that would hide a pool copy of a layer
+GUARD_LAYERS = 4
+LAYER_POOL = CELL_PAGES * 16 * 1024 * 2      # bytes: one layer's K pool
+TEMP_LIMIT = 100e6                   # bytes: ISSUE 27's, for decode
 _BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
           "u8": 1, "pred": 1}
 _INSTR = re.compile(
     r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(", re.M)
 
 
-@pytest.fixture(scope="module")
-def gpt2_345m():
-    import paddle_tpu as paddle
-    from paddle_tpu.models.gpt import GPTForPretraining, gpt2_medium
-    paddle.seed(0)
-    return GPTForPretraining(gpt2_medium())
-
-
 @pytest.fixture
-def cell_engine(gpt2_345m):
-    """GPT-2 345M (24 layers, bf16 weights) behind the cell's engine
-    settings. The engine's own pools are one slot small — the compiles
-    below take the cell's 4097-page pools as SHAPES. (An engine lives
-    one test long: conftest resets the serving layer after each.)"""
+def cell_engine():
+    """GPT-2 345M's widths at ``GUARD_LAYERS`` layers, bf16 weights,
+    behind the cell's engine settings. The engine's own pools are one
+    slot small — the compiles below take the cell's 4097-page pools as
+    SHAPES. (An engine lives one test long: conftest resets the serving
+    layer after each.)"""
+    import paddle_tpu as paddle
     from paddle_tpu import inference
+    from paddle_tpu.models.gpt import GPTForPretraining, gpt2_medium
     from paddle_tpu.serving import ServingConfig
-    cfg = inference.Config.from_layer(gpt2_345m, input_spec=[])
+    paddle.seed(0)
+    model = GPTForPretraining(gpt2_medium(num_layers=GUARD_LAYERS))
+    cfg = inference.Config.from_layer(model, input_spec=[])
     cfg.enable_tpu_bf16()
     return inference.create_serving_engine(
         cfg, ServingConfig(num_pages=65, **CELL_ENGINE))
 
 
-def _pool_sized_moves(text, exempt_sizes):
+def _pool_sized_moves(text):
     """Instructions of the optimized HLO that copy, slice or update-
     slice (alone or as the root of a fusion XLA named after them) into
-    a result of ``POOL_SIZED`` bytes or more — except the per-step
-    stacks of the layers' parameters, known by their element counts."""
+    a result as large as one layer's pool."""
     found = []
     for name, dtype, dims, op in _INSTR.findall(text):
         n = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
         what = name if op == "fusion" else op
         if (re.search(r"copy|dynamic-slice|dynamic-update-slice", what)
-                and n * _BYTES.get(dtype, 4) >= POOL_SIZED
-                and n not in exempt_sizes):
+                and n * _BYTES.get(dtype, 4) >= LAYER_POOL):
             found.append((name, op, dtype, dims))
     return found
 
 
-@pytest.mark.parametrize("kind,temp_limit", [
-    ("decode", 1.1e9), ("prefill_4x768", 1.0e9)])
+@pytest.mark.parametrize("kind", ["decode", "prefill_1x256"])
 def test_serving_program_moves_no_pool(chip, build_for_chip, cell_engine,
-                                       monkeypatch, kind, temp_limit):
+                                       monkeypatch, kind):
     """ISSUE 27's guard: the page pools go from the program's donated
     arguments through the layer scan's carry to the kernel in ONE
-    layout. No instruction copies, slices or update-slices a pool-sized
-    array, the pools are updated in place (aliased to the arguments),
-    and the temporaries are what is left: at 24 layers the per-step
-    stacks of the layers' parameters (0.60 GB), the embedding's
-    copies and the sampler — 9.16 GB before, with pool copies."""
+    layout. The pools are updated in place (aliased to the arguments),
+    and ALL the program's temporaries stay under the issue's 100 MB
+    with nothing deducted and no instruction exempted: a relayout of
+    ONE layer's K pool is 134 MB, whatever XLA names it. (What is there,
+    by the compiler's buffer assignment: the four layers' parameters
+    stacked, 92 MB, whose space the sampler's ``[64, 50304]`` arrays use
+    after the scan; 94.8 MB in decode, 94.6 in the prefill. The smallest
+    prefill bucket, because the pools take the same way through every
+    bucket and a 4x768 group's logits alone are 309 MB.)"""
     eng = cell_engine
     prog, args = (eng._decode_program() if kind == "decode"
-                  else eng._prefill_program(4, 768))
-    L = eng.cache.num_layers
-    pool = chip((L, CELL_PAGES) + eng.cache.k.shape[2:], BF16)
+                  else eng._prefill_program(1, 256))
+    pool = chip((GUARD_LAYERS, CELL_PAGES) + eng.cache.k.shape[2:], BF16)
     shaped = jax.tree.map(lambda a: chip(a.shape, a.dtype), args)
     shaped = (shaped[0], pool, pool) + tuple(shaped[3:])
     # dispatch asks the backend whether kernels can run: they can, there
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = build_for_chip(prog.lower, *shaped)
+    compiled = build_for_chip(prog._jitted.lower, *shaped)
     monkeypatch.undo()
     text = compiled.as_text()
     _assert_kernels(text, "paged_decode" if kind == "decode"
                     else "flash_fwd")
-    stacks = {L * int(np.prod(p.shape))
-              for p in eng.model.gpt.layers[0].parameters()}
-    embed = {int(np.prod(eng.model.gpt.word_embeddings.weight.shape))}
-    assert _pool_sized_moves(text, stacks | embed) == []
+    assert _pool_sized_moves(text) == []
     mem = compiled.memory_analysis()
-    pools = 2 * int(np.prod(pool.shape)) * 2
-    assert mem.alias_size_in_bytes >= pools, mem
-    assert mem.temp_size_in_bytes < temp_limit, mem
+    assert mem.alias_size_in_bytes >= 2 * GUARD_LAYERS * LAYER_POOL, mem
+    assert mem.temp_size_in_bytes < TEMP_LIMIT, mem
