@@ -575,15 +575,16 @@ def phase_serve(args, cfg):
         pos[0] = plen
 
         @jax.jit
-        def replay(params, k, v):
-            _, k, v = eng._fwd(params, jnp.asarray(ids), k, v,
-                               jnp.asarray(table[:1]),
-                               jnp.zeros((1,), jnp.int32))
-            logits, _, _ = eng._fwd(params, jnp.asarray(toks)[:, None], k,
-                                    v, jnp.asarray(table), jnp.asarray(pos))
+        def replay(params, pools):
+            _, pools, _ = eng._forward(params, jnp.asarray(ids), pools,
+                                       jnp.asarray(table[:1]),
+                                       jnp.zeros((1,), jnp.int32))
+            logits, _, _ = eng._forward(params, jnp.asarray(toks)[:, None],
+                                        pools, jnp.asarray(table),
+                                        jnp.asarray(pos))
             return logits[0, -1]
 
-        paged = replay(eng.params, eng.cache.k, eng.cache.v)
+        paged = replay(eng.params, eng.cache.pool_args())
         model.eval()
         with no_grad():
             dense = model(Tensor(np.concatenate([prompt, [tok0]])[None]

@@ -236,7 +236,7 @@ def shard_serving_cache(cache, mesh: Mesh):
     single-chip HBM serve at all."""
     sh = NamedSharding(mesh, degrade_spec(SERVE_KV_SPEC, mesh))
     # quantized pools (FLAGS_serve_kv_quant) are (pages, scales) tuples
-    cache.k, cache.v = jax.device_put((cache.k, cache.v), sh)
+    cache.pools = jax.device_put(cache.pools, sh)
     return cache
 
 

@@ -14,6 +14,10 @@ subsystem (ISSUE 10):
   ``FLAGS_moe_a2a_chunks``), router telemetry, and the reference-parity
   ``global_scatter``/``global_gather`` primitives.
 
+- ``held``     — the dropless sigmoid top-k layer of one chip of an
+  expert-parallel group (serving): told which experts it holds, routes
+  over all, one grouped product over its own.
+
 See docs/MOE.md for the routing math, dispatch modes, ep-axis layout and
 overlap knobs.
 """
@@ -21,6 +25,8 @@ overlap knobs.
 from .dispatch import (DISPATCH_MODES, combine_tensor, dispatch_slots,
                        einsum_combine, einsum_dispatch,
                        resolve_dispatch_mode, sort_combine, sort_dispatch)
+from .held import (SigmoidRouting, gated_ffn, held_experts_ffn,
+                   sigmoid_topk_routing)
 from .layer import (EP_AXIS, MOE_STATS, ExpertFFN, MoELayer,
                     expert_ffn_apply, global_gather, global_scatter,
                     moe_ep_group, note_moe_fallback, publish_router_stats,

@@ -109,6 +109,13 @@ class GPTConfig:
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
 
+    def page_kinds(self):
+        """What a serving engine keeps in pages for this model
+        (``serving.kv_cache.PageKind``): K and V of every head in every
+        layer."""
+        from ..serving.kv_cache import kv_page_kinds
+        return kv_page_kinds(self.num_layers, self.num_heads, self.head_dim)
+
     def moe_layer_indices(self):
         """Decoder-layer indices that carry an MoE FFN."""
         if not self.moe_experts:
@@ -547,7 +554,10 @@ class GPTModel(Layer):
         paged = False
         if caches is not None:
             # deferred so training runs never import the serving layer
-            from ..serving.kv_cache import PagedCacheView
+            from ..serving.kv_cache import PagedCacheView, PagedPools
+            if isinstance(caches, PagedPools):
+                return self._forward_pools(input_ids, position_ids, caches,
+                                           cache_pos)
             paged = isinstance(caches, PagedCacheView)
         B, S = input_ids.shape
         if position_ids is None:
@@ -691,6 +701,24 @@ class GPTModel(Layer):
         for row, i in zip(arr, self.cfg.moe_layer_indices()):
             _publish_row(row[2:], f"layer{i}", E, registry)
         return arr.shape[0]
+
+    def _forward_pools(self, input_ids, position_ids, caches, cache_pos):
+        """The engine's door: its :class:`PagedPools` (the ``k`` and
+        ``v`` pools this model declared, scales and LoRA beside them)
+        as the K/V view the stack reads, and the view it returns as
+        pools again."""
+        from ..serving.kv_cache import (ContextPagedCacheView,
+                                        ContextPagedPools, PagedCacheView)
+        cls = ContextPagedCacheView \
+            if isinstance(caches, ContextPagedPools) else PagedCacheView
+        x, new = self.forward(
+            input_ids, position_ids,
+            cls(*caches.pools, caches.block_table,
+                *(caches.scales or (None, None)),
+                *(caches.lora or ())), cache_pos)
+        scales = None if caches.scales is None \
+            else (new.k_scale, new.v_scale)
+        return x, caches._replace(pools=(new.k, new.v), scales=scales)
 
     def _forward_paged(self, x, caches, cache_pos):
         """Run the stack over a paged KV view. The pools are viewed as

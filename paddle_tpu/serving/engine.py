@@ -56,8 +56,8 @@ from ..monitor import flight_recorder as _flight
 from ..monitor import trace as _trace
 from ..testing import chaos
 from .detok import StreamingDetokenizer
-from .kv_cache import (ContextPagedCacheView, PagedCacheView,
-                       PagedKVCache, blocks_needed)
+from .kv_cache import (ContextPagedPools, PagedKVCache, PagedPools,
+                       blocks_needed)
 from .resilience import (DecodeWatchdogError, DispatchWorker, DrainLatch,
                          DrainReport, EngineDrained, OverloadDetector,
                          ServerOverloaded, request_spec,
@@ -163,6 +163,14 @@ class ServingConfig:
     #: OTHER tenants admit past it — the fairness floor). None
     #: (default) = no cap, admission order is byte-identical FIFO.
     tenant_quota: Optional[int] = None
+    #: prompt tokens one step's prefill pass may take: > 0 runs the
+    #: prefilling slots' next chunks oldest admission first while they
+    #: fit (the first always runs), and the rest wait for a later step —
+    #: with long chunked prompts a step is then one chunk and a decode,
+    #: not a chunk for EVERY prefilling slot, so the tokens of decoding
+    #: slots come at a chunk's pace. 0 (default) = every prefilling slot
+    #: advances each step, the grouping as it was.
+    prefill_token_budget: int = 0
 
     def resolve(self, model_max_positions: Optional[int]) -> None:
         if self.queue_policy not in QUEUE_POLICIES:
@@ -230,9 +238,12 @@ class ServingEngine:
         self.params = param_arrays(model)
         self.buffers = buffer_arrays(model)
         c = self.config
-        # one head group a chip of the mp axis: the pools' sharded axis
+        # the model declares what it keeps in pages (`cfg.page_kinds()`:
+        # K and V a head for a plain decoder; a latent and an index key
+        # for a sparse-attention one); one head group a chip of the mp
+        # axis is the pools' sharded axis
         self.cache = PagedKVCache(
-            cfg.num_layers, cfg.num_heads, cfg.head_dim,
+            kinds=cfg.page_kinds(),
             num_pages=c.num_pages, block_size=c.block_size,
             max_slots=c.max_batch_slots,
             max_blocks_per_slot=blocks_needed(c.max_context_len,
@@ -477,14 +488,17 @@ class ServingEngine:
             finally:
                 dist_env.set_mesh(prev)
 
-    def _fwd(self, params, ids, k, v, table, pos, lora=None,
-             ctx: bool = False):
-        """Pure model forward over the paged view (traced inside the
-        prefill/decode programs). ``ctx=True`` selects the
-        CONTEXT-prefill attention path (ISSUE 15): S>1 chunks attend
-        over everything already in the pages, not just themselves —
-        chunked-prefill continuations, prefix-hit tails and speculative
-        verify windows all run through it.
+    def _forward(self, params, ids, pools, table, pos, lora=None,
+                 ctx: bool = False):
+        """Pure model forward over the paged pools (traced inside the
+        prefill/decode programs): ``(logits, pools, stats)``, the pools
+        in the order they came (``cache.pool_args()``), ``stats`` what
+        the model counted for the engine (None from most).
+        ``ctx=True`` selects the CONTEXT-prefill attention path
+        (ISSUE 15): S>1 chunks attend over everything already in the
+        pages, not just themselves — chunked-prefill continuations,
+        prefix-hit tails and speculative verify windows all run
+        through it.
 
         Quantized pools (FLAGS_serve_kv_quant) arrive as
         ``(pages, scales)`` tuples and leave the same way, so
@@ -492,26 +506,36 @@ class ServingEngine:
         optional ``(a_pool, b_pool, per_slot_rows)`` triple of a
         multi-tenant engine (ISSUE 17) — the view carries it down to
         the attention blocks' bgmv delta."""
-        cls = ContextPagedCacheView if ctx else PagedCacheView
-        quant = isinstance(k, tuple)
+        cls = ContextPagedPools if ctx else PagedPools
+        quant = isinstance(pools[0], tuple)
         wrap = lambda t: None if t is None else Tensor(t)
-        la, lb, rows = lora if lora is not None else (None, None, None)
-        if quant:
-            view = cls(Tensor(k[0]), Tensor(v[0]), Tensor(table),
-                       Tensor(k[1]), Tensor(v[1]), wrap(la), wrap(lb),
-                       wrap(rows))
-        else:
-            view = cls(Tensor(k), Tensor(v), Tensor(table), None, None,
-                       wrap(la), wrap(lb), wrap(rows))
+        unw = lambda t: t._data if isinstance(t, Tensor) else t
+        view = cls(
+            tuple(Tensor(p[0] if quant else p) for p in pools),
+            Tensor(table),
+            tuple(Tensor(p[1]) for p in pools) if quant else None,
+            tuple(wrap(t) for t in lora) if lora is not None else None)
         with bind(self.model, params, dict(self.buffers)), no_grad(), \
                 trace_rng(jax.random.key(0)):
             logits, new = self.model(Tensor(ids), caches=view,
                                      cache_pos=Tensor(pos))
-        unw = lambda t: t._data if isinstance(t, Tensor) else t
+        out = tuple(unw(p) for p in new.pools)
         if quant:
-            return (unw(logits), (unw(new.k), unw(new.k_scale)),
-                    (unw(new.v), unw(new.v_scale)))
-        return unw(logits), unw(new.k), unw(new.v)
+            out = tuple(zip(out, (unw(sc) for sc in new.scales)))
+        stats = None if new.stats is None \
+            else {k: unw(a) for k, a in new.stats.items()}
+        return unw(logits), out, stats
+
+    def _fwd(self, params, ids, k, v, table, pos, lora=None,
+             ctx: bool = False):
+        """:meth:`_forward` in the K/V-pair spelling: ``(logits, k, v)``.
+        ONE caller, which this PR could not edit: the GPT probe of
+        ``benchmark/harness/serve_runner.py``. It goes, with the
+        cache's ``.k`` / ``.v``, when that probe takes
+        ``cache.pool_args()`` (ROADMAP Speed 0)."""
+        logits, (k, v), _ = self._forward(params, ids, (k, v), table, pos,
+                                          lora=lora, ctx=ctx)
+        return logits, k, v
 
     def _attribute(self, kind: str, lowered, compiled) -> None:
         """Per-program attribution from the serving executables (same
@@ -543,7 +567,7 @@ class ServingEngine:
 
     def _donate(self) -> tuple:
         from ..core.flags import get_flag
-        # pools are the 2nd/3rd argument of both program kinds; donation
+        # the pools are the 2nd argument of every program kind; donation
         # keeps decode's HBM footprint at ONE pool copy. An armed
         # watchdog disables donation: a tripped dispatch is ABANDONED
         # mid-flight, and retrying the step is only sound while the live
@@ -552,7 +576,7 @@ class ServingEngine:
         # pool copy for retryable trips.
         if float(get_flag("serve_watchdog_s") or 0.0) > 0.0:
             return ()
-        return (1, 2)
+        return (1,)
 
     def _program(self, key: tuple, build) -> AOTProgram:
         """The compiled program under ``key``; ``build()`` returns
@@ -572,13 +596,14 @@ class ServingEngine:
         return self._program(("decode",), self._decode_program)
 
     def _decode_program(self):
-        def decode_fn(params, k, v, table, pos, tokens, active, rng,
+        def decode_fn(params, pools, table, pos, tokens, active, rng,
                       temps, top_ks, top_ps, poison, *lora):
             # *lora is (a_pool, b_pool, rows) on a multi-tenant engine
-            # and EMPTY otherwise — the 12-arg signature and the traced
+            # and EMPTY otherwise — the 11-arg signature and the traced
             # program are unchanged when FLAGS/config leave LoRA off
-            logits, k, v = self._fwd(params, tokens[:, None], k, v,
-                                     table, pos, lora=lora or None)
+            logits, pools, stats = self._forward(
+                params, tokens[:, None], pools, table, pos,
+                lora=lora or None)
             # poison is all-zeros outside chaos (bit-transparent); a NaN
             # entry models a slot whose forward went non-finite. `ok` is
             # the per-slot fault-isolation flag: one bad request fails
@@ -588,14 +613,14 @@ class ServingEngine:
                 ok = jnp.isfinite(row).all(axis=-1)
                 toks = sample_tokens(row, rng, temps, top_ks, top_ps)
                 toks = jnp.where(active, toks, 0)
-            return toks, ok, k, v
+            return toks, ok, pools, stats
 
         B = self.config.max_batch_slots
         mb = self.cache.max_blocks_per_slot
         prog = AOTProgram("serve_decode", decode_fn,
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
-        return prog, (self.params, self.cache.k, self.cache.v,
+        return prog, (self.params, self.cache.pool_args(),
                       jnp.zeros((B, mb), jnp.int32),
                       jnp.zeros((B,), jnp.int32),
                       jnp.zeros((B,), jnp.int32),
@@ -630,11 +655,11 @@ class ServingEngine:
                              lambda: self._prefill_program(nb, sp))
 
     def _prefill_program(self, nb: int, sp: int):
-        def prefill_fn(params, k, v, table, ids, lens, rng, temps,
+        def prefill_fn(params, pools, table, ids, lens, rng, temps,
                        top_ks, top_ps, poison, *lora):
             pos = jnp.zeros((nb,), jnp.int32)
-            logits, k, v = self._fwd(params, ids, k, v, table, pos,
-                                     lora=lora or None)
+            logits, pools, _ = self._forward(params, ids, pools, table,
+                                             pos, lora=lora or None)
             with jax.named_scope("sampling"):
                 last = jnp.take_along_axis(
                     logits, (lens - 1).astype(jnp.int32)[:, None, None],
@@ -642,14 +667,14 @@ class ServingEngine:
                 row = last + poison[:, None]
                 ok = jnp.isfinite(row).all(axis=-1)
                 toks = sample_tokens(row, rng, temps, top_ks, top_ps)
-            return toks, ok, k, v
+            return toks, ok, pools
 
         mb = self.cache.max_blocks_per_slot
         prog = AOTProgram(f"serve_prefill_b{nb}_s{sp}", prefill_fn,
                           name=f"serve_prefill_{nb}x{sp}",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
-        return prog, (self.params, self.cache.k, self.cache.v,
+        return prog, (self.params, self.cache.pool_args(),
                       jnp.zeros((nb, mb), jnp.int32),
                       jnp.zeros((nb, sp), jnp.int32),
                       jnp.ones((nb,), jnp.int32), self._key,
@@ -668,10 +693,11 @@ class ServingEngine:
                              lambda: self._prefill_ctx_program(nb, sp))
 
     def _prefill_ctx_program(self, nb: int, sp: int):
-        def prefill_ctx_fn(params, k, v, table, ids, lens, pos, rng,
+        def prefill_ctx_fn(params, pools, table, ids, lens, pos, rng,
                            temps, top_ks, top_ps, poison, *lora):
-            logits, k, v = self._fwd(params, ids, k, v, table, pos,
-                                     lora=lora or None, ctx=True)
+            logits, pools, _ = self._forward(params, ids, pools, table,
+                                             pos, lora=lora or None,
+                                             ctx=True)
             with jax.named_scope("sampling"):
                 last = jnp.take_along_axis(
                     logits, (lens - 1).astype(jnp.int32)[:, None, None],
@@ -679,7 +705,7 @@ class ServingEngine:
                 row = last + poison[:, None]
                 ok = jnp.isfinite(row).all(axis=-1)
                 toks = sample_tokens(row, rng, temps, top_ks, top_ps)
-            return toks, ok, k, v
+            return toks, ok, pools
 
         mb = self.cache.max_blocks_per_slot
         prog = AOTProgram(f"serve_prefill_ctx_b{nb}_s{sp}",
@@ -687,7 +713,7 @@ class ServingEngine:
                           name=f"serve_prefill_ctx_{nb}x{sp}",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
-        return prog, (self.params, self.cache.k, self.cache.v,
+        return prog, (self.params, self.cache.pool_args(),
                       jnp.zeros((nb, mb), jnp.int32),
                       jnp.zeros((nb, sp), jnp.int32),
                       jnp.ones((nb,), jnp.int32),
@@ -719,11 +745,11 @@ class ServingEngine:
     def _verify_program(self):
         S = self._spec_k + 1
 
-        def verify_fn(params, k, v, table, pos, ids, active, rng,
+        def verify_fn(params, pools, table, pos, ids, active, rng,
                       temps, top_ks, top_ps, poison, *lora):
-            logits, k, v = self._fwd(params, ids, k, v, table, pos,
-                                     lora=lora or None,
-                                     ctx=True)                # [B,S,V]
+            logits, pools, _ = self._forward(params, ids, pools, table,
+                                             pos, lora=lora or None,
+                                             ctx=True)        # [B,S,V]
             with jax.named_scope("sampling"):
                 row0 = logits[:, 0, :] + poison[:, None]
                 ok_rows = jnp.isfinite(logits).all(axis=-1)       # [B,S]
@@ -752,7 +778,7 @@ class ServingEngine:
                 tok_resid = jax.random.categorical(
                     k_resid, resid, axis=-1).astype(jnp.int32)    # [B,S-1]
             return (jnp.where(active, tok0, 0), greedy, ok_rows,
-                    p_draft, tok_full, tok_resid, k, v)
+                    p_draft, tok_full, tok_resid, pools)
 
         B = self.config.max_batch_slots
         mb = self.cache.max_blocks_per_slot
@@ -760,7 +786,7 @@ class ServingEngine:
                           name="serve_verify",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
-        return prog, (self.params, self.cache.k, self.cache.v,
+        return prog, (self.params, self.cache.pool_args(),
                       jnp.zeros((B, mb), jnp.int32),
                       jnp.zeros((B,), jnp.int32),
                       jnp.zeros((B, S), jnp.int32),
@@ -1637,17 +1663,25 @@ class ServingEngine:
         state is freshly admitted with its whole effective prompt as
         the one chunk — the exact pre-ISSUE-15 grouping (same buckets,
         same dispatch count, byte-identical traffic). Chunk length is
-        ``min(FLAGS_serve_prefill_chunk, remaining)``; groups are keyed
+        ``min(FLAGS_serve_prefill_chunk, remaining)``; under
+        ``config.prefill_token_budget`` only the oldest admissions'
+        chunks that fit it are planned; groups are keyed
         by (needs-context, length bucket) because a chunk at pos > 0
         must run the context program while pos == 0 chunks keep the
         bit-compatible plain one."""
         by_key: Dict[Tuple[int, bool, int], List[RequestState]] = {}
-        for _, st in self.scheduler.active():
-            if not st.prefilling:
-                continue
-            remaining = st.prefill_len - st.prefill_pos
-            clen = min(self._chunk, remaining) if self._chunk > 0 \
-                else remaining
+        states = [st for _, st in self.scheduler.active() if st.prefilling]
+        budget = self.config.prefill_token_budget
+        if budget > 0:
+            states.sort(key=lambda s: (s.admitted_t, s.request.request_id))
+            used = 0
+            for n, st in enumerate(states):
+                used += self._chunk_len(st)
+                if n and used > budget:
+                    states = states[:n]
+                    break
+        for st in states:
+            clen = self._chunk_len(st)
             # keyed by weights epoch too (ISSUE 20): a mid-chunk prefill
             # carried across a cutover must keep its own tree, so it
             # can't share a dispatch with new-epoch admissions. The
@@ -1669,9 +1703,21 @@ class ServingEngine:
                     lb, self.buckets.batch_bucket(len(chunk)), chunk))
         return groups
 
+    def _chunk_len(self, st: RequestState) -> int:
+        """Tokens of ``st``'s prompt its next prefill dispatch takes."""
+        remaining = st.prefill_len - st.prefill_pos
+        return min(self._chunk, remaining) if self._chunk > 0 \
+            else remaining
+
     def _run_prefill(self, group: AdmissionGroup) -> None:
         nb, sp = group.batch_bucket, group.len_bucket
-        with _trace.span("serve.prefill", nb=nb, sp=sp):
+        # chunk: the tokens each row prefills now; ctx: the positions
+        # already in its pages (the context a chunked prefill, a prefix
+        # hit or a re-prefill attends over)
+        with _trace.span("serve.prefill", nb=nb, sp=sp,
+                         chunk=tuple(self._chunk_len(st)
+                                     for st in group.states),
+                         ctx=tuple(st.prefill_pos for st in group.states)):
             self._prefill_group(group, nb, sp)
 
     def _prefill_group(self, group: AdmissionGroup, nb: int,
@@ -1692,10 +1738,9 @@ class ServingEngine:
                 if st is None:
                     continue
                 eff = st.effective_prompt()
-                remaining = st.prefill_len - st.prefill_pos
-                clen = min(self._chunk, remaining) if self._chunk > 0 \
-                    else remaining
-                chunked = chunked or clen < remaining
+                clen = self._chunk_len(st)
+                chunked = chunked or \
+                    clen < st.prefill_len - st.prefill_pos
                 # COW contract: writes start at prefill_pos, which is
                 # never below the shared-prefix coverage — a shared page
                 # is read-only for this slot by construction
@@ -1733,13 +1778,13 @@ class ServingEngine:
                         prefix_hit_tokens=st.prefill_pos)
             if ctx:
                 prog = self._get_prefill_ctx(nb, sp)
-                args = (params, self.cache.k, self.cache.v,
+                args = (params, self.cache.pool_args(),
                         self.cache.table_array(rows), jnp.asarray(ids),
                         jnp.asarray(lens), jnp.asarray(pos),
                         self._next_key())
             else:
                 prog = self._get_prefill(nb, sp)
-                args = (params, self.cache.k, self.cache.v,
+                args = (params, self.cache.pool_args(),
                         self.cache.table_array(rows), jnp.asarray(ids),
                         jnp.asarray(lens), self._next_key())
             temps, tks, tps = self._sampling_arrays(states)
@@ -1749,9 +1794,9 @@ class ServingEngine:
         # back every not-yet-prefilled state of the plan (token-exact:
         # the tripped dispatch's pool writes died with its thread)
         with _trace.span("serve.prefill.dispatch"):
-            toks, ok, new_k, new_v = self._guarded_dispatch(
+            toks, ok, pools = self._guarded_dispatch(
                 "prefill", prog, args)
-            self.cache.update(new_k, new_v)
+            self.cache.update(*pools)
         with _trace.span("serve.prefill.readback"):
             toks = np.asarray(toks)
             ok = np.asarray(ok)
@@ -1865,17 +1910,16 @@ class ServingEngine:
             prog = self._get_verify()
             temps, tks, tps = self._sampling_arrays(per_slot)
             hang = chaos.active() and chaos.probe("serve.decode.hang")
-            args = (params, self.cache.k, self.cache.v,
+            args = (params, self.cache.pool_args(),
                     self._decode_table(per_slot), jnp.asarray(pos),
                     jnp.asarray(ids), jnp.asarray(active),
                     self._next_key(), temps, tks, tps,
                     self._poison_array(per_slot)) \
                 + self._lora_args(per_slot)
         with _trace.span("serve.verify.dispatch"):
-            tok0, greedy, ok_rows, p_draft, tok_full, tok_resid, new_k, \
-                new_v = self._guarded_dispatch("verify", prog, args,
-                                               hang=hang)
-            self.cache.update(new_k, new_v)
+            tok0, greedy, ok_rows, p_draft, tok_full, tok_resid, pools = \
+                self._guarded_dispatch("verify", prog, args, hang=hang)
+            self.cache.update(*pools)
         with _trace.span("serve.verify.readback"):
             tok0 = np.asarray(tok0)
             greedy = np.asarray(greedy)
@@ -2001,19 +2045,21 @@ class ServingEngine:
             prog = self._get_decode()
             temps, tks, tps = self._sampling_arrays(per_slot)
             hang = chaos.active() and chaos.probe("serve.decode.hang")
-            args = (params, self.cache.k, self.cache.v,
+            args = (params, self.cache.pool_args(),
                     self._decode_table(per_slot), jnp.asarray(pos),
                     jnp.asarray(tokens), jnp.asarray(active),
                     self._next_key(), temps, tks, tps,
                     self._poison_array(per_slot)) \
                 + self._lora_args(per_slot)
         with _trace.span("serve.decode.dispatch"):
-            toks, ok, new_k, new_v = self._guarded_dispatch(
+            toks, ok, pools, stats = self._guarded_dispatch(
                 "decode", prog, args, hang=hang)
-            self.cache.update(new_k, new_v)
+            self.cache.update(*pools)
         with _trace.span("serve.decode.readback"):
             toks = np.asarray(toks)
             ok = np.asarray(ok)
+            if stats is not None:
+                stats = {k: np.asarray(a) for k, a in stats.items()}
         with _trace.span("serve.decode.accept"):
             now = self.clock()
             dt = now - t0
@@ -2031,6 +2077,8 @@ class ServingEngine:
                           "active slots per decode dispatch",
                           buckets=tuple(range(1, B + 1))
                           ).observe(n_active)
+            if stats is not None:
+                self._count_model_stats(stats, active)
             for slot, st in list(pairs):
                 tr = st.trace
                 if tr is not None:
@@ -2045,6 +2093,30 @@ class ServingEngine:
                     self.scheduler.fail(st, "non-finite logits at decode")
                     continue
                 self._accept_token(st, int(toks[slot]), now)
+
+    def _count_model_stats(self, stats: Dict[str, np.ndarray],
+                           active: np.ndarray) -> None:
+        """What the model counted in a decode step, a row a slot, into
+        the registry and ``_stats["model_counters"]``, the active slots'
+        rows only. A key is a counter's name; ``name:label`` with a
+        ``[B, K]`` array is one series a column, labelled ``label=k``
+        (``serve_moe_routed_tokens_total:expert``)."""
+        reg = get_registry()
+        totals = self._stats.setdefault("model_counters", {})
+        for key, arr in stats.items():
+            name, _, label = key.partition(":")
+            col = arr[active].sum(axis=0)
+            counter = reg.counter(name, "counted by the model in decode "
+                                        "steps, active slots only")
+            if label:
+                for i, n in enumerate(col.tolist()):
+                    if n:
+                        counter.inc(n, **{label: str(i)})
+                        series = f"{name}{{{label}={i}}}"
+                        totals[series] = totals.get(series, 0) + n
+            else:
+                counter.inc(float(col))
+                totals[name] = totals.get(name, 0) + float(col)
 
     def _accept_token(self, st: RequestState, token: int,
                       now: float) -> None:
@@ -2307,4 +2379,4 @@ class ServingEngine:
         self._staged = None
         self._retired.clear()
         self._previous = None
-        self.cache.k = self.cache.v = None
+        self.cache.pools = dict.fromkeys(self.cache.pools)

@@ -5,7 +5,11 @@ static-shape program — the generalization of ``GPTAttention.StaticCache``
 (one contiguous ``[B, L_max, H, D]`` buffer per request) to a shared pool
 of fixed-size pages:
 
-- K/V live in ONE lane-dense pool, ``[L, P, G, bs, (H/G)*D]``: ``P``
+- what a model keeps in pages is the model's to declare
+  (:class:`PageKind`: K and V a head for a plain decoder, a latent and
+  an index key for MLA under a sparse selection); every kind shares ONE
+  allocator and block table and has ONE pool of its own;
+- a kind lives in ONE lane-dense pool, ``[L, P, G, bs, (H/G)*D]``: ``P``
   pages a layer, ``bs`` token rows a page, the heads of a token row
   fused into the minor dim (GPT-2 345M: 16 x 64 = 1024 lanes, whole
   ``(16, 128)`` bf16 tiles, nothing padded). ``G`` is the number of head
@@ -50,11 +54,16 @@ from typing import List, NamedTuple, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["BlockAllocator", "PagedKVCache", "PagedCacheView",
+__all__ = ["BlockAllocator", "PagedKVCache", "PageKind", "kv_page_kinds",
+           "PagedPools",
+           "ContextPagedPools", "PagedCacheView",
            "PagedLayerCache", "ContextPagedCacheView",
            "ContextPagedLayerCache", "write_pages", "gather_pages",
            "write_pages_quant", "gather_pages_quant", "dequant_pages",
            "blocks_needed"]
+
+#: lanes of a TPU vector register: the tile of a pool's minor dim
+LANES = 128
 
 #: physical page 0 is never allocated: it is the shared scratch target for
 #: writes from inactive slots and padded prefill tails, and is masked out
@@ -66,9 +75,71 @@ def blocks_needed(num_tokens: int, block_size: int) -> int:
     return max(0, math.ceil(int(num_tokens) / int(block_size)))
 
 
+class PageKind(NamedTuple):
+    """One kind of per-position state a model keeps in pages, as the
+    model's ``cfg.page_kinds()`` declares it: ``width`` values a
+    position (``heads`` head segments fused into that minor dim; 1 for
+    state with no head axis, an MLA latent or an index key) in each of
+    the model's ``layers`` (indices into its stack; a kind only some
+    layers keep has a pool of ``len(layers)`` layers). Every kind shares
+    the ONE block table and allocator of :class:`PagedKVCache`: page
+    ``p`` of a slot holds the same positions in every kind.
+
+    A pool's minor dim is :meth:`stored_width`: ``width`` rounded up to
+    whole 128-lane tiles when it is wider than one and not a multiple
+    (an MLA latent of 576 is stored as 640). XLA:TPU lays a minor dim
+    that is no multiple of 128 out with ANOTHER dim minor-most, and
+    every program then relayouts the whole pool on the way in and out.
+    :func:`write_pages` zero-pads the rows; a reader takes the leading
+    ``width``."""
+
+    name: str
+    width: int
+    layers: tuple
+    heads: int = 1
+
+    def stored_width(self) -> int:
+        if self.width <= LANES or self.width % LANES == 0:
+            return self.width
+        return -(-self.width // LANES) * LANES
+
+
+def kv_page_kinds(num_layers: int, num_heads: int, head_dim: int) -> tuple:
+    """The kinds of a plain decoder: ``k`` and ``v``, a head segment a
+    head, in every layer."""
+    layers = tuple(range(int(num_layers)))
+    return tuple(PageKind(n, int(num_heads) * int(head_dim), layers,
+                          int(num_heads)) for n in ("k", "v"))
+
+
+class PagedPools(NamedTuple):
+    """What the engine hands a model as ``caches`` and takes back:
+    ``pools``, one ``[L_kind, P, G, bs, W]`` array per declared
+    :class:`PageKind` in declaration order, and the ``[B, MB]`` int32
+    ``block_table`` they share. ``scales`` are the f32 scale pools of a
+    quantized cache (``FLAGS_serve_kv_quant``), one per pool; ``lora``
+    the ``(a_pool, b_pool, per_slot_rows)`` triple of a multi-tenant
+    engine; ``stats`` small per-slot arrays by name that a model may
+    return from a decode step for the engine's counters (never read as
+    an input). A NamedTuple, so a pytree."""
+
+    pools: tuple
+    block_table: object
+    scales: object = None
+    lora: object = None
+    stats: object = None
+
+
+class ContextPagedPools(PagedPools):
+    """Marker subtype selecting the **context prefill** path, as
+    :class:`ContextPagedCacheView` does for the K/V view: an S>1 chunk
+    at per-slot positions ``pos`` attends over what is already in the
+    pages as well as over itself."""
+
+
 class PagedCacheView(NamedTuple):
-    """Model-level traced view of the cache: what ``GPTModel.forward``
-    receives as ``caches``. ``k``/``v`` are the lane-dense pools
+    """The K/V-per-head form of the traced view (``GPTModel`` converts
+    :class:`PagedPools` to it at its door, and back). ``k``/``v`` are the lane-dense pools
     ``[L, P, G, bs, (H/G)*D]``; ``block_table`` is ``[B, MB]`` int32.
     Being a NamedTuple it is a pytree — it flows through jit/scan
     unchanged.
@@ -153,11 +224,14 @@ def write_pages(pages, new, block_table, pos, base=0):
     through ``block_table`` ``[B, MB]``, whose entries are relative to
     physical page ``base``. Only the ``B*S`` new rows move: inside a
     compiled step the scatter updates the (donated, carried) pool in
-    place. Returns the updated pool."""
+    place. Rows narrower than the pool's (``PageKind.stored_width``) are
+    zero-padded. Returns the updated pool."""
     _, G, bs, F = pages.shape
     B, S = new.shape[:2]
     blk, off = _physical_rows(block_table, pos, S, bs, base)
-    rows = new.reshape(B, S, G, F).astype(pages.dtype)
+    rows = new.reshape(B, S, G, -1).astype(pages.dtype)
+    if rows.shape[-1] < F:
+        rows = jnp.pad(rows, ((0, 0),) * 3 + ((0, F - rows.shape[-1]),))
     return pages.at[blk, :, off].set(rows)
 
 
@@ -298,29 +372,37 @@ class BlockAllocator:
 
 
 class PagedKVCache:
-    """Device page pools + host block tables for a fixed slot batch.
+    """Device page pools + host block tables for a fixed slot batch:
+    a pool a :class:`PageKind` (``kinds=``; K and V of ``num_layers x
+    num_heads x head_dim`` when none is given), one allocator and one
+    block table for all of them.
 
-    ``update(new_k, new_v)`` swaps in the pools a compiled step returned;
-    ``table_array()`` snapshots the host tables as the step's int32
-    argument. Slot bookkeeping (``alloc_slot``/``extend_slot``/
+    ``pool_args()`` is the argument a compiled step takes, ``update
+    (*pools)`` swaps in the pools it returned; ``table_array()``
+    snapshots the host tables as the step's int32 argument. Slot bookkeeping (``alloc_slot``/``extend_slot``/
     ``free_slot``) is pure host work — device shapes never change.
     """
 
-    def __init__(self, num_layers: int, num_heads: int, head_dim: int,
+    def __init__(self, num_layers: Optional[int] = None,
+                 num_heads: Optional[int] = None,
+                 head_dim: Optional[int] = None,
                  *, num_pages: int, block_size: int, max_slots: int,
                  max_blocks_per_slot: int, dtype=jnp.float32,
-                 head_groups: int = 1):
+                 head_groups: int = 1,
+                 kinds: Optional[Sequence[PageKind]] = None):
         from ..core.flags import get_flag
-        self.num_layers = int(num_layers)
-        self.num_heads = int(num_heads)
-        self.head_dim = int(head_dim)
+        if kinds is None:
+            kinds = kv_page_kinds(num_layers, num_heads, head_dim)
+        #: the page kinds, in the order programs take and return pools
+        self.kinds = tuple(kinds)
         #: head groups G: the pools' third axis, which a serving mesh
         #: shards over ``mp`` (one group a chip); 1 on a single chip
         self.head_groups = int(head_groups)
-        if self.num_heads % self.head_groups:
-            raise ValueError(
-                f"num_heads={num_heads} not divisible by "
-                f"head_groups={head_groups}")
+        for kd in self.kinds:
+            if kd.heads % self.head_groups:
+                raise ValueError(
+                    f"page kind {kd.name!r}: heads={kd.heads} not "
+                    f"divisible by head_groups={head_groups}")
         self.block_size = int(block_size)
         self.max_slots = int(max_slots)
         self.max_blocks_per_slot = int(max_blocks_per_slot)
@@ -328,25 +410,26 @@ class PagedKVCache:
         #: quant mode, read ONCE at construction (engine convention):
         #: "" = full-precision pools (the flags-off oracle), "int8" =
         #: int8 pools + parallel f32 per-(page, row, head) scale pools;
-        #: when quantized, self.k / self.v are (pages, scales) 2-tuples
+        #: when quantized, each pool is a (pages, scales) 2-tuple
         #: — pytrees, so they flow through the existing jit arg slots.
         self.quant = str(get_flag("serve_kv_quant") or "")
         if self.quant not in ("", "int8"):
             raise ValueError(
                 f"FLAGS_serve_kv_quant={self.quant!r}: supported modes "
                 "are '' (full precision) and 'int8'")
-        hg = self.num_heads // self.head_groups
-        shape = (num_layers, num_pages, self.head_groups, block_size,
-                 hg * head_dim)
-        if self.quant == "int8":
-            scale_shape = shape[:-1] + (hg,)      # [L, P, G, bs, H/G]
-            self.k = (jnp.zeros(shape, jnp.int8),
-                      jnp.zeros(scale_shape, jnp.float32))
-            self.v = (jnp.zeros(shape, jnp.int8),
-                      jnp.zeros(scale_shape, jnp.float32))
-        else:
-            self.k = jnp.zeros(shape, dtype)
-            self.v = jnp.zeros(shape, dtype)
+        G = self.head_groups
+        #: kind name -> ``[L_kind, P, G, bs, W/G]`` pool (quantized: a
+        #: ``(pages, scales)`` pair, scales ``[L_kind, P, G, bs, heads/G]``)
+        self.pools = {}
+        for kd in self.kinds:
+            shape = (len(kd.layers), num_pages, G, block_size,
+                     kd.stored_width() // G)
+            if self.quant == "int8":
+                self.pools[kd.name] = (
+                    jnp.zeros(shape, jnp.int8),
+                    jnp.zeros(shape[:-1] + (kd.heads // G,), jnp.float32))
+            else:
+                self.pools[kd.name] = jnp.zeros(shape, dtype)
         self.allocator = BlockAllocator(num_pages)
         self._tables = np.full((max_slots, max_blocks_per_slot),
                                SCRATCH_PAGE, np.int32)
@@ -361,20 +444,33 @@ class PagedKVCache:
         self.prefix_cache = None
 
     # -- device-side --------------------------------------------------------
-    def update(self, new_k, new_v) -> None:
-        self.k, self.v = new_k, new_v
+    def pool_args(self) -> tuple:
+        """The pools in declaration order: the one argument every
+        serving program takes, donates and returns."""
+        return tuple(self.pools[kd.name] for kd in self.kinds)
+
+    def update(self, *new) -> None:
+        """Swap in the pools a compiled step returned (declaration
+        order, as :meth:`pool_args` gave them)."""
+        for kd, pool in zip(self.kinds, new):
+            self.pools[kd.name] = pool
+
+    # the K/V pair of a plain decoder, by name
+    k = property(lambda self: self.pools["k"],
+                 lambda self, pool: self.pools.__setitem__("k", pool))
+    v = property(lambda self: self.pools["v"],
+                 lambda self, pool: self.pools.__setitem__("v", pool))
 
     def kv_bytes_per_token(self) -> int:
-        """Device bytes ONE token position costs across all layers —
-        the capacity currency the kv-quant flag halves: int8 pays
-        ``H*D`` payload + ``H`` f32 scale bytes per pool, full precision
-        pays ``H*D*itemsize``."""
-        H, D, L = self.num_heads, self.head_dim, self.num_layers
-        if self.quant == "int8":
-            per_pool = H * D * 1 + H * 4
-        else:
-            per_pool = H * D * self.dtype.itemsize
-        return 2 * L * per_pool
+        """Device bytes ONE token position costs across all kinds and
+        layers — the capacity currency the kv-quant flag halves: int8
+        pays ``width`` payload + ``heads`` f32 scale bytes a layer of a
+        kind, full precision pays ``width * itemsize`` (the width as
+        stored, ``PageKind.stored_width``)."""
+        per = (lambda kd: kd.stored_width() + 4 * kd.heads) \
+            if self.quant == "int8" \
+            else (lambda kd: kd.stored_width() * self.dtype.itemsize)
+        return sum(len(kd.layers) * per(kd) for kd in self.kinds)
 
     def table_array(self, rows: Optional[Sequence[Optional[int]]] = None):
         """Snapshot block tables as the step's int32 argument: all slots,

@@ -221,10 +221,10 @@ def test_layer_writes_stay_in_its_own_pages(tiny_model, scan, ctx):
         table[0, :3] = [5, 2, 9]            # row 1: all scratch (padded)
         ids = np.random.default_rng(3).integers(2, 250, (2, 8))
         pos = np.array([3, 0], np.int32)    # row 0 writes positions 3..10
-        _, k, v = eng._fwd(eng.params, jnp.asarray(ids, jnp.int32),
-                           eng.cache.k, eng.cache.v, jnp.asarray(table),
-                           jnp.asarray(pos), ctx=ctx)
-    for pool in (k, v):
+        _, pools, _ = eng._forward(
+            eng.params, jnp.asarray(ids, jnp.int32), eng.cache.pool_args(),
+            jnp.asarray(table), jnp.asarray(pos), ctx=ctx)
+    for pool in pools:
         pool = np.asarray(pool)
         assert pool.shape == eng.cache.k.shape
         written = np.abs(pool).reshape(L, P, -1).max(-1) > 0

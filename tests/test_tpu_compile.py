@@ -247,21 +247,45 @@ def cell_engine():
         cfg, ServingConfig(num_pages=65, **CELL_ENGINE))
 
 
-def _pool_sized_moves(text):
+def _pool_sized_moves(text, layer_pool=LAYER_POOL, rows=None):
     """Instructions of the optimized HLO that copy, slice or update-
     slice (alone or as the root of a fusion XLA named after them) into
-    a result as large as one layer's pool."""
+    a result as large as one layer's pool — with ``rows``, only results
+    whose two minor dims are a pool's ``(block_size, width)``: a
+    program may hold other arrays of that size."""
     found = []
     for name, dtype, dims, op in _INSTR.findall(text):
-        n = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+        shape = [int(d) for d in dims.split(",") if d] or [1]
         what = name if op == "fusion" else op
         if (re.search(r"copy|dynamic-slice|dynamic-update-slice", what)
-                and n * _BYTES.get(dtype, 4) >= LAYER_POOL):
+                and int(np.prod(shape)) * _BYTES.get(dtype, 4) >= layer_pool
+                and (rows is None or tuple(shape[-2:]) in rows)):
             found.append((name, op, dtype, dims))
     return found
 
 
-@pytest.mark.parametrize("kind", ["decode", "prefill_1x256"])
+def _compile_with_cell_pools(eng, kind, pages, chip, build_for_chip,
+                             monkeypatch):
+    """One of an engine's serving programs, compiled for the chip with
+    the pools at a cell's page count as SHAPES (the engine's own are a
+    few pages)."""
+    prog, args = {"decode": eng._decode_program,
+                  "prefill": lambda: eng._prefill_program(
+                      1, eng.config.prefill_buckets[0]),
+                  "prefill_ctx": lambda: eng._prefill_ctx_program(
+                      1, eng.config.prefill_buckets[0])}[kind]()
+    shaped = jax.tree.map(lambda a: chip(a.shape, a.dtype), args)
+    pools = tuple(chip((p.shape[0], pages) + p.shape[2:], p.dtype)
+                  for p in shaped[1])
+    # dispatch asks the backend whether kernels can run: they can, there
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = build_for_chip(prog._jitted.lower, shaped[0], pools,
+                              *shaped[2:])
+    monkeypatch.undo()
+    return compiled
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
 def test_serving_program_moves_no_pool(chip, build_for_chip, cell_engine,
                                        monkeypatch, kind):
     """ISSUE 27's guard: the page pools go from the program's donated
@@ -275,16 +299,8 @@ def test_serving_program_moves_no_pool(chip, build_for_chip, cell_engine,
     after the scan; 94.8 MB in decode, 94.6 in the prefill. The smallest
     prefill bucket, because the pools take the same way through every
     bucket and a 4x768 group's logits alone are 309 MB.)"""
-    eng = cell_engine
-    prog, args = (eng._decode_program() if kind == "decode"
-                  else eng._prefill_program(1, 256))
-    pool = chip((GUARD_LAYERS, CELL_PAGES) + eng.cache.k.shape[2:], BF16)
-    shaped = jax.tree.map(lambda a: chip(a.shape, a.dtype), args)
-    shaped = (shaped[0], pool, pool) + tuple(shaped[3:])
-    # dispatch asks the backend whether kernels can run: they can, there
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = build_for_chip(prog._jitted.lower, *shaped)
-    monkeypatch.undo()
+    compiled = _compile_with_cell_pools(cell_engine, kind, CELL_PAGES, chip,
+                                        build_for_chip, monkeypatch)
     text = compiled.as_text()
     _assert_kernels(text, "paged_decode" if kind == "decode"
                     else "flash_fwd")
@@ -292,3 +308,74 @@ def test_serving_program_moves_no_pool(chip, build_for_chip, cell_engine,
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * GUARD_LAYERS * LAYER_POOL, mem
     assert mem.temp_size_in_bytes < TEMP_LIMIT, mem
+
+
+# -- a model that declares its own page kinds (ISSUE 28) -----------------------
+
+GLM_CELL = "glm52_ep16.serve.closed32_ctx8k"
+
+
+@pytest.fixture(scope="module")
+def glm_model():
+    """GLM-5.2's chip share at the PUBLISHED widths (3.88 B parameters,
+    as zeros: nothing runs), and the cell's system settings."""
+    import json
+    import os
+    from paddle_tpu.nn import initializer
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "bench_glm_moe_dsa",
+        os.path.join(root, "benchmark", "models", "glm_moe_dsa.py"))
+    fam = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(fam)
+    with open(os.path.join(root, "benchmark", "configs",
+                           "glm52_ep16.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmark", "workloads",
+                           GLM_CELL + ".json")) as f:
+        system = json.load(f)
+    draw = initializer.Normal.__call__
+    initializer.Normal.__call__ = lambda self, shape, dtype=None: jnp.zeros(
+        tuple(shape), dtype or "float32")
+    try:
+        model = fam.build_model(config, 0, dtype=system["weights_dtype"])
+    finally:
+        initializer.Normal.__call__ = draw
+    return model, system
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill_ctx"])
+def test_glm_serving_program_fits_and_moves_no_pool(
+        chip, build_for_chip, glm_model, monkeypatch, kind):
+    """The same guard for the model with two page kinds, at the new
+    cell's widths and engine settings: the decode program and the
+    2,048-token context-prefill chunk compile for the chip, update BOTH
+    pools in place (the latent pool, 5 layers of 671 MB as stored, and
+    the index-key pool, 2 of 134 MB), hold no instruction of a layer's
+    pool size, and keep every temporary under ISSUE 28's 2 GB — the
+    chunk's scores against its context, its index scores ``[2048,
+    32768]`` and its expert rows included. (A latent row of 576 values
+    stored as 576 had XLA lay the pool out pages-minor and relayout all
+    3 GB of it on the way in and out of every program: 3.47 GB of
+    temporaries in decode. Stored as 640: 0.15 GB in decode, 0.98 GB in
+    the chunk.)"""
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+    model, system = glm_model
+    kw = dict(system["engine"])
+    pages = kw.pop("num_pages")
+    for key in ("prefill_buckets", "batch_buckets"):
+        kw[key] = tuple(kw[key])
+    # the engine's own pools are a few pages (an engine lives one test)
+    with flag_scope("serve_prefill_chunk", system["prefill_chunk"]):
+        eng = ServingEngine(model, ServingConfig(num_pages=33, **kw))
+    compiled = _compile_with_cell_pools(eng, kind, pages, chip,
+                                        build_for_chip, monkeypatch)
+    text = compiled.as_text()
+    rows = {p.shape[-2:] for p in eng.cache.pool_args()}
+    assert rows == {(16, 640), (16, 128)}
+    assert _pool_sized_moves(text, pages * 16 * 128 * 2, rows) == []
+    mem = compiled.memory_analysis()
+    pools = sum(int(np.prod(p.shape[2:])) * p.shape[0] * pages * 2
+                for p in eng.cache.pool_args())
+    assert mem.alias_size_in_bytes >= pools, mem
+    assert mem.temp_size_in_bytes < 2e9, mem

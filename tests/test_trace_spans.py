@@ -300,7 +300,8 @@ def test_one_engine_step_yields_the_span_table(tiny_model, clear_ring):
     root = by_name["serve.step"]
     assert root[PARENT] is None and root[STEP] == 1
     assert root[ATTRS] == {"n_active": 1, "n_groups": 1}
-    assert by_name["serve.prefill"][ATTRS] == {"nb": 1, "sp": 8}
+    assert by_name["serve.prefill"][ATTRS] == {"nb": 1, "sp": 8,
+                                               "chunk": (5,), "ctx": (0,)}
     assert by_name["serve.decode"][ATTRS] == {"n_active": 1}
     for r in recs:
         assert r[STEP] == 1
@@ -586,8 +587,11 @@ def test_resolve_paths():
     assert r(pre + "optimizer/mul") == ("optimizer", "fwd")
     assert r(pre + "while/body/dynamic_update_slice") is None
     assert r("norm") is None                # the last part is the operation
+    assert r(pre + "mla/select/top_k") == ("select", "fwd")
+    assert r(pre + "indexer/while/body/dot_general") == ("indexer", "fwd")
     assert set(BLOCKS) == {"embed", "attn", "ffn", "moe", "norm", "loss",
-                           "optimizer", "sampling", "kv_write"}
+                           "optimizer", "sampling", "kv_write", "mla",
+                           "indexer", "select"}
 
 
 def test_parse_scopes_takes_an_instructions_own_scope_and_guesses_none():
