@@ -80,10 +80,12 @@ REHEARSE = dict(
 
 # -- tolerances, per dtype ---------------------------------------------------
 # Errors are max|got - ref| / max|ref| against a float32 reference at
-# highest matmul precision. bf16 has 8 bits of mantissa (eps 7.8e-3) and
-# the flash kernel rounds its probability tile to bf16 before the second
-# matmul, so a few eps; the f32 kernels differ from XLA only in the order
-# of f32 sums and the exp implementation.
+# highest matmul precision. bf16 has 8 bits of mantissa (eps 7.8e-3): the
+# bf16 cases carry the rounding of their inputs and of their outputs, a
+# few eps — no kernel rounds a tile in between (flash and paged decode
+# multiply bf16 operands in one exact pass and send their f32 probability
+# and ds tiles through as three bf16 parts); the f32 kernels differ from
+# XLA only in the order of f32 sums and the exp implementation.
 TOL = {"bfloat16": 4e-2, "float32": 2e-3}
 #: chunked-CE loss: both sides accumulate in f32 from the same logits
 CE_LOSS_TOL = 1e-4

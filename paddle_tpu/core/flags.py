@@ -103,10 +103,13 @@ define_flag("default_dtype", "float32", "Default floating dtype.")
 define_flag("allocator_strategy", "xla", "Kept for API parity; XLA owns HBM on TPU.")
 define_flag("check_finite", False, "Check gradients finite after backward.")
 define_flag("tpu_matmul_precision", "highest",
-            "Precision for f32 dot ops (matmul/linear/einsum/attention). "
-            "'highest' = full f32 (reference CUDA parity); 'default' lets the "
-            "backend pick (bf16 passes on TPU). Convolutions follow the XLA "
-            "backend default; use AMP/bf16 for the MXU fast path.")
+            "Precision for dot ops with f32 operands (matmul/linear/einsum/"
+            "attention, the flash-attention kernels included). 'highest' = "
+            "full f32 (reference CUDA parity); 'default' lets the backend "
+            "pick (one bf16 pass on TPU). bf16 operands never needed it and "
+            "do not read it: their products are exact in one bf16 pass with "
+            "f32 accumulation. Convolutions follow the XLA backend default; "
+            "use AMP/bf16 for the MXU fast path.")
 define_flag("jit_channels_last", True,
             "Run 2-D NCHW conv/BN/pool chains channels-last (NHWC, the TPU "
             "MXU-native conv layout) inside jitted TrainStep traces: one "

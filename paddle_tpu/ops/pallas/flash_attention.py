@@ -28,10 +28,22 @@ Common to both: online-softmax forward with running (m, l) scratch,
 O(S*D) HBM traffic; causal tiles above the diagonal are compute-skipped
 via `pl.when`; in-kernel rematerialized dropout via a stateless
 murmur3-finalizer hash over absolute coordinates (the backward REGENERATES
-the mask, nothing is stored); MXU compute follows the framework matmul
-precision policy with f32 accumulation.
+the mask, nothing is stored).
 
-Tests run these same kernels on CPU via the Pallas interpreter.
+Precision (one rule, :func:`_dot`; ``paged_decode.py`` follows the same
+one): the number of MXU passes of a product follows its operands' types,
+and every product accumulates in f32. Two bf16 operands (``q k^T``,
+``dO v^T`` of an AMP step) multiply in ONE bf16 pass, which is exact: a
+product of two bf16 values has 16 significant bits. An f32 tile computed
+in the kernel (the probabilities with the dropout factor folded in,
+``ds``) against a bf16 operand goes through as THREE bf16 parts (8 + 8 +
+8 bits, the small parts summed first), each one pass: no tile is rounded
+to bf16, and the result is what f32 operands at ``Precision.HIGHEST``
+give, up to the order of f32 sums, in three passes where those take six.
+Two f32 operands follow ``FLAGS_tpu_matmul_precision``: ``HIGHEST``
+unless the flag says ``default`` (then one bf16 pass, the backend's own
+choice for f32). Nothing else chooses: no flag of this module, and the
+Pallas interpreter (the CPU tests) takes the same path as the chip.
 """
 
 from __future__ import annotations
@@ -55,16 +67,17 @@ NEG_INF = -1e30
 
 
 def _mxu_dtype(in_dtype) -> jnp.dtype:
-    """MXU input dtype: mirror XLA's matmul-precision policy.
+    """The type the MXU multiplies an operand of ``in_dtype`` in.
 
-    Default policy lowers f32 gemms to bf16 MXU passes (f32 accumulate);
-    `tpu_matmul_precision=highest/float32` keeps full f32. The interpreter
-    (CPU tests) always computes f32 so parity tolerances stay tight.
-    """
+    bfloat16 stays bfloat16, whatever the flag says: there is nothing to
+    round. float32 follows ``FLAGS_tpu_matmul_precision``, the policy for
+    f32 operands: float32 (all of the mantissa) unless the flag is
+    ``default``, which lets the backend round f32 operands to one bf16
+    pass. Any other type (float16) is computed as float32."""
     from ...core.flags import matmul_precision
-    if _interpret() or matmul_precision() is not None:
-        return jnp.float32
-    return jnp.bfloat16
+    if in_dtype == jnp.bfloat16:
+        return jnp.bfloat16
+    return jnp.float32 if matmul_precision() is not None else jnp.bfloat16
 
 
 def _causal_mask(s, qi, ki, block_q, block_k, off):
@@ -106,19 +119,49 @@ def _dropout_keep(seed_ref, b, h, qi, ki, shape, rate):
     return keep.astype(jnp.float32) / (1.0 - rate)
 
 
-def _dot(a, b, dims, cd=jnp.float32):
-    """MXU matmul: operands cast to the policy dtype, f32 accumulation.
+def _mxu_parts(x, against):
+    """``x`` as the parts the MXU multiplies against an operand of dtype
+    ``against``, the smallest first; their sum is ``x``.
 
-    f32 operands mean the policy asked for full f32 (:func:`_mxu_dtype`),
-    and that has to be said to Mosaic too: at its default precision an
-    f32 dot feeds the MXU bf16-rounded operands (measured on a v5e, PR
+    One part, in ``x``'s own compute type, when both sides share it. A
+    float32 ``x`` against a bfloat16 operand is three bf16 parts (hi, mid,
+    lo: 8 + 8 + 8 = the 24 bits of an f32 mantissa, the arithmetic of
+    ``paged_decode._probs_dot``): each part times a bf16 value is exact
+    in f32, so three one-pass products, the small parts summed first,
+    give what f32 operands at the highest precision give, in half the
+    passes. A tile two products share (``ds``) is split once."""
+    cd = _mxu_dtype(x.dtype)
+    if cd == jnp.bfloat16 or _mxu_dtype(against) == cd:
+        return (x.astype(cd),)
+    x = x.astype(jnp.float32)
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return lo, mid, hi
+
+
+def _dot(a, b, dims):
+    """MXU matmul with f32 accumulation; the passes follow the operands'
+    types (module docstring). ``a`` and ``b`` are arrays, or what
+    :func:`_mxu_parts` made of one against the other.
+
+    f32 parts mean both operands are f32 and the policy asked for all of
+    it, and that has to be said to Mosaic too: at its default precision
+    an f32 dot feeds the MXU bf16-rounded operands (measured on a v5e, PR
     22: 2.2e-3 off the f32 reference — the error of a bf16 pass — where
-    XLA's ``highest`` composition is at 1e-6)."""
-    return jax.lax.dot_general(
-        a.astype(cd), b.astype(cd), (dims, ((), ())),
-        precision=(jax.lax.Precision.HIGHEST if cd == jnp.float32
-                   else None),
-        preferred_element_type=jnp.float32)
+    XLA's ``highest`` composition is at 1e-6). bf16 parts need no
+    ``precision=``: one pass is all there is."""
+    if not isinstance(b, tuple):
+        b = _mxu_parts(b, a[0].dtype if isinstance(a, tuple) else a.dtype)
+    if not isinstance(a, tuple):
+        a = _mxu_parts(a, b[0].dtype)
+    prec = (jax.lax.Precision.HIGHEST if a[0].dtype == jnp.float32
+            else None)
+    return functools.reduce(jnp.add, [
+        jax.lax.dot_general(x, y, (dims, ((), ())), precision=prec,
+                            preferred_element_type=jnp.float32)
+        for x in a for y in b])
 
 
 # ---------------------------------------------------------------------------
@@ -128,7 +171,7 @@ def _dot(a, b, dims, cd=jnp.float32):
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k,
-                cd, off, rate):
+                off, rate):
     b, h = pl.program_id(0), pl.program_id(1)
     qi, ki = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
@@ -144,7 +187,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
 
     @pl.when(run)
     def _step():
-        s = _dot(q_ref[0, 0], k_ref[0, 0], ((1,), (1,)), cd) * scale
+        s = _dot(q_ref[0, 0], k_ref[0, 0], ((1,), (1,))) * scale
         if bias_ref is not None:
             s = s + bias_ref[0, 0].astype(jnp.float32)   # [1, bk] broadcast
         if causal:
@@ -164,8 +207,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
             # dropout on the normalized probs commutes to masking the pv
             # accumulation only; the softmax denominator stays undropped
             pv = p * _dropout_keep(seed_ref, b, h, qi, ki, p.shape, rate)
-        acc_scr[:] = acc_scr[:] * alpha + _dot(pv, v_ref[0, 0],
-                                               ((1,), (0,)), cd)
+        acc_scr[:] = acc_scr[:] * alpha + _dot(pv, v_ref[0, 0], ((1,), (0,)))
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
@@ -226,8 +268,8 @@ def _fwd_v1(q, k, v, bias, scale, causal, block_q, block_k,
         args.append(bias)
     kern = _mk_kernel(_fwd_kernel, bias is not None, lse_out=save_residuals,
                       has_seed=rate > 0.0, scale=scale, causal=causal,
-                      block_q=block_q, block_k=block_k,
-                      cd=_mxu_dtype(q.dtype), off=Sk - Sq, rate=rate)
+                      block_q=block_q, block_k=block_k, off=Sk - Sq,
+                      rate=rate)
 
     out_specs = [pl.BlockSpec((1, 1, block_q, D),
                               lambda b, h, i, j: (b, h, i, 0))]
@@ -293,7 +335,7 @@ def _heads_per_block(D: int, H: int):
 
 def _fwd2_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                  m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k,
-                 cd, off, rate, bb, hp, D):
+                 off, rate, bb, hp, D):
     bg, hg = pl.program_id(0), pl.program_id(1)
     qi, ki = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
@@ -314,7 +356,7 @@ def _fwd2_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 q = q_ref[bi, :, hh * D:(hh + 1) * D]
                 k = k_ref[bi, :, hh * D:(hh + 1) * D]
                 v = v_ref[bi, :, hh * D:(hh + 1) * D]
-                s = _dot(q, k, ((1,), (1,)), cd) * scale
+                s = _dot(q, k, ((1,), (1,))) * scale
                 if causal:
                     s = _causal_mask(s, qi, ki, block_q, block_k, off)
                 m_prev = m_scr[bi, hh][:, :1]
@@ -334,7 +376,7 @@ def _fwd2_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                     pv = p * _dropout_keep(seed_ref, b_abs, h_abs, qi, ki,
                                            p.shape, rate)
                 acc_scr[bi, hh] = acc_scr[bi, hh] * alpha \
-                    + _dot(pv, v, ((1,), (0,)), cd)
+                    + _dot(pv, v, ((1,), (0,)))
                 m_scr[bi, hh] = jnp.broadcast_to(m_new, m_scr[bi, hh].shape)
                 l_scr[bi, hh] = jnp.broadcast_to(l_new, l_scr[bi, hh].shape)
 
@@ -388,9 +430,8 @@ def _fwd2(q, k, v, scale, causal, block_q, block_k, hp, width,
             lse_r = None
         return _fwd2_kernel(seed_ref, q_r, k_r, v_r, o_r, lse_r, m_s, l_s,
                             a_s, scale=scale, causal=causal,
-                            block_q=block_q, block_k=block_k,
-                            cd=_mxu_dtype(q.dtype), off=Sk - Sq, rate=rate,
-                            bb=bb, hp=hp, D=D)
+                            block_q=block_q, block_k=block_k, off=Sk - Sq,
+                            rate=rate, bb=bb, hp=hp, D=D)
 
     out_specs = [pl.BlockSpec((bb, block_q, width),
                               lambda b, h, i, j: (b, i, h))]
@@ -424,7 +465,7 @@ def _fwd2(q, k, v, scale, causal, block_q, block_k, hp, width,
 
 def _bwd2_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                  dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
-                 scale, causal, block_q, block_k, cd, off, rate, bb, hp, D):
+                 scale, causal, block_q, block_k, off, rate, bb, hp, D):
     """Fused backward: grid (B/bb, H/hp, nk, nq) with the q sweep innermost.
 
     dk/dv accumulate across the inner q sweep in block-sized scratch and
@@ -463,11 +504,11 @@ def _bwd2_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                 delta = jnp.sum(do.astype(jnp.float32)
                                 * o.astype(jnp.float32),
                                 axis=1, keepdims=True)
-                s = _dot(q, k, ((1,), (1,)), cd) * scale
+                s = _dot(q, k, ((1,), (1,))) * scale
                 if causal:
                     s = _causal_mask(s, qi, ki, block_q, block_k, off)
                 p = jnp.exp(s - jnp.where(lse == NEG_INF, 0.0, lse))
-                dp = _dot(do, v, ((1,), (1,)), cd)
+                dp = _dot(do, v, ((1,), (1,)))
                 pv = p
                 if rate > 0.0:
                     b_abs = bg * bb + bi
@@ -476,11 +517,12 @@ def _bwd2_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                                           p.shape, rate)
                     pv = p * keepf
                     dp = dp * keepf
-                ds = p * (dp - delta) * scale
+                # two products read ds: split for the MXU once
+                ds = _mxu_parts(p * (dp - delta) * scale, k.dtype)
                 rows = pl.ds(qi * block_q, block_q)
-                dq_scr[bi, hh, rows] += _dot(ds, k, ((1,), (0,)), cd)
-                dk_scr[bi, hh] += _dot(ds, q, ((0,), (0,)), cd)
-                dv_scr[bi, hh] += _dot(pv, do, ((0,), (0,)), cd)
+                dq_scr[bi, hh, rows] += _dot(ds, k, ((1,), (0,)))
+                dk_scr[bi, hh] += _dot(ds, q, ((0,), (0,)))
+                dv_scr[bi, hh] += _dot(pv, do, ((0,), (0,)))
 
     @pl.when(qi == nq - 1)
     def _write_dkv():
@@ -530,9 +572,8 @@ def _bwd2(q, k, v, o, lse, do, scale, causal, block_q, block_k, hp, width,
         else:
             seed_ref = None
         return _bwd2_kernel(seed_ref, *refs, scale=scale, causal=causal,
-                            block_q=block_q, block_k=block_k,
-                            cd=_mxu_dtype(q.dtype), off=Sk - Sq, rate=rate,
-                            bb=bb, hp=hp, D=D)
+                            block_q=block_q, block_k=block_k, off=Sk - Sq,
+                            rate=rate, bb=bb, hp=hp, D=D)
 
     dq, dk, dv = pl.pallas_call(
         kern,
@@ -572,7 +613,7 @@ def _bwd2(q, k, v, o, lse, do, scale, causal, block_q, block_k, hp, width,
 
 def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref,
                lse_ref, dq_ref, acc_scr, *, scale, causal, block_q,
-               block_k, cd, off, rate):
+               block_k, off, rate):
     b, h = pl.program_id(0), pl.program_id(1)
     qi, ki = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
@@ -593,18 +634,18 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref,
         delta = jnp.sum(do_ref[0, 0].astype(jnp.float32)
                         * o_ref[0, 0].astype(jnp.float32),
                         axis=1, keepdims=True)           # [bq, 1]
-        s = _dot(q_ref[0, 0], k_ref[0, 0], ((1,), (1,)), cd) * scale
+        s = _dot(q_ref[0, 0], k_ref[0, 0], ((1,), (1,))) * scale
         if bias_ref is not None:
             s = s + bias_ref[0, 0].astype(jnp.float32)
         if causal:
             s = _causal_mask(s, qi, ki, block_q, block_k, off)
         # fully-masked row (lse = NEG_INF): shift by 0 so exp(-1e30) -> 0
         p = jnp.exp(s - jnp.where(lse == NEG_INF, 0.0, lse))  # [bq, bk]
-        dp = _dot(do_ref[0, 0], v_ref[0, 0], ((1,), (1,)), cd)
+        dp = _dot(do_ref[0, 0], v_ref[0, 0], ((1,), (1,)))
         if rate > 0.0:
             dp = dp * _dropout_keep(seed_ref, b, h, qi, ki, p.shape, rate)
         ds = p * (dp - delta) * scale
-        acc_scr[:] += _dot(ds, k_ref[0, 0], ((1,), (0,)), cd)
+        acc_scr[:] += _dot(ds, k_ref[0, 0], ((1,), (0,)))
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -613,7 +654,7 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref,
 
 def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref,
                 lse_ref, dk_ref, dv_ref, db_ref, dk_scr, dv_scr, db_scr, *,
-                scale, causal, block_q, block_k, cd, off, rate):
+                scale, causal, block_q, block_k, off, rate):
     b, h = pl.program_id(0), pl.program_id(1)
     ki, qi = pl.program_id(2), pl.program_id(3)          # k outer, q inner
     nq = pl.num_programs(3)
@@ -634,7 +675,7 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref,
         delta = jnp.sum(do_ref[0, 0].astype(jnp.float32)
                         * o_ref[0, 0].astype(jnp.float32),
                         axis=1, keepdims=True)           # [bq, 1]
-        s = _dot(q_ref[0, 0], k_ref[0, 0], ((1,), (1,)), cd) * scale
+        s = _dot(q_ref[0, 0], k_ref[0, 0], ((1,), (1,))) * scale
         if bias_ref is not None:
             s = s + bias_ref[0, 0].astype(jnp.float32)
         if causal:
@@ -642,15 +683,15 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref,
         # fully-masked row (lse = NEG_INF): shift by 0 so exp(-1e30) -> 0
         p = jnp.exp(s - jnp.where(lse == NEG_INF, 0.0, lse))  # [bq, bk]
         pv = p
-        dp = _dot(do_ref[0, 0], v_ref[0, 0], ((1,), (1,)), cd)
+        dp = _dot(do_ref[0, 0], v_ref[0, 0], ((1,), (1,)))
         if rate > 0.0:
             # same (b, h, qi, ki) fold as the forward: identical mask
             keepf = _dropout_keep(seed_ref, b, h, qi, ki, p.shape, rate)
             pv = p * keepf
             dp = dp * keepf
-        dv_scr[:] += _dot(pv, do_ref[0, 0], ((0,), (0,)), cd)  # p~^T dO
+        dv_scr[:] += _dot(pv, do_ref[0, 0], ((0,), (0,)))  # p~^T dO
         ds = p * (dp - delta) * scale
-        dk_scr[:] += _dot(ds, q_ref[0, 0], ((0,), (0,)), cd)  # ds^T q
+        dk_scr[:] += _dot(ds, q_ref[0, 0], ((0,), (0,)))  # ds^T q
         if db_scr is not None:
             # d(bias): ds summed over query rows (scale undone: bias adds to
             # the raw scores AFTER the q@k scaling)
@@ -708,8 +749,7 @@ def _bwd_v1(q, k, v, bias, o, lse, do, scale, causal, block_q, block_k,
     dq = pl.pallas_call(
         _mk_kernel(_dq_kernel, bias is not None, has_seed=rate > 0.0,
                    scale=scale, causal=causal, block_q=block_q,
-                   block_k=block_k, cd=_mxu_dtype(q.dtype), off=Sk - Sq,
-                   rate=rate),
+                   block_k=block_k, off=Sk - Sq, rate=rate),
         grid=(B, H, nq, nk),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, 1, block_q, D),
@@ -760,7 +800,7 @@ def _bwd_v1(q, k, v, bias, o, lse, do, scale, causal, block_q, block_k,
     outs = pl.pallas_call(
         _mk_dkv_kernel(bias is not None, has_seed=rate > 0.0, scale=scale,
                        causal=causal, block_q=block_q, block_k=block_k,
-                       cd=_mxu_dtype(q.dtype), off=Sk - Sq, rate=rate),
+                       off=Sk - Sq, rate=rate),
         grid=(B, H, nk, nq),
         in_specs=dkv_in_specs,
         out_specs=dkv_out_specs,

@@ -6,6 +6,8 @@ composition within tolerance). Runs the same Pallas kernels through the
 interpreter on CPU; the TPU path compiles the identical kernel code.
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +15,9 @@ import pytest
 
 from paddle_tpu.ops.attention import _sdpa_xla
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+# the module (the package re-exports a same-named function over it)
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 
 # the kernels are called directly: the marker selects the interpreter
 pytestmark = pytest.mark.pallas
@@ -151,14 +156,170 @@ def test_non_dividing_seq_len_picks_smaller_block():
                                atol=2e-5, rtol=2e-5)
 
 
-def test_bf16_inputs():
+@pytest.mark.parametrize("what", ["forward", "gradients"])
+def test_bf16_inputs(what):
     q, k, v = _qkv(6, dtype=np.float32)
     qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
-    out = flash_attention(qb, kb, vb)
-    assert out.dtype == jnp.bfloat16
-    ref = _ref(q, k, v)
-    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
-                               np.asarray(ref), atol=3e-2, rtol=3e-2)
+    if what == "forward":
+        got, ref = [flash_attention(qb, kb, vb)], [_ref(q, k, v)]
+    else:
+        got = jax.grad(lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2))(qb, kb, vb)
+        ref = jax.grad(lambda q, k, v: jnp.sum(_ref(q, k, v) ** 2),
+                       argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, ref):
+        assert a.dtype == jnp.bfloat16
+        # a few bf16 eps (7.8e-3) of the result's range: the inputs'
+        # rounding, and the output's
+        np.testing.assert_allclose(
+            np.asarray(a.astype(jnp.float32)), np.asarray(b),
+            atol=3e-2 * float(jnp.max(jnp.abs(b))), rtol=3e-2)
+
+
+# -- the precision rule: MXU passes follow the operands' types (ISSUE 29) ------
+
+def _kernel_dots(fn, *args):
+    """``{kernel name: [(lhs dtype, rhs dtype, out dtype, precision)]}``
+    for every ``dot_general`` inside a ``pallas_call`` of ``fn``'s jaxpr."""
+    found = {}
+
+    def walk(jaxpr, kernel):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                walk(e.params["jaxpr"], found.setdefault(e.params["name"], []))
+                continue
+            if e.primitive.name == "dot_general" and kernel is not None:
+                kernel.append((*(str(x.aval.dtype) for x in e.invars),
+                               str(e.outvars[0].aval.dtype),
+                               e.params["precision"]))
+            for val in e.params.values():
+                for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, kernel)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, None)
+    return found
+
+
+_BF16_DOT = ("bfloat16", "bfloat16", "float32", None)
+_HIGHEST = (jax.lax.Precision.HIGHEST,) * 2
+_F32_DOT = ("float32", "float32", "float32", _HIGHEST)
+# products a tile: q k^T, p v | q k^T, dO v^T, ds k, ds^T q, p^T dO
+_DOTS = {  # (route, operands): {kernel: dots a (bi, hh) iteration}
+    ("v2", "bf16"): {"flash_fwd": 1 + 3, "flash_bwd": 1 + 1 + 3 + 3 + 3},
+    ("v2", "f32"): {"flash_fwd": 2, "flash_bwd": 5},
+    ("v1", "bf16"): {"flash_fwd_v1": 1 + 3, "flash_dq_v1": 1 + 1 + 3,
+                     "flash_dkv_v1": 1 + 1 + 3 + 3},
+    ("v1", "f32"): {"flash_fwd_v1": 2, "flash_dq_v1": 3, "flash_dkv_v1": 4},
+}
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.1], ids=["nodrop", "drop0.1"])
+@pytest.mark.parametrize("operands,flag", [
+    ("bf16", "highest"), ("f32", "highest"), ("f32", "default")])
+@pytest.mark.parametrize("route", ["v2", "v1"])
+def test_mxu_passes_follow_operand_types(route, operands, flag, dropout):
+    """The rule, pinned from the jaxpr of the forward and of the VJP.
+
+    bf16 q/k/v/dO: every product inside the kernels is a bf16 x bf16
+    ``dot_general`` with an f32 result and no ``precision=``: ONE for the
+    products of two operands read from bf16 refs, THREE for an f32 tile
+    (probabilities, ``ds``) against a bf16 operand — 4 a tile forward,
+    11 in the fused backward (v1: 4, then 5 and 8), and no f32 dot is
+    left to round a tile in one part. f32 q/k/v follow the flag: f32 at
+    ``HIGHEST`` under its default, one bf16 pass under ``default`` — as
+    before the rule."""
+    from paddle_tpu.core.flags import flag_scope
+    dtype = jnp.bfloat16 if operands == "bf16" else jnp.float32
+    q, k, v = (x.astype(dtype) for x in _qkv(13))
+    bias = jnp.zeros((B, 1, 1, S), jnp.float32) if route == "v1" else None
+    key = jax.random.key(0)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, bias=bias, causal=route == "v2",
+                               dropout_rate=dropout,
+                               dropout_key=key if dropout else None)
+
+    with flag_scope("tpu_matmul_precision", flag):
+        dots = _kernel_dots(
+            lambda q, k, v: jax.vjp(attend, q, k, v)[1](q), q, k, v)
+    expect = _DOTS[route, operands]
+    assert set(dots) == set(expect)
+    if route == "v2":
+        hp, _, bb_fwd, bb_bwd = fa._v2_plan(q, None, S, S)
+        tiles = {"flash_fwd": bb_fwd * hp, "flash_bwd": bb_bwd * hp}
+    for name, found in dots.items():
+        one = _F32_DOT if (operands, flag) == ("f32", "highest") \
+            else _BF16_DOT
+        assert set(found) == {one}, (name, set(found))
+        assert len(found) == expect[name] * (
+            tiles[name] if route == "v2" else 1), name
+
+
+class _F32Results:
+    """``jax`` as the kernels' wrappers see it, with every bf16 result
+    declared f32: the kernels' final ``.astype(ref.dtype)`` is then no
+    rounding, and what they computed can be compared to f32 accuracy."""
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+    @staticmethod
+    def ShapeDtypeStruct(shape, dtype):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.float32 if dtype == jnp.bfloat16 else dtype)
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.1], ids=["nodrop", "drop0.1"])
+@pytest.mark.parametrize("case", ["full", "causal", "rect-causal", "v1-bias"])
+def test_bf16_operands_give_the_f32_path_s_numbers(monkeypatch, case,
+                                                   dropout):
+    """bf16 q/k/v/dO through the kernels against the SAME values upcast
+    to f32 through the kernels (f32 operands at ``HIGHEST``): out, dq,
+    dk, dv before the final cast agree to f32 rounding — 1e-5 of the f32
+    result's range, where a probability or ``ds`` tile rounded to bf16 in
+    one part would read 1e-3."""
+    monkeypatch.setattr(fa, "jax", _F32Results())
+    sq, sk = (128, 384) if case == "rect-causal" else (S, S)
+    rng = np.random.RandomState(14)
+
+    def mk(s):
+        return jnp.asarray(rng.randn(B, s, H, D).astype(np.float32) * 0.5) \
+            .astype(jnp.bfloat16)
+
+    q, k, v, do = mk(sq), mk(sk), mk(sk), mk(sq)
+    bias = None
+    if case == "v1-bias":
+        bias = jnp.asarray(rng.randn(B, 1, 1, sk).astype(np.float32))
+    causal = "causal" in case
+    seed = jnp.asarray([3, 5], jnp.int32)
+    blocks = (128, 128)                  # several tiles a sweep
+
+    def run(cast):
+        q_, k_, v_, do_ = (cast(x) for x in (q, k, v, do))
+        o, lse = fa._fwd(q_, k_, v_, bias, 0.125, causal, *blocks,
+                         seed=seed, rate=dropout)
+        return o, lse, lambda o, lse: fa._bwd_impl(
+            q_, k_, v_, bias, cast(o), lse, do_, 0.125, causal, *blocks,
+            seed=seed, rate=dropout)[:3]
+
+    o32, lse32, bwd32 = run(lambda x: x.astype(jnp.float32))
+    o16, lse16, bwd16 = run(lambda x: x)
+    assert o16.dtype == jnp.float32      # the final cast skipped
+    # both backwards from ONE saved output and log-sum-exp: the output as
+    # the bf16 step stores it
+    o_saved = o32.astype(jnp.bfloat16)
+    pairs = [("out", o16, o32), ("lse", lse16, lse32)] + [
+        (n, a, b) for n, a, b in zip(("dq", "dk", "dv"),
+                                     bwd16(o_saved, lse32),
+                                     bwd32(o_saved, lse32))]
+    for name, got, ref in pairs:
+        got, ref = np.asarray(got), np.asarray(ref)
+        assert got.dtype == np.float32
+        span = float(ref.max() - ref.min())
+        assert float(np.abs(got - ref).max()) <= 1e-5 * span, name
 
 
 def _host_keep(S, b, h, rate):

@@ -38,7 +38,9 @@ BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 # 512-token context (256 pages + the scratch page), LoRA rank 16 over 5
 # adapters
 TRAIN_QKV = (8, 1024, 16, 64)
+MESH_SHARD_QKV = (8, 1024, 10, 64)   # 774M on sharding2 x mp2, one shard
 PREFILL_QKV = (4, 256, 16, 64)
+PREFILL768_QKV = (4, 768, 16, 64)    # the serve cell's long bucket: block 384
 BERT_QKV = (48, 512, 12, 64)
 LOGITS = (8192, 50304)
 CE_CHUNK = 8192                      # FLAGS_chunked_ce_chunk default
@@ -123,10 +125,38 @@ def _assert_kernels(text, *names):
     (TRAIN_QKV, F32, True, 0.0, False),
     (PREFILL_QKV, F32, False, 0.0, False),   # the engine serves in f32
     (BERT_QKV, BF16, True, 0.0, True),
+    (TRAIN_QKV, BF16, False, 0.1, False),
+    (MESH_SHARD_QKV, BF16, False, 0.0, False),
+    (MESH_SHARD_QKV, BF16, True, 0.0, False),
+    (MESH_SHARD_QKV, BF16, True, 0.1, False),
+    (PREFILL768_QKV, BF16, False, 0.0, False),
+    (PREFILL768_QKV, BF16, True, 0.0, False),
+    (PREFILL768_QKV, BF16, True, 0.1, False),
 ], ids=["train-fwd", "train-fwd+bwd", "train-fwd+bwd-dropout",
-        "train-f32-fwd+bwd", "prefill-f32-fwd", "bert-bias-fwd+bwd"])
+        "train-f32-fwd+bwd", "prefill-f32-fwd", "bert-bias-fwd+bwd",
+        "train-fwd-dropout", "mesh-shard-fwd", "mesh-shard-fwd+bwd",
+        "mesh-shard-fwd+bwd-dropout", "prefill768-fwd",
+        "prefill768-fwd+bwd", "prefill768-fwd+bwd-dropout"])
 def test_flash_attention_compiles(chip, compile_for_chip, shape, dtype,
                                   grad, dropout, bias):
+    """The shapes the cells run: the one-chip train step, one shard of
+    the mesh step (10 heads), the serve cell's two prefill buckets. On
+    bf16 operands the products are bf16 dots, contracted over dim 0 of
+    both operands in the backward (``ds^T q``, ``p^T dO``), and an f32
+    tile is three bf16 parts (ISSUE 29): forms Mosaic has to take.
+
+    Scoped VMEM (limit 16 MiB), read as the least ``vmem_limit_bytes``
+    under which a kernel compiles for this described chip (bisection in
+    64 KiB steps, ISSUE 29), train shape, bf16, dropout 0.1: forward 9.62
+    MiB (11.62 with the log-sum-exp output), fused backward 10.94 —
+    where the kernels that upcast q/k/v/dO to f32 first took 12.69
+    (14.69) and 12.19: the three bf16 parts of a 512 x 512 tile, 1.5 MiB,
+    cost less than the f32 copies they replace. Without dropout 8.44
+    (10.44) and 9.56; the 4 x 768 prefill 5.75 forward, 10.38 backward.
+    f32 operands are as they were, 14.94 (16.94) and 14.44 with dropout:
+    the f32 forward WITH dropout and the log-sum-exp output at this
+    shape is over the limit and is refused (16.91 M against 16.00 M;
+    ISSUE 29 found it, no cell or model runs it: AMP steps are bf16)."""
     fa = _kernel("flash_attention")
     q = chip(shape, dtype)
     key = chip((), jax.random.key(0).dtype)
