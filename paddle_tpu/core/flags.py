@@ -209,7 +209,7 @@ define_flag("collective_timeout_s", 0.0,
             "the cold-start time.")
 define_flag("chaos", "",
             "Deterministic fault-injection spec for "
-            "paddle_tpu.testing.chaos (tests and bench.py --chaos): "
+            "paddle_tpu.testing.chaos (tests and fault drills): "
             "comma-separated 'site[@N|:prob][*times]' entries, e.g. "
             "'ckpt.write.torn@2,collective.hang:0.1'. Empty (default) = "
             "no injection, zero probe overhead.")

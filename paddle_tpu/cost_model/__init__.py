@@ -9,7 +9,7 @@ same jitted callable a user would train with.
 This module is the ONE source of truth for program cost numbers:
 :func:`normalize_cost_analysis` (shared with the per-program attribution
 in ``jit/to_static.TrainStep``) and the per-chip peak-FLOPs table that
-MFU math divides by (shared with ``bench.py``).
+the host-clock MFU gauge divides by.
 """
 
 from __future__ import annotations

@@ -16,8 +16,7 @@ capacity slices and back — selected by ``FLAGS_moe_dispatch``:
     into a [E*C + 1, D] buffer (row E*C = the drop bucket) and one gather
     back. Data moved is O(T·k·D) regardless of E and capacity — at E=8,
     k=2, cf=2 that is ~8x less than the einsum's O(T·E·C·D) stream, and
-    the gap grows linearly with E (cost-model attributed in ``bench.py
-    --moe``).
+    the gap grows linearly with E.
 
 Both consume one :class:`~paddle_tpu.incubate.moe.routing.Routing`, so
 capacity clipping and drop decisions are identical; outputs agree

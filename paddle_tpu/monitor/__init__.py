@@ -27,8 +27,7 @@ Memory/cost/forensics pillars (ISSUE 4, the space-domain counterpart):
    growth detection, and :func:`~.memory.memory_summary`;
 6. **per-program cost attribution** — FLOPs/bytes/arithmetic intensity
    from ``lowered.cost_analysis()`` via :mod:`paddle_tpu.cost_model`
-   (one shared source of truth with ``CostModel.profile_measure`` and
-   bench.py's MFU math);
+   (one shared source of truth with ``CostModel.profile_measure``);
 7. the **crash flight recorder** (:mod:`.flight_recorder`): a bounded
    ring of recent step records + events + an environment fingerprint,
    dumped to JSON on unhandled exceptions, NaN-watchdog trips, or

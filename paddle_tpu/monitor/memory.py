@@ -302,8 +302,7 @@ def publish_census(train_step=None, registry=None) \
         -> Dict[str, Dict[str, int]]:
     """Run a census and publish it as ``live_buffer_bytes`` /
     ``live_buffer_count`` gauges labelled by category (rendered by
-    ``tools/monitor_report.py --memory``; bench.py calls this before its
-    registry dump). Returns the census."""
+    ``tools/monitor_report.py --memory``). Returns the census."""
     census = live_buffer_census(train_step)
     from .metrics import get_registry
     reg = registry if registry is not None else get_registry()

@@ -15,11 +15,32 @@ follows ONE dispatch convention:
 - every fallback is counted: :func:`note_fallback` feeds the
   ``pallas_fallback_total{kernel,reason}`` counter (monitor mode) and
   the always-on :data:`PALLAS_STATS` dict, so ``tools/monitor_report.py
-  --kernels`` can show which kernels are live vs degraded;
-- a parity test in tests/test_pallas_kernels.py and a bench line in
-  ``bench.py --kernels`` (BENCH_kernels.json).
+  --kernels`` can show which kernels are live vs degraded. Under a
+  multi-device mesh GSPMD cannot partition a Mosaic kernel: one either
+  runs per shard in a ``shard_map`` (``flash_attention``, through
+  ``distributed.spmd.shard_kernel``) or takes its XLA path counted as
+  ``mesh`` (every other kernel, until it has a per-shard form);
+- a parity test in interpret mode (forward, and backward where the
+  kernel has a custom VJP) plus a kill-switch test pinning the fallback
+  bit-identical, in tests/test_pallas_kernels.py; a compile for a
+  described v5e at the GPT-2 345M shapes in tests/test_tpu_compile.py
+  (what Mosaic refuses on the chip is refused there); flash attention,
+  chunked CE and paged decode against their XLA references ON the chip
+  in ``chip_smoke.py``'s kernel phase.
+  Kernel TIME is a cell's: ``flash_attention_roofline`` and
+  ``paged_decode_roofline`` (``PERF.md`` section 3).
 
-Kernel inventory (docs/PERF_KERNELS.md):
+What never reaches a kernel: a mask that is not an additive key-padding
+mask ``[B, 1, 1, Sk]``, a head dim outside 64/128/256, a sequence that is
+short (under 256) or not a multiple of 128
+(``ops.attention._flash_supported``: flash); soft labels
+and the dense mp-sharded ``ParallelCrossEntropy`` (chunked CE); prefill,
+S > 1 (paged decode); K or N not a multiple of 128, counted as ``shape``
+(int8 matmul); an engine built with ``lora_adapters == 0`` (bgmv).
+``FLAGS_amp_int8_matmul`` (off) routes eligible ``F.linear`` calls
+under ``amp.auto_cast`` through ``int8_amp_linear``: an experiment.
+
+Kernel inventory:
 
 ==================  ==========================  =========================
 kernel              flag                        XLA fallback

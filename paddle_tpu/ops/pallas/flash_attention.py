@@ -989,9 +989,9 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     # tuning override without touching call sites (block sweeps on real
-    # hardware; see docs/PERF_GPT.md). Only applied when the caller left
-    # the block size at its default — an explicit block_q/block_k argument
-    # always wins over the environment.
+    # hardware). Only applied when the caller left the block size at its
+    # default — an explicit block_q/block_k argument always wins over the
+    # environment.
     if block_q == DEFAULT_BLOCK:
         block_q = _env_block("PTPU_FLASH_BLOCK_Q", block_q)
     if block_k == DEFAULT_BLOCK:

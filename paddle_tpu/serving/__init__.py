@@ -17,8 +17,8 @@ literature mapped onto static-shape XLA programs:
   serving signatures (``jit.aot.AOTProgram``, the TrainStep machinery),
   streaming per-token callbacks and TTFT/TPOT/throughput metrics into
   the :mod:`paddle_tpu.monitor` registry;
-- :mod:`.loadgen` is the synthetic open-loop driver behind
-  ``bench.py --serve`` (the ``BENCH_serve`` record);
+- :mod:`.loadgen` is a synthetic open-loop driver that only tests call
+  (the benchmark has its own, ``benchmark/harness/loadgen.py``);
 - :mod:`.router` scales one engine to a fleet (ISSUE 16): a
   prefix-affine front-end over N replicas with telemetry-driven load
   balancing and chaos-proof drain/death migration;

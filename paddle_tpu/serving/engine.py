@@ -30,8 +30,8 @@ The execution model (ISSUE 6; docs/SERVING.md):
   queue/occupancy gauges stream into the ``paddle_tpu.monitor`` registry
   (serving metrics are always on — an engine exists to be observed; the
   FLAGS_monitor zero-write contract covers the *training* hot path), and
-  ``metrics_summary()`` computes the p50/p99 numbers ``bench.py
-  --serve`` records.
+  ``metrics_summary()`` computes p50/p99 over the raw samples (the
+  benchmark reads its occupancy counts, ``PERF.md`` section 3).
 """
 
 from __future__ import annotations
@@ -2268,7 +2268,7 @@ class ServingEngine:
 
     def metrics_summary(self) -> dict:
         """Host-side latency/throughput summary (exact percentiles over
-        the raw per-request samples — the BENCH_serve payload)."""
+        the raw per-request samples)."""
 
         def pct(xs, q):
             return float(np.percentile(np.asarray(xs), q)) if xs else None

@@ -23,8 +23,8 @@ Arrival processes (``LoadSpec.arrival``):
   actually exercises shedding and the overload detector.
 
 Per-request ``deadline_range`` / ``priority_choices`` sampling makes the
-expiry and priority-lane paths reachable from ``bench.py --serve``. The
-extra draws only happen when the corresponding field is set, so default
+expiry and priority-lane paths reachable from a load run. The extra
+draws only happen when the corresponding field is set, so default
 specs generate byte-identical traffic to the pre-resilience generator.
 
 :class:`TokenBucket` is client-side rate limiting for loadgen-driven
@@ -82,7 +82,7 @@ class LoadSpec:
     #: with one of ``prefix_pool_size`` fixed prefixes of this many
     #: tokens (a "system prompt"), drawn with bounded-zipf reuse so a
     #: hot head of prefixes dominates — the traffic shape the radix
-    #: prefix cache exists for (BENCH_serve measures hit rate on it).
+    #: prefix cache exists for.
     #: 0 (default) = no prefixes, byte-identical to pre-ISSUE-15 specs.
     shared_prefix_len: int = 0
     #: number of distinct prefixes in the pool
@@ -104,9 +104,9 @@ class LoadSpec:
     #: ("tenant{t}/adapter{k}", k uniform from a fixed-seed SIDE
     #: generator, so arming adapters perturbs none of the default
     #: draws — arrivals/prompts/lengths replay exactly) and carries its
-    #: tenant name, reaching the per-tenant quota + batched-bgmv paths
-    #: from ``bench.py --serve``. Requires ``tenants > 0``. 0 (default)
-    #: = no adapter/tenant stamping, byte-identical to pre-LoRA specs.
+    #: tenant name, reaching the per-tenant quota + batched-bgmv paths.
+    #: Requires ``tenants > 0``. 0 (default) = no adapter/tenant
+    #: stamping, byte-identical to pre-LoRA specs.
     adapter_pool: int = 0
     #: model-lifecycle traffic tagging (ISSUE 20): > 0 = stamp each
     #: request with the A/B arm (``lifecycle_arm``) a router running

@@ -3,8 +3,8 @@ are proven against.
 
 Production fault tolerance that has never seen a fault is a guess. This
 module puts *named probe sites* on the framework's recovery-relevant
-code paths; a test (or ``bench.py --chaos``) arms a subset of them with
-a deterministic plan, and the site fires exactly where and when the plan
+code paths; a test (or ``FLAGS_chaos``) arms a subset of them with a
+deterministic plan, and the site fires exactly where and when the plan
 says — so every recovery path (torn-checkpoint fallback, collective
 timeout, skip-and-continue, elastic restart) is exercised reproducibly
 instead of waiting for production to exercise it for you.
@@ -71,7 +71,7 @@ SITES: Dict[str, str] = {
     "grad.nonfinite": "replace the TrainStep loss with NaN",
     "worker.die": "kill the training loop at a step boundary",
     # serving sites (ISSUE 8; probed by paddle_tpu.serving — built in so
-    # `bench.py --chaos` can arm them before the serving import)
+    # `FLAGS_chaos` can arm them before the serving import)
     "serve.decode.hang": "block a serving decode dispatch (bounded, "
                          "cancellable sleep) — the FLAGS_serve_watchdog_s "
                          "watchdog must convert it into "
@@ -86,8 +86,8 @@ SITES: Dict[str, str] = {
     "serve.detok.raise": "raise from the streaming detokenizer/on_token "
                          "callback of one accepted token",
     # model-lifecycle sites (ISSUE 20; probed by serving/engine.py +
-    # serving/lifecycle.py — built in so `bench.py --chaos` can arm
-    # them before the serving import)
+    # serving/lifecycle.py — built in so `FLAGS_chaos` can arm them
+    # before the serving import)
     "serve.swap.torn_manifest": "a candidate weight push reads as torn "
                                 "at verification time: swap_weights "
                                 "must refuse it and the OLD weights "

@@ -73,9 +73,8 @@ def publish_compile_counts(registry=None) -> dict:
     """Bridge the process-lifetime compile counters into the monitor
     metrics registry as gauges (``jax_backend_compiles``,
     ``jax_cache_misses``, ``jax_jaxpr_traces``, plus nn.scan's
-    ``scan_body_traces``/``scan_calls``) — called by bench.py before its
-    JSONL dump so perf records carry recompile counts. Returns the raw
-    counts dict."""
+    ``scan_body_traces``/``scan_calls``), so a registry dump carries
+    recompile counts. Returns the raw counts dict."""
     counts = compile_counts()
     try:
         from ..nn.scan import SCAN_STATS

@@ -2,9 +2,12 @@
 
 ``data_format="NHWC"`` runs the whole network channels-last internally
 (convs, BNs, pools) while keeping the public NCHW input contract — the
-input is transposed ONCE at entry. On TPU the channels-last layout is
-the MXU-native one for convolutions; see docs/PERF_GPT.md's ResNet
-note for measured numbers.
+input is transposed ONCE at entry. Why NHWC: TPU convolutions are
+channels-last natively, and per-op NCHW dimension numbers make XLA put
+layout ops around every conv, pool and BN; the same layout is what
+``nn/layout.py`` plans for an NCHW model under
+``FLAGS_jit_channels_last``. No cell trains a convolutional model, so
+what either buys on the chip is not measured (ROADMAP Design 7).
 """
 
 from __future__ import annotations

@@ -69,8 +69,7 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: excluded from the tier-1 wall-clock budget "
-        "(`-m 'not slow'`); full bench legs and other multi-minute "
-        "drills carry it")
+        "(`-m 'not slow'`); multi-minute drills carry it")
 
 
 @pytest.fixture(autouse=True)

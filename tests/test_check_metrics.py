@@ -28,7 +28,6 @@ def test_cli_exit_code():
 def _fake_repo(tmp_path, source: str, doc_names):
     (tmp_path / "paddle_tpu").mkdir()
     (tmp_path / "paddle_tpu" / "mod.py").write_text(source)
-    (tmp_path / "bench.py").write_text("")
     (tmp_path / "docs").mkdir()
     rows = "\n".join(f"| `{n}` | counter | | x |" for n in doc_names)
     (tmp_path / "docs" / "OBSERVABILITY.md").write_text(

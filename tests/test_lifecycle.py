@@ -2,8 +2,7 @@
 per-slot weight epochs, shadow/A-B traffic splitting, and the
 SLO-guarded promote-or-rollback controller — plus the flags-off
 byte-identity pins, the chaos drills for torn/corrupt/dying pushes, and
-the tooling surfaces (check_bench swap% unit, monitor_report
---lifecycle)."""
+the tooling surface (monitor_report --lifecycle)."""
 
 import gc
 import json
@@ -690,26 +689,8 @@ def test_chaos_replica_die_mid_swap_aborts(tiny_model, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# tooling: check_bench swap% direction, monitor_report --lifecycle
+# tooling: monitor_report --lifecycle
 # ---------------------------------------------------------------------------
-
-
-def test_check_bench_swap_pct_absolute_points_higher_better():
-    import check_bench
-    old = [{"metric": "serve_swap_availability_pct", "value": 100.0,
-            "unit": "swap%"}]
-    # a 9-point availability outage would hide inside a relative 10%
-    # band — the absolute-points unit must catch it
-    drop = [{"metric": "serve_swap_availability_pct", "value": 89.0,
-             "unit": "swap%"}]
-    assert check_bench.compare_common(old, drop, tolerance=0.10)
-    within = [{"metric": "serve_swap_availability_pct", "value": 99.0,
-               "unit": "swap%"}]
-    assert check_bench.compare_common(old, within, tolerance=0.10) == []
-    # growth is never a swap% regression
-    assert check_bench.compare_common(
-        [{"metric": "serve_swap_availability_pct", "value": 90.0,
-          "unit": "swap%"}], old, tolerance=0.10) == []
 
 
 def test_monitor_report_lifecycle_renders(tiny_model, tmp_path):

@@ -2,8 +2,7 @@
 int8-quantized paged KV, adapter hot-swap lifecycle and per-tenant
 quota — each behind its own kill switch with the flags-off path as the
 bit-compatible / token-exact oracle, plus the composed fuzz drill
-(quant + radix donation + COW + speculative rollback + drain/resume)
-and the bench-gate direction pins for the new units."""
+(quant + radix donation + COW + speculative rollback + drain/resume)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -440,37 +439,6 @@ def test_loadgen_adapter_pool_pin_and_side_rng():
     assert [r.adapter for _, r in on] == [r.adapter for _, r in again]
     with pytest.raises(ValueError, match="tenants"):
         build_requests(LoadSpec(adapter_pool=2))
-
-
-# ---------------------------------------------------------------------------
-# check_bench: the new units gate in the right direction
-# ---------------------------------------------------------------------------
-
-
-def test_check_bench_directions_for_multitenant_units():
-    import os
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__),
-                                    "..", "tools"))
-    import check_bench
-    assert check_bench.lower_is_better("bytes/token")
-    assert check_bench.lower_is_better("bytes/slot")
-    assert not check_bench.lower_is_better("adapters")
-    old = [{"metric": "serve_kv_bytes_per_token", "value": 100.0,
-            "unit": "bytes/token"},
-           {"metric": "serve_lora_adapters_per_chip", "value": 8.0,
-            "unit": "adapters"}]
-    worse = [{"metric": "serve_kv_bytes_per_token", "value": 120.0,
-              "unit": "bytes/token"},
-             {"metric": "serve_lora_adapters_per_chip", "value": 6.0,
-              "unit": "adapters"}]
-    problems = check_bench.compare_common(old, worse)
-    assert len(problems) == 2
-    better = [{"metric": "serve_kv_bytes_per_token", "value": 80.0,
-               "unit": "bytes/token"},
-              {"metric": "serve_lora_adapters_per_chip", "value": 10.0,
-               "unit": "adapters"}]
-    assert check_bench.compare_common(old, better) == []
 
 
 # ---------------------------------------------------------------------------

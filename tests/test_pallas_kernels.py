@@ -613,37 +613,3 @@ def test_amp_int8_linear_flag_gated():
     with amp.auto_cast(level="O1", dtype="float32"):
         y_off = F.linear(paddle.to_tensor(x_np), lin.weight, lin.bias)
     np.testing.assert_array_equal(y_off.numpy(), ref)
-
-
-# ---------------------------------------------------------------------------
-# bench record gating
-# ---------------------------------------------------------------------------
-
-
-def test_bench_kernels_metrics_are_gated_by_check_bench():
-    """kernel_*_ms lines gate as lower-is-better, kernel_*_gbps as
-    higher-is-better — the BENCH_kernels.json self-gate contract."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__),
-                                    "..", "tools"))
-    import check_bench  # noqa: E402
-    old = [
-        {"metric": "kernel_ce_fused_ms", "value": 10.0, "unit": "ms"},
-        {"metric": "kernel_ce_fused_gbps", "value": 50.0, "unit": "GB/s"},
-        {"metric": "kernel_paged_decode_ms", "value": 5.0, "unit": "ms"},
-    ]
-    new_ok = [
-        {"metric": "kernel_ce_fused_ms", "value": 10.5, "unit": "ms"},
-        {"metric": "kernel_ce_fused_gbps", "value": 48.0, "unit": "GB/s"},
-        {"metric": "kernel_paged_decode_ms", "value": 5.1, "unit": "ms"},
-    ]
-    assert check_bench.compare_common(old, new_ok) == []
-    new_bad = [
-        {"metric": "kernel_ce_fused_ms", "value": 14.0, "unit": "ms"},
-        {"metric": "kernel_ce_fused_gbps", "value": 30.0, "unit": "GB/s"},
-    ]
-    problems = check_bench.compare_common(old, new_bad)
-    assert len(problems) == 2
-    assert any("kernel_ce_fused_ms" in p for p in problems)
-    assert any("kernel_ce_fused_gbps" in p for p in problems)

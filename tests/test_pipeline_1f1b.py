@@ -530,27 +530,19 @@ def test_topology_validation_named_errors():
 # -- tooling ----------------------------------------------------------------
 
 def test_monitor_report_comms_render():
-    """tools/monitor_report.py --comms renders the overlapped-vs-exposed
-    table from comm_overlap_ms gauges plus the schedule comm model."""
+    """tools/monitor_report.py --comms renders the schedule comm model."""
     import os
     import sys
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                     "tools"))
     import monitor_report
 
-    def g(phase, v):
-        return {"name": "comm_overlap_ms", "type": "gauge", "value": v,
-                "labels": {"op": "ppermute", "mesh": "pp2_1f1b",
-                           "schedule": "1f1b", "phase": phase}}
-
-    rows = [g("serial", 10.0), g("exposed", 4.0), g("overlapped", 6.0),
-            {"name": "pipeline_bubble_fraction", "type": "gauge",
+    rows = [{"name": "pipeline_bubble_fraction", "type": "gauge",
              "value": 0.2, "labels": {"op": "ppermute", "schedule": "1f1b",
                                       "pp": 2, "microbatches": 4}}]
     out = monitor_report.render(rows, comms=True)
-    assert "Comm/compute overlap" in out
-    assert "60%" in out                       # 6 of 10 ms hidden
+    assert "Pipeline schedule comm model" in out
     assert "pipeline_bubble_fraction" in out
-    # without --comms the gauges land in the generic table instead
+    # without --comms the gauge lands in the generic table instead
     out2 = monitor_report.render(rows, comms=False)
-    assert "Comm/compute overlap" not in out2
+    assert "Pipeline schedule comm model" not in out2

@@ -648,29 +648,6 @@ def test_publish_table_hbm_skips_dead_arrays():
     assert out["dead"] == 0
 
 
-@pytest.mark.slow
-def test_bench_recsys_full_leg_contract():
-    """The FULL DLRM bench leg (dlrm_criteo_small: 8 x 200k-row tables
-    over a hot-tier-exceeding budget + the serving leg) — multi-minute,
-    hence `slow`; tier-1 runs the unit-level pins above instead. The
-    record contract: every metric line carries the units check_bench
-    knows, the dedup parity pin ran, and spill/promotion activity is
-    nonzero (bench_recsys raises otherwise)."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, root)
-    try:
-        import bench
-    finally:
-        sys.path.remove(root)
-    lines = bench.bench_recsys(quick=False)
-    by_name = {m["metric"]: m for m in lines}
-    assert by_name["recsys_dlrm_criteo_small_examples_per_sec"][
-        "unit"] == "examples/s"
-    assert by_name["recsys_tier_hit_hbm_pct"]["unit"] == "hit%"
-    assert by_name["recsys_dlrm_criteo_small_dedup_ratio"]["value"] > 1.0
-    assert by_name["recsys_serve_availability_pct"]["value"] > 0
-
-
 def test_recsys_reset_closes_registered_tables(tmp_path):
     t = TieredEmbeddingTable(100, 4, hot_rows=4, name="closing")
     path = t.backing.path

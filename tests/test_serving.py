@@ -522,32 +522,6 @@ def test_monitor_report_serve_section(tiny_model, tmp_path):
     assert "serve_queue_depth" in out
 
 
-def test_check_bench_gates_serve_record():
-    import importlib.util
-    import os
-    tools = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "tools")
-    spec = importlib.util.spec_from_file_location(
-        "check_bench", os.path.join(tools, "check_bench.py"))
-    cb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(cb)
-    old = [{"metric": "serve_gpt2_345m_tokens_per_sec", "value": 100.0,
-            "unit": "tokens/s", "vs_baseline": 1.0},
-           {"metric": "serve_gpt2_345m_decode_p99_ms", "value": 50.0,
-            "unit": "ms", "vs_baseline": 1.0}]
-    ok = [{"metric": "serve_gpt2_345m_tokens_per_sec", "value": 98.0,
-           "unit": "tokens/s", "vs_baseline": 1.0},
-          {"metric": "serve_gpt2_345m_decode_p99_ms", "value": 52.0,
-           "unit": "ms", "vs_baseline": 1.0}]
-    assert cb.compare(old, ok) == []
-    bad = [{"metric": "serve_gpt2_345m_tokens_per_sec", "value": 60.0,
-            "unit": "tokens/s", "vs_baseline": 1.0},
-           {"metric": "serve_gpt2_345m_decode_p99_ms", "value": 80.0,
-            "unit": "ms", "vs_baseline": 1.0}]
-    problems = cb.compare(old, bad)
-    assert len(problems) == 2          # throughput drop AND p99 growth
-
-
 # ---------------------------------------------------------------------------
 # scan-fallback telemetry (ISSUE 6 satellite)
 # ---------------------------------------------------------------------------

@@ -990,7 +990,7 @@ def test_run_open_loop_counts_rejections_and_throttle(tiny_model):
 
 
 # ---------------------------------------------------------------------------
-# tooling: monitor_report --serve, bench resilience metrics
+# tooling: monitor_report --serve
 # ---------------------------------------------------------------------------
 
 
@@ -1035,44 +1035,3 @@ def test_monitor_report_outcomes_and_overload_timeline(
     assert "expired" in out and "completed" in out
     assert "Overload state timeline" in out
     assert "OVERLOADED (shedding)" in out and "normal" in out
-
-
-def test_bench_serve_resilience_metric_lines():
-    import importlib.util
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod", os.path.join(here, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    avail, shed = bench.serve_resilience_metrics({
-        "num_requests": 20, "requests_completed": 16,
-        "requests_rejected": 2, "requests_shed": 0,
-        # 2 expiries total, only 1 of them queued: the in-flight one
-        # was admitted, so it hits availability but is NOT shed
-        "requests_expired": 2, "requests_expired_queued": 1})
-    assert avail == pytest.approx(80.0)
-    assert shed == pytest.approx(15.0)
-    # the gate treats a growing shed rate as the regression
-    cb = _load_tool("check_bench")
-    assert "shed%" in cb._ABS_POINT_UNITS
-    assert not cb.lower_is_better("%")
-    old = [{"metric": "serve_shed_rate", "value": 1.0, "unit": "shed%",
-            "vs_baseline": 1.0},
-           {"metric": "serve_availability_pct", "value": 99.0,
-            "unit": "%", "vs_baseline": 1.0}]
-    bad = [{"metric": "serve_shed_rate", "value": 30.0, "unit": "shed%",
-            "vs_baseline": 1.0},
-           {"metric": "serve_availability_pct", "value": 60.0,
-            "unit": "%", "vs_baseline": 1.0}]
-    assert len(cb.compare(old, bad)) == 2
-    assert cb.compare(old, old) == []
-    # shed% gates on ABSOLUTE points, so the healthy all-zero baseline
-    # still catches a regression (relative ratio is undefined at 0)
-    zero = [{"metric": "serve_shed_rate", "value": 0.0, "unit": "shed%",
-             "vs_baseline": 1.0}]
-    regressed = [{"metric": "serve_shed_rate", "value": 40.0,
-                  "unit": "shed%", "vs_baseline": 1.0}]
-    wiggle = [{"metric": "serve_shed_rate", "value": 5.0, "unit": "shed%",
-               "vs_baseline": 1.0}]
-    assert len(cb.compare(zero, regressed)) == 1
-    assert cb.compare(zero, wiggle) == []

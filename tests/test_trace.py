@@ -633,15 +633,3 @@ def test_recovery_events_single_source():
     from paddle_tpu.monitor.flight_recorder import RECOVERY_EVENTS
     assert report._recovery_events() is RECOVERY_EVENTS
     assert report._RECOVERY_EVENTS_FALLBACK == RECOVERY_EVENTS
-
-
-def test_check_bench_overhead_unit():
-    from tools.check_bench import compare
-    old = [{"metric": "serve_trace_overhead_pct", "value": 1.0,
-            "unit": "overhead%"}]
-    grown = [{"metric": "serve_trace_overhead_pct", "value": 25.0,
-              "unit": "overhead%"}]
-    assert compare(old, grown, tolerance=0.10)      # +24 points trips
-    ok = [{"metric": "serve_trace_overhead_pct", "value": 6.0,
-           "unit": "overhead%"}]
-    assert compare(old, ok, tolerance=0.10) == []   # +5 points passes

@@ -25,8 +25,8 @@ import re
 import sys
 from typing import Dict, List, Optional, Set, Tuple
 
-#: source roots scanned for emit sites, relative to the repo root
-SOURCE_ROOTS = ("paddle_tpu", "bench.py")
+#: source directories scanned for emit sites, relative to the repo root
+SOURCE_ROOTS = ("paddle_tpu",)
 
 #: the doc that is the single source of truth for metric names
 DOC_PATH = os.path.join("docs", "OBSERVABILITY.md")
@@ -74,11 +74,7 @@ def emitted_metrics(root: str) -> Dict[str, Set[str]]:
     out: Dict[str, Set[str]] = {}
     files: List[str] = []
     for src in SOURCE_ROOTS:
-        path = os.path.join(root, src)
-        if os.path.isfile(path):
-            files.append(path)
-            continue
-        for dirpath, dirnames, filenames in os.walk(path):
+        for dirpath, dirnames, filenames in os.walk(os.path.join(root, src)):
             dirnames[:] = [d for d in dirnames if d != "__pycache__"]
             files += [os.path.join(dirpath, f) for f in filenames
                       if f.endswith(".py")]

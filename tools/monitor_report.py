@@ -2,9 +2,8 @@
 
 CI/tooling companion of paddle_tpu.monitor (the analogue of the
 reference's profiler summary tables, but fed from the metrics registry):
-given the append-only JSONL written by ``MetricsRegistry.dump_jsonl`` —
-``BENCH_monitor.jsonl`` from bench.py, or an hapi ``MonitorCallback``
-stream — prints:
+given the append-only JSONL written by ``MetricsRegistry.dump_jsonl``
+(or an hapi ``MonitorCallback`` stream) — prints:
 
 - the top-k slowest timing histograms (by total seconds);
 - compile/recompile counters (TrainStep jit entries + the process-wide
@@ -13,13 +12,10 @@ stream — prints:
 - with ``--memory``: per-program HBM budget table
   (``train_step_program_*`` gauges) + the live-buffer census
   (``live_buffer_bytes`` by category, from monitor.memory);
-- with ``--comms``: the latency-hiding view — overlapped-vs-exposed comm
-  time per op from the ``comm_overlap_ms`` gauges ``bench.py
-  --multichip`` publishes (phase = serial | exposed | overlapped; eager
-  collectives are synchronous dispatches, so their table is all-exposed
-  by construction) plus the pipeline schedule's comm-model gauges
+- with ``--comms``: the pipeline schedule's comm-model gauges
   (``pipeline_comm_ops_per_step`` / ``pipeline_bubble_fraction``,
-  docs/PARALLELISM.md);
+  docs/PARALLELISM.md). Exposed collective TIME is a device metric:
+  ``collective.time_pct`` of the mesh cell (``PERF.md`` section 3);
 - with ``--moe``: the MoE router-health view — a per-layer table of the
   ``moe_router_*`` gauges (balance/drop/entropy + per-expert load
   spread), the dropped-token counter, and expert-parallel fallback
@@ -89,7 +85,7 @@ tree with per-span duration, EXCLUSIVE time and the critical path
 (docs/OBSERVABILITY.md "Structured tracing").
 
 Usage:
-    python tools/monitor_report.py BENCH_monitor.jsonl [--top 10] [--memory] [--serve] [--fleet] [--slo] [--lifecycle] [--goodput] [--comms] [--moe] [--recsys] [--fallbacks]
+    python tools/monitor_report.py monitor.jsonl [--top 10] [--memory] [--serve] [--fleet] [--slo] [--lifecycle] [--goodput] [--comms] [--moe] [--recsys] [--fallbacks]
     python tools/monitor_report.py --flight flight_recorder_123.json [--last 20]
     python tools/monitor_report.py --trace traces.json [--last 20]
     python tools/monitor_report.py --kernels
@@ -142,35 +138,9 @@ def _table(title: str, headers: List[str],
 
 
 def _comms_section(latest, used) -> List[str]:
-    """--comms: overlapped-vs-exposed comm time per op. Traced pipeline
-    collectives never hit the eager dispatch tracer, so their latency
-    hiding is measured by ``bench.py --multichip`` (serial = the op's
-    back-to-back eager time for the schedule's per-step traffic, exposed
-    = the step-time residual the mesh run actually pays, overlapped =
-    serial − exposed) and published as ``comm_overlap_ms`` gauges."""
+    """--comms: the pipeline schedule's comm model (collectives a step,
+    bubble fraction, counted fallbacks)."""
     out: List[str] = []
-    per: Dict[tuple, dict] = {}
-    for key, row in latest.items():
-        name, labels = key
-        if name != "comm_overlap_ms":
-            continue
-        used.add(key)
-        d = dict(labels)
-        phase = str(d.pop("phase", "?"))
-        per.setdefault(tuple(sorted(d.items())), {})[phase] = \
-            float(row.get("value", 0.0))
-    o_rows = []
-    for labels, d in sorted(per.items()):
-        serial = d.get("serial", 0.0)
-        exposed = d.get("exposed", 0.0)
-        overl = d.get("overlapped", max(0.0, serial - exposed))
-        share = 100.0 * overl / serial if serial > 0 else 0.0
-        o_rows.append([_fmt_labels(labels), f"{serial:,.2f}",
-                       f"{exposed:,.2f}", f"{overl:,.2f}",
-                       f"{share:.0f}%"])
-    out += _table("Comm/compute overlap per op (bench.py --multichip)",
-                  ["op/mesh/schedule", "serial ms", "exposed ms",
-                   "overlapped ms", "hidden"], o_rows)
     m_rows = []
     for key in sorted(latest):
         name, labels = key
@@ -182,9 +152,9 @@ def _comms_section(latest, used) -> List[str]:
                            f"{latest[key].get('value', 0):g}"])
     out += _table("Pipeline schedule comm model",
                   ["metric", "labels", "value"], m_rows)
-    if not o_rows and not m_rows:
-        out.append("(no comm-overlap or pipeline gauges in this dump — "
-                   "run bench.py --multichip with FLAGS_monitor on)")
+    if not m_rows:
+        out.append("(no pipeline gauges in this dump — run a pipeline "
+                   "TrainStep with FLAGS_monitor on)")
         out.append("")
     return out
 
@@ -373,8 +343,8 @@ def _recsys_section(latest, used) -> List[str]:
     out += _table("Recsys sharded-lookup fallbacks",
                   ["counter", "labels", "value"], f_rows)
     if not rows and not f_rows:
-        out.append("(no recsys_* gauges in this dump — run bench.py "
-                   "--recsys or publish_tier_metrics() first)")
+        out.append("(no recsys_* gauges in this dump — call "
+                   "publish_tier_metrics() first)")
         out.append("")
     return out
 
@@ -578,8 +548,8 @@ def _lifecycle_section(latest, used,
 
 
 #: the counted-degradation counters every subsystem publishes when its
-#: primary path cannot serve (docs: PERF_TRANSFORMER/PERF_KERNELS/
-#: PARALLELISM/MOE/RECSYS); one table answers "why is this run slow"
+#: primary path cannot serve (docs: PARALLELISM/MOE/RECSYS; nn/scan.py;
+#: ops/pallas/__init__.py); one table answers "why is this run slow"
 #: instead of five separate counter greps
 _FALLBACK_COUNTERS = ("scan_fallback_total", "pallas_fallback_total",
                       "pipeline_fallback_total", "moe_fallback_total",
@@ -946,7 +916,7 @@ def _serve_section(latest, used, raw_rows: Optional[List[dict]] = None) \
                   ["kind", "peak HBM est."], prog_rows)
     if not out:
         out = ["== Serving ==", "(no serve_* metrics in this dump — "
-               "run bench.py --serve or a ServingEngine first)", ""]
+               "run a ServingEngine first)", ""]
     return out
 
 
@@ -1302,8 +1272,8 @@ def render_kernels() -> str:
     lines = _table("ops.pallas kernel layer (this backend)",
                    ["kernel", "kill switch", "dispatch", "XLA fallback",
                     "fallbacks seen"], rows)
-    lines.append("(docs/PERF_KERNELS.md; persistent fallback counts: "
-                 "pallas_fallback_total in a monitor dump)")
+    lines.append("(paddle_tpu/ops/pallas/__init__.py; persistent "
+                 "fallback counts: pallas_fallback_total in a monitor dump)")
     return "\n".join(lines) + "\n"
 
 
