@@ -56,6 +56,14 @@ int8_matmul         FLAGS_pallas_int8           slim dequant-to-float /
 bgmv                FLAGS_pallas_bgmv           XLA adapter gather +
                                                 einsum shrink/expand
 ==================  ==========================  =========================
+
+Every kernel but one has its operands blocked by BlockSpecs over a grid.
+``paged_decode`` leaves the page pools in HBM and copies from them
+itself: one grid step a (slot, head group), a loop over the slot's LIVE
+table entries (``pos // bs + 1``), several pages a copy group into one
+of two VMEM buffers (a grid over all table entries, one page a step,
+is 98,304 grid steps a decode step of the serve cell, 70% of them past
+the slots' positions).
 """
 
 from __future__ import annotations

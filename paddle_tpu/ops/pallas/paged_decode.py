@@ -11,13 +11,26 @@ slot-contiguous context first: ``gather_pages`` writes a dense
 ``[B, MB*bs, H, D]`` copy of every slot's pages to HBM, the masked SDPA
 reads it back, and most of that traffic is wasted — a slot at position
 ``p`` only owns ``ceil(p/bs)`` of its ``MB`` table entries, the rest
-point at the scratch page. Here the block table IS the access path:
-a scalar-prefetch grid ``(slots, G, MB)`` maps logical block ``j`` of
-slot ``b`` straight to physical page ``table[b, j]`` in the BlockSpec
-index map, so each page is DMA'd from the pool into VMEM exactly once
-and the gathered context never exists in HBM. Blocks past the slot's
-position are compute-skipped (their table entries alias the scratch
-page, so their DMA is a reread of one hot page, not pool traffic).
+point at the scratch page. Here the block table IS the access path,
+and the sweep follows it only as far as the slot lives. The grid is
+``(slots, G)``: a grid step is one slot (and head group), the pools stay
+in HBM (``memory_space=HBM``: no block of them is pipelined), and the
+kernel copies pages itself. Slot ``b`` at position ``p`` owns
+``n = p // bs + 1`` table entries; its step runs ``ceil(n / k)``
+iterations of a ``fori_loop`` whose trip count comes from the
+scalar-prefetched ``pos``, each over ``k`` pages (``_pages_per_step``:
+what fits 256 KB a pool, 8 bf16 pages of 16 x 1024) copied by ``k``
+``make_async_copy`` a pool into one of two VMEM buffers, so that group
+``i + 1`` (or the NEXT grid step's first group) is in flight while group
+``i`` is scored as one ``[k*bs, F]`` tile. Entries past the slot's last
+live one are never read: a last group that runs past ``n`` copies the
+last live page again and its columns are masked, as a live page's tail
+is. Why not a grid over the table's entries, a page a step through a
+BlockSpec index map: at 345M's serve cell that is 98,304 grid steps a
+decode step, 70% of them past the slots' positions at 0.10 us each and
+the live ones 0.54 us for a 64 KB transfer — 23.0 ms where this walk
+takes 4.7 (``PERF.md`` section 6, PR 31). The grid is sequential
+(``arbitrary``): a step hands the next one its first group.
 
 The pool is the engine's own array, ``[N, G, bs, (H/G)*D]``
 (:mod:`paddle_tpu.serving.kv_cache`): a page block is
@@ -29,15 +42,15 @@ layout the array already has — XLA relays nothing around the call. (A
 pool-sized relayout copy on each side of the kernel.) ``N`` counts the
 pages of ALL layers; the caller adds the layer's first page to the table.
 
-Online softmax over the block sweep (running (m, l) row stats per head,
-f32 accumulation), additive key masking by per-slot position — the same
+Online softmax over the sweep's groups (running (m, l) row stats per
+head, f32 accumulation), additive key masking by per-slot position — the same
 math as the fallback's ``cols <= pos`` mask, so decode stays TOKEN-EXACT
 against the dense path (pinned in tests/test_pallas_kernels.py).
 
 All heads of a page ride one program, on the MXU: the query is laid out
 block-diagonally once a slot (row ``h`` holds ``q[h]`` in head ``h``'s
 ``D`` lanes and zeros elsewhere), so ``q_bd @ k^T`` is the per-head score
-``[H/G, bs]`` with no reshape of the fused dim, and ``p @ v``
+``[H/G, k*bs]`` with no reshape of the fused dim, and ``p @ v``
 ``[H/G, (H/G)*D]`` holds head ``h``'s weighted value sum in head ``h``'s
 lanes of row ``h`` (the other lanes are dropped when the slot finishes).
 bf16 pools under a bf16 query multiply in bf16 passes with f32
@@ -102,77 +115,133 @@ def _probs_dot(pr, v, prec):
     return (dot(lo) + dot(mid)) + dot(hi)
 
 
-def _decode_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, bs, hg, D,
+#: bytes of K (and as many of V) one step of the sweep copies from a pool
+#: into one of its two VMEM buffers. A page of 16 x 1024 bf16 values is
+#: 32 KB, 0.04 us of HBM time, a fraction of what starting and awaiting a
+#: step costs: a step takes as many pages as fit here.
+_STEP_BYTES = 256 * 1024
+
+
+def _pages_per_step(bs, F, itemsize, MB):
+    """``k``: the pages of a pool that one step of the sweep copies and
+    scores as ONE ``[k*bs, F]`` tile: what fits ``_STEP_BYTES``, at most
+    the table's width (pages of 16 x 1024: bf16 8, f32 4, int8 16)."""
+    return max(1, min(MB, _STEP_BYTES // (bs * F * itemsize)))
+
+
+def _decode_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, bs, hg, D, k,
                    quant=False):
-    if quant:
-        # int8 pools ride with their per-(row, head) f32 scale blocks
-        (k_ref, ks_ref, v_ref, vs_ref, o_ref,
-         qbd_scr, m_scr, l_scr, acc_scr) = refs
-    else:
-        k_ref, v_ref, o_ref, qbd_scr, m_scr, l_scr, acc_scr = refs
-    b, j = pl.program_id(0), pl.program_id(2)
-    nj = pl.num_programs(2)
+    # the pools in HBM (int8 pools ride with their scale pools:
+    # k, k_scales, v, v_scales), the output row, a [2, k, bs, width]
+    # buffer pair a pool, a DMA semaphore a (pool, buffer), and the
+    # buffer this grid step's first group was copied into
+    n = 4 if quant else 2
+    hbm, o_ref, bufs = refs[:n], refs[n], refs[n + 1:2 * n + 1]
+    sems, slot_ref = refs[2 * n + 1:]
+    b, g = pl.program_id(0), pl.program_id(1)
+    G = pl.num_programs(1)
+    step, steps = b * G + g, pl.num_programs(0) * G
+    MB, F = tbl_ref.shape[1], hg * D
+    cdt = jnp.bfloat16 if (q_ref.dtype == hbm[0].dtype == jnp.bfloat16) \
+        else jnp.float32
     # one bf16 MXU pass is exact for bf16 x bf16; f32 operands need all
     # of their mantissa
-    prec = (jax.lax.Precision.DEFAULT if qbd_scr.dtype == jnp.bfloat16
+    prec = (jax.lax.Precision.DEFAULT if cdt == jnp.bfloat16
             else jax.lax.Precision.HIGHEST)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-        # the query, block-diagonal: row h = q[h] in head h's lanes
-        qbd_scr[:] = jnp.where(
-            _head_lanes(hg, D), q_ref[0, 0].astype(jnp.float32),
-            0.0).astype(qbd_scr.dtype)
+    def last_page(bb):
+        """Index of slot ``bb``'s last live table entry."""
+        return jnp.minimum(pos_ref[bb] // bs, MB - 1)
+
+    def start_group(bb, gg, i, slot):
+        """Start the copies of pages ``i*k .. i*k+k-1`` of (slot ``bb``,
+        head group ``gg``) into buffer ``slot``. Entries past the slot's
+        last live one are clamped to it: the sweep never reads a page
+        the slot does not own, and the columns are masked below."""
+        last = last_page(bb)
+        for t in range(k):
+            page = tbl_ref[bb, jnp.minimum(i * k + t, last)]
+            for j in range(n):
+                pltpu.make_async_copy(hbm[j].at[page, gg],
+                                      bufs[j].at[slot, t],
+                                      sems.at[j, slot]).start()
+
+    def wait_group(slot):
+        for j in range(n):
+            for t in range(k):
+                pltpu.make_async_copy(hbm[j].at[0, g], bufs[j].at[slot, t],
+                                      sems.at[j, slot]).wait()
+
+    @pl.when(step == 0)
+    def _first():
+        slot_ref[0] = 0
+        start_group(b, g, 0, 0)
 
     p = pos_ref[b]
+    groups = last_page(b) // k + 1                 # ceil(live pages / k)
+    slot0 = slot_ref[0]
+    # the query, block-diagonal: row h = q[h] in head h's lanes
+    lanes = _head_lanes(hg, D)
+    q_bd = jnp.where(lanes, q_ref[0, 0].astype(jnp.float32),
+                     0.0).astype(cdt)
 
-    # blocks wholly past the written positions contribute nothing: skip
-    # the compute (their table entries alias the scratch page, so the
-    # page DMA above cost one hot-page reread, not pool bandwidth)
-    @pl.when(j * bs <= p)
-    def _step():
-        k = k_ref[0, 0].astype(qbd_scr.dtype)            # [bs, F]
-        v = v_ref[0, 0].astype(qbd_scr.dtype)
+    def sweep(i, carry):
+        m_prev, l_prev, acc = carry
+        slot = (slot0 + i) % 2
+
+        # the next group is in flight while this one is computed: the
+        # slot's own next, or the first of the next grid step's
+        @pl.when(i + 1 < groups)
+        def _next_group():
+            start_group(b, g, i + 1, 1 - slot)
+
+        @pl.when((i + 1 == groups) & (step + 1 < steps))
+        def _next_slot():
+            start_group((step + 1) // G, (step + 1) % G, 0, 1 - slot)
+
+        wait_group(slot)
+
+        def tile(j, dtype):
+            """Pool ``j``'s k pages as one ``[k*bs, width]`` tile."""
+            return bufs[j][slot].astype(dtype).reshape(k * bs, -1)
+
         if quant:
             # identical math to kv_cache.dequant_pages (each int8 value
             # times its head's scale, in f32), so the kernel stays
             # token-exact against the XLA gather fallback; the scales
-            # [bs, H/G] spread over their heads' lanes exactly: one
+            # [k*bs, H/G] spread over their heads' lanes exactly: one
             # nonzero term a lane
-            lanes = _head_lanes(hg, D).astype(jnp.float32)
-            k = k * jnp.dot(ks_ref[0, 0], lanes, precision=prec)
-            v = v * jnp.dot(vs_ref[0, 0], lanes, precision=prec)
+            spread = lanes.astype(jnp.float32)
+            kk, vv = (tile(j, cdt) * jnp.dot(
+                tile(j + 1, jnp.float32)[:, :hg], spread, precision=prec)
+                for j in (0, 2))
+        else:
+            kk, vv = tile(0, cdt), tile(1, cdt)
         # s[h, c] = q[h] . k[c, head h's lanes]
         s = jax.lax.dot_general(
-            qbd_scr[:], k, (((1,), (1,)), ((), ())), precision=prec,
-            preferred_element_type=jnp.float32) * scale  # [H/G, bs]
-        cols = j * bs + jax.lax.broadcasted_iota(jnp.int32, (hg, bs), 1)
+            q_bd, kk, (((1,), (1,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32) * scale  # [H/G, k*bs]
+        cols = i * (k * bs) + jax.lax.broadcasted_iota(
+            jnp.int32, (hg, k * bs), 1)
         # slot b sees written positions 0..p (current token included) —
         # identical to the fallback's additive key mask
         s = jnp.where(cols <= p, s, NEG_INF)
-        m_prev = m_scr[:, :1]                            # [H/G, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         shift = jnp.where(m_new == NEG_INF, 0.0, m_new)
         pr = jnp.exp(s - shift)                          # masked -> 0
         alpha = jnp.exp(m_prev - shift)
-        l_scr[:] = jnp.broadcast_to(
-            alpha * l_scr[:, :1] + jnp.sum(pr, axis=1, keepdims=True),
-            l_scr.shape)
+        l_new = alpha * l_prev + jnp.sum(pr, axis=1, keepdims=True)
         # acc[h] += pr[h] @ v: head h's sum lands in head h's lanes
-        pv = _probs_dot(pr, v, prec)                     # [H/G, F]
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        return m_new, l_new, acc * alpha + _probs_dot(pr, vv, prec)
 
-    @pl.when(j == nj - 1)
-    def _finish():
-        l = l_scr[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)             # inactive slot
-        o_ref[0, 0] = jnp.sum(
-            jnp.where(_head_lanes(hg, D), acc_scr[:] / safe_l, 0.0),
-            axis=0, keepdims=True).astype(o_ref.dtype)
+    _, l, acc = jax.lax.fori_loop(
+        0, groups, sweep,
+        (jnp.full((hg, 1), NEG_INF, jnp.float32),
+         jnp.zeros((hg, 1), jnp.float32), jnp.zeros((hg, F), jnp.float32)))
+    slot_ref[0] = (slot0 + groups) % 2
+    safe_l = jnp.where(l == 0.0, 1.0, l)                 # inactive slot
+    o_ref[0, 0] = jnp.sum(jnp.where(lanes, acc / safe_l, 0.0),
+                          axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 def _paged_decode(q, pools, block_table, pos, *, scale, quant, name):
@@ -181,37 +250,42 @@ def _paged_decode(q, pools, block_table, pos, *, scale, quant, name):
     B, H, D = q.shape
     _, G, bs, F = pools[0].shape
     hg = H // G
-    MB = block_table.shape[1]
-    # bf16 end to end only when query and pool both are
-    cdt = (jnp.bfloat16 if q.dtype == pools[0].dtype == jnp.bfloat16
-           else jnp.float32)
-
-    def page(width):
-        return pl.BlockSpec((1, 1, bs, width),
-                            lambda b, g, j, tbl, p: (tbl[b, j], g, 0, 0))
+    k = _pages_per_step(bs, F, pools[0].dtype.itemsize,
+                        block_table.shape[1])
+    if quant:
+        # a copy out of HBM moves whole 128-lane tiles and a row of
+        # scales is H/G wide: the scale pools go in padded to a tile (a
+        # pool-sized copy a call, which lane-dense scale pools in
+        # kv_cache would end; no cell runs int8 pages)
+        lanes = ((0, 0),) * 3 + ((0, -hg % 128),)
+        pools = (pools[0], jnp.pad(pools[1], lanes),
+                 pools[2], jnp.pad(pools[3], lanes))
 
     def row():
-        return pl.BlockSpec((1, 1, 1, F), lambda b, g, j, tbl, p: (b, g, 0, 0))
+        return pl.BlockSpec((1, 1, 1, F), lambda b, g, tbl, p: (b, g, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                           # table, pos
-        grid=(B, G, MB),
-        in_specs=[row()] + [page(a.shape[-1]) for a in pools],
+        grid=(B, G),
+        # the pools stay where they are: the kernel copies the pages
+        # the table names, and nothing else of them moves
+        in_specs=[row()] + [pl.BlockSpec(memory_space=pltpu.HBM)
+                            for _ in pools],
         out_specs=row(),
-        scratch_shapes=[
-            pltpu.VMEM((hg, F), cdt),
-            pltpu.VMEM((hg, 8), jnp.float32),
-            pltpu.VMEM((hg, 8), jnp.float32),
-            pltpu.VMEM((hg, F), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((2, k, bs, a.shape[-1]), a.dtype)
+                        for a in pools] + [
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=float(scale), bs=bs,
-                          hg=hg, D=D, quant=quant),
+                          hg=hg, D=D, k=k, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, G, 1, F), q.dtype),
+        # a grid step starts the copies of the next one's first group
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),
         name=name,
     )(block_table.astype(jnp.int32), pos.astype(jnp.int32),
@@ -242,10 +316,10 @@ def paged_decode_attention_quant(q, k_pages, k_scales, v_pages, v_scales,
 
     Same contract as :func:`paged_decode_attention`, plus the parallel
     f32 scale pools ``k_scales``/``v_scales`` ``[N, G, bs, H/G]``. The
-    scale blocks ride the SAME block-table index maps as their pages, so
-    the dequantize (``int8 * scale``) happens in VMEM right before the
-    existing online-softmax sweep — the dequantized context never exists
-    in HBM. Must match ``kv_cache.gather_pages_quant`` + masked SDPA
+    scale blocks are copied by the SAME page ids as their pages, so the
+    dequantize (``int8 * scale``) happens in VMEM right before the
+    online-softmax update — the dequantized context never exists in
+    HBM. Must match ``kv_cache.gather_pages_quant`` + masked SDPA
     token-exactly (same dequant math, f32 accumulation).
     """
     return _paged_decode(q, (k_pages, k_scales, v_pages, v_scales),

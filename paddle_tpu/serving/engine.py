@@ -320,6 +320,7 @@ class ServingEngine:
         self._step_seq = 0
         self._stats = {"prefill_dispatches": 0, "decode_dispatches": 0,
                        "decode_slot_steps": 0, "decode_batch_max": 0,
+                       "decode_live_pages": 0, "decode_table_pages": 0,
                        "tokens_generated": 0, "program_compiles": 0,
                        "prefill_chunks": 0, "prefill_tokens": 0,
                        "verify_dispatches": 0, "spec_proposed": 0,
@@ -2041,6 +2042,12 @@ class ServingEngine:
                 active[slot] = True
                 per_slot[slot] = st
             n_active = int(active.sum())
+            # how much of the block table this step's attention walks:
+            # a slot at position p owns p // block_size + 1 entries of
+            # its table row (the paged-decode kernel sweeps those only)
+            live_pages = int((pos[active] // self.cache.block_size + 1)
+                             .sum())
+            table_pages = n_active * self.cache.max_blocks_per_slot
             t0 = self.clock()
             prog = self._get_decode()
             temps, tks, tps = self._sampling_arrays(per_slot)
@@ -2068,8 +2075,17 @@ class ServingEngine:
             st_["decode_slot_steps"] += n_active
             st_["decode_batch_max"] = max(st_["decode_batch_max"],
                                           n_active)
+            st_["decode_live_pages"] += live_pages
+            st_["decode_table_pages"] += table_pages
             self._observe("decode_step", dt)
             reg = get_registry()
+            reg.counter("serve_decode_live_pages_total",
+                        "block-table entries the active slots of decode "
+                        "steps own (pos // block_size + 1 a slot)"
+                        ).inc(live_pages)
+            reg.counter("serve_decode_table_pages_total",
+                        "block-table entries of those slots' rows "
+                        "(active slots x table width)").inc(table_pages)
             reg.histogram("serve_decode_step_seconds",
                           "decode dispatch wall time (all slots)"
                           ).observe(dt)
@@ -2304,6 +2320,11 @@ class ServingEngine:
                 self._stats["decode_slot_steps"]
                 / self._stats["decode_dispatches"]
                 if self._stats["decode_dispatches"] else None),
+            # share of the decode steps' block-table rows that was live
+            "serve_decode_live_pages_total":
+                self._stats["decode_live_pages"],
+            "serve_decode_table_pages_total":
+                self._stats["decode_table_pages"],
             "ttft_p99_s": pct(lat["ttft"], 99),
             "prefill_tokens": self._stats["prefill_tokens"],
             "prefill_chunks": self._stats["prefill_chunks"],

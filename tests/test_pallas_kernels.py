@@ -368,6 +368,96 @@ def test_paged_decode_kernel_parity(G, dtype, quant, layer, tol):
                                np.asarray(ref), rtol=tol, atol=tol)
 
 
+#: the page walk's cases: pools whose pages are 32 KB, so a sweep step
+#: takes k = 8 of a 20-entry table row (three groups, the last one short):
+#: (pool dtype, head groups, block size); q is [B, 8 * G, 128]
+WALKS = {"plain": (jnp.float32, 1, 8), "int8": (jnp.int8, 1, 32),
+         "two-groups": (jnp.float32, 2, 8)}
+WALK_MB, WALK_HG, WALK_D = 20, 8, 128
+#: a slot's position on every edge of a page, of a group of k pages and
+#: of the table, from (k, bs, MB)
+WALK_EDGES = {"pos-0": lambda k, bs, MB: 0,
+              "page-end": lambda k, bs, MB: bs - 1,
+              "page-start": lambda k, bs, MB: bs,
+              "group-end": lambda k, bs, MB: k * bs - 1,
+              "group-start": lambda k, bs, MB: k * bs,
+              "table-end": lambda k, bs, MB: MB * bs - 1}
+
+
+def _walk_case(variant, edges):
+    """Random pools ``[N, G, bs, 1024]`` (every page holds finite values,
+    owned or not), a slot an edge of ``edges`` owning ``pos // bs + 1``
+    distinct pages, then ONE INACTIVE slot: position 0, its whole row
+    the scratch page; page ``N - 1`` is nobody's. Returns ``(kernel, q,
+    pools, tbl, pos)``."""
+    from paddle_tpu.ops.pallas import paged_decode as pd
+    dtype, G, bs = WALKS[variant]
+    quant = dtype == jnp.int8
+    F = WALK_HG * WALK_D
+    k = pd._pages_per_step(bs, F, jnp.dtype(dtype).itemsize, WALK_MB)
+    assert 1 < k < WALK_MB and WALK_MB % k, "the walk needs several groups"
+    pos = np.array([WALK_EDGES[e](k, bs, WALK_MB) for e in edges] + [0],
+                   np.int32)
+    B, rng = len(pos), np.random.RandomState(3)
+    N = 2 + (B - 1) * WALK_MB
+    tbl = np.zeros((B, WALK_MB), np.int32)
+    for b in range(B - 1):
+        n = pos[b] // bs + 1
+        tbl[b, :n] = 1 + b * WALK_MB + rng.permutation(WALK_MB)[:n]
+    pools = []
+    for _ in "kv":
+        if quant:
+            pools += [jnp.asarray(rng.randint(-127, 128, (N, G, bs, F))
+                                  .astype(np.int8)),
+                      jnp.asarray(rng.uniform(0.01, 0.03,
+                                              (N, G, bs, WALK_HG))
+                                  .astype(np.float32))]
+        else:
+            pools.append(jnp.asarray(rng.randn(N, G, bs, F)
+                                     .astype(np.float32)))
+    q = jnp.asarray(rng.randn(B, WALK_HG * G, WALK_D).astype(np.float32))
+    kernel = (pd.paged_decode_attention_quant if quant
+              else pd.paged_decode_attention)
+    return kernel, q, tuple(pools), jnp.asarray(tbl), jnp.asarray(pos)
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("edge", list(WALK_EDGES) + ["all-in-one-call"])
+@pytest.mark.parametrize("variant", list(WALKS))
+def test_paged_decode_walk_parity(variant, edge):
+    """The sweep follows a slot's live pages, k pages a step: a position
+    on every edge of a page and of a page group, a table width k does
+    not divide (the last group is clamped and masked), an inactive slot
+    on the scratch page beside it, slots of very different lengths in
+    one call — against the gather fallback."""
+    kernel, q, pools, tbl, pos = _walk_case(
+        variant, WALK_EDGES if edge == "all-in-one-call" else [edge])
+    scale = 1.0 / np.sqrt(WALK_D)
+    ref = _dense_decode_ref(q, pools, tbl, pos, 0, scale)
+    got = kernel(q, *pools, tbl, pos, scale=scale)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.pallas
+def test_paged_decode_never_reads_past_the_live_pages():
+    """Table entries past a slot's last live one never reach the
+    arithmetic: pointed at a page of NaN (a masked score is replaced,
+    but 0 * NaN in ``p @ v`` would not be), the output is what the
+    scratch page there gives, bit for bit, and finite."""
+    kernel, q, pools, tbl, pos = _walk_case("plain", WALK_EDGES)
+    spare, bs = pools[0].shape[0] - 1, pools[0].shape[2]
+    poisoned = tuple(p.at[spare].set(jnp.nan) for p in pools)
+    dead = jnp.arange(WALK_MB)[None, :] > (pos // bs)[:, None]
+    assert int(dead.sum()) > 0
+    scale = 1.0 / np.sqrt(WALK_D)
+    want = kernel(q, *pools, tbl, pos, scale=scale)
+    got = kernel(q, *poisoned, jnp.where(dead, spare, tbl), pos,
+                 scale=scale)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 @pytest.mark.pallas
 def test_paged_decode_bf16_keeps_f32_probabilities():
     """The bf16 path multiplies in bf16 passes but rounds nothing the

@@ -469,6 +469,30 @@ def test_metrics_flow_through_registry(tiny_model):
     assert summary["decode_step_p99_s"] >= summary["decode_step_p50_s"]
 
 
+def test_decode_live_page_counters(tiny_model):
+    """``serve_decode_live_pages_total / serve_decode_table_pages_total``
+    is the share of the decode steps' block-table rows that was live,
+    counted on the host from the positions the step was built with. A
+    request of prompt length n decodes tokens 1..3 at positions n, n+1,
+    n+2 (prefill gave token 0), each owning ``pos // 4 + 1`` of its row's
+    16 entries — whichever step each slot's decode ran in."""
+    lens, new, bs, width = (3, 7, 14), 4, 4, 64 // 4
+    with scoped_registry() as reg:
+        eng = _engine(tiny_model)
+        rng = np.random.default_rng(12)
+        eng.generate([rng.integers(2, 250, (n,)).astype(np.int32)
+                      for n in lens], max_new_tokens=new)
+        live = sum((n + t) // bs + 1 for n in lens for t in range(new - 1))
+        table = len(lens) * (new - 1) * width
+        assert eng._stats["decode_slot_steps"] == len(lens) * (new - 1)
+        assert reg.get("serve_decode_live_pages_total").value() == live
+        assert reg.get("serve_decode_table_pages_total").value() == table
+    summary = eng.metrics_summary()
+    assert (summary["serve_decode_live_pages_total"]
+            / summary["serve_decode_table_pages_total"]) == live / table
+    assert live / table == pytest.approx(26 / 144)
+
+
 def test_loadgen_deterministic_and_open_loop():
     spec = LoadSpec(num_requests=5, rate_rps=100.0,
                     prompt_len_range=(4, 8), max_new_range=(2, 4),

@@ -193,26 +193,35 @@ def test_chunked_ce_compiles(chip, compile_for_chip, dtype, grad):
                     *(["chunked_ce_dlogits"] if grad else []))
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=["plain", "int8"])
-@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
-def test_paged_decode_compiles(chip, compile_for_chip, dtype, quant):
+@pytest.mark.parametrize("dtype,quant,slots,table,pool", [
+    (BF16, False, SLOTS, TABLE, POOL), (F32, False, SLOTS, TABLE, POOL),
+    (BF16, True, SLOTS, TABLE, POOL), (F32, True, SLOTS, TABLE, POOL),
+    (BF16, False, 64, (64, 64), (24 * 4097, 1, 16, 1024)),
+], ids=["bf16-plain", "f32-plain", "bf16-int8", "f32-int8",
+        "bf16-plain-closed64"])
+def test_paged_decode_compiles(chip, compile_for_chip, dtype, quant, slots,
+                               table, pool):
     """Refused until ISSUE 22: a dot_general batched over a non-leading
-    dimension is not a form Mosaic parses."""
+    dimension is not a form Mosaic parses. Since ISSUE 31 the kernel
+    copies its pages itself (a loop of ``make_async_copy`` out of pools
+    left in HBM, two VMEM buffers a pool): the serve cell's own call —
+    64 slots, a table of 64 entries, the pools of all 24 layers — is the
+    last case."""
     pd = _kernel("paged_decode")
-    q = chip((SLOTS, 16, 64), dtype)
-    table, pos = chip(TABLE, I32), chip((SLOTS,), I32)
+    q = chip((slots, 16, 64), dtype)
+    table, pos = chip(table, I32), chip((slots,), I32)
     if quant:
-        pool, scales = chip(POOL, I8), chip(POOL[:3] + (16,), F32)
+        pages, scales = chip(pool, I8), chip(pool[:3] + (16,), F32)
         text = compile_for_chip(
             lambda q, k, ks, v, vs, t, p: pd.paged_decode_attention_quant(
                 q, k, ks, v, vs, t, p, scale=0.125),
-            q, pool, scales, pool, scales, table, pos)
+            q, pages, scales, pages, scales, table, pos)
     else:
-        pool = chip(POOL, dtype)
+        pages = chip(pool, dtype)
         text = compile_for_chip(
             lambda q, k, v, t, p: pd.paged_decode_attention(
                 q, k, v, t, p, scale=0.125),
-            q, pool, pool, table, pos)
+            q, pages, pages, table, pos)
     _assert_kernels(text, "paged_decode_int8" if quant else "paged_decode")
 
 
