@@ -8,7 +8,8 @@ vocab 50304) trains and serves with:
   kernels  each main-path Pallas kernel COMPILED (never interpreted)
            against its XLA reference: flash attention forward and
            backward in bf16 and the f32 forward, chunked cross-entropy,
-           paged decode
+           paged decode (a K/V head a query head; 16 query heads a K/V
+           head under a window)
 
 That a TrainStep, a ServingEngine or a four-chip mesh still runs on the
 chip, and still agrees with a plain reference, is what the benchmark's
@@ -50,11 +51,17 @@ REAL = dict(
     flash_train=(8, 1024, 16, 64), flash_prefill=(4, 256, 16, 64),
     ce_logits=(8192, 50304), ce_chunk=8192,
     paged=dict(slots=8, heads=16, head_dim=64, block=16, blocks=32),
+    # command_a_plus_ep8's window layers: 128 query heads on 8 K/V heads,
+    # a window of 4,096 over a table of 28,672 positions, 385 pages a slot
+    paged_window=dict(slots=24, heads=128, kv_heads=8, head_dim=128,
+                      block=16, blocks=1792, window=4096),
 )
 REHEARSE = dict(
     flash_train=(1, 256, 2, 64), flash_prefill=(1, 256, 2, 64),
     ce_logits=(16, 384), ce_chunk=128,
     paged=dict(slots=2, heads=2, head_dim=8, block=4, blocks=4),
+    paged_window=dict(slots=3, heads=4, kv_heads=2, head_dim=8, block=4,
+                      blocks=16, window=8),
 )
 
 # -- tolerances, per dtype ---------------------------------------------------
@@ -318,6 +325,57 @@ def phase_kernels(args, cfg):
         out_r = jax.jit(paged_ref)(*(x.astype(jnp.float32) for x in lo),
                                    table, pos)
         report(f"paged_decode q{(B, H, D)} pool{(P, 1, bs, H * D)} {name}",
+               [rel_err(out_k, out_r)], TOL[name])
+
+    # -- grouped K/V heads under a window: the sweep starts at the window's
+    #    first page, entries before it point at the scratch page ----------
+    p = cfg["paged_window"]
+    B, H, Hkv, D, bs, MB, W = (p["slots"], p["heads"], p["kv_heads"],
+                               p["head_dim"], p["block"], p["blocks"],
+                               p["window"])
+    live = (W + bs - 1) // bs + 1                # entries a window touches
+    P = B * live + 1
+    L = MB * bs
+    # inside the window, at its edge, past it with the first visible
+    # position inside a page and on a page's first row, the table's end
+    pos = np.resize(np.array([0, W - 1, W, W + bs // 2, 2 * W + bs - 1,
+                              L - 1, W // 2, L // 2 + 3]), B).astype(np.int32)
+    first = np.maximum(pos - W + 1, 0)
+    table = np.zeros((B, MB), np.int32)
+    for b in range(B):
+        e0, e1 = first[b] // bs, pos[b] // bs
+        table[b, e0:e1 + 1] = 1 + b * live + np.arange(e1 - e0 + 1)
+    e0 = jnp.asarray(first // bs)
+    table, pos, first = (jnp.asarray(a) for a in (table, pos, first))
+    scale = 1.0 / float(np.sqrt(D))
+
+    def window_ref(q, k, v, table, pos, first):
+        # the window's entries alone: a dense copy of the table's 28,672
+        # positions a slot would not fit
+        ent = jnp.minimum(e0[:, None] + jnp.arange(live)[None, :], MB - 1)
+        tbl = jnp.take_along_axis(table, ent, axis=1)
+        gk, gv = gather_pages(k, tbl, D), gather_pages(v, tbl, D)
+        cols = (e0 * bs)[:, None] + jnp.arange(live * bs)[None, :]
+        ok = (cols <= pos[:, None]) & (cols >= first[:, None])
+        with jax.default_matmul_precision("highest"):
+            s = jnp.einsum("bngd,bknd->bngk",
+                           q.reshape(B, Hkv, H // Hkv, D), gk) * scale
+            pr = jax.nn.softmax(jnp.where(ok[:, None, None], s, -1e30), -1)
+            return jnp.einsum("bngk,bknd->bngd", pr, gv).reshape(B, H, D)
+
+    for dtype in (jnp.float32, jnp.bfloat16):
+        name = jnp.dtype(dtype).name
+        lo = (normal((B, H, D)).astype(dtype),
+              normal((P, 1, bs, Hkv * D)).astype(dtype),
+              normal((P, 1, bs, Hkv * D)).astype(dtype))
+        out_k = run_kernel(
+            lambda q, k, v, t, p, f: pd.paged_decode_attention(
+                q, k, v, t, p, scale=scale, first=f),
+            *lo, table, pos, first, want=("paged_decode",))
+        out_r = jax.jit(window_ref)(*(x.astype(jnp.float32) for x in lo),
+                                    table, pos, first)
+        report(f"paged_decode q{(B, H, D)} on {Hkv} K/V heads, window {W}, "
+               f"pool{(P, 1, bs, Hkv * D)} {name}",
                [rel_err(out_k, out_r)], TOL[name])
 
 

@@ -25,12 +25,19 @@ from ..core.tensor import Tensor, apply
 
 
 def _sdpa_xla(q, k, v, mask, dropout_p, is_causal, dropout_key):
-    """Reference composition: works on [B, S, H, D]."""
+    """Reference composition: works on [B, S, H, D]; ``k``/``v`` may
+    hold ``Hkv`` heads, ``Hkv | H`` (query head ``h`` reads K/V head
+    ``h // (H / Hkv)``), and are not repeated."""
     B, Sq, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Hkv = k.shape[1], k.shape[2]
     prec = matmul_precision()
     scale = 1.0 / math.sqrt(D)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=prec) * scale
+    if Hkv != H:
+        scores = jnp.einsum(
+            "bqngd,bknd->bngqk", q.reshape(B, Sq, Hkv, H // Hkv, D), k,
+            precision=prec).reshape(B, H, Sq, Sk) * scale
+    else:
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=prec) * scale
     if is_causal:
         causal = jnp.tril(jnp.ones((Sq, Sk), bool), k=Sk - Sq)
         scores = jnp.where(causal[None, None], scores, -1e30)
@@ -43,6 +50,10 @@ def _sdpa_xla(q, k, v, mask, dropout_p, is_causal, dropout_key):
     if dropout_p > 0.0 and dropout_key is not None:
         keep = jax.random.bernoulli(dropout_key, 1.0 - dropout_p, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0)
+    if Hkv != H:
+        return jnp.einsum(
+            "bngqk,bknd->bqngd", probs.reshape(B, Hkv, H // Hkv, Sq, Sk), v,
+            precision=prec).reshape(B, Sq, H, D)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v, precision=prec)
 
 

@@ -63,7 +63,9 @@ itself: one grid step a (slot, head group), a loop over the slot's LIVE
 table entries (``pos // bs + 1``), several pages a copy group into one
 of two VMEM buffers (a grid over all table entries, one page a step,
 is 98,304 grid steps a decode step of the serve cell, 70% of them past
-the slots' positions).
+the slots' positions). Query heads may share a K/V head (the query is
+laid out block-diagonally over a K/V head's lanes) and a slot may have a
+first visible position (a window: the sweep starts at its table entry).
 """
 
 from __future__ import annotations

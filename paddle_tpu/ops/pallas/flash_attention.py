@@ -243,8 +243,9 @@ def _mk_kernel(kern, has_bias, n_in=3, lse_out=True, has_seed=False, **kw):
 
 
 def _fwd_v1(q, k, v, bias, scale, causal, block_q, block_k,
-            save_residuals=True, seed=None, rate=0.0):
-    """q,k,v: [B, H, S, D]. Returns (o, lse[B, H, S, 8]) — the lse rows
+            save_residuals=True, seed=None, rate=0.0, kv_group=1):
+    """q: [B, H, S, D]; k,v: [B, H/kv_group, S, D] (query head ``h`` reads
+    K/V head ``h // kv_group``). Returns (o, lse[B, H, S, 8]) — the lse rows
     stay in the narrow tile exactly as the kernel wrote them so the backward
     can consume them without an XLA re-broadcast; lse is None when
     save_residuals=False (inference: no lse write, saves S*128 f32 HBM
@@ -254,7 +255,9 @@ def _fwd_v1(q, k, v, bias, scale, causal, block_q, block_k,
     nq, nk = Sq // block_q, Sk // block_k
 
     qs = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
-    ks = pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h, j, 0))
+    ks = pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h, j, 0)) \
+        if kv_group == 1 else pl.BlockSpec(
+            (1, 1, block_k, D), lambda b, h, i, j: (b, h // kv_group, j, 0))
     in_specs = []
     args = []
     if rate > 0.0:
@@ -397,8 +400,10 @@ def _fwd2_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _fwd2(q, k, v, scale, causal, block_q, block_k, hp, width,
-          save_residuals=True, seed=None, rate=0.0, block_b=4):
-    """q,k,v: [B, S, E]. Returns (o [B,S,E], lse [B,H,Sq,8] or None)."""
+          save_residuals=True, seed=None, rate=0.0, block_b=4, kv_group=1):
+    """q: [B, S, E]; k,v: [B, S, E / kv_group] (one head a column block:
+    query block ``h`` reads K/V block ``h // kv_group``). Returns
+    (o [B,S,E], lse [B,H,Sq,8] or None)."""
     B, Sq, E = q.shape
     Sk = k.shape[1]
     D = width // hp
@@ -409,7 +414,9 @@ def _fwd2(q, k, v, scale, causal, block_q, block_k, hp, width,
     bb = max(block_b, 1)
 
     qs = pl.BlockSpec((bb, block_q, width), lambda b, h, i, j: (b, i, h))
-    ks = pl.BlockSpec((bb, block_k, width), lambda b, h, i, j: (b, j, h))
+    ks = pl.BlockSpec((bb, block_k, width), lambda b, h, i, j: (b, j, h)) \
+        if kv_group == 1 else pl.BlockSpec(
+            (bb, block_k, width), lambda b, h, i, j: (b, j, h // kv_group))
     in_specs = []
     args = []
     if rate > 0.0:
@@ -873,20 +880,29 @@ def _v2_plan(q, bias, block_q, block_k):
 def _fwd(q, k, v, bias, scale, causal, block_q, block_k,
          save_residuals=True, seed=None, rate=0.0):
     """Route [B, S, H, D] inputs to the layout-native v2 kernels (no
-    transpose materializes) or the v1 [B, H, S, D] kernels (bias case)."""
+    transpose materializes) or the v1 [B, H, S, D] kernels (bias case;
+    grouped K/V heads that share a lane block). ``k``/``v`` may hold
+    fewer heads than ``q``: query head ``h`` reads K/V head
+    ``h // (H / Hkv)``, through the K/V blocks' index maps — nothing is
+    repeated in HBM."""
+    kv_group = q.shape[2] // k.shape[2]
     plan = _v2_plan(q, bias, block_q, block_k)
+    if plan is not None and kv_group > 1 and plan[0] != 1:
+        plan = None
     if plan is not None:
         hp, width, bb_fwd, _ = plan
         B, Sq, H, D = q.shape
         E = H * D
-        o, lse = _fwd2(q.reshape(B, Sq, E), k.reshape(B, k.shape[1], E),
-                       v.reshape(B, v.shape[1], E), scale, causal, block_q,
+        o, lse = _fwd2(q.reshape(B, Sq, E), k.reshape(B, k.shape[1], -1),
+                       v.reshape(B, v.shape[1], -1), scale, causal, block_q,
                        block_k, hp, width, save_residuals=save_residuals,
-                       seed=seed, rate=rate, block_b=bb_fwd)
+                       seed=seed, rate=rate, block_b=bb_fwd,
+                       kv_group=kv_group)
         return o.reshape(q.shape), lse
     qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
     o, lse = _fwd_v1(qt, kt, vt, bias, scale, causal, block_q, block_k,
-                     save_residuals=save_residuals, seed=seed, rate=rate)
+                     save_residuals=save_residuals, seed=seed, rate=rate,
+                     kv_group=kv_group)
     return jnp.swapaxes(o, 1, 2), lse
 
 
@@ -984,10 +1000,21 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
     dropout_rate/dropout_key: in-kernel attention dropout via a stateless
     counter-based hash (works on TPU and in the interpreter); masks are
     regenerated from the seed in the backward, nothing is stored.
+    ``k``/``v`` may be ``[B, Sk, Hkv, D]`` with ``Hkv`` dividing ``H``
+    (grouped-query attention: query head ``h`` reads K/V head
+    ``h // (H / Hkv)``): the forward alone, for serving — no gradient,
+    no dropout.
     Returns [B, S, H, D].
     """
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
+    grouped = k.shape[2] != H
+    if grouped and (H % k.shape[2] or v.shape[2] != k.shape[2]
+                    or dropout_rate):
+        raise ValueError(
+            f"flash attention: {H} query heads over {k.shape[2]} K and "
+            f"{v.shape[2]} V heads (dropout {dropout_rate}): grouped heads "
+            "need Hkv | H, the same for K and V, and no dropout")
     # tuning override without touching call sites (block sweeps on real
     # hardware). Only applied when the caller left the block size at its
     # default — an explicit block_q/block_k argument always wins over the
@@ -1016,5 +1043,8 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
             words.astype(jnp.uint32), jnp.float32)
     else:
         seed_f = jnp.zeros((2,), jnp.float32)
+    if grouped:
+        return _fwd(q, k, v, bias, float(scale), bool(causal), int(block_q),
+                    int(block_k), save_residuals=False)[0]
     return _flash(q, k, v, bias, seed_f, float(scale), bool(causal),
                   int(block_q), int(block_k), rate)

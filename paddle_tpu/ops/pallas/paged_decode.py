@@ -86,11 +86,15 @@ __all__ = ["paged_decode_attention", "paged_decode_attention_quant"]
 NEG_INF = -1e30
 
 
-def _head_lanes(hg, D):
-    """``[H/G, (H/G)*D]`` bool: lane ``f`` of the fused dim belongs to
-    head ``f // D`` (compares, no vector division)."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, (hg, hg * D), 1)
-    head = jax.lax.broadcasted_iota(jnp.int32, (hg, hg * D), 0)
+def _head_lanes(hg, D, kv_group=1):
+    """``[H/G, F]`` bool: lane ``f`` of the fused dim belongs to K/V head
+    ``f // D``, which query heads ``kv_group * (f // D) ..`` read — one
+    each when ``kv_group`` is 1 (compares, no vector division by ``D``)."""
+    F = hg // kv_group * D
+    lane = jax.lax.broadcasted_iota(jnp.int32, (hg, F), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (hg, F), 0)
+    if kv_group > 1:
+        head = head // kv_group
     return (lane >= head * D) & (lane < (head + 1) * D)
 
 
@@ -129,19 +133,23 @@ def _pages_per_step(bs, F, itemsize, MB):
     return max(1, min(MB, _STEP_BYTES // (bs * F * itemsize)))
 
 
-def _decode_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, bs, hg, D, k,
-                   quant=False):
-    # the pools in HBM (int8 pools ride with their scale pools:
+def _decode_kernel(tbl_ref, pos_ref, *refs, scale, bs, hg, D, k,
+                   quant=False, kv_group=1, windowed=False):
+    # the slots' first visible positions (a third prefetched scalar array,
+    # under a window only), the query, the pools in HBM (int8 pools ride with their scale pools:
     # k, k_scales, v, v_scales), the output row, a [2, k, bs, width]
     # buffer pair a pool, a DMA semaphore a (pool, buffer), and the
     # buffer this grid step's first group was copied into
+    first_ref, refs = (refs[0], refs[1:]) if windowed else (None, refs)
+    q_ref, refs = refs[0], refs[1:]
     n = 4 if quant else 2
     hbm, o_ref, bufs = refs[:n], refs[n], refs[n + 1:2 * n + 1]
     sems, slot_ref = refs[2 * n + 1:]
     b, g = pl.program_id(0), pl.program_id(1)
     G = pl.num_programs(1)
     step, steps = b * G + g, pl.num_programs(0) * G
-    MB, F = tbl_ref.shape[1], hg * D
+    n_kv = hg // kv_group
+    MB, F = tbl_ref.shape[1], n_kv * D
     cdt = jnp.bfloat16 if (q_ref.dtype == hbm[0].dtype == jnp.bfloat16) \
         else jnp.float32
     # one bf16 MXU pass is exact for bf16 x bf16; f32 operands need all
@@ -153,14 +161,23 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, bs, hg, D, k,
         """Index of slot ``bb``'s last live table entry."""
         return jnp.minimum(pos_ref[bb] // bs, MB - 1)
 
+    def first_page(bb):
+        """Index of the first table entry slot ``bb``'s sweep reads: the
+        page of its first visible position under a window, else 0."""
+        return first_ref[bb] // bs if windowed else 0
+
     def start_group(bb, gg, i, slot):
-        """Start the copies of pages ``i*k .. i*k+k-1`` of (slot ``bb``,
-        head group ``gg``) into buffer ``slot``. Entries past the slot's
-        last live one are clamped to it: the sweep never reads a page
-        the slot does not own, and the columns are masked below."""
+        """Start the copies of pages ``i*k .. i*k+k-1`` of the sweep of
+        (slot ``bb``, head group ``gg``) into buffer ``slot``. Entries
+        past the slot's last live one are clamped to it: the sweep never
+        reads a page the slot does not own, and the columns are masked
+        below."""
         last = last_page(bb)
         for t in range(k):
-            page = tbl_ref[bb, jnp.minimum(i * k + t, last)]
+            entry = i * k + t
+            if windowed:
+                entry = first_page(bb) + entry
+            page = tbl_ref[bb, jnp.minimum(entry, last)]
             for j in range(n):
                 pltpu.make_async_copy(hbm[j].at[page, gg],
                                       bufs[j].at[slot, t],
@@ -178,12 +195,18 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, bs, hg, D, k,
         start_group(b, g, 0, 0)
 
     p = pos_ref[b]
-    groups = last_page(b) // k + 1                 # ceil(live pages / k)
+    # ceil(pages of the sweep / k)
+    groups = ((last_page(b) - first_page(b)) // k + 1 if windowed
+              else last_page(b) // k + 1)
     slot0 = slot_ref[0]
-    # the query, block-diagonal: row h = q[h] in head h's lanes
-    lanes = _head_lanes(hg, D)
-    q_bd = jnp.where(lanes, q_ref[0, 0].astype(jnp.float32),
-                     0.0).astype(cdt)
+    # the query, block-diagonal: row h = q[h] in the lanes of the K/V
+    # head it reads (its own when kv_group is 1)
+    lanes = _head_lanes(hg, D, kv_group)
+    q_row = q_ref[0, 0].astype(jnp.float32)
+    if kv_group > 1:
+        # [H/G, D] rows, repeated under every K/V head's lanes
+        q_row = jnp.concatenate([q_row] * n_kv, axis=1)
+    q_bd = jnp.where(lanes, q_row, 0.0).astype(cdt)
 
     def sweep(i, carry):
         m_prev, l_prev, acc = carry
@@ -224,8 +247,13 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, bs, hg, D, k,
         cols = i * (k * bs) + jax.lax.broadcasted_iota(
             jnp.int32, (hg, k * bs), 1)
         # slot b sees written positions 0..p (current token included) —
-        # identical to the fallback's additive key mask
-        s = jnp.where(cols <= p, s, NEG_INF)
+        # identical to the fallback's additive key mask; under a window,
+        # from its first visible position on
+        seen = cols <= p
+        if windowed:
+            cols = first_page(b) * bs + cols
+            seen = (cols <= p) & (cols >= first_ref[b])
+        s = jnp.where(seen, s, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         shift = jnp.where(m_new == NEG_INF, 0.0, m_new)
         pr = jnp.exp(s - shift)                          # masked -> 0
@@ -240,16 +268,36 @@ def _decode_kernel(tbl_ref, pos_ref, q_ref, *refs, scale, bs, hg, D, k,
          jnp.zeros((hg, 1), jnp.float32), jnp.zeros((hg, F), jnp.float32)))
     slot_ref[0] = (slot0 + groups) % 2
     safe_l = jnp.where(l == 0.0, 1.0, l)                 # inactive slot
-    o_ref[0, 0] = jnp.sum(jnp.where(lanes, acc / safe_l, 0.0),
-                          axis=0, keepdims=True).astype(o_ref.dtype)
+    out = jnp.where(lanes, acc / safe_l, 0.0)
+    if kv_group > 1:
+        # row h keeps the D lanes of its K/V head: the other heads' lane
+        # blocks of the row are zero
+        o_ref[0, 0] = functools.reduce(jnp.add, [
+            out[:, j * D:(j + 1) * D] for j in range(n_kv)]
+        ).astype(o_ref.dtype)
+    else:
+        o_ref[0, 0] = jnp.sum(out, axis=0,
+                              keepdims=True).astype(o_ref.dtype)
 
 
-def _paged_decode(q, pools, block_table, pos, *, scale, quant, name):
+def _paged_decode(q, pools, block_table, pos, *, scale, quant, name,
+                  first=None):
     """The one pallas_call behind both entry points. ``pools`` is
-    ``(k, v)`` or ``(k, k_scales, v, v_scales)``."""
+    ``(k, v)`` or ``(k, k_scales, v, v_scales)``; the number of query
+    heads a K/V head follows from ``q`` and the pools' row width."""
     B, H, D = q.shape
     _, G, bs, F = pools[0].shape
     hg = H // G
+    kv_group = hg * D // F
+    if kv_group < 1 or hg % kv_group or hg // kv_group * D != F:
+        raise ValueError(
+            f"paged_decode: {H} query heads of {D} over {G} groups do not "
+            f"divide pool rows of {F}")
+    if quant and kv_group > 1:
+        raise NotImplementedError(
+            "paged_decode over int8 pages with fewer K/V heads than query "
+            "heads")
+    windowed = first is not None
     k = _pages_per_step(bs, F, pools[0].dtype.itemsize,
                         block_table.shape[1])
     if quant:
@@ -261,11 +309,18 @@ def _paged_decode(q, pools, block_table, pos, *, scale, quant, name):
         pools = (pools[0], jnp.pad(pools[1], lanes),
                  pools[2], jnp.pad(pools[3], lanes))
 
-    def row():
-        return pl.BlockSpec((1, 1, 1, F), lambda b, g, tbl, p: (b, g, 0, 0))
+    # a slot's query and output: one fused row when a query head has a
+    # K/V head of its own, [H/G, D] rows when several share one
+    rows = (1, F) if kv_group == 1 else (hg, D)
 
+    def row():
+        return pl.BlockSpec((1, 1) + rows, lambda b, g, *_: (b, g, 0, 0))
+
+    scalars = (block_table.astype(jnp.int32), pos.astype(jnp.int32))
+    if windowed:
+        scalars += (first.astype(jnp.int32),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                           # table, pos
+        num_scalar_prefetch=len(scalars),         # table, pos(, first)
         grid=(B, G),
         # the pools stay where they are: the kernel copies the pages
         # the table names, and nothing else of them moves
@@ -280,33 +335,40 @@ def _paged_decode(q, pools, block_table, pos, *, scale, quant, name):
     )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=float(scale), bs=bs,
-                          hg=hg, D=D, k=k, quant=quant),
+                          hg=hg, D=D, k=k, quant=quant, kv_group=kv_group,
+                          windowed=windowed),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, G, 1, F), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, G) + rows, q.dtype),
         # a grid step starts the copies of the next one's first group
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),
         name=name,
-    )(block_table.astype(jnp.int32), pos.astype(jnp.int32),
-      q.reshape(B, G, 1, F), *pools)
+    )(*scalars, q.reshape((B, G) + rows), *pools)
     return out.reshape(B, H, D)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_table, pos, *,
-                           scale: float):
+                           scale: float, first=None):
     """One decode step of attention over paged KV state.
 
     ``q``: ``[B, H, D]`` (the decode token's query, S dim squeezed);
-    ``k_pages``/``v_pages``: ``[N, G, bs, (H/G)*D]`` pools;
+    ``k_pages``/``v_pages``: ``[N, G, bs, (Hkv/G)*D]`` pools, ``Hkv``
+    K/V heads of which query head ``n`` reads head ``n // (H / Hkv)``
+    (``Hkv == H``: a head each);
     ``block_table``: ``[B, MB]`` int32 PHYSICAL page ids (the layer's
     first page already added);
     ``pos``: ``[B]`` int32 per-slot positions (the current token's
-    logical index — attended inclusively, like the XLA fallback).
+    logical index — attended inclusively, like the XLA fallback);
+    ``first``: ``[B]`` int32, a slot's first visible position (a window
+    of W: ``max(0, pos - W + 1)``), or None for all of ``0 .. pos``.
+    The sweep then starts at that position's table entry, and entries
+    before it are never read (they may point at the scratch page).
     Returns ``[B, H, D]`` in q's dtype.
     """
     return _paged_decode(q, (k_pages, v_pages), block_table, pos,
-                         scale=scale, quant=False, name="paged_decode")
+                         scale=scale, quant=False, name="paged_decode",
+                         first=first)
 
 
 def paged_decode_attention_quant(q, k_pages, k_scales, v_pages, v_scales,

@@ -238,10 +238,20 @@ class ServingEngine:
         self.params = param_arrays(model)
         self.buffers = buffer_arrays(model)
         c = self.config
+        # throughput features (ISSUE 15), each behind its own
+        # kill-switch flag with the flags-off path bit-compatible; read
+        # ONCE here so an engine's behavior (and its compiled program
+        # set) is stable for its lifetime — tests flip them with
+        # flag_scope around construction
+        from ..core.flags import get_flag
+        self._chunk = int(get_flag("serve_prefill_chunk") or 0)
+        self._spec_k = int(get_flag("serve_spec_k") or 0)
+        self._spec_ngram = max(1, int(get_flag("serve_spec_ngram") or 1))
         # the model declares what it keeps in pages (`cfg.page_kinds()`:
         # K and V a head for a plain decoder; a latent and an index key
-        # for a sparse-attention one); one head group a chip of the mp
-        # axis is the pools' sharded axis
+        # for a sparse-attention one) and how long a page lives; one head
+        # group a chip of the mp axis is the pools' sharded axis. A
+        # window lifetime is given pages for the longest program: a chunk
         self.cache = PagedKVCache(
             kinds=cfg.page_kinds(),
             num_pages=c.num_pages, block_size=c.block_size,
@@ -249,7 +259,26 @@ class ServingEngine:
             max_blocks_per_slot=blocks_needed(c.max_context_len,
                                               c.block_size),
             dtype=jnp.dtype(c.cache_dtype),
-            head_groups=mp)
+            head_groups=mp,
+            max_chunk=self._chunk or max(c.prefill_buckets))
+        if self.cache.windows:
+            # what assumes that a page lives as long as its slot
+            for flag, what in (
+                    ("serve_prefix_cache", "the radix prefix cache donates "
+                     "and maps pages that hold a prompt's first positions"),
+                    ("serve_spec_k", "a rejected draft is rolled back by "
+                     "truncate_slot"),
+                    ("serve_kv_quant", "int8 pages are not read by the "
+                     "windowed kernels")):
+                if get_flag(flag):
+                    raise ValueError(
+                        f"FLAGS_{flag} with a window page lifetime "
+                        f"({type(cfg).__name__}.page_kinds()): {what}, and "
+                        "a window's pages are freed as the slot advances")
+            if self.mesh is not None:
+                raise ValueError(
+                    "a serving mesh with a window page lifetime: the "
+                    "window tables are not sharded (SERVE_KV_SPEC)")
         if self.mesh is not None:
             from ..distributed.spmd import shard_serving_cache
             shard_serving_cache(self.cache, self.mesh)
@@ -284,15 +313,6 @@ class ServingEngine:
             "serve_deadline", c.slo_deadline,
             windows=c.slo_windows, clock=clock)
             if c.slo_deadline > 0 else None)
-        # throughput features (ISSUE 15), each behind its own
-        # kill-switch flag with the flags-off path bit-compatible; read
-        # ONCE here so an engine's behavior (and its compiled program
-        # set) is stable for its lifetime — tests flip them with
-        # flag_scope around construction
-        from ..core.flags import get_flag
-        self._chunk = int(get_flag("serve_prefill_chunk") or 0)
-        self._spec_k = int(get_flag("serve_spec_k") or 0)
-        self._spec_ngram = max(1, int(get_flag("serve_spec_ngram") or 1))
         self.prefix_cache = None
         if bool(get_flag("serve_prefix_cache")):
             from .prefix_cache import RadixPrefixCache
@@ -513,7 +533,9 @@ class ServingEngine:
         unw = lambda t: t._data if isinstance(t, Tensor) else t
         view = cls(
             tuple(Tensor(p[0] if quant else p) for p in pools),
-            Tensor(table),
+            # a table a page lifetime where the model declares windows
+            tuple(Tensor(t) for t in table) if isinstance(table, tuple)
+            else Tensor(table),
             tuple(Tensor(p[1]) for p in pools) if quant else None,
             tuple(wrap(t) for t in lora) if lora is not None else None)
         with bind(self.model, params, dict(self.buffers)), no_grad(), \
@@ -617,12 +639,11 @@ class ServingEngine:
             return toks, ok, pools, stats
 
         B = self.config.max_batch_slots
-        mb = self.cache.max_blocks_per_slot
         prog = AOTProgram("serve_decode", decode_fn,
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
         return prog, (self.params, self.cache.pool_args(),
-                      jnp.zeros((B, mb), jnp.int32),
+                      self.cache.table_like(B),
                       jnp.zeros((B,), jnp.int32),
                       jnp.zeros((B,), jnp.int32),
                       jnp.zeros((B,), bool), self._key,
@@ -670,13 +691,12 @@ class ServingEngine:
                 toks = sample_tokens(row, rng, temps, top_ks, top_ps)
             return toks, ok, pools
 
-        mb = self.cache.max_blocks_per_slot
         prog = AOTProgram(f"serve_prefill_b{nb}_s{sp}", prefill_fn,
                           name=f"serve_prefill_{nb}x{sp}",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
         return prog, (self.params, self.cache.pool_args(),
-                      jnp.zeros((nb, mb), jnp.int32),
+                      self.cache.table_like(nb),
                       jnp.zeros((nb, sp), jnp.int32),
                       jnp.ones((nb,), jnp.int32), self._key,
                       jnp.ones((nb,), jnp.float32),
@@ -708,14 +728,13 @@ class ServingEngine:
                 toks = sample_tokens(row, rng, temps, top_ks, top_ps)
             return toks, ok, pools
 
-        mb = self.cache.max_blocks_per_slot
         prog = AOTProgram(f"serve_prefill_ctx_b{nb}_s{sp}",
                           prefill_ctx_fn,
                           name=f"serve_prefill_ctx_{nb}x{sp}",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
         return prog, (self.params, self.cache.pool_args(),
-                      jnp.zeros((nb, mb), jnp.int32),
+                      self.cache.table_like(nb),
                       jnp.zeros((nb, sp), jnp.int32),
                       jnp.ones((nb,), jnp.int32),
                       jnp.zeros((nb,), jnp.int32), self._key,
@@ -782,13 +801,12 @@ class ServingEngine:
                     p_draft, tok_full, tok_resid, pools)
 
         B = self.config.max_batch_slots
-        mb = self.cache.max_blocks_per_slot
         prog = AOTProgram(f"serve_verify_s{S}", verify_fn,
                           name="serve_verify",
                           donate_argnums=self._donate(),
                           on_attribute=self._attribute)
         return prog, (self.params, self.cache.pool_args(),
-                      jnp.zeros((B, mb), jnp.int32),
+                      self.cache.table_like(B),
                       jnp.zeros((B,), jnp.int32),
                       jnp.zeros((B, S), jnp.int32),
                       jnp.zeros((B,), bool), self._key,
@@ -1752,6 +1770,9 @@ class ServingEngine:
                 lens[i] = clen
                 pos[i] = st.prefill_pos
                 rows[i] = st.slot
+            self._advance_windows(
+                (st.slot, int(pos[i]), int(lens[i]))
+                for i, st in enumerate(states) if st is not None)
             t0 = self.clock()
             if self._t_first_work is None:
                 self._t_first_work = t0
@@ -2022,11 +2043,67 @@ class ServingEngine:
                             "back by block-table truncation").inc(
                     rolled_back)
 
-    def _run_decode(self, pairs, params) -> None:
-        with _trace.span("serve.decode", n_active=len(pairs)):
-            self._decode_batch(pairs, params)
+    def _advance_windows(self, moves) -> None:
+        """``(slot, pos, n)`` a slot whose next program writes positions
+        ``pos .. pos+n-1``: the window page lifetimes free what no later
+        query reaches and cover what is written (``cache.advance``).
+        Nothing, not a span, for a model whose pages all live as long as
+        their slots."""
+        if not self.cache.windows:
+            return
+        with _trace.span("serve.kv.release") as sp:
+            freed = sum(self.cache.advance(*m) for m in moves)
+            sp.set(freed=freed)
+        if freed:
+            # emits-metrics: serve_kv_window_pages_freed_total
+            self._count("serve_kv_window_pages_freed_total", freed,
+                        "pages of a window lifetime freed because their "
+                        "positions left every later query's reach")
 
-    def _decode_batch(self, pairs, params) -> None:
+    def _count(self, name: str, n: float, doc: str, **labels) -> None:
+        """One count into the registry and into ``_stats
+        ["model_counters"]`` under the series' name, as
+        :meth:`_count_model_stats` keeps what the model counted."""
+        get_registry().counter(name, doc).inc(n, **labels)
+        series = name + ("{" + ",".join(
+            f"{k}={v}" for k, v in labels.items()) + "}" if labels else "")
+        totals = self._stats.setdefault("model_counters", {})
+        totals[series] = totals.get(series, 0) + n
+
+    def _count_window_reads(self, pos: np.ndarray, sp) -> None:
+        """What a decode step over ``pos`` (the active slots') holds and
+        has to read in each page lifetime, host arithmetic: into the
+        counters and onto the ``serve.decode`` span."""
+        bs = self.cache.block_size
+        whole = pos // bs + 1
+        held = {"slot": int(whole.sum())}
+        read = {"slot": int((pos + 1).sum())}
+        for w in self.cache.windows:
+            first = np.maximum(pos - w.window + 1, 0)
+            held["window"] = held.get("window", 0) \
+                + int((whole - first // bs).sum())
+            read["window"] = read.get("window", 0) \
+                + int((pos + 1 - first).sum())
+        # emits-metrics: serve_kv_pages_live_total, serve_kv_pages_unwindowed_total, serve_attn_read_positions_total
+        for life, n in held.items():
+            self._count("serve_kv_pages_live_total", n,
+                        "pages the active slots of decode steps hold, by "
+                        "page lifetime", lifetime=life)
+        self._count("serve_kv_pages_unwindowed_total",
+                    held["slot"] * len(self.cache.windows),
+                    "pages the window lifetimes would hold if nothing were "
+                    "freed (ceil((pos + 1) / block_size) a slot)")
+        for life, n in read.items():
+            self._count("serve_attn_read_positions_total", n,
+                        "positions a decode step's kernels have to read in "
+                        "a layer of that page lifetime", lifetime=life)
+        sp.set(read_full=read["slot"], read_window=read["window"])
+
+    def _run_decode(self, pairs, params) -> None:
+        with _trace.span("serve.decode", n_active=len(pairs)) as sp:
+            self._decode_batch(pairs, params, sp)
+
+    def _decode_batch(self, pairs, params, sp) -> None:
         with _trace.span("serve.decode.build"):
             B = self.config.max_batch_slots
             pos = np.zeros((B,), np.int32)
@@ -2048,6 +2125,10 @@ class ServingEngine:
             live_pages = int((pos[active] // self.cache.block_size + 1)
                              .sum())
             table_pages = n_active * self.cache.max_blocks_per_slot
+            if self.cache.windows:
+                self._advance_windows(
+                    (slot, int(pos[slot]), 1) for slot, _ in pairs)
+                self._count_window_reads(pos[active], sp)
             t0 = self.clock()
             prog = self._get_decode()
             temps, tks, tps = self._sampling_arrays(per_slot)

@@ -7,8 +7,10 @@ of fixed-size pages:
 
 - what a model keeps in pages is the model's to declare
   (:class:`PageKind`: K and V a head for a plain decoder, a latent and
-  an index key for MLA under a sparse selection); every kind shares ONE
-  allocator and block table and has ONE pool of its own;
+  an index key for MLA under a sparse selection) and how long a page of
+  it lives (``PageKind.lifetime``: as long as its slot, or a window of
+  the last W positions); kinds of one lifetime share ONE allocator and
+  block table, and every kind has ONE pool of its own;
 - a kind lives in ONE lane-dense pool, ``[L, P, G, bs, (H/G)*D]``: ``P``
   pages a layer, ``bs`` token rows a page, the heads of a token row
   fused into the minor dim (GPT-2 345M: 16 x 64 = 1024 lanes, whole
@@ -55,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["BlockAllocator", "PagedKVCache", "PageKind", "kv_page_kinds",
+           "WindowPages",
            "PagedPools",
            "ContextPagedPools", "PagedCacheView",
            "PagedLayerCache", "ContextPagedCacheView",
@@ -81,9 +84,13 @@ class PageKind(NamedTuple):
     position (``heads`` head segments fused into that minor dim; 1 for
     state with no head axis, an MLA latent or an index key) in each of
     the model's ``layers`` (indices into its stack; a kind only some
-    layers keep has a pool of ``len(layers)`` layers). Every kind shares
-    the ONE block table and allocator of :class:`PagedKVCache`: page
-    ``p`` of a slot holds the same positions in every kind.
+    layers keep has a pool of ``len(layers)`` layers). ``lifetime`` says
+    how long a page lives: ``"slot"``, until its slot is freed, or an
+    int ``W``, while a later query can still reach one of its positions
+    (a query at ``i`` sees keys ``j`` with ``0 <= i - j < W``). Kinds of
+    one lifetime share a block table, an allocator and a page count of
+    :class:`PagedKVCache`: page ``p`` of a slot holds the same positions
+    in every kind of that lifetime.
 
     A pool's minor dim is :meth:`stored_width`: ``width`` rounded up to
     whole 128-lane tiles when it is wider than one and not a multiple
@@ -97,6 +104,7 @@ class PageKind(NamedTuple):
     width: int
     layers: tuple
     heads: int = 1
+    lifetime: object = "slot"
 
     def stored_width(self) -> int:
         if self.width <= LANES or self.width % LANES == 0:
@@ -371,15 +379,107 @@ class BlockAllocator:
                 self._free.append(p)
 
 
+class WindowPages:
+    """The block table and allocator of the kinds that keep only the
+    last ``window`` positions of a slot (``PageKind.lifetime`` an int).
+
+    The table keeps the slot lifetime's LOGICAL indexing (entry ``j``
+    holds positions ``j*bs .. j*bs+bs-1``), so the one addressing rule
+    (:func:`_physical_rows`) and the kernels' walk need nothing new: an
+    entry whose positions no later query can reach points at the scratch
+    page again and its page is back on the free list. A slot whose next
+    program writes positions ``pos .. pos+n-1`` (every later query sits
+    at ``>= pos``) holds entries ``(pos - window + 1) // bs`` to
+    ``(pos + n - 1) // bs``: at most ``ceil((window + chunk) / bs) + 1``
+    pages for programs of up to ``chunk`` positions, which is what a
+    slot is given — so this lifetime never refuses an admission, and
+    admission stays the slot lifetime's to decide."""
+
+    def __init__(self, window: int, *, block_size: int, max_slots: int,
+                 max_blocks_per_slot: int, max_chunk: int):
+        self.window = int(window)
+        if self.window < 1:
+            raise ValueError(f"a window lifetime of {window} positions")
+        self.block_size = int(block_size)
+        #: pages a slot can hold at once (the bound of the class docstring)
+        self.pages_per_slot = min(
+            int(max_blocks_per_slot),
+            blocks_needed(self.window + int(max_chunk), block_size) + 1)
+        self.num_pages = 1 + int(max_slots) * self.pages_per_slot
+        self.allocator = BlockAllocator(self.num_pages)
+        self.tables = np.full((max_slots, max_blocks_per_slot),
+                              SCRATCH_PAGE, np.int32)
+        #: live entries of a slot are ``[first, end)``
+        self._first = [0] * max_slots
+        self._end = [0] * max_slots
+        #: pages freed because their positions left the window (cumulative)
+        self.freed = 0
+
+    def live_blocks(self, slot: int) -> int:
+        return self._end[slot] - self._first[slot]
+
+    def first_position(self, slot: int) -> int:
+        """The lowest position the slot still holds a page for."""
+        return self._first[slot] * self.block_size
+
+    def advance(self, slot: int, pos: int, n: int) -> int:
+        """The slot's next program writes positions ``pos .. pos+n-1``
+        and no later query sits below ``pos``: free the pages no such
+        query reaches, then cover the positions to be written. Returns
+        the pages freed."""
+        bs, tbl = self.block_size, self.tables[slot]
+        first, end = self._first[slot], self._end[slot]
+        keep = max(0, int(pos) - self.window + 1) // bs
+        freed = 0
+        if keep > first:
+            stop = min(keep, end)
+            if stop > first:
+                self.allocator.free(tbl[first:stop].tolist())
+                tbl[first:stop] = SCRATCH_PAGE
+                freed = stop - first
+                self.freed += freed
+            first = keep
+            end = max(end, first)
+        need = blocks_needed(int(pos) + int(n), bs)
+        if need > end:
+            if need > tbl.shape[0]:
+                raise ValueError(
+                    f"slot {slot}: {pos + n} tokens exceed the "
+                    f"{tbl.shape[0] * bs}-token slot capacity")
+            pages = self.allocator.alloc(need - end)
+            if pages is None:
+                raise RuntimeError(
+                    f"window lifetime (W={self.window}): slot {slot} needs "
+                    f"{need - first} pages for positions {pos}..{pos + n - 1}"
+                    f" but a slot is given {self.pages_per_slot} — a program "
+                    "wrote more positions than the cache's max_chunk")
+            tbl[end:need] = pages
+            end = need
+        self._first[slot], self._end[slot] = first, end
+        return freed
+
+    def free_slot(self, slot: int) -> None:
+        first, end = self._first[slot], self._end[slot]
+        if end > first:
+            self.allocator.free(self.tables[slot, first:end].tolist())
+        self.tables[slot, :] = SCRATCH_PAGE
+        self._first[slot] = self._end[slot] = 0
+
+
 class PagedKVCache:
     """Device page pools + host block tables for a fixed slot batch:
     a pool a :class:`PageKind` (``kinds=``; K and V of ``num_layers x
     num_heads x head_dim`` when none is given), one allocator and one
-    block table for all of them.
+    block table a lifetime: this object's own for the ``"slot"`` kinds
+    (``num_pages`` pages), a :class:`WindowPages` in ``windows`` for
+    each window ``W`` a kind declares (its page count derived from the
+    slots, ``W``, ``max_chunk`` and the block size).
 
     ``pool_args()`` is the argument a compiled step takes, ``update
     (*pools)`` swaps in the pools it returned; ``table_array()``
-    snapshots the host tables as the step's int32 argument. Slot bookkeeping (``alloc_slot``/``extend_slot``/
+    snapshots the host tables as the step's int32 argument (one array;
+    with window lifetimes a tuple, the slot lifetime's first). Slot
+    bookkeeping (``alloc_slot``/``extend_slot``/``advance``/
     ``free_slot``) is pure host work — device shapes never change.
     """
 
@@ -389,7 +489,8 @@ class PagedKVCache:
                  *, num_pages: int, block_size: int, max_slots: int,
                  max_blocks_per_slot: int, dtype=jnp.float32,
                  head_groups: int = 1,
-                 kinds: Optional[Sequence[PageKind]] = None):
+                 kinds: Optional[Sequence[PageKind]] = None,
+                 max_chunk: Optional[int] = None):
         from ..core.flags import get_flag
         if kinds is None:
             kinds = kv_page_kinds(num_layers, num_heads, head_dim)
@@ -418,12 +519,23 @@ class PagedKVCache:
                 f"FLAGS_serve_kv_quant={self.quant!r}: supported modes "
                 "are '' (full precision) and 'int8'")
         G = self.head_groups
+        #: a :class:`WindowPages` a window lifetime, in the order the
+        #: kinds first name them; ``max_chunk`` is the longest run of
+        #: positions one program writes into a slot
+        self.windows = tuple(
+            WindowPages(w, block_size=block_size, max_slots=max_slots,
+                        max_blocks_per_slot=max_blocks_per_slot,
+                        max_chunk=(max_chunk if max_chunk
+                                   else max_blocks_per_slot * block_size))
+            for w in dict.fromkeys(kd.lifetime for kd in self.kinds
+                                   if kd.lifetime != "slot"))
+        pages_of = {w.window: w.num_pages for w in self.windows}
         #: kind name -> ``[L_kind, P, G, bs, W/G]`` pool (quantized: a
         #: ``(pages, scales)`` pair, scales ``[L_kind, P, G, bs, heads/G]``)
         self.pools = {}
         for kd in self.kinds:
-            shape = (len(kd.layers), num_pages, G, block_size,
-                     kd.stored_width() // G)
+            shape = (len(kd.layers), pages_of.get(kd.lifetime, num_pages),
+                     G, block_size, kd.stored_width() // G)
             if self.quant == "int8":
                 self.pools[kd.name] = (
                     jnp.zeros(shape, jnp.int8),
@@ -476,15 +588,28 @@ class PagedKVCache:
         """Snapshot block tables as the step's int32 argument: all slots,
         or one row per entry of ``rows`` — a ``None`` entry (a padded
         prefill row) gets an all-scratch row, so its garbage K/V can
-        never land in another slot's pages."""
-        if rows is None:
-            return jnp.asarray(self._tables)
-        t = np.full((len(rows), self.max_blocks_per_slot), SCRATCH_PAGE,
-                    np.int32)
-        for i, s in enumerate(rows):
-            if s is not None:
-                t[i] = self._tables[s]
-        return jnp.asarray(t)
+        never land in another slot's pages. With window lifetimes, a
+        tuple: this table, then one of each of ``windows``."""
+        def snap(tables):
+            if rows is None:
+                return jnp.asarray(tables)
+            t = np.full((len(rows), self.max_blocks_per_slot),
+                        SCRATCH_PAGE, np.int32)
+            for i, s in enumerate(rows):
+                if s is not None:
+                    t[i] = tables[s]
+            return jnp.asarray(t)
+
+        if not self.windows:
+            return snap(self._tables)
+        return (snap(self._tables),) + tuple(snap(w.tables)
+                                             for w in self.windows)
+
+    def table_like(self, n: int):
+        """An all-scratch table argument of ``n`` rows, in the form
+        :meth:`table_array` gives: what a program is compiled against."""
+        z = jnp.zeros((n, self.max_blocks_per_slot), jnp.int32)
+        return (z,) * (1 + len(self.windows)) if self.windows else z
 
     @property
     def max_context_len(self) -> int:
@@ -563,12 +688,27 @@ class PagedKVCache:
         self._tables[slot, have:need] = pages
         return True
 
+    def advance(self, slot: int, pos: int, n: int = 1) -> int:
+        """Tell the window lifetimes that the slot's next program writes
+        positions ``pos .. pos+n-1`` and that no later query sits below
+        ``pos`` (a decode step: ``n`` 1; a prefill chunk: its length):
+        pages out of every later query's reach are freed, the positions
+        to be written covered. Returns the pages freed. Nothing to do
+        without a window lifetime: the slot lifetime's pages were
+        allocated by ``alloc_slot`` / ``extend_slot``."""
+        return sum(w.advance(slot, pos, n) for w in self.windows)
+
     def truncate_slot(self, slot: int, num_tokens: int) -> int:
         """Shrink the slot to cover only ``num_tokens`` positions — the
         speculative-decode rollback: pages holding ONLY rejected draft
         K/V leave the block table and drop their reference. Never cuts
         into the COW-shared prefix (committed tokens always cover it).
         Returns the number of pages released."""
+        if self.windows:
+            raise NotImplementedError(
+                "truncate_slot (the speculative-decode rollback) under a "
+                "window page lifetime: a rejected draft's rows may already "
+                "have pushed pages out of the window")
         keep = blocks_needed(num_tokens, self.block_size)
         pages = self._slot_pages[slot]
         if keep >= len(pages):
@@ -603,3 +743,5 @@ class PagedKVCache:
         self._slot_pages[slot] = []
         self._slot_shared[slot] = 0
         self._tables[slot, :] = SCRATCH_PAGE
+        for w in self.windows:
+            w.free_slot(slot)

@@ -17,8 +17,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-UNIT_MODULES = ("test_arithmetic", "test_contract", "test_loadgen",
-                "test_program_spans", "test_trace_reduce")
+UNIT_MODULES = ("test_arithmetic", "test_arithmetic_cohere2_moe",
+                "test_contract", "test_loadgen", "test_program_spans",
+                "test_trace_reduce")
 
 
 def _take_in(names):

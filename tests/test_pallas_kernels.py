@@ -368,6 +368,134 @@ def test_paged_decode_kernel_parity(G, dtype, quant, layer, tol):
                                np.asarray(ref), rtol=tol, atol=tol)
 
 
+def _grouped_state(rng, g, dtype, window, B=3, MB=8, bs=4, n_kv=2, D=8):
+    """A pool ``[P, 1, bs, n_kv*D]`` whose SCRATCH page holds NaN, a
+    table whose entries before a slot's window point at it (as
+    ``kv_cache.WindowPages`` leaves them), positions on both sides of
+    the window with its first position INSIDE a page, and the query of
+    ``g * n_kv`` heads. Returns ``(q, k, v, tbl, pos, first)``."""
+    pos = np.array([21, 2, MB * bs - 1][:B], np.int32)
+    first = np.maximum(pos - window + 1, 0) if window else np.zeros_like(pos)
+    P = B * MB + 1
+    tbl = np.zeros((B, MB), np.int32)
+    for b in range(B):
+        e0, e1 = first[b] // bs, pos[b] // bs
+        tbl[b, e0:e1 + 1] = 1 + b * MB + np.arange(e0, e1 + 1)
+    pools = []
+    for _ in "kv":
+        a = rng.randn(P, 1, bs, n_kv * D).astype(np.float32)
+        a[0] = np.nan
+        pools.append(jnp.asarray(a).astype(dtype))
+    q = jnp.asarray(rng.randn(B, g * n_kv, D).astype(np.float32)).astype(dtype)
+    return (q, *pools, jnp.asarray(tbl), jnp.asarray(pos),
+            jnp.asarray(first) if window else None)
+
+
+def _grouped_decode_ref(q, k, v, tbl, pos, first, scale):
+    """Gather + masked softmax with query head n on K/V head n // g,
+    over pools whose scratch page is zeroed (a masked NaN would still
+    poison p @ v: the kernel must never read it)."""
+    from paddle_tpu.serving.kv_cache import gather_pages
+    B, H, D = q.shape
+    clean = lambda a: a.at[0].set(0).astype(jnp.float32)
+    gk, gv = (gather_pages(clean(a), tbl, D) for a in (k, v))
+    n_kv = gk.shape[2]
+    cols = jnp.arange(gk.shape[1])[None, :]
+    ok = cols <= pos[:, None]
+    if first is not None:
+        ok &= cols >= first[:, None]
+    s = jnp.einsum("bngd,bknd->bngk", q.astype(jnp.float32).reshape(
+        B, n_kv, H // n_kv, D), gk) * scale
+    pr = jax.nn.softmax(jnp.where(ok[:, None, None], s, -1e30), axis=-1)
+    return jnp.einsum("bngk,bknd->bngd", pr, gv).reshape(B, H, D)
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 4e-3)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("g,window", [(16, 0), (16, 10), (4, 0), (4, 10),
+                                      (1, 10)])
+def test_paged_decode_grouped_heads_and_window_parity(g, window, dtype, tol):
+    """Query heads sharing a K/V head (16 and 4 a head; 1 under a
+    window) and the sweep from the window's first page — whose first
+    visible position lies inside the page (21 - 10 + 1 = 12 is a page's
+    first row, 31 - 9 = 22 is not) — against the XLA reference. The
+    scratch page holds NaN and every entry before the window points at
+    it: an output that is finite never read one."""
+    from paddle_tpu.ops.pallas.paged_decode import paged_decode_attention
+    rng = np.random.RandomState(g + window)
+    q, k, v, tbl, pos, first = _grouped_state(rng, g, dtype, window)
+    scale = 1.0 / np.sqrt(8)
+    ref = _grouped_decode_ref(q, k, v, tbl, pos, first, scale)
+    got = jax.jit(lambda *a: paged_decode_attention(
+        *a[:5], scale=scale, first=a[5] if window else None))(
+        q, k, v, tbl, pos, first if window else pos)
+    assert got.dtype == q.dtype and got.shape == q.shape
+    assert np.isfinite(np.asarray(got.astype(jnp.float32))).all()
+    np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                               np.asarray(ref), rtol=tol, atol=tol)
+
+
+#: sha256 of `str(jax.make_jaxpr(paged_decode_attention))` at q (4, 16,
+#: 64), pools (40, 1, 16, 1024), table (4, 12), taken from the commit
+#: BEFORE kv_group and the window were added (01875443, PR 31): the GPT-2
+#: path has to trace to the kernel it was, instruction for instruction
+_PR31_JAXPR = {
+    "bfloat16": "55fe7efe36f24a1ed3cc504cf6f54a845226f395c68099bb8ca728647bd95f2a",
+    "float32": "3086236ad9126a66be363bd006a08f134298395bb0dbbaec0beeaa72b36bf4c2"}
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_paged_decode_one_kv_head_a_query_head_traces_as_before(dtype):
+    """`kv_group` 1 and no window: the traced kernel is PR 31's, jaxpr
+    for jaxpr (the serve cell `closed64` runs it 24 times a decode
+    step). A change of jax's printer would move the hash with no change
+    here: take it anew from that commit then."""
+    import hashlib
+    from paddle_tpu.ops.pallas.paged_decode import paged_decode_attention
+    q = jnp.zeros((4, 16, 64), dtype)
+    pool = jnp.zeros((40, 1, 16, 1024), dtype)
+    text = str(jax.make_jaxpr(
+        lambda *a: paged_decode_attention(*a, scale=0.125))(
+        q, pool, pool, jnp.zeros((4, 12), jnp.int32),
+        jnp.zeros((4,), jnp.int32)))
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _PR31_JAXPR[jnp.dtype(dtype).name]
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("D,g", [(128, 4), (64, 4), (64, 3)],
+                         ids=["v2-d128", "v1-d64", "v1-odd-group"])
+def test_flash_attention_grouped_kv_heads(D, g):
+    """K and V at fewer heads than the query (the K/V blocks' index maps
+    divide the head index): the layout-native kernel where a head is a
+    whole lane block, the v1 kernel otherwise, against the XLA
+    composition with grouped heads — which repeats nothing either."""
+    from paddle_tpu.ops.attention import _sdpa_xla
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    rng = np.random.RandomState(D + g)
+    B, S, n_kv = 2, 256, 2
+    q = jnp.asarray(rng.randn(B, S, g * n_kv, D).astype(np.float32))
+    k, v = (jnp.asarray(rng.randn(B, S, n_kv, D).astype(np.float32))
+            for _ in "kv")
+    got = flash_attention(q, k, v, causal=True)
+    want = _sdpa_xla(q, k, v, None, 0.0, True, None)
+    # and the composition itself against repeated heads
+    rep = lambda a: jnp.repeat(a, g, axis=2)
+    np.testing.assert_allclose(
+        np.asarray(want),
+        np.asarray(_sdpa_xla(q, rep(k), rep(v), None, 0.0, True, None)),
+        rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    with pytest.raises(ValueError, match="grouped heads"):
+        flash_attention(q, k, v, dropout_rate=0.1,
+                        dropout_key=jax.random.key(0))
+
+
 #: the page walk's cases: pools whose pages are 32 KB, so a sweep step
 #: takes k = 8 of a 20-entry table row (three groups, the last one short):
 #: (pool dtype, head groups, block size); q is [B, 8 * G, 128]

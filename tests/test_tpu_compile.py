@@ -418,3 +418,95 @@ def test_glm_serving_program_fits_and_moves_no_pool(
                 for p in eng.cache.pool_args())
     assert mem.alias_size_in_bytes >= pools, mem
     assert mem.temp_size_in_bytes < 2e9, mem
+
+
+# -- two page lifetimes, grouped K/V heads (ISSUE 32) ---------------------------
+
+COHERE_CELL = "command_a_plus_ep8.serve.closed24_mixed"
+
+
+@pytest.fixture(scope="module")
+def cohere_model():
+    """Command A+'s chip share at the PUBLISHED widths (4.73 B
+    parameters, as zeros: nothing runs), and the cell's system
+    settings."""
+    import json
+    import os
+    from paddle_tpu.nn import initializer
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "bench_cohere2_moe",
+        os.path.join(root, "benchmark", "models", "cohere2_moe.py"))
+    fam = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(fam)
+    with open(os.path.join(root, "benchmark", "configs",
+                           "command_a_plus_ep8.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmark", "workloads",
+                           COHERE_CELL + ".json")) as f:
+        system = json.load(f)
+    draw = initializer.Normal.__call__
+    initializer.Normal.__call__ = lambda self, shape, dtype=None: jnp.zeros(
+        tuple(shape), dtype or "float32")
+    try:
+        model = fam.build_model(config, 0, dtype=system["weights_dtype"])
+    finally:
+        initializer.Normal.__call__ = draw
+    return model, system
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill", "prefill_ctx"])
+def test_cohere_serving_program_fits_and_moves_no_pool(
+        chip, build_for_chip, cohere_model, monkeypatch, kind):
+    """The three serving programs of the model with two page lifetimes,
+    at the cell's widths and engine settings, compile for the chip with
+    the pools at the cell's page counts as shapes (20,481 pages of the
+    slot lifetime a full layer; 24 x 385 + 1 of the window lifetime a
+    window layer): the decode step runs the paged kernel (128 query
+    heads on 8 K/V heads, the windowed sweep) and NO gather fallback,
+    the first chunk the flash kernel over grouped heads; every pool is
+    updated in place, no instruction is as large as a layer's pool, and
+    the temporaries leave room beside 9.47 GB of weights and 3.16 GB of
+    pages on a 16 GiB chip."""
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+    model, system = cohere_model
+    kw = dict(system["engine"])
+    pages = kw.pop("num_pages")
+    for key in ("prefill_buckets", "batch_buckets"):
+        kw[key] = tuple(kw[key])
+    # the engine's own pools are small (an engine lives one test): two
+    # slots, so the window lifetime is 2 x 385 + 1 pages
+    kw["max_batch_slots"] = 2
+    with flag_scope("serve_prefill_chunk", system["prefill_chunk"]):
+        eng = ServingEngine(model, ServingConfig(num_pages=33, **kw))
+    (win,) = eng.cache.windows
+    assert win.pages_per_slot == 385
+    slots = system["engine"]["max_batch_slots"]
+    counts = {"slot": pages, win.window: slots * win.pages_per_slot + 1}
+    prog, args = {"decode": eng._decode_program,
+                  "prefill": lambda: eng._prefill_program(1, 2048),
+                  "prefill_ctx": lambda: eng._prefill_ctx_program(1, 2048)
+                  }[kind]()
+    shaped = jax.tree.map(lambda a: chip(a.shape, a.dtype), args)
+    pools = tuple(chip((p.shape[0], counts[kd.lifetime]) + p.shape[2:],
+                       p.dtype)
+                  for kd, p in zip(eng.cache.kinds, shaped[1]))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = build_for_chip(prog._jitted.lower, shaped[0], pools,
+                              *shaped[2:])
+    monkeypatch.undo()
+    text = compiled.as_text()
+    if kind != "prefill_ctx":       # the context path is an XLA loop
+        _assert_kernels(text, {"decode": "paged_decode",
+                               "prefill": "flash_fwd"}[kind])
+    rows = {p.shape[-2:] for p in eng.cache.pool_args()}
+    assert rows == {(16, 1024)}
+    assert _pool_sized_moves(text, counts[win.window] * 16 * 1024 * 2,
+                             rows) == []
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(p.shape)) * 2 for p in pools)
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    # 16 GiB - 9.47 GB - 3.16 GB leaves 4.5 GB; a chunk's expert rows and
+    # scores are the largest
+    assert mem.temp_size_in_bytes < 3.0e9, mem
+    print(kind, "temp", mem.temp_size_in_bytes / 1e9, "GB")
