@@ -4,7 +4,9 @@ reference parity: python/paddle/distributed/fleet/utils/recompute.py
 (RecomputeFunction.forward/backward:63,182 — CUDA RNG-state stashing +
 re-forward under enable_grad). The TPU-native redesign is `jax.checkpoint`:
 under jit the XLA backward rematerializes the segment instead of saving
-activations; in eager the tape's VJP closure holds only the segment inputs.
+activations; in eager the tape's VJP closure holds only the segment inputs
+(and, where the segment ran a flash-attention kernel, the kernel's output
+and log-sum-exp: :func:`resolve_checkpoint_policy`).
 RNG consistency is free here — dropout keys are split at Python trace time
 (core/random.trace_rng), so the rematerialized forward replays the same
 keys without the reference's fork_rng dance.
@@ -12,20 +14,22 @@ keys without the reference's fork_rng dance.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
 
 from ....core.tensor import Tensor, apply
 from ....nn.layer import Layer
+from ....ops.pallas import FLASH_RESIDUAL_NAMES
 
 __all__ = ["recompute", "recompute_sequential", "resolve_checkpoint_policy"]
 
 #: named selective-remat policies (jax.checkpoint_policies). The TPU
 #: default for transformer stacks is ``dots_with_no_batch_dims_saveable``:
 #: keep MXU (matmul) outputs resident, rematerialize only the cheap
-#: elementwise tail — far less recompute FLOPs than full remat for a
-#: modest HBM cost (the T5X/MaxText recipe).
+#: elementwise tail — far less recompute FLOPs than recomputing the
+#: whole segment for a modest HBM cost (the T5X/MaxText recipe).
 _POLICY_NAMES = (
     # NOTE: only plain PREDICATES belong here. jax.checkpoint_policies
     # also exports factories (offload_dot_with_no_batch_dims,
@@ -46,22 +50,51 @@ _POLICY_ALIASES = {
 }
 
 
+@functools.lru_cache(maxsize=None)
+def _named_policy(name):
+    """The predicate of one policy name (None: the default). Built once
+    a name: ``nn.scan`` keys its trace cache on the predicate's identity."""
+    cp = jax.checkpoint_policies
+    if name == "nothing_saveable":
+        return cp.nothing_saveable
+    keep = cp.save_only_these_names(*FLASH_RESIDUAL_NAMES)
+    if name is None:
+        return keep
+    return cp.save_from_both_policies(getattr(cp, name), keep)
+
+
 def resolve_checkpoint_policy(policy):
     """Resolve a remat policy spec to a ``jax.checkpoint_policies`` predicate.
 
-    Accepts None (full remat — jax.checkpoint's default), a callable
-    (returned as-is), or a policy name / alias string. Model configs carry
-    the string form (``recompute_policy='dots_with_no_batch_dims_saveable'``)
-    so configs stay picklable/serializable."""
-    if policy is None or callable(policy):
+    Whatever a policy recomputes, it never re-runs a flash-attention
+    forward: the kernel's output and log-sum-exp
+    (``ops.pallas.FLASH_RESIDUAL_NAMES``, which the differentiated
+    forward tags) are kept, since the second run would write the same
+    bits and costs as much as the backward kernel. They exist only where
+    a flash kernel was differentiated; a block without one resolves to
+    the policy it names.
+
+    - None (the default, "recompute everything else"):
+      ``save_only_these_names(<the kernel's residuals>)``;
+    - a policy name or alias (model configs carry the string form,
+      ``recompute_policy='dots_with_no_batch_dims_saveable'``, so they
+      stay picklable): that policy AND the kernel's residuals
+      (``save_from_both_policies``; no dots policy keeps a custom
+      call's output);
+    - ``"full"`` / ``"nothing_saveable"``: jax's literal meaning, nothing
+      is kept and the kernel runs again;
+    - a callable: returned as it is."""
+    if callable(policy):
         return policy
+    if policy is None:
+        return _named_policy(None)
     name = _POLICY_ALIASES.get(str(policy), str(policy))
     if name not in _POLICY_NAMES:
         raise ValueError(
             f"unknown recompute policy {policy!r}; expected one of "
             f"{sorted(_POLICY_NAMES + tuple(_POLICY_ALIASES))} or a "
             "jax.checkpoint_policies callable")
-    return getattr(jax.checkpoint_policies, name)
+    return _named_policy(name)
 
 
 def recompute(function, *args, use_reentrant: bool = True,
@@ -75,10 +108,12 @@ def recompute(function, *args, use_reentrant: bool = True,
     applies inside a jitted TrainStep trace).
 
     ``policy`` (TPU-native extension): a ``jax.checkpoint_policies``
-    predicate for SELECTIVE checkpointing — e.g.
+    predicate or its name for SELECTIVE checkpointing — e.g.
     ``dots_with_no_batch_dims_saveable`` keeps matmul outputs resident and
     rematerializes only the cheap elementwise tail, a far better
-    FLOPs/HBM trade than full recompute on TPU.
+    FLOPs/HBM trade than recomputing everything on TPU. Under every
+    policy but ``"full"`` a flash-attention kernel in the segment keeps
+    its output and log-sum-exp (:func:`resolve_checkpoint_policy`).
     """
     del use_reentrant, preserve_rng_state   # parity knobs; single behavior
     policy = resolve_checkpoint_policy(policy)

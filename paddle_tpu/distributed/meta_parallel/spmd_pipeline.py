@@ -498,7 +498,9 @@ class PipelineStageStack(Layer):
         T = M + S - 1
         stage = self._stage_apply
         if self.remat:
-            stage = jax.checkpoint(stage, static_argnums=())
+            from ..fleet.utils.recompute import resolve_checkpoint_policy
+            stage = jax.checkpoint(stage,
+                                   policy=resolve_checkpoint_policy(None))
 
         def shard_body(xs, key, *local_leaves):
             local = {self._name_map[r]: a

@@ -30,13 +30,15 @@ disabled the heal).
 from __future__ import annotations
 
 import re
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, FrozenSet, List, Optional, Sequence,
+                    Tuple)
 
 import jax
 
 from ..nn.layer import BLOCKS
 
-__all__ = ["AOTProgram", "SCOPES", "scopes", "parse_scopes"]
+__all__ = ["AOTProgram", "SCOPES", "scopes", "parse_scopes", "index_program",
+           "kernel_calls"]
 
 #: HLO module name (``jit_train_step``; a device trace's `XLA Modules`
 #: line carries the same) -> ``{instruction name: (block, phase)}`` of
@@ -48,6 +50,12 @@ __all__ = ["AOTProgram", "SCOPES", "scopes", "parse_scopes"]
 #: the executable or its text.
 SCOPES: Dict[str, Dict[str, Tuple[str, str]]] = {}
 
+#: HLO module name -> the names of its instructions that are Mosaic
+#: (Pallas) kernel calls (``flash_fwd.19``: the kernel's name and XLA's
+#: number), of the same executable as :data:`SCOPES`' entry.
+KERNEL_CALLS: Dict[str, FrozenSet[str]] = {}
+
+_MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
 _HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r"op_name=\"([^\"]*)\"")
@@ -96,10 +104,45 @@ def parse_scopes(hlo_text: str) -> Tuple[str, Dict[str, Tuple[str, str]]]:
     return (m.group(1) if m else ""), table
 
 
+def index_program(hlo_text: str) -> str:
+    """Keep the scope index and the kernel calls of an optimized HLO
+    module (``compiled.as_text()``) under its name, which is returned.
+    Every :class:`AOTProgram` build does; a program compiled some other
+    way (for a described chip) is read the same way through this."""
+    module, table = parse_scopes(hlo_text)
+    SCOPES[module] = table
+    KERNEL_CALLS[module] = frozenset(
+        m.group(1) for m in map(_HLO_INSTRUCTION.match, hlo_text.splitlines())
+        if m and _MOSAIC_CALL in m.string)
+    return module
+
+
 def scopes(module_name: str) -> Optional[Dict[str, Tuple[str, str]]]:
     """The scope index of the newest executable built under this HLO
     module name, or None when none was."""
     return SCOPES.get(module_name)
+
+
+def kernel_calls(module_name: str, block: Optional[str] = None,
+                 phase: Optional[str] = None) -> Optional[List[str]]:
+    """The Mosaic kernel calls of the newest executable built under
+    this HLO module name, sorted, or None when none was; with ``block``
+    and/or ``phase``, those the scope index places there.
+    ``kernel_calls("jit_train_step", "attn", "remat")`` is what a
+    recomputed layer body runs of attention's kernels AGAIN: no
+    ``flash_fwd`` under any policy ``resolve_checkpoint_policy`` builds
+    but ``"full"``, under which there is one a layer body. An
+    interpreted kernel (the CPU tests) is no call."""
+    calls = KERNEL_CALLS.get(module_name)
+    if calls is None:
+        return None
+    index = SCOPES[module_name]
+
+    def there(name):
+        b, p = index.get(name, (None, None))
+        return block in (None, b) and phase in (None, p)
+
+    return sorted(filter(there, calls))
 
 
 def _inputs_drifted(compiled, args) -> bool:
@@ -167,8 +210,7 @@ class AOTProgram:
             lowered = self._jitted.lower(*args)
         compiled = lowered.compile()
         self.builds += 1
-        module, table = parse_scopes(compiled.as_text())
-        SCOPES[module] = table
+        index_program(compiled.as_text())
         if self._on_attribute is not None:
             self._on_attribute(self.kind, lowered, compiled)
         return compiled

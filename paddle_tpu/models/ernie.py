@@ -54,8 +54,9 @@ class ErnieConfig:
     #: (nn.scan; O(1) trace/compile in num_layers, state_dict unchanged)
     scan_layers: bool = True
     use_recompute: bool = False
-    #: selective-remat policy name (fleet.utils.recompute.
-    #: resolve_checkpoint_policy); None = full remat
+    #: remat policy name (fleet.utils.recompute.
+    #: resolve_checkpoint_policy); None = everything recomputed but the
+    #: flash kernel's output and log-sum-exp, 'full' = nothing kept
     recompute_policy: Optional[str] = None
 
 

@@ -22,7 +22,8 @@ TPU-native design (GSPMD, single logical program):
   vocab-sharded into ParallelCrossEntropy (the c_softmax_with_cross_entropy
   pattern) so the [B, S, V] logits tensor is never materialised replicated.
 - ``use_recompute`` wraps each block in jax.checkpoint (reference:
-  fleet/utils/recompute.py) to trade FLOPs for HBM.
+  fleet/utils/recompute.py) to trade FLOPs for HBM; the flash kernel's
+  output and log-sum-exp are kept, so its forward runs once a step.
 - ``sequence_parallel`` pins the residual stream's seq axis to the ``sp``
   mesh axis so LayerNorm/dropout activations are sequence-sharded
   (reference: sequence_parallel_utils.py scatter/gather pattern).
@@ -73,10 +74,14 @@ class GPTConfig:
     attention_dropout_prob: float = 0.1
     initializer_range: float = 0.02
     use_recompute: bool = False
-    #: selective-remat policy name for use_recompute (see
-    #: fleet.utils.recompute.resolve_checkpoint_policy); None = full remat.
-    #: 'dots_with_no_batch_dims_saveable' keeps MXU outputs resident and
-    #: rematerializes only the elementwise tail — the TPU default trade.
+    #: remat policy name for use_recompute (see
+    #: fleet.utils.recompute.resolve_checkpoint_policy). None recomputes
+    #: the block from its input EXCEPT the flash-attention forward, whose
+    #: output and log-sum-exp are kept (16.5 MB a layer at B=8, S=1024,
+    #: E=1024; the kernel would cost as much again as its backward).
+    #: 'dots_with_no_batch_dims_saveable' also keeps MXU outputs resident
+    #: and rematerializes only the elementwise tail — the TPU default
+    #: trade. 'full' keeps nothing: the kernel runs a second time.
     recompute_policy: Optional[str] = None
     #: run the decoder stack as one jax.lax.scan over layer-stacked params
     #: (nn.scan): O(1) trace+compile in num_layers, per-layer state_dict

@@ -14,9 +14,12 @@ scan-over-stacked-params recipe:
   real blocks (``layers.0.attn.qkv_weight`` state_dict names, ``LayerList``
   indexing/iteration, per-layer ``Parameter.spec`` TP shardings) — the
   stack is an internal, trace-time layout (docs/PARITY.md);
-- selective remat composes INSIDE the body: ``jax.checkpoint(body,
-  policy=...)`` saves MXU outputs and rematerializes the elementwise tail
-  (``prevent_cse=False`` per the jax guidance for remat-in-scan);
+- remat composes INSIDE the body: ``jax.checkpoint(body, policy=...)``
+  (``prevent_cse=False`` per the jax guidance for remat-in-scan). What a
+  policy keeps is ``fleet.utils.recompute.resolve_checkpoint_policy``'s
+  to say: by default the flash kernel's output and log-sum-exp and
+  nothing else (a recomputed body never re-runs the kernel's forward), a
+  dots policy the MXU outputs besides, ``"full"`` nothing;
 - RNG: each layer folds its index into the scan's base key, so dropout
   masks stay distinct per layer (the loop path draws per-layer keys from
   the trace counter instead — same distribution, different realization).
@@ -220,8 +223,9 @@ def scan_layers(blocks, x, *extra, policy=None, use_recompute: bool = False,
     ``blocks``: homogeneous Layers (pre-validated with
     :func:`can_scan_layers`). ``extra``: broadcast (non-scanned) Tensor
     arguments passed to every block call, e.g. an attention mask.
-    ``policy``: a ``jax.checkpoint_policies`` predicate (or name — see
-    ``fleet.utils.recompute.resolve_checkpoint_policy``) for selective
+    ``policy``: a ``jax.checkpoint_policies`` predicate (or name, or None
+    — see ``fleet.utils.recompute.resolve_checkpoint_policy``, which
+    keeps the flash kernel's residuals under each but ``"full"``) for
     remat; only applied when ``use_recompute``.
 
     ``num_aux``: when > 0, each block's forward returns ``(x, aux_1, ...,
@@ -315,8 +319,9 @@ def scan_layers(blocks, x, *extra, policy=None, use_recompute: bool = False,
     # closure-captured value with semantic effect; the cache's strong ref
     # to the first call's closure keeps `template` alive, so id(template)
     # cannot be reused while the entry lives.
-    policy_tok = ((getattr(policy, "__name__", None), id(policy))
-                  if policy is not None else None)
+    # the resolver hands out ONE predicate a policy name, so its
+    # identity tells the policies apart
+    policy_tok = id(policy)
     # _config_sig(template) rides in the token so an IN-PLACE config edit
     # (e.g. setting every layer's dropout p) changes the key and retraces —
     # a cached trace must never replay stale config values

@@ -82,8 +82,18 @@ __all__ = [
     "bgmv", "bgmv_xla",
     "kernels", "kernel_enabled", "note_fallback", "backend_supported",
     "interpret",
-    "PALLAS_STATS", "reset_pallas_stats",
+    "PALLAS_STATS", "reset_pallas_stats", "FLASH_RESIDUAL_NAMES",
 ]
+
+#: ``checkpoint_name`` tags of what flash attention's differentiated
+#: forward keeps for its backward kernel: the output ``o`` (the size of
+#: the layer's input) and one column of the log-sum-exp rows (``[B, H,
+#: S]`` float32). A remat policy that saves these names never re-runs the
+#: forward kernel to rebuild them, and every policy that
+#: ``fleet.utils.recompute.resolve_checkpoint_policy`` builds does,
+#: except ``"full"``. Here, not in ``flash_attention.py``, so that
+#: resolving a policy does not load Pallas.
+FLASH_RESIDUAL_NAMES = ("flash_attention_o", "flash_attention_lse")
 
 #: always-on fallback observability (monitor-independent, like
 #: nn.scan.SCAN_STATS): {(kernel, reason): count}

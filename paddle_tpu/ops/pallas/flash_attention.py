@@ -54,10 +54,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import interpret as _interpret
+from . import FLASH_RESIDUAL_NAMES, interpret as _interpret
 
 # 512x512 tiles win on v5e: fewer grid steps amortize the VMEM loads and the
 # p-tile (512*512*4B = 1 MiB) still fits comfortably; measured ~28% faster
@@ -938,11 +939,24 @@ def _flash_fwd(q, k, v, bias, seed_f, scale, causal, block_q, block_k,
                rate):
     o, lse = _fwd(q, k, v, bias, scale, causal, block_q, block_k,
                   seed=_seed_arr(seed_f), rate=rate)
+    # What the backward keeps is NAMED, so that a remat policy can keep
+    # it too (FLASH_RESIDUAL_NAMES). The named output is also what the
+    # rest of the block reads: a recomputed block then needs nothing
+    # that only the kernel can give. `o` is named as [B, S, E], the
+    # kernel's own layout (as [B, S, H, D] XLA:TPU kept the layers'
+    # stack S-minor and relaid 16 MB a layer out each way). Of the
+    # log-sum-exp tile's 8 equal columns one is kept: [B, H, S] lies
+    # dense in HBM, where the TPU layout pads 8 lanes to 128 (0.5 MB a
+    # layer at B=8, S=1024, H=16 against 64).
+    o = checkpoint_name(o.reshape(o.shape[:2] + (-1,)),
+                        FLASH_RESIDUAL_NAMES[0]).reshape(o.shape)
+    lse = checkpoint_name(lse[..., 0], FLASH_RESIDUAL_NAMES[1])
     return o, (q, k, v, bias, seed_f, o, lse)
 
 
 def _flash_bwd(scale, causal, block_q, block_k, rate, res, do):
     q, k, v, bias, seed_f, o, lse = res
+    lse = jnp.broadcast_to(lse[..., None], lse.shape + (8,))
     dq, dk, dv, db = _bwd_impl(q, k, v, bias, o, lse, do, scale, causal,
                                block_q, block_k, seed=_seed_arr(seed_f),
                                rate=rate)

@@ -336,3 +336,95 @@ def test_scan_with_cache_carries_the_cache_whole():
     np.testing.assert_allclose(np.asarray(log._data)[:, 0],
                                [1.5, 3.0, 4.5, 6.0])
     np.testing.assert_allclose(y.numpy(), ref.numpy(), rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# recompute keeps the flash kernel's residuals (ISSUE 34): the trace cache
+# under the resolved policies, and the serving programs left as they were
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("policy", [None, "dots_saveable", "full"])
+def test_eager_recompute_scan_replays_its_cached_trace(policy):
+    """The resolver hands out ONE predicate a policy name and the scan's
+    trace cache keys on its identity: a warm eager step traces no body,
+    whichever policy (a predicate built anew each call would retrace
+    every step)."""
+    from paddle_tpu.nn import scan as nn_scan
+    paddle.seed(5)
+    m = GPTForPretraining(gpt_tiny(num_layers=2, use_recompute=True,
+                                   recompute_policy=policy))
+    m.train()
+    ids, labels = _batch(seed=1)
+    crit = GPTPretrainingCriterion()
+    crit(m(ids), labels).backward()
+    nn_scan.reset_scan_stats()
+    crit(m(ids), labels).backward()
+    assert nn_scan.SCAN_STATS["scan_calls"] == 1
+    assert nn_scan.SCAN_STATS["body_traces"] == 0
+
+
+#: sha256 of `str(jaxpr)` of what the serving programs are made of, taken
+#: from the commit BEFORE the flash forward's residuals were named
+#: (35758db6, PR 32): the primal `_flash` and the engine's programs hold
+#: no `jax.checkpoint` and never reach the differentiated forward, so
+#: they trace to what they were, equation for equation. A change of
+#: jax's printer would move the hashes with no change here: take them
+#: anew from that commit then.
+_PR32_JAXPR = {
+    "flash-bfloat16": "57f36327ebbdc9d7a0bfd7fccfd28e8e59c6790420392e1a765c529661660373",
+    "flash-float32": "24619bab93376ee6347032162f5b697c8eb6baddb9404d8860df0958134b54de",
+    "prefill": "0d96281ad90948c3279430b67cc0e6c2aee0bca051010f9768bab7c82e7951a7",
+    "decode": "0a2cd1db06bc721d0a1b97c3740edb4d66ac1e3f75b7b77fb8a7bbf5522f4aee"}
+
+
+def _sha(jaxpr) -> str:
+    import hashlib
+    return hashlib.sha256(str(jaxpr).encode()).hexdigest()
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_inference_traces_as_before(dtype):
+    """`flash_attention` with no gradient asked is the primal: no
+    log-sum-exp output, no named residual."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    q = jnp.zeros((2, 256, 2, 64), dtype)
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v: flash_attention(q, k, v, causal=True))(q, q, q)
+    assert "name[" not in str(jaxpr)
+    assert _sha(jaxpr) == _PR32_JAXPR["flash-" + dtype]
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_serving_programs_trace_as_before(monkeypatch, kind):
+    """The `closed64` engine's programs at a rehearsal size that the
+    flash gate takes (heads of 64, a 256-token bucket; bf16 weights and
+    cache as in the cell): the prefill program holds `flash_fwd` and the
+    decode program `paged_decode`, and both are the parent's."""
+    import jax
+    from paddle_tpu import inference
+    from paddle_tpu.serving import ServingConfig
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    paddle.seed(0)
+    model = GPTForPretraining(gpt_tiny(hidden_size=128, num_heads=2,
+                                       max_position_embeddings=512))
+    cfg = inference.Config.from_layer(model, input_spec=[])
+    cfg.enable_tpu_bf16()
+    eng = inference.create_serving_engine(cfg, ServingConfig(
+        max_batch_slots=8, block_size=16, max_context_len=512,
+        prefill_buckets=(256,), batch_buckets=(1, 4),
+        cache_dtype="bfloat16"))
+    try:
+        prog, args = (eng._prefill_program(1, 256) if kind == "prefill"
+                      else eng._decode_program())
+        jaxpr = prog._jitted.trace(*args).jaxpr
+    finally:
+        eng.shutdown()
+    text = str(jaxpr)
+    assert ("flash_fwd" if kind == "prefill" else "paged_decode") in text
+    assert "checkpoint" not in text and "name[" not in text
+    assert _sha(jaxpr) == _PR32_JAXPR[kind]

@@ -349,6 +349,96 @@ def test_serving_program_moves_no_pool(chip, build_for_chip, cell_engine,
     assert mem.temp_size_in_bytes < TEMP_LIMIT, mem
 
 
+# -- the one-chip train step, whole (ISSUE 34) ----------------------------------
+
+
+class _Built(Exception):
+    """Carries the executable out of a TrainStep's first call."""
+
+
+def _train_step_for_chip(policy, chip, build_for_chip, monkeypatch):
+    """The step of `gpt2_345m.train.b8s1024` (AMP O1, AdamW, recompute,
+    B=8, S=1024) at ``GUARD_LAYERS`` layers, compiled for the chip. A
+    TrainStep builds its program in its first call: that build is made
+    for the described device, on the arguments' SHAPES, and the call
+    ends there."""
+    import paddle_tpu as paddle
+    from paddle_tpu.jit import aot
+    from paddle_tpu.models.gpt import (GPTForPretraining,
+                                       GPTPretrainingCriterion, gpt2_medium)
+    paddle.seed(0)
+    model = GPTForPretraining(gpt2_medium(
+        num_layers=GUARD_LAYERS, use_recompute=True, recompute_policy=policy))
+    crit = GPTPretrainingCriterion()
+
+    def loss_fn(layer, ids, labels):
+        with paddle.amp.auto_cast(level="O1"):
+            return crit(layer(ids), labels)
+
+    step = paddle.jit.TrainStep(model, loss_fn, paddle.optimizer.AdamW(
+        learning_rate=1e-4, weight_decay=0.01,
+        parameters=model.parameters()))
+
+    def build(self, args):
+        shaped = jax.tree.map(lambda a: chip(a.shape, a.dtype), args)
+        raise _Built(build_for_chip(self._jitted.lower, *shaped))
+
+    monkeypatch.setattr(aot.AOTProgram, "_build", build)
+    # dispatch asks the backend whether kernels can run: they can, there
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ids = np.zeros(TRAIN_QKV[:2], np.int32)
+    with pytest.raises(_Built) as built:
+        step(ids, ids)
+    monkeypatch.undo()
+    return built.value.args[0]
+
+
+def test_train_step_keeps_the_flash_residuals_dense(chip, build_for_chip,
+                                                    monkeypatch):
+    """ISSUE 34's guard. A recomputed layer body runs no `flash_fwd`
+    again, by `aot.kernel_calls`' reading of the optimized program (one
+    a body under ``"full"``; attention's hidden dropout kernel IS run
+    again under both, its output is not kept), and what keeping the
+    kernel's output and log-sum-exp costs stays near what the issue
+    reckons, 16 MB of ``o`` + 4 MB of ``lse [B, H, S, 8]`` a layer.
+
+    By the compiler's buffer assignment for the described chip the
+    step's temporaries grow 33.0 MiB a layer over ``"full"`` (the same
+    at 4, 8 and 24 layers: 16.5 are the two stacks the scans carry,
+    `bf16[L, 8, 1024, 1024]` and `f32[L, 8, 16, 1024]`; where the other
+    16.5 lie it does not say), so the limit is twice the issue's
+    figure. Kept as the kernel writes it, ``f32[8, 16, 1024, 8]`` under
+    a ``T(8, 128)`` tiling, the log-sum-exp pads its 8 lanes to 128, 64
+    MiB a layer, and the growth read 159.8 MiB a layer: 3.7 GiB at the
+    cell's 24 layers. So ONE column is kept, and the backward widens
+    it."""
+    from paddle_tpu.jit import aot
+    kept = _train_step_for_chip(None, chip, build_for_chip, monkeypatch)
+    full = _train_step_for_chip("full", chip, build_for_chip, monkeypatch)
+    _assert_kernels(kept.as_text(), "flash_fwd", "flash_bwd",
+                    "chunked_ce_lse", "chunked_ce_dlogits")
+
+    def attn_kernels(compiled):
+        module = aot.index_program(compiled.as_text())
+        assert module == "jit_train_step"
+        return [sorted(n.split(".")[0]
+                       for n in aot.kernel_calls(module, "attn", phase))
+                for phase in ("fwd", "remat", "bwd")]
+
+    assert attn_kernels(full) == [["flash_fwd", "fused_dropout"],
+                                  ["flash_fwd", "fused_dropout"],
+                                  ["flash_bwd", "fused_dropout"]]
+    assert attn_kernels(kept) == [["flash_fwd", "fused_dropout"],
+                                  ["fused_dropout"],
+                                  ["flash_bwd", "fused_dropout"]]
+
+    B, S, H, D = TRAIN_QKV
+    reckoned = B * S * H * D * 2 + B * H * S * 8 * 4
+    grew = (kept.memory_analysis().temp_size_in_bytes
+            - full.memory_analysis().temp_size_in_bytes)
+    assert 0 < grew <= 2 * GUARD_LAYERS * reckoned, (grew, reckoned)
+
+
 # -- a model that declares its own page kinds (ISSUE 28) -----------------------
 
 GLM_CELL = "glm52_ep16.serve.closed32_ctx8k"

@@ -620,6 +620,42 @@ ENTRY %main (a: f32[4]) -> f32[4] {
                      "add.6": ("norm", "bwd")}
 
 
+def test_kernel_calls_are_the_mosaic_calls_read_by_scope():
+    """`aot.kernel_calls`: the instructions that are Mosaic custom calls,
+    all of a program's or those the scope index places in a block and a
+    phase (ISSUE 34: what a recomputed body runs of attention AGAIN)."""
+    text = '''HloModule jit_toy_kernels, is_scheduled=true
+
+ENTRY %main (a: f32[4]) -> f32[4] {
+  %a = f32[4] parameter(0)
+  %flash_fwd.2 = f32[4] custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(toy)/jvp()/while/body/closed_call/attn/flash_fwd/pallas_call"}
+  %flash_fwd.3 = f32[4] custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(toy)/transpose(jvp())/while/body/closed_call/checkpoint/rematted_computation/attn/flash_fwd/pallas_call"}
+  %fused_dropout.5 = f32[4] custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(toy)/transpose(jvp())/while/body/closed_call/checkpoint/rematted_computation/ffn/fused_dropout/pallas_call"}
+  %flash_bwd.4 = f32[4] custom-call(%flash_fwd.3), custom_call_target="tpu_custom_call", metadata={op_name="jit(toy)/transpose(jvp())/while/body/closed_call/checkpoint/attn/flash_bwd/pallas_call"}
+  %alloc.7 = f32[4] custom-call(), custom_call_target="AllocateBuffer"
+  %loose.8 = f32[4] custom-call(%a), custom_call_target="tpu_custom_call"
+  ROOT %add.6 = f32[4] add(%flash_bwd.4, %a), metadata={op_name="jit(toy)/transpose(jvp(norm))/add"}
+}
+'''
+    assert aot.kernel_calls("jit_toy_kernels") is None
+    assert aot.index_program(text) == "jit_toy_kernels"
+    assert aot.scopes("jit_toy_kernels")["add.6"] == ("norm", "bwd")
+    assert aot.kernel_calls("jit_toy_kernels") == [
+        "flash_bwd.4", "flash_fwd.2", "flash_fwd.3", "fused_dropout.5",
+        "loose.8"]
+    assert aot.kernel_calls("jit_toy_kernels", "attn") == [
+        "flash_bwd.4", "flash_fwd.2", "flash_fwd.3"]
+    assert aot.kernel_calls("jit_toy_kernels", "attn", "remat") \
+        == ["flash_fwd.3"]
+    assert aot.kernel_calls("jit_toy_kernels", phase="remat") == [
+        "flash_fwd.3", "fused_dropout.5"]
+    assert aot.kernel_calls("jit_toy_kernels", "loss") == []
+    # an interpreted or XLA-path program has an index and no call
+    step = _gpt_step(use_recompute=True)
+    step(_ids(), _ids())
+    assert aot.kernel_calls("jit_train_step") == []
+
+
 def test_scopes_of_a_toy_gpt_step():
     step = _gpt_step(use_recompute=True)
     step(_ids(), _ids())
