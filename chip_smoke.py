@@ -9,7 +9,7 @@ vocab 50304) trains and serves with:
            against its XLA reference: flash attention forward and
            backward in bf16 and the f32 forward, chunked cross-entropy,
            paged decode (a K/V head a query head; 16 query heads a K/V
-           head under a window)
+           head under a window; 32 absorbed queries over ONE latent pool)
 
 That a TrainStep, a ServingEngine or a four-chip mesh still runs on the
 chip, and still agrees with a plain reference, is what the benchmark's
@@ -55,6 +55,11 @@ REAL = dict(
     # a window of 4,096 over a table of 28,672 positions, 385 pages a slot
     paged_window=dict(slots=24, heads=128, kv_heads=8, head_dim=128,
                       block=16, blocks=1792, window=4096),
+    # xing4_29b_ep1's decode step: 64 slots, 32 heads' absorbed queries of
+    # 512 + 64 in rows of 640 lanes, a table of 18,432 positions; the
+    # longest slot here 8,192 (the float32 reference gathers every one)
+    paged_latent=dict(slots=64, heads=32, rank=512, rope=64, width=640,
+                      block=16, blocks=1152, longest=8192),
 )
 REHEARSE = dict(
     flash_train=(1, 256, 2, 64), flash_prefill=(1, 256, 2, 64),
@@ -62,6 +67,8 @@ REHEARSE = dict(
     paged=dict(slots=2, heads=2, head_dim=8, block=4, blocks=4),
     paged_window=dict(slots=3, heads=4, kv_heads=2, head_dim=8, block=4,
                       blocks=16, window=8),
+    paged_latent=dict(slots=3, heads=4, rank=16, rope=4, width=20, block=4,
+                      blocks=8, longest=24),
 )
 
 # -- tolerances, per dtype ---------------------------------------------------
@@ -155,7 +162,7 @@ def kernel_names(text: str) -> set:
     """The package's named pallas_calls present in a compiled program."""
     check("tpu_custom_call" in text, "no tpu_custom_call in the program")
     names = ("flash_fwd", "flash_bwd", "chunked_ce_lse",
-             "chunked_ce_dlogits", "paged_decode")
+             "chunked_ce_dlogits", "paged_decode", "paged_mla_decode")
     return {n for n in names if n in text}
 
 
@@ -376,6 +383,51 @@ def phase_kernels(args, cfg):
                                     table, pos, first)
         report(f"paged_decode q{(B, H, D)} on {Hkv} K/V heads, window {W}, "
                f"pool{(P, 1, bs, Hkv * D)} {name}",
+               [rel_err(out_k, out_r)], TOL[name])
+
+    # -- dense latent attention: ONE pool, its rows the keys of every head
+    #    and, their first `rank` lanes, the values ---------------------------
+    p = cfg["paged_latent"]
+    B, H, r, dr, Wd, bs, MB, longest = (
+        p["slots"], p["heads"], p["rank"], p["rope"], p["width"], p["block"],
+        p["blocks"], p["longest"])
+    live = longest // bs
+    P = B * live + 1
+    # a page's first and last row, a page's edge, chunk edges, the longest
+    pos = np.resize(np.array([0, bs - 1, bs, longest // 16 - 1,
+                              longest // 4 - 1, longest // 2 + 3,
+                              longest - bs - 1, longest - 1]),
+                    B).astype(np.int32)
+    table = np.zeros((B, MB), np.int32)
+    for b in range(B):
+        n = pos[b] // bs + 1
+        table[b, :n] = 1 + b * live + np.arange(n)
+    table, pos = jnp.asarray(table), jnp.asarray(pos)
+    # the cell's 192^-0.5 * m^2 = 0.1447 to a percent (the nope dims are
+    # a quarter of the rank there)
+    scale = float((r // 4 + dr) ** -0.5 * 2.0)
+    pad = jnp.arange(Wd) < r + dr
+
+    def latent_ref(q, pool, table, pos):
+        rows = pool[table[:, :live]].reshape(B, live * bs, Wd)
+        seen = jnp.arange(live * bs)[None, :] <= pos[:, None]
+        with jax.default_matmul_precision("highest"):
+            s = jnp.einsum("bhw,bkw->bhk", q, rows) * scale
+            pr = jax.nn.softmax(jnp.where(seen[:, None], s, -1e30), -1)
+            return jnp.einsum("bhk,bkr->bhr", pr, rows[..., :r])
+
+    for dtype in (jnp.float32, jnp.bfloat16):
+        name = jnp.dtype(dtype).name
+        lo = (jnp.where(pad, normal((B, H, Wd)), 0.0).astype(dtype),
+              jnp.where(pad, normal((P, 1, bs, Wd)), 0.0).astype(dtype))
+        out_k = run_kernel(
+            lambda q, pool, t, p: pd.paged_mla_decode(
+                q, pool, t, p, scale=scale, value_width=r),
+            *lo, table, pos, want=("paged_mla_decode",))
+        out_r = jax.jit(latent_ref)(*(x.astype(jnp.float32) for x in lo),
+                                    table, pos)
+        report(f"paged_mla_decode q{(B, H, Wd)} values {r} "
+               f"pool{(P, 1, bs, Wd)} {name}",
                [rel_err(out_k, out_r)], TOL[name])
 
 

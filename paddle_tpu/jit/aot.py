@@ -60,6 +60,11 @@ _HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r"op_name=\"([^\"]*)\"")
 _PATH_WORD = re.compile(r"[A-Za-z_]+")
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+#: ``op_name``s XLA:TPU gives instructions of its own making, which
+#: carry no path: the grouped product ``jax.lax.ragged_dot`` is expanded
+#: into (a Mosaic kernel call a product)
+_COMPILER_MADE = ("ragged-dot",)
 
 
 def _resolve(op_name: str) -> Optional[Tuple[str, str]]:
@@ -86,7 +91,12 @@ def parse_scopes(hlo_text: str) -> Tuple[str, Dict[str, Tuple[str, str]]]:
     """(module name, {instruction name: (block, phase)}) from the text
     of an optimized HLO module (``compiled.as_text()``).
 
-    An instruction resolves by its OWN ``op_name`` and by nothing else.
+    An instruction resolves by its OWN ``op_name``, with one exception:
+    an instruction XLA made itself under a name of its own
+    (:data:`_COMPILER_MADE`: ``ragged-dot-none``, the kernel call a
+    ``ragged_dot`` becomes on a TPU; 36% of the Command A+ cell's device
+    time read as unscoped for it, PERF.md, PR 32) takes the block of the
+    instructions that READ it, where they all agree.
     One whose path holds no block (XLA names a fusion after its root,
     and the root may be plumbing; a layout copy carries no metadata at
     all) stays out of the table, and its time reads as unscoped:
@@ -95,12 +105,26 @@ def parse_scopes(hlo_text: str) -> Tuple[str, Dict[str, Tuple[str, str]]]:
     between blocks without showing."""
     m = _HLO_MODULE.match(hlo_text)
     table: Dict[str, Tuple[str, str]] = {}
-    for line in hlo_text.splitlines():
+    made: Dict[str, set] = {}           # compiler-made -> its readers' hits
+    lines = hlo_text.splitlines()
+    for line in lines:
         inst = _HLO_INSTRUCTION.match(line)
         op_name = _HLO_OP_NAME.search(line) if inst else None
         hit = _resolve(op_name.group(1)) if op_name else None
         if hit is not None:
             table[inst.group(1)] = hit
+        elif op_name and op_name.group(1).startswith(_COMPILER_MADE):
+            made[inst.group(1)] = set()
+    if made:
+        for line in lines:
+            inst = _HLO_INSTRUCTION.match(line)
+            hit = table.get(inst.group(1)) if inst else None
+            if hit is not None:
+                for operand in _HLO_OPERAND.findall(line[inst.end():]):
+                    if operand in made:
+                        made[operand].add(hit)
+        table.update({name: hits.pop() for name, hits in made.items()
+                      if len(hits) == 1})
     return (m.group(1) if m else ""), table
 
 

@@ -128,9 +128,16 @@ class GlmMoeDsaConfig:
         from ..serving.kv_cache import PageKind
         full = tuple(i for i, t in enumerate(self.indexer_types)
                      if t == "full")
-        return (PageKind("latent", self.latent_width,
-                         tuple(range(self.num_layers))),
+        return (latent_page_kind(self),
                 PageKind("index", self.index_head_dim, full))
+
+
+def latent_page_kind(cfg):
+    """The page kind of an MLA family: ``[c_kv ; rope(k_r)]`` of a
+    position, no head axis, in every layer, for as long as the slot."""
+    from ..serving.kv_cache import PageKind
+    return PageKind("latent", cfg.kv_lora_rank + cfg.qk_rope_head_dim,
+                    tuple(range(cfg.num_layers)))
 
 
 def glm_moe_dsa_tiny(**kw) -> GlmMoeDsaConfig:
@@ -170,12 +177,15 @@ def _layer_norm(x, w, b, eps=1e-6):
     return (y * w.astype(F32) + b.astype(F32)).astype(x.dtype)
 
 
-def _rotary(x, positions, theta):
+def _rotary(x, positions, theta, inv=None):
     """Interleaved rotary embedding over the last axis of ``x``
     ``[B, S, (H,) d]``: pairs ``(x[2i], x[2i+1])`` turn by
-    ``position * theta^(-2i/d)``; float32 inside."""
+    ``position * theta^(-2i/d)``, or by ``position * inv[i]`` where a
+    family hands its own ``d/2`` frequencies (``xing4.yarn_inv_freq``);
+    float32 inside."""
     d = x.shape[-1]
-    inv = jnp.exp(-math.log(theta) * jnp.arange(0, d, 2, dtype=F32) / d)
+    if inv is None:
+        inv = jnp.exp(-math.log(theta) * jnp.arange(0, d, 2, dtype=F32) / d)
     ang = positions.astype(F32)[..., None] * inv                # [B, S, d/2]
     if x.ndim == 4:
         ang = ang[:, :, None, :]
@@ -267,12 +277,35 @@ def topk_members(scores, k: int):
         & (scores > _NEG)
 
 
+def mla_project(at, h, positions, cfg, inv=None):
+    """The MLA projections of ``h`` ``[B, S, D]`` through ``at`` (a
+    :class:`GlmAttention`): ``(c_q, q_nope [B, S, H, dn], q_rope
+    [B, S, H, dr] turned, latent [B, S, r + dr])``, the latent
+    ``[RMSNorm(c_kv) ; rope(k_r)]`` being what a position caches.
+    ``inv``: the rotary's own frequencies (:func:`_rotary`)."""
+    B, S, _ = h.shape
+    H, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    c_q = _rms_norm(_mm(h, at.wq_a._data), at.q_norm.weight._data,
+                    cfg.rms_norm_eps)
+    q = _mm(c_q, at.wq_b._data).reshape(B, S, H, dn + dr)
+    q_nope = q[..., :dn]
+    q_rope = _rotary(q[..., dn:], positions, cfg.rope_theta, inv)
+    kv = _mm(h, at.wkv_a._data)
+    c_kv = _rms_norm(kv[..., :cfg.kv_lora_rank],
+                     at.kv_norm.weight._data, cfg.rms_norm_eps)
+    k_r = _rotary(kv[..., cfg.kv_lora_rank:], positions,
+                  cfg.rope_theta, inv)
+    return c_q, q_nope, q_rope, jnp.concatenate([c_kv, k_r], axis=-1)
+
+
 def mla_context_attention(q_nope, q_rope, pool, table, base, pos, w_kvb,
-                          member, cfg: GlmMoeDsaConfig):
+                          member, cfg, scale: Optional[float] = None):
     """Prefill attention ``[B, S, H*dv]`` of a chunk at ``pos`` over
     what the latent pages hold (the chunk's own rows included), limited
-    to ``member`` ``[B, S, L]`` (None: every position ``<= t``). One
-    block of the context at a time: its K and V are expanded from the
+    to ``member`` ``[B, S, L]`` (None: every position ``<= t``, dense
+    attention). ``cfg``: any config with the MLA widths and
+    ``context_block``; ``scale``: the scores' factor where it is not
+    ``qk_head_dim ** -0.5``. One block of the context at a time: its K and V are expanded from the
     latent and scored in ONE product over ``[nope ; rope]`` (the rope
     key repeated over the heads: two products summed cost a further
     pass over the scores), masked and folded into an online softmax.
@@ -284,7 +317,8 @@ def mla_context_attention(q_nope, q_rope, pool, table, base, pos, w_kvb,
     pb = _pages_a_block(mb, bs, cfg.context_block)
     kb = pb * bs
     n_blk = (jnp.max(pos) + S + kb - 1) // kb
-    scale = 1.0 / math.sqrt(cfg.qk_head_dim)
+    if scale is None:
+        scale = 1.0 / math.sqrt(cfg.qk_head_dim)
     q_pos = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     dt = q_nope.dtype
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
@@ -323,12 +357,15 @@ def mla_context_attention(q_nope, q_rope, pool, table, base, pos, w_kvb,
 
 
 def mla_sparse_decode(q_nope, q_rope, pool, table, base, idx, valid, w_kvb,
-                      cfg: GlmMoeDsaConfig):
+                      cfg, scale: Optional[float] = None):
     """Decode attention ``[B, H*dv]`` over the selected positions
     ``idx`` ``[B, K]`` (``valid`` marks the real ones): only those
     latent rows are read, through the table, and attention runs in
     absorbed form — ``W_kvb``'s key half folded into the query, its
-    value half applied to the weighted latent."""
+    value half applied to the weighted latent. With ``idx`` every
+    position of the table and ``valid`` the causal mask it is the dense
+    decode's XLA form (``xing4``, where the kernel is off); ``scale``:
+    the scores' factor where it is not ``qk_head_dim ** -0.5``."""
     B, H, dn = q_nope.shape
     dv, r = cfg.v_head_dim, cfg.kv_lora_rank
     bs = pool.shape[2]
@@ -343,13 +380,51 @@ def mla_sparse_decode(q_nope, q_rope, pool, table, base, idx, valid, w_kvb,
                    preferred_element_type=F32) \
         + jnp.einsum("bhd,bkd->bhk", q_rope, k_r,
                      preferred_element_type=F32)
-    s = jnp.where(valid[:, None], s / math.sqrt(cfg.qk_head_dim), _NEG)
+    s = jnp.where(valid[:, None], s / math.sqrt(cfg.qk_head_dim)
+                  if scale is None else s * scale, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     o_lat = jnp.einsum("bhk,bkr->bhr", p.astype(dt), c_kv,
                        preferred_element_type=F32).astype(dt)
     o = jnp.einsum("bhr,rhd->bhd", o_lat, w[..., dn:],
                    preferred_element_type=F32)
     return o.reshape(B, H * dv).astype(dt)
+
+
+def moe_layer(moe, h, stats, cfg, taps=None):
+    """An expert layer (a :class:`GlmMoE`) on ``h`` ``[B, S, D]``: the
+    held experts' part for the tokens routed to them plus the shared
+    expert; in a decode step, a row a slot of what it counted into
+    ``stats``. ``cfg``: any config with ``num_experts_per_tok``,
+    ``routed_scaling_factor`` and ``experts_held``."""
+    B, S, D = h.shape
+    flat = h.reshape(B * S, D)
+    # the router's operand, ONE array for its product and for a probe
+    flat32 = flat.astype(F32)
+    routing = sigmoid_topk_routing(
+        flat32, moe.router.weight._data, moe.router.bias._data,
+        cfg.num_experts_per_tok, cfg.routed_scaling_factor)
+    first, held = cfg.experts_held
+    y, _, here = held_experts_ffn(
+        flat, routing, moe.experts.w_in._data, moe.experts.w_out._data,
+        first)
+    y = y + gated_ffn(flat, moe.shared.w_in._data,
+                      moe.shared.w_out._data)
+    if taps is not None:
+        taps.setdefault("router_topk", []).append(
+            routing.idx.reshape(B, S, -1))
+        taps.setdefault("router_probe", []).append(dict(
+            scores=routing.scores.reshape(B, S, -1)[:, -1],
+            x=flat32.reshape(B, S, D)[:, -1]))
+    if S == 1:
+        # a row a slot, for the engine's counters (active slots only)
+        given = jnp.sum(
+            (routing.idx - first)[..., None] == jnp.arange(held),
+            axis=1, dtype=jnp.int32)                        # [B, held]
+        skipped = jnp.sum(~here, axis=1, dtype=jnp.int32)
+        for key, v in (("serve_moe_routed_tokens_total:expert", given),
+                       ("serve_moe_skipped_pairs_total", skipped)):
+            stats[key] = stats[key] + v if key in stats else v
+    return y.reshape(B, S, D).astype(h.dtype)
 
 
 # -- layers ---------------------------------------------------------------------
@@ -543,7 +618,7 @@ class GlmMoeDsaForCausalLM(Layer):
                                   layer.mlp.w_out._data).astype(x.dtype)
             else:
                 with jax.named_scope("moe"):
-                    y = self._moe(layer.moe, h, stats)
+                    y = moe_layer(layer.moe, h, stats, cfg, self.taps)
             x = x + y
         if S == 1:
             # what a decode step counted, a row a slot: the engine adds
@@ -565,20 +640,9 @@ class GlmMoeDsaForCausalLM(Layer):
                    base_idx, sel):
         from ..serving.kv_cache import write_pages
         cfg, at = self.cfg, layer.attn
-        B, S, _ = h.shape
-        H, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        S = h.shape[1]
         with jax.named_scope("mla"):
-            c_q = _rms_norm(_mm(h, at.wq_a._data), at.q_norm.weight._data,
-                            cfg.rms_norm_eps)
-            q = _mm(c_q, at.wq_b._data).reshape(B, S, H, dn + dr)
-            q_nope = q[..., :dn]
-            q_rope = _rotary(q[..., dn:], positions, cfg.rope_theta)
-            kv = _mm(h, at.wkv_a._data)
-            c_kv = _rms_norm(kv[..., :cfg.kv_lora_rank],
-                             at.kv_norm.weight._data, cfg.rms_norm_eps)
-            k_r = _rotary(kv[..., cfg.kv_lora_rank:], positions,
-                          cfg.rope_theta)
-            latent = jnp.concatenate([c_kv, k_r], axis=-1)
+            c_q, q_nope, q_rope, latent = mla_project(at, h, positions, cfg)
         with jax.named_scope("kv_write"):
             pools = dict(pools, latent=write_pages(
                 pools["latent"], latent[:, :, None, :], table, pos,
@@ -648,36 +712,3 @@ class GlmMoeDsaForCausalLM(Layer):
                     pools["index"], table[:self.taps.get("live", B)],
                     base_idx, 0, table.shape[1])))
         return pools, sel
-
-    # -- experts -------------------------------------------------------------------
-    def _moe(self, moe, h, stats):
-        cfg = self.cfg
-        B, S, D = h.shape
-        flat = h.reshape(B * S, D)
-        # the router's operand, ONE array for its product and for a probe
-        flat32 = flat.astype(F32)
-        routing = sigmoid_topk_routing(
-            flat32, moe.router.weight._data, moe.router.bias._data,
-            cfg.num_experts_per_tok, cfg.routed_scaling_factor)
-        first, held = cfg.experts_held
-        y, _, here = held_experts_ffn(
-            flat, routing, moe.experts.w_in._data, moe.experts.w_out._data,
-            first)
-        y = y + gated_ffn(flat, moe.shared.w_in._data,
-                          moe.shared.w_out._data)
-        if self.taps is not None:
-            self.taps.setdefault("router_topk", []).append(
-                routing.idx.reshape(B, S, -1))
-            self.taps.setdefault("router_probe", []).append(dict(
-                scores=routing.scores.reshape(B, S, -1)[:, -1],
-                x=flat32.reshape(B, S, D)[:, -1]))
-        if S == 1:
-            # a row a slot, for the engine's counters (active slots only)
-            given = jnp.sum(
-                (routing.idx - first)[..., None] == jnp.arange(held),
-                axis=1, dtype=jnp.int32)                        # [B, held]
-            skipped = jnp.sum(~here, axis=1, dtype=jnp.int32)
-            for key, v in (("serve_moe_routed_tokens_total:expert", given),
-                           ("serve_moe_skipped_pairs_total", skipped)):
-                stats[key] = stats[key] + v if key in stats else v
-        return y.reshape(B, S, D).astype(h.dtype)

@@ -66,6 +66,14 @@ is 98,304 grid steps a decode step of the serve cell, 70% of them past
 the slots' positions). Query heads may share a K/V head (the query is
 laid out block-diagonally over a K/V head's lanes) and a slot may have a
 first visible position (a window: the sweep starts at its table entry).
+Its LATENT form, ``paged_mla_decode`` (same flag, same sweep, same
+precision): ONE pool whose rows are the keys of every head and, their
+first ``value_width`` lanes, the values (MLA's ``[c_kv ; rope(k_r)]``,
+576 values in 640 lanes); a page is copied ONCE a group where the pool
+handed in as K and as V would be copied twice, each head's absorbed
+query reads the whole row (no block diagonal), and the output is the
+weighted latent a head (``models/xing4.py`` folds ``W_kvb`` into the
+query before and applies its value half after).
 """
 
 from __future__ import annotations
@@ -77,7 +85,7 @@ from ...core.flags import get_flag
 
 __all__ = [
     "flash_attention", "chunked_ce_loss", "paged_decode_attention",
-    "paged_decode_attention_quant",
+    "paged_decode_attention_quant", "paged_mla_decode",
     "int8_matmul", "int8_linear", "int8_amp_linear", "quantize_per_channel",
     "bgmv", "bgmv_xla",
     "kernels", "kernel_enabled", "note_fallback", "backend_supported",
@@ -238,6 +246,11 @@ def paged_decode_attention(*args, **kw):
 
 def paged_decode_attention_quant(*args, **kw):
     from .paged_decode import paged_decode_attention_quant as _pd
+    return _pd(*args, **kw)
+
+
+def paged_mla_decode(*args, **kw):
+    from .paged_decode import paged_mla_decode as _pd
     return _pd(*args, **kw)
 
 

@@ -62,6 +62,14 @@ Everything else multiplies in f32 at the highest precision. The page
 size ``bs`` set by ``ServingConfig.block_size`` is the KV block size —
 there is no separate kernel block knob.
 
+The LATENT form (:func:`paged_mla_decode`; trace name
+``paged_mla_decode``) is the same sweep over ONE pool: a row is MLA's
+``[c_kv ; rope(k_r)]`` of a position, the keys of every head and, in its
+first ``value_width`` lanes, their values. A page is copied once a
+group, each head's absorbed query is a whole row (no block diagonal:
+every head reads every lane), scores are ``q @ k^T`` ``[H, k*bs]`` and
+the accumulator ``p @ k[:, :value_width]`` ``[H, value_width]``.
+
 ``G > 1`` is the layout of a pool sharded over an ``mp`` mesh. Under a
 mesh ``kernel_enabled`` routes decode to the XLA fallback, so until a
 per-shard call exists only the tests run the kernel with two groups.
@@ -81,7 +89,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import interpret as _interpret
 
-__all__ = ["paged_decode_attention", "paged_decode_attention_quant"]
+__all__ = ["paged_decode_attention", "paged_decode_attention_quant",
+           "paged_mla_decode"]
 
 NEG_INF = -1e30
 
@@ -134,15 +143,18 @@ def _pages_per_step(bs, F, itemsize, MB):
 
 
 def _decode_kernel(tbl_ref, pos_ref, *refs, scale, bs, hg, D, k,
-                   quant=False, kv_group=1, windowed=False):
+                   quant=False, kv_group=1, windowed=False, latent=0):
     # the slots' first visible positions (a third prefetched scalar array,
     # under a window only), the query, the pools in HBM (int8 pools ride with their scale pools:
     # k, k_scales, v, v_scales), the output row, a [2, k, bs, width]
     # buffer pair a pool, a DMA semaphore a (pool, buffer), and the
-    # buffer this grid step's first group was copied into
+    # buffer this grid step's first group was copied into. The latent
+    # form (`latent` > 0: the width of the values) has ONE pool, whose
+    # rows are the keys of every head and whose first `latent` lanes are
+    # the values: a page is copied once and read as both
     first_ref, refs = (refs[0], refs[1:]) if windowed else (None, refs)
     q_ref, refs = refs[0], refs[1:]
-    n = 4 if quant else 2
+    n = 1 if latent else 4 if quant else 2
     hbm, o_ref, bufs = refs[:n], refs[n], refs[n + 1:2 * n + 1]
     sems, slot_ref = refs[2 * n + 1:]
     b, g = pl.program_id(0), pl.program_id(1)
@@ -150,6 +162,8 @@ def _decode_kernel(tbl_ref, pos_ref, *refs, scale, bs, hg, D, k,
     step, steps = b * G + g, pl.num_programs(0) * G
     n_kv = hg // kv_group
     MB, F = tbl_ref.shape[1], n_kv * D
+    if latent:
+        F = latent                   # the accumulator's width: the values'
     cdt = jnp.bfloat16 if (q_ref.dtype == hbm[0].dtype == jnp.bfloat16) \
         else jnp.float32
     # one bf16 MXU pass is exact for bf16 x bf16; f32 operands need all
@@ -201,12 +215,16 @@ def _decode_kernel(tbl_ref, pos_ref, *refs, scale, bs, hg, D, k,
     slot0 = slot_ref[0]
     # the query, block-diagonal: row h = q[h] in the lanes of the K/V
     # head it reads (its own when kv_group is 1)
-    lanes = _head_lanes(hg, D, kv_group)
-    q_row = q_ref[0, 0].astype(jnp.float32)
-    if kv_group > 1:
-        # [H/G, D] rows, repeated under every K/V head's lanes
-        q_row = jnp.concatenate([q_row] * n_kv, axis=1)
-    q_bd = jnp.where(lanes, q_row, 0.0).astype(cdt)
+    if latent:
+        # every head's absorbed query reads the whole row: no diagonal
+        q_bd = q_ref[0, 0].astype(cdt)                   # [H, width]
+    else:
+        lanes = _head_lanes(hg, D, kv_group)
+        q_row = q_ref[0, 0].astype(jnp.float32)
+        if kv_group > 1:
+            # [H/G, D] rows, repeated under every K/V head's lanes
+            q_row = jnp.concatenate([q_row] * n_kv, axis=1)
+        q_bd = jnp.where(lanes, q_row, 0.0).astype(cdt)
 
     def sweep(i, carry):
         m_prev, l_prev, acc = carry
@@ -228,7 +246,10 @@ def _decode_kernel(tbl_ref, pos_ref, *refs, scale, bs, hg, D, k,
             """Pool ``j``'s k pages as one ``[k*bs, width]`` tile."""
             return bufs[j][slot].astype(dtype).reshape(k * bs, -1)
 
-        if quant:
+        if latent:
+            kk = tile(0, cdt)
+            vv = kk[:, :latent]
+        elif quant:
             # identical math to kv_cache.dequant_pages (each int8 value
             # times its head's scale, in f32), so the kernel stays
             # token-exact against the XLA gather fallback; the scales
@@ -268,6 +289,9 @@ def _decode_kernel(tbl_ref, pos_ref, *refs, scale, bs, hg, D, k,
          jnp.zeros((hg, 1), jnp.float32), jnp.zeros((hg, F), jnp.float32)))
     slot_ref[0] = (slot0 + groups) % 2
     safe_l = jnp.where(l == 0.0, 1.0, l)                 # inactive slot
+    if latent:
+        o_ref[0, 0] = (acc / safe_l).astype(o_ref.dtype)
+        return
     out = jnp.where(lanes, acc / safe_l, 0.0)
     if kv_group > 1:
         # row h keeps the D lanes of its K/V head: the other heads' lane
@@ -281,15 +305,21 @@ def _decode_kernel(tbl_ref, pos_ref, *refs, scale, bs, hg, D, k,
 
 
 def _paged_decode(q, pools, block_table, pos, *, scale, quant, name,
-                  first=None):
-    """The one pallas_call behind both entry points. ``pools`` is
-    ``(k, v)`` or ``(k, k_scales, v, v_scales)``; the number of query
-    heads a K/V head follows from ``q`` and the pools' row width."""
+                  first=None, latent=0):
+    """The one pallas_call behind the entry points. ``pools`` is
+    ``(k, v)``, ``(k, k_scales, v, v_scales)`` or, in the latent form,
+    ``(pool,)``; the number of query heads a K/V head follows from ``q``
+    and the pools' row width."""
     B, H, D = q.shape
     _, G, bs, F = pools[0].shape
     hg = H // G
-    kv_group = hg * D // F
-    if kv_group < 1 or hg % kv_group or hg // kv_group * D != F:
+    kv_group = 1 if latent else hg * D // F
+    if latent:
+        if G != 1 or D != F or not 0 < latent <= F:
+            raise ValueError(
+                f"paged_mla_decode: queries of {D} and values of {latent} "
+                f"over pool rows of {F} in {G} groups")
+    elif kv_group < 1 or hg % kv_group or hg // kv_group * D != F:
         raise ValueError(
             f"paged_decode: {H} query heads of {D} over {G} groups do not "
             f"divide pool rows of {F}")
@@ -312,8 +342,11 @@ def _paged_decode(q, pools, block_table, pos, *, scale, quant, name,
     # a slot's query and output: one fused row when a query head has a
     # K/V head of its own, [H/G, D] rows when several share one
     rows = (1, F) if kv_group == 1 else (hg, D)
+    if latent:
+        rows = (hg, F)
+    out_rows = (hg, latent) if latent else rows
 
-    def row():
+    def row(rows=rows):
         return pl.BlockSpec((1, 1) + rows, lambda b, g, *_: (b, g, 0, 0))
 
     scalars = (block_table.astype(jnp.int32), pos.astype(jnp.int32))
@@ -326,7 +359,7 @@ def _paged_decode(q, pools, block_table, pos, *, scale, quant, name,
         # the table names, and nothing else of them moves
         in_specs=[row()] + [pl.BlockSpec(memory_space=pltpu.HBM)
                             for _ in pools],
-        out_specs=row(),
+        out_specs=row(out_rows),
         scratch_shapes=[pltpu.VMEM((2, k, bs, a.shape[-1]), a.dtype)
                         for a in pools] + [
             pltpu.SemaphoreType.DMA((len(pools), 2)),
@@ -336,16 +369,16 @@ def _paged_decode(q, pools, block_table, pos, *, scale, quant, name,
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=float(scale), bs=bs,
                           hg=hg, D=D, k=k, quant=quant, kv_group=kv_group,
-                          windowed=windowed),
+                          windowed=windowed, latent=latent),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, G) + rows, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, G) + out_rows, q.dtype),
         # a grid step starts the copies of the next one's first group
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),
         name=name,
     )(*scalars, q.reshape((B, G) + rows), *pools)
-    return out.reshape(B, H, D)
+    return out.reshape(B, H, latent or D)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_table, pos, *,
@@ -387,3 +420,23 @@ def paged_decode_attention_quant(q, k_pages, k_scales, v_pages, v_scales,
     return _paged_decode(q, (k_pages, k_scales, v_pages, v_scales),
                          block_table, pos, scale=scale, quant=True,
                          name="paged_decode_int8")
+
+
+def paged_mla_decode(q, pool, block_table, pos, *, scale: float,
+                     value_width: int):
+    """One decode step of DENSE latent attention (MLA in absorbed form)
+    over ONE paged pool.
+
+    ``q``: ``[B, H, W]``, a head's absorbed query ``[q_nope W_uk ;
+    rope(q_rope)]`` zero-padded to the pool's row width ``W``;
+    ``pool``: ``[N, 1, bs, W]``, a position's ``[c_kv ; rope(k_r)]``
+    (576 values stored in 640 lanes), the keys of EVERY head and, in
+    its first ``value_width`` lanes, their values: the sweep copies a
+    page once where ``paged_decode_attention(q, pool, pool, ...)`` would
+    copy it twice; ``block_table``, ``pos``: as there. Returns
+    ``[B, H, value_width]``, the weighted latent a head, which the
+    caller takes through ``W_uv`` and ``W_o``. Same sweep over a slot's
+    live pages, same precision."""
+    return _paged_decode(q, (pool,), block_table, pos, scale=scale,
+                         quant=False, name="paged_mla_decode",
+                         latent=int(value_width))

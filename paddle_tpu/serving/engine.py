@@ -261,6 +261,11 @@ class ServingEngine:
             dtype=jnp.dtype(c.cache_dtype),
             head_groups=mp,
             max_chunk=self._chunk or max(c.prefill_buckets))
+        #: the model's ONE page kind is an MLA latent: its decode steps
+        #: sweep every cached position (a family that selects positions
+        #: declares an index kind beside it, and counts its own reads)
+        self._dense_latent = [kd.name for kd in self.cache.kinds] \
+            == ["latent"]
         if self.cache.windows:
             # what assumes that a page lives as long as its slot
             for flag, what in (
@@ -2099,6 +2104,18 @@ class ServingEngine:
                         "a layer of that page lifetime", lifetime=life)
         sp.set(read_full=read["slot"], read_window=read["window"])
 
+    def _count_latent_reads(self, pos: np.ndarray, sp) -> None:
+        """What a decode step over ``pos`` (the active slots') has to
+        read of a latent pool that attention sweeps DENSELY: every
+        cached position, ``pos + 1`` a slot a layer; host arithmetic,
+        into the counter and onto the ``serve.decode`` span."""
+        read = int((pos + 1).sum())
+        # emits-metrics: serve_attn_read_positions_total
+        self._count("serve_attn_read_positions_total", read,
+                    "positions a decode step's kernels have to read in "
+                    "a layer of that page lifetime", lifetime="slot")
+        sp.set(read_latent=read)
+
     def _run_decode(self, pairs, params) -> None:
         with _trace.span("serve.decode", n_active=len(pairs)) as sp:
             self._decode_batch(pairs, params, sp)
@@ -2129,6 +2146,8 @@ class ServingEngine:
                 self._advance_windows(
                     (slot, int(pos[slot]), 1) for slot, _ in pairs)
                 self._count_window_reads(pos[active], sp)
+            elif self._dense_latent:
+                self._count_latent_reads(pos[active], sp)
             t0 = self.clock()
             prog = self._get_decode()
             temps, tks, tps = self._sampling_arrays(per_slot)

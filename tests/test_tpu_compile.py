@@ -600,3 +600,87 @@ def test_cohere_serving_program_fits_and_moves_no_pool(
     # scores are the largest
     assert mem.temp_size_in_bytes < 3.0e9, mem
     print(kind, "temp", mem.temp_size_in_bytes / 1e9, "GB")
+
+
+# -- a four-stream residual path, dense latent decode (ISSUE 35) -----------------
+
+XING_CELL = "xing4_29b_ep1.serve.closed64_ctx2k"
+
+
+def test_paged_mla_decode_compiles_at_the_cells_shape(chip, compile_for_chip):
+    """The latent form at the Xing cell's own call: 64 slots, 32 heads'
+    absorbed queries of 576 in 640 lanes, a table of 1,152 entries, ONE
+    pool of six layers' 24,577 pages of 16 x 640."""
+    pd = _kernel("paged_decode")
+    text = compile_for_chip(
+        lambda q, pool, t, p: pd.paged_mla_decode(
+            q, pool, t, p, scale=0.1447, value_width=512),
+        chip((64, 32, 640), BF16), chip((6 * 24577, 1, 16, 640), BF16),
+        chip((64, 1152), I32), chip((64,), I32))
+    _assert_kernels(text, "paged_mla_decode")
+
+
+@pytest.fixture(scope="module")
+def xing_model():
+    """Xing4.0's published widths over ONE dense and ONE expert layer
+    (all 64 experts; 1.81 B parameters, as zeros: nothing runs; the
+    cell's six layers would be 9.6 GB of host memory in this process),
+    and the cell's system settings."""
+    import json
+    import os
+    from paddle_tpu.nn import initializer
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "bench_xing4", os.path.join(root, "benchmark", "models", "xing4.py"))
+    fam = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(fam)
+    with open(os.path.join(root, "benchmark", "configs",
+                           "xing4_29b_ep1.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmark", "workloads",
+                           XING_CELL + ".json")) as f:
+        system = json.load(f)
+    config.update(num_hidden_layers=2, layers_held=[1, 2])
+    draw = initializer.Normal.__call__
+    initializer.Normal.__call__ = lambda self, shape, dtype=None: jnp.zeros(
+        tuple(shape), dtype or "float32")
+    try:
+        model = fam.build_model(config, 0, dtype=system["weights_dtype"])
+    finally:
+        initializer.Normal.__call__ = draw
+    return model, system
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill", "prefill_ctx"])
+def test_xing_serving_program_fits_and_moves_no_pool(
+        chip, build_for_chip, xing_model, monkeypatch, kind):
+    """The serving programs of the four-stream family at the cell's
+    widths and engine settings over a dense and an expert layer, compiled
+    for the chip with the pool at the cell's page count as a shape: the
+    decode step runs `paged_mla_decode` (no gather fallback), the pool is
+    updated in place with no instruction of a layer's pool size, and the
+    temporaries — a chunk's four streams in float32, its expert rows, its
+    `[2048, 131072]` logits — leave room beside 9.59 GB of weights and
+    3.02 GB of pages on a 16 GiB chip."""
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+    model, system = xing_model
+    kw = dict(system["engine"])
+    pages = kw.pop("num_pages")
+    for key in ("prefill_buckets", "batch_buckets"):
+        kw[key] = tuple(kw[key])
+    kw["prefill_buckets"] = kw["prefill_buckets"][-1:]      # the 2,048 one
+    with flag_scope("serve_prefill_chunk", system["prefill_chunk"]):
+        eng = ServingEngine(model, ServingConfig(num_pages=33, **kw))
+    compiled = _compile_with_cell_pools(eng, kind, pages, chip,
+                                        build_for_chip, monkeypatch)
+    text = compiled.as_text()
+    if kind == "decode":
+        _assert_kernels(text, "paged_mla_decode")
+    rows = {p.shape[-2:] for p in eng.cache.pool_args()}
+    assert rows == {(16, 640)}
+    assert _pool_sized_moves(text, pages * 16 * 640 * 2, rows) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pages * 16 * 640 * 2, mem
+    # 16 GiB - 9.59 GB - 3.02 GB leaves 4.5 GB
+    assert mem.temp_size_in_bytes < 3.0e9, mem
+    print(kind, "temp", mem.temp_size_in_bytes / 1e9, "GB")
