@@ -91,6 +91,7 @@ __all__ = [
     "kernels", "kernel_enabled", "note_fallback", "backend_supported",
     "interpret",
     "PALLAS_STATS", "reset_pallas_stats", "FLASH_RESIDUAL_NAMES",
+    "FLASH_CAUSAL_WORK", "note_flash_causal_work",
 ]
 
 #: ``checkpoint_name`` tags of what flash attention's differentiated
@@ -106,6 +107,13 @@ FLASH_RESIDUAL_NAMES = ("flash_attention_o", "flash_attention_lse")
 #: always-on fallback observability (monitor-independent, like
 #: nn.scan.SCAN_STATS): {(kernel, reason): count}
 PALLAS_STATS: Dict[tuple, int] = {}
+#: always-on, filled as programs are TRACED: ``{kernel: [computed,
+#: kept]}``, the score elements the causal calls of ``flash_fwd`` and
+#: ``flash_bwd`` compute and the elements of those the causal mask
+#: keeps, summed over the calls traced so far. What is computed and not
+#: kept is the price of the diagonal (``flash_attention._causal_plan``);
+#: 1 - kept / computed is the benchmark's ``flash.masked_work_pct.train``.
+FLASH_CAUSAL_WORK: Dict[str, List[int]] = {}
 _STATS_LOCK = threading.Lock()
 
 #: the registry rows behind :func:`kernels` — name -> (flag, fallback
@@ -128,6 +136,16 @@ _REGISTRY = {
 def reset_pallas_stats() -> None:
     with _STATS_LOCK:
         PALLAS_STATS.clear()
+        FLASH_CAUSAL_WORK.clear()
+
+
+def note_flash_causal_work(kernel: str, computed: int, kept: int) -> None:
+    """Add one traced causal call of a flash kernel to
+    :data:`FLASH_CAUSAL_WORK`."""
+    with _STATS_LOCK:
+        row = FLASH_CAUSAL_WORK.setdefault(kernel, [0, 0])
+        row[0] += computed
+        row[1] += kept
 
 
 def note_fallback(kernel: str, reason: str) -> None:
@@ -203,7 +221,8 @@ def kernels() -> List[dict]:
     """Enumerate the kernel layer: name, kill-switch flag (and its
     current value), whether dispatch would serve the Pallas body right
     now (``live``), the XLA fallback that serves otherwise, and the
-    fallback counts observed so far. Consumed by
+    fallback counts observed so far; the ``flash_attention`` row also
+    holds :data:`FLASH_CAUSAL_WORK` (``causal_work``). Consumed by
     ``tools/monitor_report.py --kernels`` and the registry tests."""
     import jax
     rows = []
@@ -215,6 +234,10 @@ def kernels() -> List[dict]:
         with _STATS_LOCK:
             fb = {k[1]: v for k, v in PALLAS_STATS.items()
                   if k[0] == name}
+            work = {"causal_work": {
+                k: {"computed": c, "kept": m}
+                for k, (c, m) in FLASH_CAUSAL_WORK.items()}} \
+                if name == "flash_attention" else {}
         rows.append({
             "kernel": name,
             "flag": f"FLAGS_{flag}" if flag else None,
@@ -222,6 +245,7 @@ def kernels() -> List[dict]:
             "live": bool(live),
             "fallback": fallback,
             "fallbacks_seen": fb,
+            **work,
         })
     return rows
 

@@ -26,7 +26,14 @@ split.
 
 Common to both: online-softmax forward with running (m, l) scratch,
 O(S*D) HBM traffic; causal tiles above the diagonal are compute-skipped
-via `pl.when`; in-kernel rematerialized dropout via a stateless
+via `pl.when`. The v2 kernels know two kinds of running tile
+(:func:`_causal_plan`): one wholly below the diagonal runs no mask code,
+and one that straddles it takes its query rows in groups of 128, each
+multiplied only against the key columns it can see and masked only in
+the 128 x 128 piece on the diagonal (a 512 x 512 tile computes 10 of its
+16 pieces; a forward whose grid step holds one head keeps the tile
+whole, and v1 computes and masks every running tile whole). In-kernel
+rematerialized dropout via a stateless
 murmur3-finalizer hash over absolute coordinates (the backward REGENERATES
 the mask, nothing is stored).
 
@@ -58,7 +65,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import FLASH_RESIDUAL_NAMES, interpret as _interpret
+from . import (FLASH_RESIDUAL_NAMES, interpret as _interpret,
+               note_flash_causal_work)
 
 # 512x512 tiles win on v5e: fewer grid steps amortize the VMEM loads and the
 # p-tile (512*512*4B = 1 MiB) still fits comfortably; measured ~28% faster
@@ -81,19 +89,29 @@ def _mxu_dtype(in_dtype) -> jnp.dtype:
     return jnp.float32 if matmul_precision() is not None else jnp.bfloat16
 
 
-def _causal_mask(s, qi, ki, block_q, block_k, off):
-    """Bottom-right-aligned causal mask: query row i sees keys j <= i + off
-    where off = Sk - Sq (matches _sdpa_xla's tril(k=Sk-Sq) semantics for
-    chunked prefill against a longer KV cache)."""
-    rows = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    cols = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+def _causal_mask(s, row0, col0, off):
+    """Bottom-right-aligned causal mask of a score piece whose first
+    element is (query row ``row0``, key column ``col0``): query row i sees
+    keys j <= i + off where off = Sk - Sq (matches _sdpa_xla's
+    tril(k=Sk-Sq) semantics for chunked prefill against a longer KV
+    cache)."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     return jnp.where(rows + off >= cols, s, NEG_INF)
 
 
-def _dropout_keep(seed_ref, b, h, qi, ki, shape, rate):
-    """Deterministic keep mask scaled by 1/(1-rate).
+def _mask_from(s, lo, row0, col0, off):
+    """``s`` with the causal mask applied to its columns from ``lo`` on
+    (a multiple of 128); the columns before are all seen."""
+    if lo == 0:
+        return _causal_mask(s, row0, col0, off)
+    return jnp.concatenate(
+        [s[:, :lo], _causal_mask(s[:, lo:], row0, col0 + lo, off)], axis=1)
+
+
+def _dropout_keep(seed_ref, b, h, row0, col0, shape, rate):
+    """Deterministic keep mask scaled by 1/(1-rate), of a piece whose
+    first element is (query row ``row0``, key column ``col0``).
 
     A STATELESS counter-based hash (murmur3 finalizer) over the absolute
     (batch, head, query-row, key-col) coordinates + the step seed: the
@@ -101,12 +119,13 @@ def _dropout_keep(seed_ref, b, h, qi, ki, shape, rate):
     bits — the dropout analogue of flash's no-residual rematerialization
     (reference's fused attention stores its uint8 mask, fmha_ref.h). A
     pure function of indices is bit-reproducible across the fwd/dq/dkv
-    kernels by construction, which Mosaic's stateful hardware PRNG is not.
+    kernels by construction, which Mosaic's stateful hardware PRNG is
+    not, and an element's keep bit does not depend on how a kernel cuts
+    its tiles.
     """
-    bq, bk = shape
-    rows = (qi * bq + jax.lax.broadcasted_iota(jnp.int32, shape, 0)) \
+    rows = (row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)) \
         .astype(jnp.uint32)
-    cols = (ki * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1)) \
+    cols = (col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)) \
         .astype(jnp.uint32)
     bh = (b.astype(jnp.uint32) * jnp.uint32(0xAC564B05)
           + h.astype(jnp.uint32) * jnp.uint32(19349663))
@@ -192,7 +211,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         if bias_ref is not None:
             s = s + bias_ref[0, 0].astype(jnp.float32)   # [1, bk] broadcast
         if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, off)
+            s = _causal_mask(s, qi * block_q, ki * block_k, off)
 
         m_prev = m_scr[:, :1]                            # [bq, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -207,7 +226,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         if rate > 0.0:
             # dropout on the normalized probs commutes to masking the pv
             # accumulation only; the softmax denominator stays undropped
-            pv = p * _dropout_keep(seed_ref, b, h, qi, ki, p.shape, rate)
+            pv = p * _dropout_keep(seed_ref, b, h, qi * block_q,
+                                   ki * block_k, p.shape, rate)
         acc_scr[:] = acc_scr[:] * alpha + _dot(pv, v_ref[0, 0], ((1,), (0,)))
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
@@ -337,9 +357,96 @@ def _heads_per_block(D: int, H: int):
     return None, None
 
 
+def _group_rows(block: int) -> int:
+    """Rows of one row group of a tile that straddles the causal diagonal
+    (:func:`_causal_plan`); it divides ``block``, and a block of its own
+    size is one group. Follows from the block alone: nobody sets it."""
+    return min(block, 128)
+
+
+def _causal_plan(Sq, Sk, block_q, block_k, groups=True):
+    """How the v2 kernels take the tiles of a causal call: ``(plan,
+    computed)``.
+
+    A tile is placed by its ``reach``, the tile column under the diagonal
+    element of its first query row (``qi * block_q + off - ki * block_k``;
+    row ``i`` of the tile sees its columns ``<= i + reach``). At
+    ``reach <= -block_q`` nothing is seen and the tile is skipped; at
+    ``reach >= block_k - 1`` everything is and the tile runs with no mask
+    code. Between the two the tile STRADDLES the diagonal, and its query
+    rows go in row groups of ``_group_rows(block_q)`` rows: a group
+    ``(r0, rows, width, lo)`` multiplies rows ``[r0, r0 + rows)`` against
+    the tile's first ``width`` key columns only, the last it can see, and
+    masks the piece ``[lo, width)`` on the diagonal (``lo`` None: the
+    group is wholly below it); a group that sees nothing is left out.
+    ``plan`` maps each ``reach`` a straddling tile of this call has to
+    its groups. It is None, and a straddling tile is ONE group under a
+    mask of all of it, where a group's edge would not fall on a multiple
+    of the group's rows: ``off`` or a block is not one, or the block is
+    one group; and where the caller says ``groups=False``.
+    ``computed`` is the score elements a head computes."""
+    off = Sk - Sq
+    sub = _group_rows(block_q)
+    aligned = (groups and sub < block_q and off % sub == 0
+               and block_q % sub == 0 and block_k % sub == 0)
+    plan = {}
+    computed = 0
+    for qi in range(Sq // block_q):
+        for ki in range(Sk // block_k):
+            reach = qi * block_q + off - ki * block_k
+            if reach <= -block_q:
+                continue
+            if reach >= block_k - 1 or not aligned:
+                computed += block_q * block_k
+                continue
+            if reach not in plan:
+                plan[reach] = tuple(
+                    (r0, sub, min(reach + r0 + sub, block_k),
+                     reach + r0 if reach + r0 < block_k else None)
+                    for r0 in range(0, block_q, sub) if reach + r0 + sub > 0)
+            computed += sum(rows * width for _, rows, width, _ in plan[reach])
+    return (plan if aligned else None), computed
+
+
+def _plan_of(kernel, causal, B, H, Sq, Sk, block_q, block_k, groups=True):
+    """The :func:`_causal_plan` of one call of ``kernel`` (None when it
+    is not causal), which this adds to the always-on record of what the
+    causal diagonal costs (``ops.pallas.FLASH_CAUSAL_WORK``): the score
+    elements the kernel will compute against those the mask keeps."""
+    if not causal:
+        return None
+    plan, computed = _causal_plan(Sq, Sk, block_q, block_k, groups)
+    kept = sum(min(max(i + Sk - Sq + 1, 0), Sk) for i in range(Sq))
+    note_flash_causal_work(kernel, B * H * computed, B * H * kept)
+    return plan
+
+
+def _causal_tiles(causal, plan, reach, block_q, block_k, bb, hp, rows):
+    """Run ``rows(bi, hh, r0, n, width, lo)`` for every row group of the
+    kind of tile this grid step holds (:func:`_causal_plan`), for each of
+    the program's ``bb`` batch rows and ``hp`` heads."""
+    def tile(*groups):
+        for bi in range(bb):
+            for hh in range(hp):
+                for group in groups:
+                    rows(bi, hh, *group)
+
+    if not causal:
+        tile((0, block_q, block_k, None))
+        return
+    pl.when(reach >= block_k - 1)(
+        functools.partial(tile, (0, block_q, block_k, None)))
+    if plan is None:
+        pl.when((reach > -block_q) & (reach < block_k - 1))(
+            functools.partial(tile, (0, block_q, block_k, 0)))
+        return
+    for at, groups in plan.items():
+        pl.when(reach == at)(functools.partial(tile, *groups))
+
+
 def _fwd2_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                  m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k,
-                 off, rate, bb, hp, D):
+                 off, rate, bb, hp, D, plan):
     bg, hg = pl.program_id(0), pl.program_id(1)
     qi, ki = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
@@ -350,39 +457,41 @@ def _fwd2_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    run = ((qi * block_q + block_q - 1 + off >= ki * block_k)
-           if causal else True)
+    def _rows(bi, hh, r0, n, width, lo):
+        """ONE online-softmax update of the tile's query rows ``[r0, r0 +
+        n)`` against its first ``width`` key columns."""
+        lanes = slice(hh * D, (hh + 1) * D)
+        rows = slice(r0, r0 + n)
+        q = q_ref[bi, rows, lanes]
+        k = k_ref[bi, :width, lanes]
+        v = v_ref[bi, :width, lanes]
+        s = _dot(q, k, ((1,), (1,))) * scale
+        if lo is not None:
+            s = _mask_from(s, lo, qi * block_q + r0, ki * block_k, off)
+        m_prev = m_scr[bi, hh, rows][:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        shift = jnp.where(m_new == NEG_INF, 0.0, m_new)
+        p = jnp.exp(s - shift)
+        if lo is not None and plan is None:
+            # a row may see nothing of the tile. (A row of a plan's group
+            # sees its own diagonal element: m_new is finite, and exp
+            # gives the 0.)
+            p = jnp.where(s == NEG_INF, 0.0, p)
+        alpha = jnp.exp(m_prev - shift)
+        l_new = alpha * l_scr[bi, hh, rows][:, :1] \
+            + jnp.sum(p, axis=1, keepdims=True)
+        pv = p
+        if rate > 0.0:
+            pv = p * _dropout_keep(seed_ref, bg * bb + bi, hg * hp + hh,
+                                   qi * block_q + r0, ki * block_k,
+                                   p.shape, rate)
+        acc_scr[bi, hh, rows] = acc_scr[bi, hh, rows] * alpha \
+            + _dot(pv, v, ((1,), (0,)))
+        m_scr[bi, hh, rows] = jnp.broadcast_to(m_new, (n, m_scr.shape[-1]))
+        l_scr[bi, hh, rows] = jnp.broadcast_to(l_new, (n, l_scr.shape[-1]))
 
-    @pl.when(run)
-    def _step():
-        for bi in range(bb):
-            for hh in range(hp):
-                q = q_ref[bi, :, hh * D:(hh + 1) * D]
-                k = k_ref[bi, :, hh * D:(hh + 1) * D]
-                v = v_ref[bi, :, hh * D:(hh + 1) * D]
-                s = _dot(q, k, ((1,), (1,))) * scale
-                if causal:
-                    s = _causal_mask(s, qi, ki, block_q, block_k, off)
-                m_prev = m_scr[bi, hh][:, :1]
-                m_new = jnp.maximum(m_prev,
-                                    jnp.max(s, axis=1, keepdims=True))
-                shift = jnp.where(m_new == NEG_INF, 0.0, m_new)
-                p = jnp.exp(s - shift)
-                if causal:
-                    p = jnp.where(s == NEG_INF, 0.0, p)
-                alpha = jnp.exp(m_prev - shift)
-                l_new = alpha * l_scr[bi, hh][:, :1] \
-                    + jnp.sum(p, axis=1, keepdims=True)
-                pv = p
-                if rate > 0.0:
-                    b_abs = bg * bb + bi
-                    h_abs = hg * hp + hh
-                    pv = p * _dropout_keep(seed_ref, b_abs, h_abs, qi, ki,
-                                           p.shape, rate)
-                acc_scr[bi, hh] = acc_scr[bi, hh] * alpha \
-                    + _dot(pv, v, ((1,), (0,)))
-                m_scr[bi, hh] = jnp.broadcast_to(m_new, m_scr[bi, hh].shape)
-                l_scr[bi, hh] = jnp.broadcast_to(l_new, l_scr[bi, hh].shape)
+    _causal_tiles(causal, plan, qi * block_q + off - ki * block_k,
+                  block_q, block_k, bb, hp, _rows)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -413,6 +522,14 @@ def _fwd2(q, k, v, scale, causal, block_q, block_k, hp, width,
     while B % block_b:
         block_b //= 2
     bb = max(block_b, 1)
+    # A grid step that holds ONE head of one batch row (a head of 128
+    # lanes at batch 1: Command A+'s first chunks) has nothing to run
+    # beside a group's chain of product, row maximum, exponential and
+    # product, and four chains in a row cost the forward more than the
+    # masked area saves: 3.68 ms a call for 3.50 at (1, 2048, 128 heads on
+    # 8, 128), where two or more heads a step gain 8% (on the chip, PR 36)
+    plan = _plan_of("flash_fwd", causal, B, H, Sq, Sk, block_q, block_k,
+                    groups=bb * hp > 1)
 
     qs = pl.BlockSpec((bb, block_q, width), lambda b, h, i, j: (b, i, h))
     ks = pl.BlockSpec((bb, block_k, width), lambda b, h, i, j: (b, j, h)) \
@@ -439,7 +556,7 @@ def _fwd2(q, k, v, scale, causal, block_q, block_k, hp, width,
         return _fwd2_kernel(seed_ref, q_r, k_r, v_r, o_r, lse_r, m_s, l_s,
                             a_s, scale=scale, causal=causal,
                             block_q=block_q, block_k=block_k, off=Sk - Sq,
-                            rate=rate, bb=bb, hp=hp, D=D)
+                            rate=rate, bb=bb, hp=hp, D=D, plan=plan)
 
     out_specs = [pl.BlockSpec((bb, block_q, width),
                               lambda b, h, i, j: (b, i, h))]
@@ -473,7 +590,8 @@ def _fwd2(q, k, v, scale, causal, block_q, block_k, hp, width,
 
 def _bwd2_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                  dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
-                 scale, causal, block_q, block_k, off, rate, bb, hp, D):
+                 scale, causal, block_q, block_k, off, rate, bb, hp, D,
+                 plan):
     """Fused backward: grid (B/bb, H/hp, nk, nq) with the q sweep innermost.
 
     dk/dv accumulate across the inner q sweep in block-sized scratch and
@@ -495,42 +613,41 @@ def _bwd2_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    run = ((qi * block_q + block_q - 1 + off >= ki * block_k)
-           if causal else True)
+    def _rows(bi, hh, r0, n, width, lo):
+        """The tile's query rows ``[r0, r0 + n)`` against its first
+        ``width`` key columns: one accumulation into their rows of dq and
+        into the first ``width`` rows of dk and dv."""
+        lanes = slice(hh * D, (hh + 1) * D)
+        rows = slice(r0, r0 + n)
+        q = q_ref[bi, rows, lanes]
+        do = do_ref[bi, rows, lanes]
+        o = o_ref[bi, rows, lanes]
+        k = k_ref[bi, :width, lanes]
+        v = v_ref[bi, :width, lanes]
+        lse = lse_ref[bi, hh, rows][:, :1]
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=1, keepdims=True)
+        s = _dot(q, k, ((1,), (1,))) * scale
+        if lo is not None:
+            s = _mask_from(s, lo, qi * block_q + r0, ki * block_k, off)
+        p = jnp.exp(s - jnp.where(lse == NEG_INF, 0.0, lse))
+        dp = _dot(do, v, ((1,), (1,)))
+        pv = p
+        if rate > 0.0:
+            keepf = _dropout_keep(seed_ref, bg * bb + bi, hg * hp + hh,
+                                  qi * block_q + r0, ki * block_k,
+                                  p.shape, rate)
+            pv = p * keepf
+            dp = dp * keepf
+        # two products read ds: split for the MXU once
+        ds = _mxu_parts(p * (dp - delta) * scale, k.dtype)
+        dq_rows = pl.ds(pl.multiple_of(qi * block_q + r0, 128), n)
+        dq_scr[bi, hh, dq_rows] += _dot(ds, k, ((1,), (0,)))
+        dk_scr[bi, hh, :width] += _dot(ds, q, ((0,), (0,)))
+        dv_scr[bi, hh, :width] += _dot(pv, do, ((0,), (0,)))
 
-    @pl.when(run)
-    def _step():
-        for bi in range(bb):
-            for hh in range(hp):
-                sl = slice(hh * D, (hh + 1) * D)
-                q = q_ref[bi, :, sl]
-                k = k_ref[bi, :, sl]
-                v = v_ref[bi, :, sl]
-                do = do_ref[bi, :, sl]
-                o = o_ref[bi, :, sl]
-                lse = lse_ref[bi, hh][:, :1]
-                delta = jnp.sum(do.astype(jnp.float32)
-                                * o.astype(jnp.float32),
-                                axis=1, keepdims=True)
-                s = _dot(q, k, ((1,), (1,))) * scale
-                if causal:
-                    s = _causal_mask(s, qi, ki, block_q, block_k, off)
-                p = jnp.exp(s - jnp.where(lse == NEG_INF, 0.0, lse))
-                dp = _dot(do, v, ((1,), (1,)))
-                pv = p
-                if rate > 0.0:
-                    b_abs = bg * bb + bi
-                    h_abs = hg * hp + hh
-                    keepf = _dropout_keep(seed_ref, b_abs, h_abs, qi, ki,
-                                          p.shape, rate)
-                    pv = p * keepf
-                    dp = dp * keepf
-                # two products read ds: split for the MXU once
-                ds = _mxu_parts(p * (dp - delta) * scale, k.dtype)
-                rows = pl.ds(qi * block_q, block_q)
-                dq_scr[bi, hh, rows] += _dot(ds, k, ((1,), (0,)))
-                dk_scr[bi, hh] += _dot(ds, q, ((0,), (0,)))
-                dv_scr[bi, hh] += _dot(pv, do, ((0,), (0,)))
+    _causal_tiles(causal, plan, qi * block_q + off - ki * block_k,
+                  block_q, block_k, bb, hp, _rows)
 
     @pl.when(qi == nq - 1)
     def _write_dkv():
@@ -561,6 +678,7 @@ def _bwd2(q, k, v, o, lse, do, scale, causal, block_q, block_k, hp, width,
     while B % block_b:
         block_b //= 2
     bb = max(block_b, 1)
+    plan = _plan_of("flash_bwd", causal, B, H, Sq, Sk, block_q, block_k)
 
     qs = pl.BlockSpec((bb, block_q, width), lambda b, h, j, i: (b, i, h))
     ks = pl.BlockSpec((bb, block_k, width), lambda b, h, j, i: (b, j, h))
@@ -581,7 +699,7 @@ def _bwd2(q, k, v, o, lse, do, scale, causal, block_q, block_k, hp, width,
             seed_ref = None
         return _bwd2_kernel(seed_ref, *refs, scale=scale, causal=causal,
                             block_q=block_q, block_k=block_k, off=Sk - Sq,
-                            rate=rate, bb=bb, hp=hp, D=D)
+                            rate=rate, bb=bb, hp=hp, D=D, plan=plan)
 
     dq, dk, dv = pl.pallas_call(
         kern,
@@ -646,12 +764,13 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref,
         if bias_ref is not None:
             s = s + bias_ref[0, 0].astype(jnp.float32)
         if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, off)
+            s = _causal_mask(s, qi * block_q, ki * block_k, off)
         # fully-masked row (lse = NEG_INF): shift by 0 so exp(-1e30) -> 0
         p = jnp.exp(s - jnp.where(lse == NEG_INF, 0.0, lse))  # [bq, bk]
         dp = _dot(do_ref[0, 0], v_ref[0, 0], ((1,), (1,)))
         if rate > 0.0:
-            dp = dp * _dropout_keep(seed_ref, b, h, qi, ki, p.shape, rate)
+            dp = dp * _dropout_keep(seed_ref, b, h, qi * block_q,
+                                    ki * block_k, p.shape, rate)
         ds = p * (dp - delta) * scale
         acc_scr[:] += _dot(ds, k_ref[0, 0], ((1,), (0,)))
 
@@ -687,14 +806,15 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, o_ref,
         if bias_ref is not None:
             s = s + bias_ref[0, 0].astype(jnp.float32)
         if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, off)
+            s = _causal_mask(s, qi * block_q, ki * block_k, off)
         # fully-masked row (lse = NEG_INF): shift by 0 so exp(-1e30) -> 0
         p = jnp.exp(s - jnp.where(lse == NEG_INF, 0.0, lse))  # [bq, bk]
         pv = p
         dp = _dot(do_ref[0, 0], v_ref[0, 0], ((1,), (1,)))
         if rate > 0.0:
             # same (b, h, qi, ki) fold as the forward: identical mask
-            keepf = _dropout_keep(seed_ref, b, h, qi, ki, p.shape, rate)
+            keepf = _dropout_keep(seed_ref, b, h, qi * block_q,
+                                  ki * block_k, p.shape, rate)
             pv = p * keepf
             dp = dp * keepf
         dv_scr[:] += _dot(pv, do_ref[0, 0], ((0,), (0,)))  # p~^T dO

@@ -230,7 +230,9 @@ def test_mxu_passes_follow_operand_types(route, operands, flag, dropout):
     11 in the fused backward (v1: 4, then 5 and 8), and no f32 dot is
     left to round a tile in one part. f32 q/k/v follow the flag: f32 at
     ``HIGHEST`` under its default, one bf16 pass under ``default`` — as
-    before the rule."""
+    before the rule. A causal v2 kernel holds those products once for a
+    tile below the diagonal and once a row group of a tile that
+    straddles it (ISSUE 36), every one of them by the same rule."""
     from paddle_tpu.core.flags import flag_scope
     dtype = jnp.bfloat16 if operands == "bf16" else jnp.float32
     q, k, v = (x.astype(dtype) for x in _qkv(13))
@@ -249,7 +251,11 @@ def test_mxu_passes_follow_operand_types(route, operands, flag, dropout):
     assert set(dots) == set(expect)
     if route == "v2":
         hp, _, bb_fwd, bb_bwd = fa._v2_plan(q, None, S, S)
-        tiles = {"flash_fwd": bb_fwd * hp, "flash_bwd": bb_bwd * hp}
+        plan, _ = fa._causal_plan(S, S, S, S)
+        bodies = 1 + sum(len(groups) for groups in plan.values())
+        assert bodies == 1 + S // 128
+        tiles = {"flash_fwd": bb_fwd * hp * bodies,
+                 "flash_bwd": bb_bwd * hp * bodies}
     for name, found in dots.items():
         one = _F32_DOT if (operands, flag) == ("f32", "highest") \
             else _BF16_DOT
@@ -392,6 +398,202 @@ def test_dropout_matches_host_mask_reference(causal, block):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-4, rtol=5e-4,
                                    err_msg=f"d{name}")
+
+
+# -- the causal diagonal: two kinds of running tile (ISSUE 36) ------------------
+
+def _one_group(block):
+    """``_group_rows`` of the body before row groups: a tile that
+    straddles the diagonal is ONE group under a mask of all of it."""
+    return block
+
+
+def _keep_of(Bv, Hv, Sv, rate):
+    return jnp.asarray(np.stack([np.stack(
+        [_host_keep(Sv, b, h, rate) for h in range(Hv)]) for b in range(Bv)]))
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.1], ids=["nodrop", "drop0.1"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_causal_s1024_block512_matches_the_composition(dtype, dropout):
+    """The train cells' tiling: S=1024 in blocks of 512 is three running
+    tiles a head, two of them on the diagonal in four row groups each and
+    one below it with no mask code. Forward and the three gradients
+    against the XLA composition (float32 at ``highest``; under dropout
+    with the kernel's own keep bits, rebuilt on the host)."""
+    Bv, Sv, Hv, Dv = 1, 1024, 2, 64
+    plan, computed = fa._causal_plan(Sv, Sv, 512, 512)
+    assert [len(g) for g in plan.values()] == [4] and computed == 9 * 256 * 256
+    rng = np.random.RandomState(36)
+    mk = lambda: jnp.asarray(  # noqa: E731
+        rng.randn(Bv, Sv, Hv, Dv).astype(np.float32) * 0.5)
+    q, k, v, G = mk(), mk(), mk(), mk()
+    keep = _keep_of(Bv, Hv, Sv, dropout) if dropout else 1.0
+    cm = jnp.tril(jnp.ones((Sv, Sv), bool))
+    seed_f = jnp.zeros((2,), jnp.float32)
+
+    def ref(q_, k_, v_):
+        with jax.default_matmul_precision("highest"):
+            s = jnp.einsum("bqhd,bkhd->bhqk", q_, k_) * 0.125
+            p = jax.nn.softmax(jnp.where(cm[None, None], s, -1e30), axis=-1)
+            return jnp.einsum("bhqk,bkhd->bqhd", p * keep, v_)
+
+    def kern(q_, k_, v_):
+        return fa._flash(q_, k_, v_, None, seed_f, 0.125, True, 512, 512,
+                         dropout).astype(jnp.float32)
+
+    cast = lambda x: x.astype(dtype)  # noqa: E731
+    got = [kern(cast(q), cast(k), cast(v))] + list(jax.grad(
+        lambda *a: jnp.sum(kern(*a) * G), argnums=(0, 1, 2))(
+            cast(q), cast(k), cast(v)))
+    want = [ref(q, k, v)] + list(jax.grad(
+        lambda *a: jnp.sum(ref(*a) * G), argnums=(0, 1, 2))(q, k, v))
+    for a, b, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        a = np.asarray(a.astype(jnp.float32))
+        if dtype == "bfloat16":          # test_bf16_inputs' tolerance
+            np.testing.assert_allclose(
+                a, np.asarray(b), rtol=3e-2, err_msg=name,
+                atol=3e-2 * float(jnp.max(jnp.abs(b))))
+        else:
+            tol = 2e-5 if name == "out" else 5e-4
+            np.testing.assert_allclose(a, np.asarray(b), atol=tol, rtol=tol,
+                                       err_msg=name)
+
+
+def _through_kernels(q, k, v, do, blocks, rate=0.1):
+    seed = jnp.asarray([3, 5], jnp.int32)
+    o, lse = fa._fwd(q, k, v, None, 0.125, True, *blocks, seed=seed,
+                     rate=rate)
+    return (o, lse) + fa._bwd_impl(q, k, v, None, o, lse, do, 0.125, True,
+                                   *blocks, seed=seed, rate=rate)[:3]
+
+
+@pytest.mark.parametrize("block,sub", [(512, 128), (512, 256), (384, 128),
+                                       (256, 128)])
+def test_every_row_group_plan_gives_the_one_group_numbers(monkeypatch,
+                                                          block, sub):
+    """The same tensors, dropout ON, under every row-group size a block
+    can be given and under the one-group body: out, log-sum-exp, dq, dk
+    and dv agree to f32 rounding (only the order of a row's f32 sum may
+    differ) — so an element's keep bit does not depend on the plan, and
+    no group reads or writes a column it should not."""
+    Sv = 2 * block
+    rng = np.random.RandomState(block + sub)
+    mk = lambda: jnp.asarray(  # noqa: E731
+        rng.randn(1, Sv, 2, 64).astype(np.float32) * 0.5)
+    q, k, v, do = mk(), mk(), mk(), mk()
+    monkeypatch.setattr(fa, "_group_rows", _one_group)
+    assert fa._causal_plan(Sv, Sv, block, block)[0] is None
+    want = _through_kernels(q, k, v, do, (block, block))
+    monkeypatch.setattr(fa, "_group_rows", lambda b: sub)
+    plan, _ = fa._causal_plan(Sv, Sv, block, block)
+    assert [len(g) for g in plan.values()] == [block // sub]
+    got = _through_kernels(q, k, v, do, (block, block))
+    for a, b, name in zip(got, want, ("out", "lse", "dq", "dk", "dv")):
+        a, b = np.asarray(a), np.asarray(b)
+        span = float(b.max() - b.min())
+        assert float(np.abs(a - b).max()) <= 1e-5 * span, name
+
+
+@pytest.mark.parametrize("sq,sk,sub,engages", [
+    (256, 512, 128, True), (512, 640, 256, False)],
+    ids=["off256-sub128", "off128-sub256"])
+def test_row_groups_behind_a_cache(monkeypatch, sq, sk, sub, engages):
+    """Queries behind a longer cache (``off`` = Sk - Sq). ``off`` a
+    multiple of the group's rows: the groups engage, at the two places a
+    straddling tile of 256 x 512 can lie. ``off`` = 128 under groups of
+    256: a group's edge would fall inside a lane tile, and the call
+    takes the one-group body. Either way the composition's numbers."""
+    monkeypatch.setattr(fa, "_group_rows", lambda b: min(b, sub))
+    bq, bk = fa._pick_block(sq, 512), fa._pick_block(sk, 512)
+    plan, computed = fa._causal_plan(sq, sk, bq, bk)
+    if engages:
+        # tile (0, 0): rows see 256 more columns than their own index
+        assert plan == {256: ((0, 128, 384, 256), (128, 128, 512, 384))}
+        assert computed == 128 * (384 + 512)
+    else:
+        assert plan is None and computed == 512 * 640
+    rng = np.random.RandomState(sq + sk)
+    q = jnp.asarray(rng.randn(1, sq, 2, 64).astype(np.float32))
+    k, v = (jnp.asarray(rng.randn(1, sk, 2, 64).astype(np.float32))
+            for _ in "kv")
+    loss = lambda f: lambda q, k, v: jnp.sum(  # noqa: E731
+        f(q, k, v, causal=True) ** 2)
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, causal=True)),
+        np.asarray(_ref(q, k, v, causal=True)), atol=2e-5, rtol=2e-5)
+    for a, b in zip(jax.grad(loss(flash_attention), (0, 1, 2))(q, k, v),
+                    jax.grad(loss(_ref), (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-4, rtol=5e-4)
+
+
+@pytest.mark.parametrize("Bv", [2, 1], ids=["two-rows-a-step", "one-head-a-step"])
+def test_row_groups_under_grouped_kv_heads(Bv):
+    """Command A+'s first chunks: query heads of 128 on a quarter as many
+    K/V heads, several tiles a head (the K/V blocks' index maps divide
+    the head index; a row group reads the K/V block's first rows). With
+    ONE head of one batch row a grid step the forward keeps its
+    straddling tiles whole (it lost 4% on the chip in groups), and still
+    runs no mask code below the diagonal."""
+    from paddle_tpu.ops import pallas as pallas_ops
+    rng = np.random.RandomState(4)
+    q = jnp.asarray(rng.randn(Bv, 512, 8, 128).astype(np.float32))
+    k, v = (jnp.asarray(rng.randn(Bv, 512, 2, 128).astype(np.float32))
+            for _ in "kv")
+    pallas_ops.reset_pallas_stats()
+    got = flash_attention(q, k, v, causal=True, block_q=256, block_k=256)
+    whole = Bv * 8 * 3 * 256 * 256
+    assert pallas_ops.FLASH_CAUSAL_WORK["flash_fwd"][0] == (
+        whole if Bv == 1 else whole * 5 // 6)
+    rep = lambda a: jnp.repeat(a, 4, axis=2)  # noqa: E731
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_ref(q, rep(k), rep(v), causal=True)),
+        atol=2e-5, rtol=2e-5)
+
+
+def _masked_work_reader():
+    """The benchmark's reader of the record, loaded as ``run.py`` does."""
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                        "layer_metrics", "flash.masked_work_pct.train.py")
+    spec = importlib.util.spec_from_file_location("masked_work", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+@pytest.mark.parametrize("rows,share,masked", [(_one_group, 0.75, 33.27),
+                                               (None, 0.5625, 11.02)],
+                         ids=["one-group", "the-plan"])
+def test_causal_work_record(monkeypatch, rows, share, masked):
+    """What a traced causal call adds to the always-on record, a kernel:
+    the score elements it will compute and those the mask keeps. S=1024
+    in blocks of 512: 0.75 S^2 computed under one group (three whole
+    tiles of four), 0.5625 under the plan (a diagonal tile computes 10
+    of its 16 pieces of 128 x 128), S (S + 1) / 2 = 0.5005 S^2 kept; the
+    benchmark's ``flash.masked_work_pct.train`` reads the record."""
+    from paddle_tpu.ops import pallas as pallas_ops
+    if rows is not None:
+        monkeypatch.setattr(fa, "_group_rows", rows)
+    Bv, Sv, Hv = 2, 1024, 2
+    q = jnp.zeros((Bv, Sv, Hv, 64), jnp.bfloat16)
+    pallas_ops.reset_pallas_stats()
+    # non-causal calls are not in the record
+    jax.make_jaxpr(lambda q: flash_attention(q, q, q))(q)
+    assert pallas_ops.FLASH_CAUSAL_WORK == {}
+    assert _masked_work_reader()(None) is None
+    jax.make_jaxpr(jax.grad(lambda q: jnp.sum(flash_attention(
+        q, q, q, causal=True).astype(jnp.float32))))(q)
+    each = [Bv * Hv * int(share * Sv * Sv), Bv * Hv * Sv * (Sv + 1) // 2]
+    assert pallas_ops.FLASH_CAUSAL_WORK == {"flash_fwd": each,
+                                            "flash_bwd": each}
+    row = {r["kernel"]: r for r in pallas_ops.kernels()}["flash_attention"]
+    assert row["causal_work"]["flash_bwd"] == {"computed": each[0],
+                                               "kept": each[1]}
+    # `flash.masked_work_pct.train`: 100 x (1 - kept / computed)
+    assert round(_masked_work_reader()(None), 2) == masked
 
 
 def test_dropout_public_api_guards():

@@ -364,17 +364,21 @@ def test_eager_recompute_scan_replays_its_cached_trace(policy):
     assert nn_scan.SCAN_STATS["body_traces"] == 0
 
 
-#: sha256 of `str(jaxpr)` of what the serving programs are made of, taken
-#: from the commit BEFORE the flash forward's residuals were named
-#: (35758db6, PR 32): the primal `_flash` and the engine's programs hold
-#: no `jax.checkpoint` and never reach the differentiated forward, so
-#: they trace to what they were, equation for equation. A change of
-#: jax's printer would move the hashes with no change here: take them
-#: anew from that commit then.
-_PR32_JAXPR = {
-    "flash-bfloat16": "57f36327ebbdc9d7a0bfd7fccfd28e8e59c6790420392e1a765c529661660373",
-    "flash-float32": "24619bab93376ee6347032162f5b697c8eb6baddb9404d8860df0958134b54de",
-    "prefill": "0d96281ad90948c3279430b67cc0e6c2aee0bca051010f9768bab7c82e7951a7",
+#: sha256 of `str(jaxpr)` of what the serving programs are made of. The
+#: primal `_flash` and the engine's programs hold no `jax.checkpoint` and
+#: never reach the differentiated forward: naming the flash forward's
+#: residuals (PR 34) left them equation for equation what they were at
+#: the commit before (35758db6, PR 32), and `decode`, which holds no
+#: flash kernel, still is. The flash kernel's BODY is in the jaxpr of
+#: whatever calls it, so PR 36 (the causal diagonal's two kinds of tile)
+#: moved `flash-bfloat16`, `flash-float32` and `prefill`: those three are
+#: taken anew from PR 36's tree. A change of jax's printer would move
+#: the hashes with no change here: take them anew from these commits then.
+_PINNED_JAXPR = {
+    "flash-bfloat16": "c89b58f6acf76ad7f41e1e7e6621a9cbd5943c269dd007e1cb678c0ee43734e9",
+    "flash-float32": "d6278090f2808dcddfacb87bf538f1e76c2c15aa65afb90607dbd98eb0a06923",
+    "prefill": "e50a8e2696ae29eb7af8fe420dc55ec056dd4b26729ba0b0cf0436a51346aa8a",
+    # PR 32's, untouched
     "decode": "0a2cd1db06bc721d0a1b97c3740edb4d66ac1e3f75b7b77fb8a7bbf5522f4aee"}
 
 
@@ -395,7 +399,7 @@ def test_flash_inference_traces_as_before(dtype):
     jaxpr = jax.make_jaxpr(
         lambda q, k, v: flash_attention(q, k, v, causal=True))(q, q, q)
     assert "name[" not in str(jaxpr)
-    assert _sha(jaxpr) == _PR32_JAXPR["flash-" + dtype]
+    assert _sha(jaxpr) == _PINNED_JAXPR["flash-" + dtype]
 
 
 @pytest.mark.pallas
@@ -427,4 +431,4 @@ def test_serving_programs_trace_as_before(monkeypatch, kind):
     text = str(jaxpr)
     assert ("flash_fwd" if kind == "prefill" else "paged_decode") in text
     assert "checkpoint" not in text and "name[" not in text
-    assert _sha(jaxpr) == _PR32_JAXPR[kind]
+    assert _sha(jaxpr) == _PINNED_JAXPR[kind]
