@@ -55,7 +55,8 @@ black_list = {
 # f32 statistics internally; a blanket cast would also hit its f32 state
 # buffers (see _cast_target). fused_conv_bn resolves the conv-operand cast
 # itself (nn/functional.py) so its f32 EMA buffers ride through untouched.
-_keep_dtype = {"batch_norm", "fused_conv_bn"}
+# checkpoint_name only names a value for the remat policy (models/gpt.py).
+_keep_dtype = {"batch_norm", "fused_conv_bn", "checkpoint_name"}
 
 _tls = threading.local()
 

@@ -5,8 +5,9 @@ reference parity: python/paddle/distributed/fleet/utils/recompute.py
 re-forward under enable_grad). The TPU-native redesign is `jax.checkpoint`:
 under jit the XLA backward rematerializes the segment instead of saving
 activations; in eager the tape's VJP closure holds only the segment inputs
-(and, where the segment ran a flash-attention kernel, the kernel's output
-and log-sum-exp: :func:`resolve_checkpoint_policy`).
+and the values the segment names as worth keeping (a flash-attention
+kernel's output and log-sum-exp; a GPT block's products and its
+attention branch: :func:`resolve_checkpoint_policy`).
 RNG consistency is free here — dropout keys are split at Python trace time
 (core/random.trace_rng), so the rematerialized forward replays the same
 keys without the reference's fork_rng dance.
@@ -23,13 +24,26 @@ from ....core.tensor import Tensor, apply
 from ....nn.layer import Layer
 from ....ops.pallas import FLASH_RESIDUAL_NAMES
 
-__all__ = ["recompute", "recompute_sequential", "resolve_checkpoint_policy"]
+__all__ = ["recompute", "recompute_sequential", "resolve_checkpoint_policy",
+           "flash_residuals_policy", "LAYER_RESIDUAL_NAMES"]
 
-#: named selective-remat policies (jax.checkpoint_policies). The TPU
-#: default for transformer stacks is ``dots_with_no_batch_dims_saveable``:
-#: keep MXU (matmul) outputs resident, rematerialize only the cheap
-#: elementwise tail — far less recompute FLOPs than recomputing the
-#: whole segment for a modest HBM cost (the T5X/MaxText recipe).
+#: ``checkpoint_name`` tags of the values a differentiated decoder block
+#: (``models.gpt``) would rebuild with an MXU product or a collective:
+#: the attention branch (attention's out-projection after its
+#: tensor-parallel sum and the hidden dropout: the operand of the
+#: mid-layer residual add), the FFN's first product before its
+#: activation, and the fused QKV product. The default policy keeps them
+#: beside the flash residuals, so a recomputed body runs its norms, the
+#: residual add and the activation again and no product; a body that
+#: holds no such name (BERT, ERNIE, the transformer encoder) resolves as
+#: before.
+LAYER_RESIDUAL_NAMES = ("attn_branch", "ffn_in_product", "qkv_product")
+
+#: named selective-remat policies (jax.checkpoint_policies) a user may
+#: choose; the default is none of them (:func:`resolve_checkpoint_policy`).
+#: ``dots_with_no_batch_dims_saveable`` keeps every MXU (matmul) output
+#: resident and rematerializes the elementwise tail (the T5X/MaxText
+#: recipe), at the HBM cost of every product a body makes.
 _POLICY_NAMES = (
     # NOTE: only plain PREDICATES belong here. jax.checkpoint_policies
     # also exports factories (offload_dot_with_no_batch_dims,
@@ -51,38 +65,67 @@ _POLICY_ALIASES = {
 
 
 @functools.lru_cache(maxsize=None)
+def _keep_names(names):
+    return jax.checkpoint_policies.save_only_these_names(*names)
+
+
+@functools.lru_cache(maxsize=None)
 def _named_policy(name):
     """The predicate of one policy name (None: the default). Built once
     a name: ``nn.scan`` keys its trace cache on the predicate's identity."""
     cp = jax.checkpoint_policies
     if name == "nothing_saveable":
         return cp.nothing_saveable
-    keep = cp.save_only_these_names(*FLASH_RESIDUAL_NAMES)
+    keep = _keep_names(FLASH_RESIDUAL_NAMES + LAYER_RESIDUAL_NAMES)
     if name is None:
         return keep
     return cp.save_from_both_policies(getattr(cp, name), keep)
 
 
+def flash_residuals_policy():
+    """Keep the flash kernels' output and log-sum-exp and nothing else.
+    The pipeline stage's remat policy: a stage holds its residuals for
+    every tick of its schedule (microbatches + stages - 1), so it keeps
+    none of a block's :data:`LAYER_RESIDUAL_NAMES`. Kept, they would be
+    32 MiB a layer a tick at GPT-2 345M's widths and microbatches of 2
+    (``tests/test_tpu_compile.py`` compiles the fill-drain step both
+    ways for a described v5e)."""
+    return _keep_names(FLASH_RESIDUAL_NAMES)
+
+
 def resolve_checkpoint_policy(policy):
     """Resolve a remat policy spec to a ``jax.checkpoint_policies`` predicate.
 
-    Whatever a policy recomputes, it never re-runs a flash-attention
-    forward: the kernel's output and log-sum-exp
-    (``ops.pallas.FLASH_RESIDUAL_NAMES``, which the differentiated
-    forward tags) are kept, since the second run would write the same
-    bits and costs as much as the backward kernel. They exist only where
-    a flash kernel was differentiated; a block without one resolves to
-    the policy it names.
+    Every policy but ``"full"`` keeps the values a recomputed body would
+    rebuild with a kernel, a product or a collective, where the
+    differentiated forward names them:
 
-    - None (the default, "recompute everything else"):
-      ``save_only_these_names(<the kernel's residuals>)``;
+    - a flash-attention kernel's output and log-sum-exp
+      (``ops.pallas.FLASH_RESIDUAL_NAMES``): the second run would write
+      the same bits and costs as much as the backward kernel;
+    - a GPT block's :data:`LAYER_RESIDUAL_NAMES`: the attention branch
+      (so the body runs attention's out-projection, its tensor-parallel
+      all-reduce and its dropout kernel once), the FFN's first product
+      before its activation and the fused QKV product. A recomputed GPT
+      body then runs its two norms, the residual add and its activation
+      again, and no product. What is kept is what the forward produced,
+      so the loss and every gradient are what ``"full"`` and no
+      recompute give: bit for bit on the CPU, and on a TPU to the last
+      bits that each program's order of summation sets (they part
+      ``"full"`` from no recompute there too).
+
+    A body that names none of them (BERT, ERNIE, the transformer
+    encoder) resolves to what the policy says alone.
+
+    - None (the default): ``save_only_these_names(<all those names>)``;
     - a policy name or alias (model configs carry the string form,
       ``recompute_policy='dots_with_no_batch_dims_saveable'``, so they
-      stay picklable): that policy AND the kernel's residuals
+      stay picklable): that policy AND the named values
       (``save_from_both_policies``; no dots policy keeps a custom
-      call's output);
+      call's output, nor the attention branch after its dropout);
     - ``"full"`` / ``"nothing_saveable"``: jax's literal meaning, nothing
-      is kept and the kernel runs again;
+      is kept and every product and kernel runs again. It holds the
+      least: the choice for a stack that does not fit memory otherwise;
     - a callable: returned as it is."""
     if callable(policy):
         return policy
@@ -108,12 +151,12 @@ def recompute(function, *args, use_reentrant: bool = True,
     applies inside a jitted TrainStep trace).
 
     ``policy`` (TPU-native extension): a ``jax.checkpoint_policies``
-    predicate or its name for SELECTIVE checkpointing — e.g.
-    ``dots_with_no_batch_dims_saveable`` keeps matmul outputs resident and
-    rematerializes only the cheap elementwise tail, a far better
-    FLOPs/HBM trade than recomputing everything on TPU. Under every
-    policy but ``"full"`` a flash-attention kernel in the segment keeps
-    its output and log-sum-exp (:func:`resolve_checkpoint_policy`).
+    predicate or its name for SELECTIVE checkpointing. The default keeps
+    what the segment names as costly to rebuild (a flash kernel's output
+    and log-sum-exp, a GPT block's products and attention branch) and
+    recomputes the rest; ``dots_with_no_batch_dims_saveable`` keeps every
+    matmul output besides; ``"full"`` keeps nothing, for a segment that
+    does not fit memory otherwise (:func:`resolve_checkpoint_policy`).
     """
     del use_reentrant, preserve_rng_state   # parity knobs; single behavior
     policy = resolve_checkpoint_policy(policy)

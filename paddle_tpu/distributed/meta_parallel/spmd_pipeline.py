@@ -498,9 +498,8 @@ class PipelineStageStack(Layer):
         T = M + S - 1
         stage = self._stage_apply
         if self.remat:
-            from ..fleet.utils.recompute import resolve_checkpoint_policy
-            stage = jax.checkpoint(stage,
-                                   policy=resolve_checkpoint_policy(None))
+            from ..fleet.utils.recompute import flash_residuals_policy
+            stage = jax.checkpoint(stage, policy=flash_residuals_policy())
 
         def shard_body(xs, key, *local_leaves):
             local = {self._name_map[r]: a

@@ -38,7 +38,7 @@ import jax
 from ..nn.layer import BLOCKS
 
 __all__ = ["AOTProgram", "SCOPES", "scopes", "parse_scopes", "index_program",
-           "kernel_calls"]
+           "kernel_calls", "products"]
 
 #: HLO module name (``jit_train_step``; a device trace's `XLA Modules`
 #: line carries the same) -> ``{instruction name: (block, phase)}`` of
@@ -55,9 +55,23 @@ SCOPES: Dict[str, Dict[str, Tuple[str, str]]] = {}
 #: number), of the same executable as :data:`SCOPES`' entry.
 KERNEL_CALLS: Dict[str, FrozenSet[str]] = {}
 
+#: HLO module name -> the names of its instructions that run an MXU
+#: product or a collective (:func:`index_program` says which), of the
+#: same executable as :data:`SCOPES`' entry.
+PRODUCTS: Dict[str, FrozenSet[str]] = {}
+
 _MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
 _HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+#: the opcode: the first word after the result's type that opens a
+#: parenthesis (a layout's ``T(8,128)`` follows a colon, not a space)
+_HLO_OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
+_HLO_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_PRODUCT_OPS = frozenset({"dot", "convolution"})
+_COLLECTIVE_OPS = frozenset({
+    "all-reduce", "all-gather", "reduce-scatter", "collective-permute",
+    "all-to-all"})
 _HLO_OP_NAME = re.compile(r"op_name=\"([^\"]*)\"")
 _PATH_WORD = re.compile(r"[A-Za-z_]+")
 _HLO_OPERAND = re.compile(r"%([\w.\-]+)")
@@ -129,15 +143,53 @@ def parse_scopes(hlo_text: str) -> Tuple[str, Dict[str, Tuple[str, str]]]:
 
 
 def index_program(hlo_text: str) -> str:
-    """Keep the scope index and the kernel calls of an optimized HLO
-    module (``compiled.as_text()``) under its name, which is returned.
-    Every :class:`AOTProgram` build does; a program compiled some other
-    way (for a described chip) is read the same way through this."""
+    """Keep the scope index, the kernel calls and the products of an
+    optimized HLO module (``compiled.as_text()``) under its name, which
+    is returned. Every :class:`AOTProgram` build does; a program
+    compiled some other way (for a described chip) is read the same way
+    through this.
+
+    The products (:data:`PRODUCTS`) are the instructions that run an MXU
+    product or a collective: a fusion whose computation holds a ``dot``
+    or a ``convolution``, such an instruction unfused, and
+    ``all-reduce``, ``all-gather``, ``reduce-scatter``,
+    ``collective-permute`` and ``all-to-all`` (their ``-start`` forms;
+    never a ``-done``). Instructions INSIDE a fused computation are the
+    fusion's own work and are not counted apart."""
     module, table = parse_scopes(hlo_text)
+    calls = set()
+    body: Dict[str, List[Tuple[str, str, Optional[str]]]] = {}
+    where: List[Tuple[str, str, Optional[str]]] = []
+    for line in hlo_text.splitlines():          # one pass: calls, bodies
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            where = body.setdefault(head.group(1), [])
+            continue
+        inst = _HLO_INSTRUCTION.match(line)
+        if inst is None:
+            continue
+        if _MOSAIC_CALL in line:
+            calls.add(inst.group(1))
+        code = _HLO_OPCODE.search(line, inst.end() - 1)
+        callee = _HLO_CALLS.search(line)
+        where.append((inst.group(1), code.group(1) if code else "",
+                      callee.group(1) if callee else None))
+    fused = {callee for insts in body.values()
+             for _, code, callee in insts if code == "fusion"}
+
+    def runs(code: str, callee: Optional[str]) -> bool:
+        if code.endswith("-start"):
+            code = code[:-len("-start")]
+        if code in _PRODUCT_OPS or code in _COLLECTIVE_OPS:
+            return True
+        return (code == "fusion" and callee is not None
+                and any(runs(c, k) for _, c, k in body.get(callee, ())))
+
     SCOPES[module] = table
-    KERNEL_CALLS[module] = frozenset(
-        m.group(1) for m in map(_HLO_INSTRUCTION.match, hlo_text.splitlines())
-        if m and _MOSAIC_CALL in m.string)
+    KERNEL_CALLS[module] = frozenset(calls)
+    PRODUCTS[module] = frozenset(
+        name for comp, insts in body.items() if comp not in fused
+        for name, code, callee in insts if runs(code, callee))
     return module
 
 
@@ -147,18 +199,10 @@ def scopes(module_name: str) -> Optional[Dict[str, Tuple[str, str]]]:
     return SCOPES.get(module_name)
 
 
-def kernel_calls(module_name: str, block: Optional[str] = None,
-                 phase: Optional[str] = None) -> Optional[List[str]]:
-    """The Mosaic kernel calls of the newest executable built under
-    this HLO module name, sorted, or None when none was; with ``block``
-    and/or ``phase``, those the scope index places there.
-    ``kernel_calls("jit_train_step", "attn", "remat")`` is what a
-    recomputed layer body runs of attention's kernels AGAIN: no
-    ``flash_fwd`` under any policy ``resolve_checkpoint_policy`` builds
-    but ``"full"``, under which there is one a layer body. An
-    interpreted kernel (the CPU tests) is no call."""
-    calls = KERNEL_CALLS.get(module_name)
-    if calls is None:
+def _placed(record: Dict[str, FrozenSet[str]], module_name: str,
+            block: Optional[str], phase: Optional[str]) -> Optional[List[str]]:
+    names = record.get(module_name)
+    if names is None:
         return None
     index = SCOPES[module_name]
 
@@ -166,7 +210,37 @@ def kernel_calls(module_name: str, block: Optional[str] = None,
         b, p = index.get(name, (None, None))
         return block in (None, b) and phase in (None, p)
 
-    return sorted(filter(there, calls))
+    return sorted(filter(there, names))
+
+
+def kernel_calls(module_name: str, block: Optional[str] = None,
+                 phase: Optional[str] = None) -> Optional[List[str]]:
+    """The Mosaic kernel calls of the newest executable built under
+    this HLO module name, sorted, or None when none was; with ``block``
+    and/or ``phase``, those the scope index places there.
+    ``kernel_calls("jit_train_step", "attn", "remat")`` is what a
+    recomputed layer body runs of attention's kernels AGAIN: nothing
+    under the default policy (the flash output and the attention branch
+    after the hidden dropout are kept, so neither ``flash_fwd`` nor that
+    dropout's ``fused_dropout`` runs twice); under ``"full"`` both, once
+    a layer body. An interpreted kernel (the CPU tests) is no call."""
+    return _placed(KERNEL_CALLS, module_name, block, phase)
+
+
+def products(module_name: str, block: Optional[str] = None,
+             phase: Optional[str] = None) -> Optional[List[str]]:
+    """The MXU products and collectives (:func:`index_program`) of the
+    newest executable built under this HLO module name, sorted, or None
+    when none was; with ``block`` and/or ``phase``, those the scope
+    index places there. ``products("jit_train_step", phase="remat")`` is
+    what a recomputed layer body runs again of them: nothing under the
+    default policy, the QKV, out-projection and FFN-in products (and,
+    on a tensor-parallel mesh, the out-projection's all-reduce) a body
+    under ``"full"``. Under ZeRO, the step built again after its first
+    call (the parameters then come back sharded) also all-gathers the
+    two norms' parameters in its recomputed body, under every policy:
+    two instructions that are no product."""
+    return _placed(PRODUCTS, module_name, block, phase)
 
 
 def _inputs_drifted(compiled, args) -> bool:

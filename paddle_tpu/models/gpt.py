@@ -22,8 +22,11 @@ TPU-native design (GSPMD, single logical program):
   vocab-sharded into ParallelCrossEntropy (the c_softmax_with_cross_entropy
   pattern) so the [B, S, V] logits tensor is never materialised replicated.
 - ``use_recompute`` wraps each block in jax.checkpoint (reference:
-  fleet/utils/recompute.py) to trade FLOPs for HBM; the flash kernel's
-  output and log-sum-exp are kept, so its forward runs once a step.
+  fleet/utils/recompute.py) to trade FLOPs for HBM. By default a block
+  keeps what only a kernel, a product or a collective would rebuild (the
+  flash kernel's output and log-sum-exp, the QKV and FFN-in products,
+  the attention branch after its dropout), so its recomputed forward
+  runs no product.
 - ``sequence_parallel`` pins the residual stream's seq axis to the ``sp``
   mesh axis so LayerNorm/dropout activations are sequence-sharded
   (reference: sequence_parallel_utils.py scatter/gather pattern).
@@ -38,12 +41,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.flags import matmul_precision
 from ..core.tensor import apply
 from ..distributed import env as dist_env
-from ..distributed.fleet.utils.recompute import recompute
+from ..distributed.fleet.utils.recompute import (LAYER_RESIDUAL_NAMES,
+                                                  recompute)
 from ..distributed.meta_parallel.parallel_layers.mp_layers import (
     VocabParallelEmbedding, ParallelCrossEntropy)
 from ..nn import functional as F
@@ -75,13 +80,20 @@ class GPTConfig:
     initializer_range: float = 0.02
     use_recompute: bool = False
     #: remat policy name for use_recompute (see
-    #: fleet.utils.recompute.resolve_checkpoint_policy). None recomputes
-    #: the block from its input EXCEPT the flash-attention forward, whose
-    #: output and log-sum-exp are kept (16.5 MB a layer at B=8, S=1024,
-    #: E=1024; the kernel would cost as much again as its backward).
-    #: 'dots_with_no_batch_dims_saveable' also keeps MXU outputs resident
-    #: and rematerializes only the elementwise tail — the TPU default
-    #: trade. 'full' keeps nothing: the kernel runs a second time.
+    #: fleet.utils.recompute.resolve_checkpoint_policy). None keeps what
+    #: a recomputed block would rebuild with a kernel, a product or a
+    #: collective: the flash kernel's output and log-sum-exp, the fused
+    #: QKV product, the FFN's first product before GELU and the attention
+    #: branch (the out-projection after its tensor-parallel all-reduce and
+    #: dropout, which the mid-layer residual add reads): 144.5 MiB a layer
+    #: at B=8, S=1024, E=1024 under AMP O1. The block then runs its two
+    #: norms, that add and GELU again and no product; its loss and
+    #: gradients are what 'full' gives (bit for bit on the CPU; on a TPU
+    #: the gradients' last bits follow each program's order of summation,
+    #: as 'full' and no recompute differ from each other there).
+    #: 'dots_with_no_batch_dims_saveable' keeps every MXU output
+    #: besides. 'full' keeps nothing, every product and the flash forward
+    #: run a second time: for a stack that does not fit memory otherwise.
     recompute_policy: Optional[str] = None
     #: run the decoder stack as one jax.lax.scan over layer-stacked params
     #: (nn.scan): O(1) trace+compile in num_layers, per-layer state_dict
@@ -131,6 +143,20 @@ class GPTConfig:
 
 def _mesh():
     return dist_env.get_mesh()
+
+
+_ATTN_BRANCH, _FFN_IN, _QKV = LAYER_RESIDUAL_NAMES
+
+
+def _name_value(a, tag):
+    return checkpoint_name(a, tag)
+
+
+def _keep(t, tag):
+    """``t`` named ``tag`` for the remat policy, which keeps it
+    (``resolve_checkpoint_policy``). Only a training forward without a
+    cache names anything: a serving program holds no ``name`` equation."""
+    return apply(_name_value, t, name="checkpoint_name", tag=tag)
 
 
 # shared layout-pin helper; BATCH expands to the composite data axes
@@ -206,6 +232,8 @@ class GPTAttention(Layer):
             # bgmv. Absent pools (the default) add nothing to the graph.
             qkv = qkv + self._lora_delta(x, cache)
         qkv = _constrain(qkv, BATCH, None, None, MP, None)
+        if self.training and cache is None:
+            qkv = _keep(qkv, _QKV)
         from ..tensor.manipulation import split as tsplit, squeeze
         q, k, v = (squeeze(t, 2) for t in tsplit(qkv, 3, axis=2))
 
@@ -401,6 +429,8 @@ class GPTMLP(Layer):
     def forward(self, x):
         h = F.linear(x, self.w_in, self.b_in)
         h = _constrain(h, BATCH, None, MP)
+        if self.training:
+            h = _keep(h, _FFN_IN)
         h = F.gelu(h, approximate=True)
         y = F.linear(h, self.w_out, None)
         y = _constrain(y, BATCH, None, None)
@@ -439,7 +469,13 @@ class GPTDecoderLayer(Layer):
             a, cache = self.attn(self.ln1(x), cache, pos=pos)
         # each half's dropout and residual add count with the half
         with jax.named_scope("attn"):
-            x = x + self.dropout1(a)
+            d = self.dropout1(a)
+            if self.training and cache is None:
+                # the add's operand, not its sum: a kept sum is stored in
+                # the stream's dtype where an unkept one may be fused on at
+                # float32 (XLA's excess precision), and the bits would part
+                d = _keep(d, _ATTN_BRANCH)
+            x = x + d
         if sp:
             x = _constrain(x, BATCH, sp, None)
         h = self._ffn(self.ln2(x))

@@ -17,9 +17,12 @@ scan-over-stacked-params recipe:
 - remat composes INSIDE the body: ``jax.checkpoint(body, policy=...)``
   (``prevent_cse=False`` per the jax guidance for remat-in-scan). What a
   policy keeps is ``fleet.utils.recompute.resolve_checkpoint_policy``'s
-  to say: by default the flash kernel's output and log-sum-exp and
-  nothing else (a recomputed body never re-runs the kernel's forward), a
-  dots policy the MXU outputs besides, ``"full"`` nothing;
+  to say: by default the values the body names as costly to rebuild and
+  nothing else (the flash kernel's output and log-sum-exp; a GPT
+  block's QKV and FFN-in products and its attention branch after
+  dropout, so a recomputed GPT body runs no product and no all-reduce
+  again), a dots
+  policy the MXU outputs besides, ``"full"`` nothing (the least memory);
 - RNG: each layer folds its index into the scan's base key, so dropout
   masks stay distinct per layer (the loop path draws per-layer keys from
   the trace counter instead — same distribution, different realization).
@@ -225,8 +228,8 @@ def scan_layers(blocks, x, *extra, policy=None, use_recompute: bool = False,
     arguments passed to every block call, e.g. an attention mask.
     ``policy``: a ``jax.checkpoint_policies`` predicate (or name, or None
     — see ``fleet.utils.recompute.resolve_checkpoint_policy``, which
-    keeps the flash kernel's residuals under each but ``"full"``) for
-    remat; only applied when ``use_recompute``.
+    keeps the values a body names as costly to rebuild under each but
+    ``"full"``) for remat; only applied when ``use_recompute``.
 
     ``num_aux``: when > 0, each block's forward returns ``(x, aux_1, ...,
     aux_{num_aux})`` and the per-layer aux values leave the scan as

@@ -395,23 +395,27 @@ def _train_step_for_chip(policy, chip, build_for_chip, monkeypatch):
 
 def test_train_step_keeps_the_flash_residuals_dense(chip, build_for_chip,
                                                     monkeypatch):
-    """ISSUE 34's guard. A recomputed layer body runs no `flash_fwd`
-    again, by `aot.kernel_calls`' reading of the optimized program (one
-    a body under ``"full"``; attention's hidden dropout kernel IS run
-    again under both, its output is not kept), and what keeping the
-    kernel's output and log-sum-exp costs stays near what the issue
-    reckons, 16 MB of ``o`` + 4 MB of ``lse [B, H, S, 8]`` a layer.
+    """A recomputed layer body runs no Mosaic kernel again, by
+    `aot.kernel_calls`' reading of the optimized program: no `flash_fwd`
+    (its output and log-sum-exp are kept) and no `fused_dropout` (the
+    attention branch after the hidden dropout is kept); under
+    ``"full"`` both run again, once a body. Nor does it run an MXU
+    product again (`aot.products`): the QKV, out-projection and FFN-in
+    products are three a body under ``"full"``, none under the default.
 
-    By the compiler's buffer assignment for the described chip the
-    step's temporaries grow 33.0 MiB a layer over ``"full"`` (the same
-    at 4, 8 and 24 layers: 16.5 are the two stacks the scans carry,
-    `bf16[L, 8, 1024, 1024]` and `f32[L, 8, 16, 1024]`; where the other
-    16.5 lie it does not say), so the limit is twice the issue's
-    figure. Kept as the kernel writes it, ``f32[8, 16, 1024, 8]`` under
-    a ``T(8, 128)`` tiling, the log-sum-exp pads its 8 lanes to 128, 64
-    MiB a layer, and the growth read 159.8 MiB a layer: 3.7 GiB at the
-    cell's 24 layers. So ONE column is kept, and the backward widens
-    it."""
+    What keeping costs stays near what is reckoned a layer: `o` (16 MiB)
+    and the log-sum-exp tile as the kernel writes it (4 MiB of
+    ``[B, H, S, 8]``; kept whole, its 8 lanes would pad to 128 under the
+    ``T(8, 128)`` tiling, 64 MiB a layer, so ONE column is kept and the
+    backward widens it), plus the block's kept set: the attention
+    branch (16 MiB: bfloat16 under AMP O1, as the residual stream), the
+    FFN's first product (64 MiB) and the fused QKV product (48 MiB). By
+    the compiler's buffer assignment for the described chip the step's
+    preallocated temporaries grow by the kept set once (577 MiB at 4
+    layers: 144 a layer); the temporaries `memory_analysis()` reports
+    grow by 1,155 MiB, twice that, as they grew 33.0 MiB a layer for the
+    flash residuals' 16.5. So the limit is twice the reckoned bytes, and
+    at least once is kept."""
     from paddle_tpu.jit import aot
     kept = _train_step_for_chip(None, chip, build_for_chip, monkeypatch)
     full = _train_step_for_chip("full", chip, build_for_chip, monkeypatch)
@@ -428,15 +432,172 @@ def test_train_step_keeps_the_flash_residuals_dense(chip, build_for_chip,
     assert attn_kernels(full) == [["flash_fwd", "fused_dropout"],
                                   ["flash_fwd", "fused_dropout"],
                                   ["flash_bwd", "fused_dropout"]]
+    assert len(aot.products("jit_train_step", phase="remat")) == 3
     assert attn_kernels(kept) == [["flash_fwd", "fused_dropout"],
-                                  ["fused_dropout"],
+                                  [],
                                   ["flash_bwd", "fused_dropout"]]
+    assert aot.products("jit_train_step", phase="remat") == []
 
     B, S, H, D = TRAIN_QKV
-    reckoned = B * S * H * D * 2 + B * H * S * 8 * 4
+    E = H * D
+    flash = B * S * H * D * 2 + B * H * S * 8 * 4
+    block = B * S * E * 2 + B * S * 4 * E * 2 + B * S * 3 * E * 2
     grew = (kept.memory_analysis().temp_size_in_bytes
             - full.memory_analysis().temp_size_in_bytes)
-    assert 0 < grew <= 2 * GUARD_LAYERS * reckoned, (grew, reckoned)
+    reckoned = GUARD_LAYERS * (flash + block)
+    assert reckoned <= grew <= 2 * reckoned, (grew, reckoned)
+
+
+def _step_on_described_mesh(topo, build_for_chip, monkeypatch, hybrid,
+                            model, loss_fn, batch, tpu=True, **step_kw):
+    """The first build of a TrainStep (``model()``, AdamW) over the
+    fleet mesh of ``hybrid`` degrees, compiled for the described 2x2
+    (``tpu``: the dispatch gates are told the backend is a TPU, so the
+    kernels are taken)."""
+    import paddle_tpu as paddle
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu.distributed import env as dist_env, fleet
+    from paddle_tpu.jit import aot
+    strategy = fleet.DistributedStrategy()
+    strategy.hybrid_configs = dict(dict(dp_degree=1, mp_degree=1,
+                                        pp_degree=1, sharding_degree=1),
+                                   **hybrid)
+    fleet.init(is_collective=True, strategy=strategy)
+    mesh = fleet.get_hybrid_communicate_group().mesh
+    described = Mesh(np.array(topo.devices[:4]).reshape(mesh.devices.shape),
+                     mesh.axis_names)
+    try:
+        layer = model()
+        step = paddle.jit.TrainStep(
+            layer, loss_fn, paddle.optimizer.AdamW(
+                learning_rate=1e-4, weight_decay=0.01,
+                parameters=layer.parameters()),
+            mesh=mesh, data_spec=P(("dp", "sharding")), **step_kw)
+    except BaseException:
+        fleet.reset()
+        dist_env.reset()
+        raise
+
+    def on_described(a):
+        spec = a.sharding.spec if isinstance(a.sharding, NamedSharding) \
+            else P()
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(described, spec))
+
+    def build(self, args):
+        # the model's layout pins and flash's shard_map read the mesh of
+        # the distributed env while the step is traced
+        dist_env.set_mesh(described)
+        raise _Built(build_for_chip(self._jitted.lower,
+                                    *jax.tree.map(on_described, args)))
+
+    monkeypatch.setattr(aot.AOTProgram, "_build", build)
+    if tpu:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ids = np.zeros((batch, TRAIN_QKV[1]), np.int32)
+    try:
+        with pytest.raises(_Built) as built:
+            step(ids, ids)
+    finally:
+        monkeypatch.undo()
+        fleet.reset()
+        dist_env.reset()
+    return built.value.args[0]
+
+
+def _amp_loss(loss):
+    import paddle_tpu as paddle
+
+    def loss_fn(layer, ids, labels):
+        with paddle.amp.auto_cast(level="O1"):
+            return loss(layer, ids, labels)
+
+    return loss_fn
+
+
+def test_mesh_train_step_remat_runs_no_all_reduce(topo, build_for_chip,
+                                                  monkeypatch):
+    """`gpt2_774m.train.mesh4`'s step (774M widths at ``GUARD_LAYERS``
+    layers, AMP O1, AdamW with ZeRO over ``sharding``, global B=16)
+    compiled for the described 2x2 as sharding2 x mp2: the forward and
+    the backward hold the tensor-parallel all-reduces, the recomputed
+    layer body none, and no product either. Without the kept attention
+    branch it rebuilds it through the out-projection AND its
+    all-reduce: one in five of a layer body's all-reduces."""
+    import paddle_tpu as paddle
+    from paddle_tpu.jit import aot
+    from paddle_tpu.models.gpt import (GPTForPretraining,
+                                       GPTPretrainingCriterion, gpt2_large)
+    crit = GPTPretrainingCriterion()
+
+    def model():
+        paddle.seed(0)
+        return GPTForPretraining(gpt2_large(num_layers=GUARD_LAYERS,
+                                            use_recompute=True))
+
+    compiled = _step_on_described_mesh(
+        topo, build_for_chip, monkeypatch,
+        dict(mp_degree=2, sharding_degree=2), model,
+        _amp_loss(lambda layer, ids, labels: crit(layer(ids), labels)), 16,
+        zero_axis="sharding")
+    module = aot.index_program(compiled.as_text())
+    assert module == "jit_train_step"
+
+    def all_reduces(phase):
+        return [n for n in aot.products(module, phase=phase)
+                if n.startswith("all-reduce")]
+
+    assert all_reduces("fwd") and all_reduces("bwd")
+    assert aot.products(module, phase="remat") == []
+
+
+def test_pipeline_stage_keeps_no_block_value(topo, build_for_chip,
+                                             monkeypatch):
+    """Why the fill-drain pipeline's stage remat keeps the flash
+    residuals alone (``flash_residuals_policy``) and not a block's
+    ``LAYER_RESIDUAL_NAMES`` too: a stage holds what it keeps for every
+    tick of its schedule. GPT-2 345M's widths at 8 layers over pp=4 (two
+    a stage), AMP O1, AdamW, 4 microbatches of 2 (7 ticks), compiled
+    for the described 2x2 under the stage's policy and under the
+    default: the default's temporaries are larger by at least the kept
+    set a layer a tick (the attention branch 4 MiB, the FFN-in product
+    16, the QKV product 12: 448 MiB), and read 907 MiB larger, twice
+    that, as a kept stack reads in `memory_analysis()` (above).
+
+    The stage's Mosaic kernels do not lower inside the pipeline's
+    partially manual shard_map on a TPU ("cannot be automatically
+    partitioned"), so the step is traced with the dispatch gates of a
+    CPU backend: XLA attention and dropout, which name no flash
+    residual; the block's names are there all the same."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.gpt import GPTForPretrainingPipe, gpt2_medium
+    rc = importlib.import_module("paddle_tpu.distributed.fleet.utils."
+                                 "recompute")
+    layers, micro, batch, stages = 8, 4, 8, 4
+
+    def model():
+        paddle.seed(0)
+        return GPTForPretrainingPipe(gpt2_medium(num_layers=layers),
+                                     num_microbatches=micro,
+                                     schedule="fill_drain")
+
+    def temp(policy):
+        with monkeypatch.context() as m:
+            m.setattr(rc, "flash_residuals_policy", policy)
+            compiled = _step_on_described_mesh(
+                topo, build_for_chip, m, dict(pp_degree=stages),
+                model, _amp_loss(
+                    lambda layer, ids, labels:
+                    layer.pretraining_loss(ids, labels)),
+                batch, tpu=False)
+        return compiled.memory_analysis().temp_size_in_bytes
+
+    stage = temp(rc.flash_residuals_policy)
+    default = temp(lambda: rc.resolve_checkpoint_policy(None))
+    B, S, E = batch // micro, TRAIN_QKV[1], 1024
+    a_tick = B * S * E * 2 + B * S * 4 * E * 2 + B * S * 3 * E * 2
+    reckoned = layers // stages * (micro + stages - 1) * a_tick
+    assert default - stage >= reckoned, (default - stage, reckoned)
 
 
 # -- a model that declares its own page kinds (ISSUE 28) -----------------------

@@ -656,6 +656,57 @@ ENTRY %main (a: f32[4]) -> f32[4] {
     assert aot.kernel_calls("jit_train_step") == []
 
 
+def test_products_are_the_mxu_products_and_collectives_read_by_scope():
+    """`aot.products`: a fusion whose computation holds a `dot` or a
+    `convolution` (through a nested fusion too), such an instruction
+    unfused, and the collectives by their one `-start` (or plain) form;
+    never what sits inside a fused computation, never a `-done`."""
+    text = '''HloModule jit_toy_products, is_scheduled=true
+
+%inner.1 (p0: bf16[8,8]) -> bf16[8,8] {
+  %p0 = bf16[8,8] parameter(0)
+  ROOT %convolution.1 = bf16[8,8] convolution(%p0, %p0), dim_labels=bf_io->bf
+}
+
+%outer.2 (p0: bf16[8,8]) -> bf16[8,8] {
+  %p0 = bf16[8,8] parameter(0)
+  ROOT %fusion.9 = bf16[8,8] fusion(%p0), kind=kOutput, calls=%inner.1
+}
+
+%plain.3 (p0: f32[8]) -> f32[8] {
+  %p0 = f32[8] parameter(0)
+  ROOT %add.1 = f32[8] add(%p0, %p0)
+}
+
+%sum.4 (x: f32[], y: f32[]) -> f32[] {
+  %x = f32[] parameter(0)
+  %y = f32[] parameter(1)
+  ROOT %add.2 = f32[] add(%x, %y)
+}
+
+ENTRY %main (a: bf16[8,8], b: f32[8]) -> f32[8] {
+  %a = bf16[8,8]{1,0:T(8,128)(2,1)} parameter(0)
+  %b = f32[8] parameter(1)
+  %convolution_fusion.5 = bf16[8,8]{1,0:T(8,128)(2,1)} fusion(%a), kind=kOutput, calls=%outer.2, metadata={op_name="jit(toy)/transpose(jvp())/while/body/checkpoint/rematted_computation/ffn/dot_general"}
+  %dot.6 = bf16[8,8] dot(%a, %a), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(toy)/jvp()/attn/dot_general"}
+  %add_fusion.7 = f32[8] fusion(%b), kind=kLoop, calls=%plain.3, metadata={op_name="jit(toy)/transpose(jvp())/while/body/checkpoint/rematted_computation/ffn/add"}
+  %all-reduce.8 = f32[8] all-reduce(%add_fusion.7), replica_groups={{0,1}}, to_apply=%sum.4, metadata={op_name="jit(toy)/transpose(jvp())/while/body/checkpoint/rematted_computation/attn/psum"}
+  %collective-permute-start.9 = (f32[8], f32[8]) collective-permute-start(%b), source_target_pairs={{0,1}}
+  %collective-permute-done.10 = f32[8] collective-permute-done(%collective-permute-start.9)
+  ROOT %add.11 = f32[8] add(%all-reduce.8, %collective-permute-done.10)
+}
+'''
+    assert aot.products("jit_toy_products") is None
+    assert aot.index_program(text) == "jit_toy_products"
+    assert aot.products("jit_toy_products") == [
+        "all-reduce.8", "collective-permute-start.9", "convolution_fusion.5",
+        "dot.6"]
+    assert aot.products("jit_toy_products", phase="remat") == [
+        "all-reduce.8", "convolution_fusion.5"]
+    assert aot.products("jit_toy_products", "attn") == [
+        "all-reduce.8", "dot.6"]
+
+
 def test_scopes_of_a_toy_gpt_step():
     step = _gpt_step(use_recompute=True)
     step(_ids(), _ids())
