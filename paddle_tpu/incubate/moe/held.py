@@ -5,7 +5,9 @@ experts' part of the result for the tokens routed to them.
     s   = sigmoid(x W_r)                       float32, all E experts
     T   = top-k of s + b                       b: the selection bias
                                                (``noaux_tc``); weighs by s
-    g_e = scale * s_e / sum_{e' in T} s_e'     (``norm_topk_prob``)
+    g_e = scale * s_e / (sum_{e' in T} s_e' + eps)   (``norm_topk_prob``;
+                                               eps 0 unless a family
+                                               states one)
     y   = sum_{e in T, first <= e < first + held} g_e FFN_e(x)
     FFN(x) = W_out (silu(x W_gate) * (x W_up))
 
@@ -45,16 +47,22 @@ class SigmoidRouting(NamedTuple):
 
 
 def sigmoid_topk_routing(x, w_router, bias, top_k: int,
-                         scale: float = 1.0) -> SigmoidRouting:
+                         scale: float = 1.0,
+                         eps: float = 0.0) -> SigmoidRouting:
     """Sigmoid scores in float32 whatever ``x`` is; selection by
     ``s + bias`` (ties: the lower expert id), weights from ``s`` alone,
-    renormalized over the chosen and scaled."""
+    renormalized over the chosen (``eps`` added to their sum where a
+    family states one; 0 adds nothing to the program) and scaled."""
     s = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
-    gates = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    # in the order the eps-free form traces to: the product, the sum,
+    # the quotient
+    scaled = scale * chosen
+    total = jnp.sum(chosen, axis=-1, keepdims=True)
+    gates = scaled / (total + eps if eps else total)
     return SigmoidRouting(idx.astype(jnp.int32), gates, s)
 
 

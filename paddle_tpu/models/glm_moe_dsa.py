@@ -196,6 +196,23 @@ def _rotary(x, positions, theta, inv=None):
     return out.reshape(x.shape).astype(x.dtype)
 
 
+def _rotary_half(x, positions, theta):
+    """Half-split rotary embedding (``rotate_half``) over the last axis
+    of ``x`` ``[B, S, (H,) d]``: dims ``i`` and ``i + d/2`` turn as a
+    pair by ``position * theta^(-2i/d)``; float32 inside. The same
+    rotation as :func:`_rotary` on the dims in another order."""
+    d = x.shape[-1]
+    inv = jnp.exp(-math.log(theta) * jnp.arange(0, d, 2, dtype=F32) / d)
+    ang = positions.astype(F32)[..., None] * inv                # [B, S, d/2]
+    if x.ndim == 4:
+        ang = ang[:, :, None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(F32)
+    a, b = xf[..., :d // 2], xf[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                           axis=-1).astype(x.dtype)
+
+
 def _pages_a_block(max_blocks: int, block_size: int, want: int) -> int:
     """Pages one pass of the context takes: the largest divisor of the
     table's width that covers at most ``want`` positions."""
@@ -393,22 +410,29 @@ def mla_sparse_decode(q_nope, q_rope, pool, table, base, idx, valid, w_kvb,
 def moe_layer(moe, h, stats, cfg, taps=None):
     """An expert layer (a :class:`GlmMoE`) on ``h`` ``[B, S, D]``: the
     held experts' part for the tokens routed to them plus the shared
-    expert; in a decode step, a row a slot of what it counted into
-    ``stats``. ``cfg``: any config with ``num_experts_per_tok``,
-    ``routed_scaling_factor`` and ``experts_held``."""
+    expert where the layer has one; in a decode step, a row a slot of
+    what it counted into ``stats``. ``cfg``: any config with
+    ``num_experts_per_tok``, ``routed_scaling_factor`` and
+    ``experts_held``; where it has them, ``router_eps`` (added to the
+    chosen scores' sum) and ``count_experts_read`` (a decode step also
+    hands the engine this layer's pairs an expert, ``[B, held]``, beside
+    the other expert layers' under one key: the experts given a pair are
+    the experts whose weights the step reads)."""
     B, S, D = h.shape
     flat = h.reshape(B * S, D)
     # the router's operand, ONE array for its product and for a probe
     flat32 = flat.astype(F32)
     routing = sigmoid_topk_routing(
         flat32, moe.router.weight._data, moe.router.bias._data,
-        cfg.num_experts_per_tok, cfg.routed_scaling_factor)
+        cfg.num_experts_per_tok, cfg.routed_scaling_factor,
+        getattr(cfg, "router_eps", 0.0))
     first, held = cfg.experts_held
     y, _, here = held_experts_ffn(
         flat, routing, moe.experts.w_in._data, moe.experts.w_out._data,
         first)
-    y = y + gated_ffn(flat, moe.shared.w_in._data,
-                      moe.shared.w_out._data)
+    shared = getattr(moe, "shared", None)
+    if shared is not None:
+        y = y + gated_ffn(flat, shared.w_in._data, shared.w_out._data)
     if taps is not None:
         taps.setdefault("router_topk", []).append(
             routing.idx.reshape(B, S, -1))
@@ -424,6 +448,11 @@ def moe_layer(moe, h, stats, cfg, taps=None):
         for key, v in (("serve_moe_routed_tokens_total:expert", given),
                        ("serve_moe_skipped_pairs_total", skipped)):
             stats[key] = stats[key] + v if key in stats else v
+        if getattr(cfg, "count_experts_read", False):
+            # emits-metrics: serve_moe_experts_read_total
+            key = "serve_moe_experts_read_total#nonzero"
+            stats[key] = jnp.concatenate([stats[key], given], axis=1) \
+                if key in stats else given
     return y.reshape(B, S, D).astype(h.dtype)
 
 

@@ -34,7 +34,7 @@ class HookRemoveHelper:
 #: the fixed vocabulary of block scopes: device time splits by these
 #: (jit/aot.py ``scopes``), whatever XLA names its fusions
 BLOCKS = ("embed", "attn", "ffn", "moe", "norm", "loss", "optimizer",
-          "sampling", "kv_write", "mla", "indexer", "select", "mhc")
+          "sampling", "kv_write", "mla", "indexer", "select", "mhc", "conv")
 
 
 class Layer:

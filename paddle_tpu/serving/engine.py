@@ -251,7 +251,10 @@ class ServingEngine:
         # K and V a head for a plain decoder; a latent and an index key
         # for a sparse-attention one) and how long a page lives; one head
         # group a chip of the mp axis is the pools' sharded axis. A
-        # window lifetime is given pages for the longest program: a chunk
+        # window lifetime is given pages for the longest program: a chunk.
+        # Beside its pages a model may declare what it keeps a slot
+        # (`cfg.state_kinds()`: a short convolution's last inputs)
+        state_kinds = getattr(cfg, "state_kinds", tuple)()
         self.cache = PagedKVCache(
             kinds=cfg.page_kinds(),
             num_pages=c.num_pages, block_size=c.block_size,
@@ -260,7 +263,8 @@ class ServingEngine:
                                               c.block_size),
             dtype=jnp.dtype(c.cache_dtype),
             head_groups=mp,
-            max_chunk=self._chunk or max(c.prefill_buckets))
+            max_chunk=self._chunk or max(c.prefill_buckets),
+            state_kinds=state_kinds)
         #: the model's ONE page kind is an MLA latent: its decode steps
         #: sweep every cached position (a family that selects positions
         #: declares an index kind beside it, and counts its own reads)
@@ -284,6 +288,23 @@ class ServingEngine:
                 raise ValueError(
                     "a serving mesh with a window page lifetime: the "
                     "window tables are not sharded (SERVE_KV_SPEC)")
+        if self.cache.state_kinds:
+            # what assumes that all a slot holds is its pages
+            for flag, what in (
+                    ("serve_prefix_cache", "a prefix hit maps pages, and the "
+                     "state at the hit's end is not kept"),
+                    ("serve_spec_k", "truncate_slot rolls pages back, and a "
+                     "rejected draft's rows have already moved the state"),
+                    ("serve_kv_quant", "the state is kept in the cache "
+                     "dtype, and int8 pages are not read beside it")):
+                if get_flag(flag):
+                    raise ValueError(
+                        f"FLAGS_{flag} with a state kind "
+                        f"({type(cfg).__name__}.state_kinds()): {what}")
+            if self.mesh is not None:
+                raise ValueError(
+                    "a serving mesh with a state kind: the state arrays are "
+                    "not sharded (SERVE_KV_SPEC)")
         if self.mesh is not None:
             from ..distributed.spmd import shard_serving_cache
             shard_serving_cache(self.cache, self.mesh)
@@ -515,7 +536,7 @@ class ServingEngine:
                 dist_env.set_mesh(prev)
 
     def _forward(self, params, ids, pools, table, pos, lora=None,
-                 ctx: bool = False):
+                 ctx: bool = False, lens=None):
         """Pure model forward over the paged pools (traced inside the
         prefill/decode programs): ``(logits, pools, stats)``, the pools
         in the order they came (``cache.pool_args()``), ``stats`` what
@@ -531,8 +552,23 @@ class ServingEngine:
         ``cache.update`` keeps the tuple structure; ``lora`` is the
         optional ``(a_pool, b_pool, per_slot_rows)`` triple of a
         multi-tenant engine (ISSUE 17) — the view carries it down to
-        the attention blocks' bgmv delta."""
+        the attention blocks' bgmv delta.
+
+        Where the model declares state kinds, ``pools`` ends in their
+        arrays and ``table`` in the rows' slots (``cache.table_array``),
+        and ``lens`` are the rows' real positions (a decode step's: 1);
+        the view carries them as ``state`` and ``rows``."""
         cls = ContextPagedPools if ctx else PagedPools
+        state = rows = None
+        if self.cache.state_kinds:
+            n = len(self.cache.kinds)
+            pools, state = pools[:n], pools[n:]
+            *table, slots = table
+            table = tuple(table) if len(table) > 1 else table[0]
+            if lens is None:
+                lens = jnp.ones(slots.shape, jnp.int32)
+            state = tuple(Tensor(a) for a in state)
+            rows = (Tensor(slots), Tensor(lens))
         quant = isinstance(pools[0], tuple)
         wrap = lambda t: None if t is None else Tensor(t)
         unw = lambda t: t._data if isinstance(t, Tensor) else t
@@ -542,7 +578,8 @@ class ServingEngine:
             tuple(Tensor(t) for t in table) if isinstance(table, tuple)
             else Tensor(table),
             tuple(Tensor(p[1]) for p in pools) if quant else None,
-            tuple(wrap(t) for t in lora) if lora is not None else None)
+            tuple(wrap(t) for t in lora) if lora is not None else None,
+            state=state, rows=rows)
         with bind(self.model, params, dict(self.buffers)), no_grad(), \
                 trace_rng(jax.random.key(0)):
             logits, new = self.model(Tensor(ids), caches=view,
@@ -550,6 +587,8 @@ class ServingEngine:
         out = tuple(unw(p) for p in new.pools)
         if quant:
             out = tuple(zip(out, (unw(sc) for sc in new.scales)))
+        if state is not None:
+            out = out + tuple(unw(a) for a in new.state)
         stats = None if new.stats is None \
             else {k: unw(a) for k, a in new.stats.items()}
         return unw(logits), out, stats
@@ -686,7 +725,8 @@ class ServingEngine:
                        top_ks, top_ps, poison, *lora):
             pos = jnp.zeros((nb,), jnp.int32)
             logits, pools, _ = self._forward(params, ids, pools, table,
-                                             pos, lora=lora or None)
+                                             pos, lora=lora or None,
+                                             lens=lens)
             with jax.named_scope("sampling"):
                 last = jnp.take_along_axis(
                     logits, (lens - 1).astype(jnp.int32)[:, None, None],
@@ -723,7 +763,7 @@ class ServingEngine:
                            temps, top_ks, top_ps, poison, *lora):
             logits, pools, _ = self._forward(params, ids, pools, table,
                                              pos, lora=lora or None,
-                                             ctx=True)
+                                             ctx=True, lens=lens)
             with jax.named_scope("sampling"):
                 last = jnp.take_along_axis(
                     logits, (lens - 1).astype(jnp.int32)[:, None, None],
@@ -1778,6 +1818,9 @@ class ServingEngine:
             self._advance_windows(
                 (st.slot, int(pos[i]), int(lens[i]))
                 for i, st in enumerate(states) if st is not None)
+            self._count_state_rows(
+                pos[[i for i, st in enumerate(states) if st is not None]],
+                "prefill_ctx")
             t0 = self.clock()
             if self._t_first_work is None:
                 self._t_first_work = t0
@@ -2116,6 +2159,28 @@ class ServingEngine:
                     "a layer of that page lifetime", lifetime="slot")
         sp.set(read_latent=read)
 
+    def _count_state_rows(self, pos: np.ndarray, program: str) -> None:
+        """The rows of a program over a model's state kinds, by where
+        their state came from, host arithmetic on their first positions
+        ``pos``: a row at position 0 began from a fresh state (it reads
+        zeros, whatever its slot held), a row past it from the state its
+        slot carried (``program``: ``prefill_ctx`` or ``decode``, the
+        programs such a row runs in). Nothing for a model whose state is
+        all in pages."""
+        if not self.cache.state_kinds:
+            return
+        fresh = int((pos == 0).sum())
+        carried = len(pos) - fresh
+        # emits-metrics: serve_conv_state_fresh_total, serve_conv_state_carried_total
+        if fresh:
+            self._count("serve_conv_state_fresh_total", fresh,
+                        "program rows that began from a fresh state "
+                        "(position 0: the slot's state is not read)")
+        if carried:
+            self._count("serve_conv_state_carried_total", carried,
+                        "program rows that began from the state their slot "
+                        "carried, by program", program=program)
+
     def _run_decode(self, pairs, params) -> None:
         with _trace.span("serve.decode", n_active=len(pairs)) as sp:
             self._decode_batch(pairs, params, sp)
@@ -2148,6 +2213,7 @@ class ServingEngine:
                 self._count_window_reads(pos[active], sp)
             elif self._dense_latent:
                 self._count_latent_reads(pos[active], sp)
+            self._count_state_rows(pos[active], "decode")
             t0 = self.clock()
             prog = self._get_decode()
             temps, tks, tps = self._sampling_arrays(per_slot)
@@ -2216,12 +2282,18 @@ class ServingEngine:
         the registry and ``_stats["model_counters"]``, the active slots'
         rows only. A key is a counter's name; ``name:label`` with a
         ``[B, K]`` array is one series a column, labelled ``label=k``
-        (``serve_moe_routed_tokens_total:expert``)."""
+        (``serve_moe_routed_tokens_total:expert``); ``name#nonzero``
+        with a ``[B, K]`` array counts the columns the active rows give
+        anything (``serve_moe_experts_read_total#nonzero``: the experts
+        given a pair, a column an expert of each layer)."""
         reg = get_registry()
         totals = self._stats.setdefault("model_counters", {})
         for key, arr in stats.items():
-            name, _, label = key.partition(":")
+            name, nonzero, _ = key.partition("#nonzero")
+            name, _, label = name.partition(":")
             col = arr[active].sum(axis=0)
+            if nonzero:
+                col = np.count_nonzero(col)
             counter = reg.counter(name, "counted by the model in decode "
                                         "steps, active slots only")
             if label:

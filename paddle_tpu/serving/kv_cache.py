@@ -36,7 +36,13 @@ of fixed-size pages:
   scheduler trades in;
 - every device shape is static: block tables and per-slot positions are
   small int32 *arguments* of the compiled step, so admitting/evicting a
-  request between steps never recompiles anything.
+  request between steps never recompiles anything;
+- what a model keeps a SLOT rather than a position (the last inputs of
+  a short convolution: :class:`StateKind`) is not paged: one array a
+  kind, ``[L_kind, slots + 1, *shape]``, row ``slots`` the scratch row
+  of padded and inactive rows, riding the same donated argument as the
+  pools. A row whose first position is 0 reads zeros there instead of
+  what its slot holds, so admission and slot reuse write nothing.
 
 Everything that touches pages goes through two primitives and their
 ``_quant`` twins — :func:`write_pages` (one XLA scatter of the new rows)
@@ -57,7 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["BlockAllocator", "PagedKVCache", "PageKind", "kv_page_kinds",
-           "WindowPages",
+           "StateKind", "WindowPages",
            "PagedPools",
            "ContextPagedPools", "PagedCacheView",
            "PagedLayerCache", "ContextPagedCacheView",
@@ -120,6 +126,20 @@ def kv_page_kinds(num_layers: int, num_heads: int, head_dim: int) -> tuple:
                           int(num_heads)) for n in ("k", "v"))
 
 
+class StateKind(NamedTuple):
+    """What a model keeps a SLOT, not a position, as its
+    ``cfg.state_kinds()`` declares it: ``shape`` values a slot in each
+    of the model's ``layers`` (indices into its stack), in the cache's
+    dtype. A short convolution of length ``L`` keeps its last ``L - 1``
+    inputs: ``(L - 1, width)``. The cache holds a kind as ONE array
+    ``[len(layers), max_slots + 1, *shape]``; row ``max_slots`` is the
+    scratch row a padded or inactive row reads and writes."""
+
+    name: str
+    shape: tuple
+    layers: tuple
+
+
 class PagedPools(NamedTuple):
     """What the engine hands a model as ``caches`` and takes back:
     ``pools``, one ``[L_kind, P, G, bs, W]`` array per declared
@@ -129,13 +149,19 @@ class PagedPools(NamedTuple):
     the ``(a_pool, b_pool, per_slot_rows)`` triple of a multi-tenant
     engine; ``stats`` small per-slot arrays by name that a model may
     return from a decode step for the engine's counters (never read as
-    an input). A NamedTuple, so a pytree."""
+    an input). ``state``, one array per declared :class:`StateKind`,
+    and ``rows``, the ``([B] slot, [B] real positions)`` pair that says
+    which slot's state a row carries and how many of its positions are
+    real (a padded bucket's tail is not), where the model declares
+    state kinds. A NamedTuple, so a pytree."""
 
     pools: tuple
     block_table: object
     scales: object = None
     lora: object = None
     stats: object = None
+    state: object = None
+    rows: object = None
 
 
 class ContextPagedPools(PagedPools):
@@ -490,7 +516,8 @@ class PagedKVCache:
                  max_blocks_per_slot: int, dtype=jnp.float32,
                  head_groups: int = 1,
                  kinds: Optional[Sequence[PageKind]] = None,
-                 max_chunk: Optional[int] = None):
+                 max_chunk: Optional[int] = None,
+                 state_kinds: Sequence[StateKind] = ()):
         from ..core.flags import get_flag
         if kinds is None:
             kinds = kv_page_kinds(num_layers, num_heads, head_dim)
@@ -542,6 +569,14 @@ class PagedKVCache:
                     jnp.zeros(shape[:-1] + (kd.heads // G,), jnp.float32))
             else:
                 self.pools[kd.name] = jnp.zeros(shape, dtype)
+        #: the state kinds, in the order programs take and return them
+        #: (after the pools)
+        self.state_kinds = tuple(state_kinds)
+        #: kind name -> ``[L_kind, max_slots + 1, *shape]``
+        self.states = {
+            sk.name: jnp.zeros((len(sk.layers), max_slots + 1)
+                               + tuple(sk.shape), dtype)
+            for sk in self.state_kinds}
         self.allocator = BlockAllocator(num_pages)
         self._tables = np.full((max_slots, max_blocks_per_slot),
                                SCRATCH_PAGE, np.int32)
@@ -557,15 +592,25 @@ class PagedKVCache:
 
     # -- device-side --------------------------------------------------------
     def pool_args(self) -> tuple:
-        """The pools in declaration order: the one argument every
-        serving program takes, donates and returns."""
-        return tuple(self.pools[kd.name] for kd in self.kinds)
+        """The pools in declaration order, then the state kinds' arrays:
+        the one argument every serving program takes, donates and
+        returns."""
+        return tuple(self.pools[kd.name] for kd in self.kinds) \
+            + tuple(self.states[sk.name] for sk in self.state_kinds)
 
     def update(self, *new) -> None:
-        """Swap in the pools a compiled step returned (declaration
-        order, as :meth:`pool_args` gave them)."""
+        """Swap in the pools (and states) a compiled step returned
+        (declaration order, as :meth:`pool_args` gave them)."""
         for kd, pool in zip(self.kinds, new):
             self.pools[kd.name] = pool
+        for sk, state in zip(self.state_kinds, new[len(self.kinds):]):
+            self.states[sk.name] = state
+
+    def state_bytes_per_slot(self) -> int:
+        """Device bytes ONE slot's state costs across all state kinds
+        and their layers (nothing a position: it does not grow)."""
+        return sum(len(sk.layers) * math.prod(sk.shape)
+                   for sk in self.state_kinds) * self.dtype.itemsize
 
     # the K/V pair of a plain decoder, by name
     k = property(lambda self: self.pools["k"],
@@ -589,7 +634,19 @@ class PagedKVCache:
         or one row per entry of ``rows`` — a ``None`` entry (a padded
         prefill row) gets an all-scratch row, so its garbage K/V can
         never land in another slot's pages. With window lifetimes, a
-        tuple: this table, then one of each of ``windows``."""
+        tuple: this table, then one of each of ``windows``; with state
+        kinds, a tuple ending in the ``[n]`` int32 slot of each row (a
+        ``None`` entry: the scratch row ``max_slots``)."""
+        tables = self._tables_array(rows)
+        if not self.state_kinds:
+            return tables
+        slots = np.arange(self.max_slots, dtype=np.int32) if rows is None \
+            else np.asarray([self.max_slots if s is None else s
+                             for s in rows], np.int32)
+        return (tables if isinstance(tables, tuple) else (tables,)) \
+            + (jnp.asarray(slots),)
+
+    def _tables_array(self, rows):
         def snap(tables):
             if rows is None:
                 return jnp.asarray(tables)
@@ -609,6 +666,9 @@ class PagedKVCache:
         """An all-scratch table argument of ``n`` rows, in the form
         :meth:`table_array` gives: what a program is compiled against."""
         z = jnp.zeros((n, self.max_blocks_per_slot), jnp.int32)
+        if self.state_kinds:
+            return (z,) * (1 + len(self.windows)) \
+                + (jnp.zeros((n,), jnp.int32),)
         return (z,) * (1 + len(self.windows)) if self.windows else z
 
     @property

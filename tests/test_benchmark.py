@@ -18,7 +18,8 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 UNIT_MODULES = ("test_arithmetic", "test_arithmetic_cohere2_moe",
-                "test_arithmetic_xing4", "test_contract", "test_loadgen", "test_program_spans",
+                "test_arithmetic_xing4", "test_arithmetic_lfm2_moe",
+                "test_contract", "test_loadgen", "test_program_spans",
                 "test_trace_reduce")
 
 

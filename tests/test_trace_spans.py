@@ -591,7 +591,7 @@ def test_resolve_paths():
     assert r(pre + "indexer/while/body/dot_general") == ("indexer", "fwd")
     assert set(BLOCKS) == {"embed", "attn", "ffn", "moe", "norm", "loss",
                            "optimizer", "sampling", "kv_write", "mla",
-                           "indexer", "select", "mhc"}
+                           "indexer", "select", "mhc", "conv"}
 
 
 def test_parse_scopes_takes_an_instructions_own_scope_and_guesses_none():
